@@ -24,52 +24,23 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-    _LEGACY_SHARD_MAP = False
-except ImportError:  # older jax: experimental module, check_rep + auto
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY_SHARD_MAP = True
+from ..framework.errors import InvalidArgumentError, TransientDeviceError
+from ..framework.flags import flag as _flag
+from .mesh import get_mesh
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
     # replication check off: collectives like all_gather produce values
     # that ARE replicated over the group axis, but the static checker
     # can't always infer it.  ``axis_names`` restricts which mesh axes the
-    # body is manual over (legacy jax spells that as the ``auto``
-    # complement).
-    if _LEGACY_SHARD_MAP:
-        auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                if axis_names is not None else frozenset())
-        if auto and jax.default_backend() == "cpu":
-            # XLA's CPU SPMD partitioner can't lower PARTIAL-auto bodies:
-            # lax.axis_index emits a PartitionId it rejects outright, and
-            # pipe-axis ppermute/all_gather trip a manual-subgroup CHECK
-            # in spmd_partitioner.cc.  Fall back to fully-manual (every
-            # axis manual) — numerically identical, the auto axes just
-            # lose their sharding hints, so the body's ``constrain``
-            # calls (which would now name manual axes) are suppressed.
-            from .mesh import suppress_constraints
-
-            @functools.wraps(f)
-            def f_manual(*args, **kwargs):
-                with suppress_constraints():
-                    return f(*args, **kwargs)
-
-            return _shard_map(f_manual, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False,
-                              auto=frozenset())
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False, auto=auto)
+    # body is manual over.
     kw = {"axis_names": set(axis_names)} if axis_names is not None else {}
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                       check_vma=False, **kw)
 
-from ..framework.errors import InvalidArgumentError, TransientDeviceError
-from ..framework.flags import flag as _flag
-from .mesh import get_mesh
 
 __all__ = [
     "ReduceOp",

@@ -65,8 +65,8 @@ def init(role_maker=None, is_collective: bool = True,
         )
     _env.init_parallel_env()
     strategy = strategy or DistributedStrategy()
-    devices = list(devices) if devices is not None else jax.devices()
-    n = len(devices)
+    # devices=None reaches build_mesh as None: it owns the default order
+    n = len(devices) if devices is not None else jax.device_count()
     fixed = (strategy.mp_degree * strategy.pp_degree * strategy.sep_degree
              * strategy.ep_degree)
     sharding_degree = strategy.sharding_degree

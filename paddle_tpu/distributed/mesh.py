@@ -17,8 +17,6 @@ concept at all).
 """
 from __future__ import annotations
 
-import contextlib
-import threading
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -33,8 +31,6 @@ __all__ = [
     "set_mesh",
     "mesh_axis_size",
     "data_axes",
-    "suppress_constraints",
-    "constraints_suppressed",
     "PartitionSpec",
     "NamedSharding",
     "Mesh",
@@ -71,12 +67,14 @@ def build_mesh(
     usually a config bug, not a plan.
     """
     if devices is None:
-        devices = list(jax.devices())
+        # process-major, then by id: contiguous ICI blocks per host, DCN
+        # on the outer axes.  jax.devices() usually already satisfies
+        # this, but the mesh must not depend on backend enumeration luck
+        # (on a v5e 2x2 host ids 0..3 sit at (0,0) (1,0) (0,1) (1,1), so
+        # the innermost axis pairs ICI neighbours).
+        devices = sorted(jax.devices(),
+                         key=lambda d: (d.process_index, d.id))
         if jax.process_count() > 1:
-            # process-major: contiguous ICI blocks per host, DCN on the
-            # outer axes.  jax.devices() usually already satisfies this,
-            # but the mesh must not depend on backend enumeration luck.
-            devices.sort(key=lambda d: (d.process_index, d.id))
             local = len(devices) // jax.process_count()
             inner = mp * ep * sep * sharding
             if local and inner > 1 and local % inner != 0 \
@@ -130,31 +128,6 @@ def get_mesh() -> Mesh:
 def mesh_axis_size(axis: str, mesh: Optional[Mesh] = None) -> int:
     mesh = mesh or get_mesh()
     return mesh.shape[axis]
-
-
-_suppress_tls = threading.local()
-
-
-def constraints_suppressed() -> bool:
-    """True while inside a :func:`suppress_constraints` scope (per thread)."""
-    return getattr(_suppress_tls, "depth", 0) > 0
-
-
-@contextlib.contextmanager
-def suppress_constraints():
-    """Make ``meta_parallel.constrain`` a no-op while tracing.
-
-    Needed when a region is traced inside a FULLY-manual ``shard_map``:
-    every mesh axis is manual there, so ``with_sharding_constraint`` over
-    ``model``/``data`` is both illegal (jax rejects specs naming manual
-    axes) and meaningless (the body already sees per-device values).  The
-    pipeline schedules use this on backends where partial-auto shard_map
-    can't lower (see ``collective.shard_map``)."""
-    _suppress_tls.depth = getattr(_suppress_tls, "depth", 0) + 1
-    try:
-        yield
-    finally:
-        _suppress_tls.depth -= 1
 
 
 def data_axes(mesh: Optional[Mesh] = None) -> List[str]:

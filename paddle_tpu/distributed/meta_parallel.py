@@ -23,7 +23,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..framework import random as _random
 from ..nn import initializer as I
 from ..nn.layer_base import Layer, Parameter, current_rng_key
-from . import mesh as mesh_mod
 from .mesh import get_mesh
 
 __all__ = [
@@ -74,12 +73,16 @@ def _lora_leg(layer, x, y):
 
 
 def constrain(x, *spec):
-    """Apply a sharding constraint when tracing (no-op eagerly, and a
-    no-op inside ``mesh.suppress_constraints`` scopes — fully-manual
-    shard_map bodies, where specs naming manual axes are rejected)."""
-    if isinstance(x, jax.core.Tracer) and not mesh_mod.constraints_suppressed():
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(get_mesh(), P(*spec)))
+    """Apply a sharding constraint when tracing (no-op eagerly, and on a
+    one-device mesh, where a constraint says nothing — but would still
+    hand the step's outputs back under a mesh sharding its single-device
+    inputs did not have, which costs every training loop and serving
+    engine on one chip a second, placement-specialised XLA compile)."""
+    if isinstance(x, jax.core.Tracer):
+        mesh = get_mesh()
+        if mesh.size > 1:
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, P(*spec)))
     return x
 
 

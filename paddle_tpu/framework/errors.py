@@ -3,7 +3,7 @@
 TPU-native equivalent of ``PADDLE_ENFORCE*`` and ``platform::errors``
 (reference: paddle/fluid/platform/enforce.h; errors typed as
 InvalidArgument/NotFound/OutOfRange/... in paddle/fluid/platform/errors.h).
-We keep the typed-error taxonomy (it surfaces in user-visible messages and in
+We keep the typed-error hierarchy (it surfaces in user-visible messages and in
 tests) but implement it as plain Python exceptions — the XLA runtime already
 produces rich device-side errors, so no status-decoding layer is needed.
 """
@@ -87,8 +87,9 @@ class DivergenceError(EnforceNotMet):
 
 
 #: lowercase substrings of XLA / jax runtime error messages that indicate a
-#: transient condition worth retrying (the runtime has no typed taxonomy —
-#: status strings are the stable surface, same approach as gRPC clients)
+#: transient condition worth retrying (the runtime has no typed error
+#: classes — status strings are the stable surface, same approach as gRPC
+#: clients)
 _TRANSIENT_PATTERNS = (
     "resource_exhausted",
     "resource exhausted",
@@ -101,6 +102,18 @@ _TRANSIENT_PATTERNS = (
     "socket closed",
     "too many pings",
     "transient",
+)
+
+#: ... unless the message also says the condition is permanent.  On a
+#: locally attached chip RESOURCE_EXHAUSTED is an out-of-memory verdict on
+#: THIS program ("XLA:TPU compile permanent error. Ran out of memory in
+#: memory space hbm", "Error allocating device buffer"): the same program
+#: on the same device fails the same way, and a retry only re-pays a
+#: compile that can take minutes before failing again.
+_PERMANENT_PATTERNS = (
+    "permanent error",
+    "out of memory",
+    "error allocating device buffer",
 )
 
 #: exception type names (by class name, so jaxlib need not be imported
@@ -119,10 +132,12 @@ def is_transient(exc: BaseException) -> bool:
     if isinstance(exc, UnavailableError):
         return True
     if isinstance(exc, EnforceNotMet):
-        return False  # typed taxonomy: everything else is deterministic
+        return False  # typed errors: everything else is deterministic
     name = type(exc).__name__
     if name in _RUNTIME_ERROR_TYPES or isinstance(exc, (RuntimeError, OSError)):
         msg = str(exc).lower()
+        if any(p in msg for p in _PERMANENT_PATTERNS):
+            return False
         return any(p in msg for p in _TRANSIENT_PATTERNS)
     return False
 

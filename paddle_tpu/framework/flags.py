@@ -100,10 +100,11 @@ define_flag("executor_cache_capacity", 64,
             "distinct (program version, feed signature, fetch set) pins one "
             "XLA executable; unbounded growth is a slow leak, a too-small "
             "cap recompiles every run (surfaced as analysis rule R403).")
-define_flag("persistent_compilation_cache", "",
-            "Non-empty: enable JAX's persistent compilation cache at this "
-            "directory ('1'/'true' picks a default under ~/.cache), so "
-            "repeated process launches skip XLA recompiles. See "
+define_flag("persistent_compilation_cache", False,
+            "Enable JAX's persistent compilation cache so repeated process "
+            "launches skip XLA recompiles. The directory is "
+            "JAX_COMPILATION_CACHE_DIR when that is set, else the fixed "
+            "<checkout>/.cache/xla; see "
             "sysconfig.enable_persistent_compilation_cache().")
 define_flag("kernel_autotune", "on",
             "Pallas kernel tile-size tuning mode (ops/autotune.py): 'on' "
@@ -113,7 +114,7 @@ define_flag("kernel_autotune", "on",
             "timings are meaningless).")
 define_flag("kernel_tuning_cache", "",
             "Persistent kernel-tuning cache (JSON). Empty picks the "
-            "default ~/.cache/paddle_tpu/kernel_tuning.json; '0'/'off' "
+            "default <checkout>/.cache/kernel_tuning.json; '0'/'off' "
             "disables persistence (winners live for the process only); "
             "any other value is the cache file path. Pre-warm it by "
             "running representative shapes once, then ship the file — "

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..framework import device as _device
 from ..distributed.meta_parallel import (
     ColumnParallelLinear,
     RowParallelLinear,
@@ -35,23 +36,19 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "gpt_tiny", "gpt_small"]
 
 def _fused_epilogues(feature_dim=None) -> bool:
     """Gate for the fused Pallas epilogues (same shape as _use_flash's
-    gate: a real TPU backend, aligned dims, no model/sep sharding)."""
-    try:
-        from ..ops.autotune import fused_epilogues_eligible
-    except ImportError:  # pallas/jax mismatch → plain XLA path
-        return False
+    gate: a real TPU backend, aligned dims, a one-device mesh)."""
+    from ..ops.autotune import fused_epilogues_eligible
+
     return fused_epilogues_eligible(feature_dim)
 
 
 def _paged_flash(head_dim, page_size) -> bool:
     """Gate for the Pallas paged-flash-decode kernel (same shape as
-    ``_fused_epilogues``: TPU backend, aligned dims, no model/sep
-    sharding).  Off-gate, ``forward_paged`` keeps the gather-then-attend
+    ``_fused_epilogues``: TPU backend, aligned dims, a one-device
+    mesh).  Off-gate, ``forward_paged`` keeps the gather-then-attend
     path — the bit-identical CPU/fallback reference."""
-    try:
-        from ..ops.paged_attention import paged_flash_eligible
-    except ImportError:  # pallas/jax mismatch → plain XLA path
-        return False
+    from ..ops.paged_attention import paged_flash_eligible
+
     return paged_flash_eligible(head_dim, page_size)
 
 
@@ -189,12 +186,9 @@ class ParallelAttention(Layer):
             # long-context path: the Pallas flash kernel buys O(S)
             # attention memory at speed parity with XLA's fused attention
             # (see _use_flash for the measured gate)
-            try:
-                from ..ops.flash_attention import flash_attention
-            except ImportError:  # pallas/jax mismatch → dense fallback,
-                pass             # like scaled_dot_product_attention
-            else:
-                ctx = flash_attention(q, k, v, causal=True)
+            from ..ops.flash_attention import flash_attention
+
+            ctx = flash_attention(q, k, v, causal=True)
         if ctx is None:
             scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(self.head_dim)
             causal = jnp.tril(jnp.ones((S, S), bool))
@@ -214,19 +208,15 @@ class ParallelAttention(Layer):
         wins below seq 4096, parity at 4096-8192 — the kernel's advantage
         is O(S) attention memory, not speed).  Also requires: no extra
         mask (the kernel handles the causal one), no probs-dropout in
-        effect, MXU-friendly head dim, a real TPU backend, and no model/
-        sep sharding — pallas_call has no GSPMD partitioning rule, so a
-        sharded-heads call would all-gather q/k/v onto every chip (the
-        dense einsum partitions naturally; TP meshes keep it)."""
-        from ..distributed.mesh import get_mesh
+        effect, MXU-friendly head dim, a real TPU backend, and a mesh
+        that admits kernels (``autotune.mesh_admits_kernels`` — the dense
+        einsum partitions naturally; multi-chip meshes keep it)."""
+        from ..ops.autotune import mesh_admits_kernels
 
-        mesh = get_mesh()
         return (attn_mask is None and S >= 4096 and S % 128 == 0
                 and self.head_dim in (64, 128, 256)
                 and (self.drop.p == 0.0 or not self.training)
-                and mesh.shape.get("model", 1) == 1
-                and mesh.shape.get("sep", 1) == 1
-                and jax.default_backend() == "tpu")
+                and _device.on_tpu() and mesh_admits_kernels())
 
     def _sp_attention(self, q, k, v):
         from jax.sharding import PartitionSpec as P

@@ -7,7 +7,7 @@ the TPU framework's native equivalents.  pybind11 isn't available in this
 image, so the ABI is plain C over ctypes.
 
 The shared library builds from the in-tree source on first use (g++ -O2)
-and is cached under ``~/.cache/paddle_tpu/native`` keyed by a source hash —
+and is cached under ``<checkout>/.cache/native`` keyed by a source hash —
 the same "compile on first touch, cache after" contract as XLA kernels.
 """
 from __future__ import annotations
@@ -18,9 +18,11 @@ import os
 import subprocess
 import threading
 
+from ..sysconfig import cache_root as _cache_root
+
 __all__ = ["ingest_lib", "c_api_path", "NativeBuildError"]
 
-_CACHE_DIR = os.path.expanduser("~/.cache/paddle_tpu/native")
+_CACHE_DIR = os.path.join(_cache_root(), "native")
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "ingest.cc")
 
 _lock = threading.Lock()

@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ...framework import device as _device
+
 __all__ = ["scaled_dot_product_attention"]
 
 
@@ -30,20 +32,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     v = jnp.asarray(value)
 
     if use_pallas is None:
-        use_pallas = False
-        try:
-            # gate threshold measured in-model on v5e: XLA's fused bf16
-            # attention is flash-class, so the kernel only engages where
-            # it doesn't lose (parity at seq >= 4096, with O(S) memory)
-            if (jax.default_backend() == "tpu" and attn_mask is None
-                    and dropout_p == 0.0 and q.shape[1] >= 4096
-                    and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
-                    and q.shape[-1] in (64, 128, 256)):
-                from ...ops import flash_attention as _  # noqa: F401
-
-                use_pallas = True
-        except ImportError:
-            use_pallas = False
+        # gate threshold measured in-model on v5e: XLA's fused bf16
+        # attention is flash-class, so the kernel only engages where
+        # it doesn't lose (parity at seq >= 4096, with O(S) memory)
+        use_pallas = (_device.on_tpu() and attn_mask is None
+                      and dropout_p == 0.0 and q.shape[1] >= 4096
+                      and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
+                      and q.shape[-1] in (64, 128, 256))
     if use_pallas:
         from ...ops.flash_attention import flash_attention
 

@@ -23,7 +23,6 @@ high-water above the alert fraction).
 """
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Dict, Optional
@@ -71,11 +70,15 @@ def estimate_flops(jitted, *args, **kwargs) -> Optional[float]:
         return None
 
 
-def _peak_flops() -> float:
-    """Peak chip FLOP/s for the MFU denominator — same convention as
-    bench.py (v5e bf16 dense ≈ 197 TFLOP/s, PADDLE_TPU_PEAK_TFLOPS
-    overrides)."""
-    return float(os.environ.get("PADDLE_TPU_PEAK_TFLOPS", "197")) * 1e12
+def _peak_flops() -> Optional[float]:
+    """Peak chip FLOP/s for the MFU denominator, from the one table
+    bench.py also reads (``framework.device.PEAK_BF16_TFLOPS``).  None for
+    a device kind the table does not list (the CPU, an unknown chip):
+    then MFU is not reported — there is no default peak to divide by."""
+    from ..framework.device import peak_bf16_tflops
+
+    peak = peak_bf16_tflops()
+    return None if peak is None else peak * 1e12
 
 
 class StepTelemetry:
@@ -131,7 +134,7 @@ class StepTelemetry:
         self._g_mfu = r.gauge(
             "paddle_tpu_mfu",
             "model FLOPs utilization estimate (cost_analysis flops / "
-            "elapsed / PADDLE_TPU_PEAK_TFLOPS)")
+            "elapsed / the device kind's peak; 0 = not reported)")
 
     # -- producers -----------------------------------------------------------
     def record_data_wait(self, ms: float) -> None:
@@ -201,7 +204,8 @@ class StepTelemetry:
             # scale by the post-warm step share so a 1-warmup run stays
             # consistent (examples/step is constant in a train loop)
             ex_per_step = self.examples / max(self.steps, 1)
-            mfu = self.flops_post_warm / elapsed / _peak_flops()
+            peak = _peak_flops()
+            mfu = (self.flops_post_warm / elapsed / peak) if peak else 0.0
             return steps_per_s, steps_per_s * ex_per_step, mfu
 
     def _update_derived(self):
@@ -265,7 +269,7 @@ def render_summary_section() -> str:
                  f"{snap['examples_per_s']:.1f} examples/s post-warmup")
     if snap["mfu"] > 0:
         lines.append(f"  MFU ~{snap['mfu']:.1%} "
-                     f"(cost_analysis FLOPs / PADDLE_TPU_PEAK_TFLOPS)")
+                     f"(cost_analysis FLOPs / the device kind's peak)")
     if snap["hbm_limit_bytes"] > 0:
         frac = snap["hbm_peak_bytes"] / snap["hbm_limit_bytes"]
         lines.append(f"  HBM high-water {snap['hbm_peak_bytes'] / 2**30:.2f} "

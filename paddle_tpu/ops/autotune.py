@@ -4,9 +4,7 @@ generic measured-search engine in ``paddle_tpu.tuning.engine``.
 The hand kernels in this package ship tile-size defaults that were tuned
 on one shape class (flash attention's 512-blocks on 32k sequences, the
 conv+BN epilogue's 512x256 on ResNet layers).  FlashAttention-class
-kernels are famously block-size-sensitive, and the measured gap is real:
-BENCH_r04 has ResNet-50 at 0.17 MFU and 32k causal flash at 0.38 while
-BERT reaches 0.50.  The Triton/AutoTVM answer — a small template space,
+kernels are famously block-size-sensitive.  The Triton/AutoTVM answer — a small template space,
 compile + time each candidate on the real shapes, memoize the winner —
 lives in the engine; this module keeps what is kernel-specific:
 
@@ -41,11 +39,13 @@ Usage::
 """
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from ..framework.flags import flag
 from ..tuning import engine as _engine
@@ -65,13 +65,17 @@ __all__ = [
     "autotune", "TunedKernel", "tile_candidates", "vmem_fits",
     "cache_path", "clear_cache", "get_counters", "reset_counters",
     "mark_warm", "is_warm", "reset_warm", "registered_kernels",
-    "fused_epilogues_eligible",
+    "fused_epilogues_eligible", "mesh_admits_kernels",
 ]
 
 # -- Mosaic tiling / VMEM constants ------------------------------------------
 SUBLANE = 8      # f32 sublane tile; candidate row blocks are multiples
 LANE = 128       # lane tile; candidate column blocks are multiples
 VMEM_BYTES = 16 * 1024 * 1024  # per-core VMEM (v4/v5e/v5p all ~16 MB)
+#: the zero every BlockSpec index map returns for a whole dim.  The package
+#: turns x64 on, so a Python ``0`` there becomes an i64 — which Mosaic
+#: cannot return from an index map (``func.return (i32, i64)``).
+I0 = np.int32(0)
 #: fraction of VMEM a candidate's resident blocks may claim — the rest is
 #: double-buffering headroom for the pipelined DMA in/out streams
 VMEM_BUDGET_FRAC = 0.7
@@ -145,6 +149,20 @@ def _time_once(fn, args) -> float:
     return measure_ms(jax.jit(fn), args, repeats=3)
 
 
+def _outside_trace(fn: Callable):
+    """Run ``fn()`` outside whatever jit trace the caller is in.  Tuning
+    usually triggers INSIDE a model's jit trace, where every ``jnp`` call
+    — building the stand-in arrays, calling the candidate — is staged
+    into the outer program: the candidates would be traced, never
+    compiled or run, and the "timing" would be tracing time.  JAX's trace
+    state is thread-local, so a fresh thread sees none of it: the
+    stand-ins are concrete and each candidate is a real, separately
+    compiled execution on the backend.  (``ensure_compile_time_eval``
+    does not do: it leaks into the candidate's own kernel trace.)"""
+    with concurrent.futures.ThreadPoolExecutor(max_workers=1) as pool:
+        return pool.submit(fn).result()
+
+
 class TunedKernel:
     """A kernel whose tile parameters the autotuner owns.
 
@@ -201,22 +219,24 @@ class TunedKernel:
         """Resolve the config for these args without running the kernel:
         in-memory hit -> disk hit -> measured search (TPU, or mode
         'force') -> heuristic default."""
-        import jax
-
         kw = {k: v for k, v in kwargs.items() if k not in self.params}
         key = self.cache_key(*args, **kw)
         mode = str(flag("kernel_autotune")).lower()
         measurable = mode == "force" or (
-            mode != "off" and jax.default_backend() == "tpu")
+            mode != "off" and _device.on_tpu())
         synth = None  # built once, only if a search actually measures
 
         def measure(cand: dict) -> float:
-            nonlocal synth
-            if synth is None:
-                synth = _synthetic_args(args)
             merged = {**kw, **cand}
-            return _time_once(lambda *a, _m=merged: self.fn(*a, **_m),
-                              synth)
+
+            def timed() -> float:
+                nonlocal synth
+                if synth is None:
+                    synth = _synthetic_args(args)
+                return _time_once(
+                    lambda *a, _m=merged: self.fn(*a, **_m), synth)
+
+            return _outside_trace(timed)
 
         return _engine.resolve(
             "kernel", self.name, key,
@@ -225,17 +245,21 @@ class TunedKernel:
             heuristic=lambda: self.heuristic(*args, **kw),
             measurable=measurable)
 
+    def resolve(self, *args, **kwargs) -> dict:
+        """The full config for these args: explicit (non-None) values of
+        the tile params in ``kwargs`` win, the rest come from
+        :meth:`config` — which is skipped when every param is explicit."""
+        overrides = {k: kwargs[k] for k in self.params
+                     if kwargs.get(k) is not None}
+        if len(overrides) == len(self.params):
+            return overrides
+        return {**self.config(*args, **kwargs), **overrides}
+
     # -- call ----------------------------------------------------------------
     def __call__(self, *args, **kwargs):
-        overrides = {k: kwargs.pop(k) for k in self.params
-                     if kwargs.get(k) is not None}
+        cfg = self.resolve(*args, **kwargs)
         for k in self.params:
-            kwargs.pop(k, None)  # drop explicit Nones
-        if len(overrides) < len(self.params):
-            cfg = self.config(*args, **kwargs)
-            cfg.update(overrides)
-        else:
-            cfg = overrides
+            kwargs.pop(k, None)
         return self.fn(*args, **kwargs, **cfg)
 
     def __repr__(self):
@@ -253,21 +277,27 @@ def autotune(name: str, *, params: Sequence[str], space: Callable,
     return deco
 
 
-# -- model-integration gate --------------------------------------------------
+# -- model-integration gates -------------------------------------------------
+def mesh_admits_kernels() -> bool:
+    """``pallas_call`` has no GSPMD partitioning rule: JAX refuses to lower
+    a Mosaic kernel inside ANY jit that spans more than one device
+    ("Mosaic kernels cannot be automatically partitioned"), whichever axis
+    is sharded — ``data`` as much as ``model``.  The model hot paths trace
+    under the global mesh, so their kernel gates open only on a one-device
+    mesh; multi-chip meshes keep the XLA paths until the kernels are
+    wrapped in ``shard_map``."""
+    from ..distributed.mesh import get_mesh
+
+    return get_mesh().size == 1
+
+
 def fused_epilogues_eligible(feature_dim: Optional[int] = None) -> bool:
     """Should a model hot path call the fused Pallas epilogues?  Mirrors
     the flash-attention gate: a real TPU backend (interpret mode loses),
-    lane-aligned feature dim, and no model/sep sharding — ``pallas_call``
-    has no GSPMD partitioning rule, so a sharded call would all-gather
-    its operands onto every chip."""
-    import jax
-
-    if not flag("fused_epilogues") or jax.default_backend() != "tpu":
+    lane-aligned feature dim, and a mesh that admits kernels
+    (:func:`mesh_admits_kernels`)."""
+    if not flag("fused_epilogues") or not _device.on_tpu():
         return False
     if feature_dim is not None and feature_dim % LANE != 0:
         return False
-    from ..distributed.mesh import get_mesh
-
-    mesh = get_mesh()
-    return (mesh.shape.get("model", 1) == 1
-            and mesh.shape.get("sep", 1) == 1)
+    return mesh_admits_kernels()

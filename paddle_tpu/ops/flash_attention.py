@@ -47,9 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from . import autotune as _at
 
 __all__ = ["flash_attention", "flash_attention_fwd_lse",
@@ -214,7 +212,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
         pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
         pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
     ]
-    interpret = jax.default_backend() != "tpu"
+    interpret = not _device.on_tpu()
 
     if triangle:
         nq = S // block_q
@@ -401,7 +399,7 @@ def _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k,
     deltas = delta.reshape(B * H, S, 1)
 
     _I0 = np.int32(0)
-    interpret = jax.default_backend() != "tpu"
+    interpret = not _device.on_tpu()
     triangle = _use_triangle(causal, q_offset, S, K, bq, bk)
     block_q, block_k = bq, bk
 
@@ -476,7 +474,7 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k,
     deltas = delta.reshape(B * H, S, 1)
 
     _I0 = np.int32(0)
-    interpret = jax.default_backend() != "tpu"
+    interpret = not _device.on_tpu()
     triangle = _use_triangle(causal, q_offset, S, K, bq, bk)
     block_q, block_k = bq, bk
 

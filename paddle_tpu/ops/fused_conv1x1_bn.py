@@ -23,6 +23,12 @@ i.e. one full read of Y removed (~25-33% of the tensor traffic on these
 bandwidth-bound layers).  The normalize pass stays in XLA where it fuses
 with the residual add and ReLU for free.
 
+Both kernels are differentiable (the ResNet training step runs through
+them): each carries a closed-form VJP in plain XLA, like the other
+epilogues — ``pallas_call`` itself has no transpose rule, and the VJPs
+are two GEMMs (stats) and one masked elementwise pass (apply) that XLA
+already schedules well.
+
 Reference capability matched: the fused_ops family
 (paddle/fluid/operators/fused/conv_fusion_op.cc — cuDNN conv+bias+act
 fusion); the TPU-native answer fuses what the TPU is short on (HBM
@@ -45,13 +51,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
 __all__ = ["conv1x1_bn_stats", "conv1x1_bn_relu", "bn_apply_relu"]
+
 
 
 def _kernel(x_ref, w_ref, y_ref, sum_ref, sq_ref, acc_s, acc_q):
@@ -101,14 +106,10 @@ def _heuristic(x, w):
     return {"block_m": 512, "block_n": 256}
 
 
-@_at.autotune("conv1x1_bn_stats", params=("block_m", "block_n"),
-              space=_space, heuristic=_heuristic)
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
-def _conv1x1_bn_stats(x, w, *, block_m: int, block_n: int):
+def _stats_pallas(x, w, *, block_m: int, block_n: int):
     M, K = x.shape
-    K2, N = w.shape
-    if K != K2:
-        raise InvalidArgumentError(f"shape mismatch {x.shape} @ {w.shape}")
+    N = w.shape[1]
     # Mosaic lowers (sublane, lane)-tiled blocks: bm must be a multiple of
     # 8 and bn a multiple of 128, or non-aligned shapes (M=100, N=200)
     # fail to lower on a real TPU.  Padding already keeps the stats exact.
@@ -121,19 +122,18 @@ def _conv1x1_bn_stats(x, w, *, block_m: int, block_n: int):
     xp = x if Mp == M else jnp.pad(x, ((0, Mp - M), (0, 0)))
     wp = w if Np == N else jnp.pad(w, ((0, 0), (0, Np - N)))
 
-    interpret = jax.default_backend() != "tpu"  # CPU tests: interpret mode
     y, s, q = pl.pallas_call(
         _kernel,
-        interpret=interpret,
+        interpret=not _device.on_tpu(),  # CPU tests: interpret mode
         grid=(Np // bn, Mp // bm),  # M minor: sequential stats sweep
         in_specs=[
-            pl.BlockSpec((bm, K), lambda n, m: (m, 0)),
-            pl.BlockSpec((K, bn), lambda n, m: (0, n)),
+            pl.BlockSpec((bm, K), lambda n, m: (m, _at.I0)),
+            pl.BlockSpec((K, bn), lambda n, m: (_at.I0, n)),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda n, m: (m, n)),
-            pl.BlockSpec((1, bn), lambda n, m: (0, n)),
-            pl.BlockSpec((1, bn), lambda n, m: (0, n)),
+            pl.BlockSpec((1, bn), lambda n, m: (_at.I0, n)),
+            pl.BlockSpec((1, bn), lambda n, m: (_at.I0, n)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Mp, Np), x.dtype),
@@ -150,6 +150,37 @@ def _conv1x1_bn_stats(x, w, *, block_m: int, block_n: int):
     return y[:M, :N], s[0, :N], q[0, :N]
 
 
+@_at.autotune("conv1x1_bn_stats", params=("block_m", "block_n"),
+              space=_space, heuristic=_heuristic)
+def _conv1x1_bn_stats(x, w, *, block_m: int, block_n: int):
+    return _stats_pallas(x, w, block_m=block_m, block_n=block_n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _stats(x, w, block_m, block_n):
+    return _stats_pallas(x, w, block_m=block_m, block_n=block_n)
+
+
+def _stats_fwd(x, w, block_m, block_n):
+    y, s, q = _stats_pallas(x, w, block_m=block_m, block_n=block_n)
+    return (y, s, q), (x, w, y)
+
+
+def _stats_bwd(block_m, block_n, res, cts):
+    x, w, y = res
+    dy, ds, dq = cts
+    # Σy is linear and Σy² quadratic in y: their cotangents fold into one
+    # effective dY, then the usual two GEMMs
+    g = (dy.astype(jnp.float32) + ds[None, :]
+         + 2.0 * y.astype(jnp.float32) * dq[None, :]).astype(x.dtype)
+    dx = jnp.dot(g, w.T, preferred_element_type=jnp.float32)
+    dw = jnp.dot(x.T, g, preferred_element_type=jnp.float32)
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+_stats.defvjp(_stats_fwd, _stats_bwd)
+
+
 def conv1x1_bn_stats(x, w, *, block_m: Optional[int] = None,
                      block_n: Optional[int] = None):
     """``Y = X @ W`` plus per-output-channel ``(Σy, Σy²)`` in ONE pass.
@@ -161,9 +192,13 @@ def conv1x1_bn_stats(x, w, *, block_m: Optional[int] = None,
 
     Tile sizes default to the autotuner (``ops.autotune``): measured on
     TPU, the 512x256 heuristic elsewhere.  Pass ``block_m``/``block_n``
-    explicitly to bypass tuning.
+    explicitly to bypass tuning.  Differentiable in x and w.
     """
-    return _conv1x1_bn_stats(x, w, block_m=block_m, block_n=block_n)
+    x, w = jnp.asarray(x), jnp.asarray(w)
+    if x.ndim != 2 or w.ndim != 2 or x.shape[1] != w.shape[0]:
+        raise InvalidArgumentError(f"shape mismatch {x.shape} @ {w.shape}")
+    cfg = _conv1x1_bn_stats.resolve(x, w, block_m=block_m, block_n=block_n)
+    return _stats(x, w, int(cfg["block_m"]), int(cfg["block_n"]))
 
 
 def _apply_kernel(*refs, has_residual):
@@ -197,10 +232,8 @@ def _apply_heuristic(y, scale, shift, residual):
     return {"block_m": 512, "block_n": 256}
 
 
-@_at.autotune("conv1x1_bn_apply", params=("block_m", "block_n"),
-              space=_apply_space, heuristic=_apply_heuristic)
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
-def _bn_apply(y, scale, shift, residual, *, block_m: int, block_n: int):
+def _apply_pallas(y, scale, shift, residual, *, block_m: int, block_n: int):
     M, N = y.shape
     bm = min(block_m, max(M, 8))
     bn = min(block_n, max(N, 128))
@@ -218,8 +251,8 @@ def _bn_apply(y, scale, shift, residual, *, block_m: int, block_n: int):
     operands = [yp, scp, shp]
     in_specs = [
         pl.BlockSpec((bm, bn), lambda n, m: (m, n)),
-        pl.BlockSpec((1, bn), lambda n, m: (0, n)),
-        pl.BlockSpec((1, bn), lambda n, m: (0, n)),
+        pl.BlockSpec((1, bn), lambda n, m: (_at.I0, n)),
+        pl.BlockSpec((1, bn), lambda n, m: (_at.I0, n)),
     ]
     if has_residual:
         rp = residual if (Mp, Np) == (M, N) else jnp.pad(
@@ -227,10 +260,9 @@ def _bn_apply(y, scale, shift, residual, *, block_m: int, block_n: int):
         operands.append(rp)
         in_specs.append(pl.BlockSpec((bm, bn), lambda n, m: (m, n)))
 
-    interpret = jax.default_backend() != "tpu"
     out = pl.pallas_call(
         functools.partial(_apply_kernel, has_residual=has_residual),
-        interpret=interpret,
+        interpret=not _device.on_tpu(),
         grid=(Np // bn, Mp // bm),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda n, m: (m, n)),
@@ -239,6 +271,42 @@ def _bn_apply(y, scale, shift, residual, *, block_m: int, block_n: int):
             dimension_semantics=("parallel", "parallel")),
     )(*operands)
     return out[:M, :N]
+
+
+@_at.autotune("conv1x1_bn_apply", params=("block_m", "block_n"),
+              space=_apply_space, heuristic=_apply_heuristic)
+def _bn_apply(y, scale, shift, residual, *, block_m: int, block_n: int):
+    return _apply_pallas(y, scale, shift, residual,
+                         block_m=block_m, block_n=block_n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _apply(y, scale, shift, residual, block_m, block_n):
+    return _apply_pallas(y, scale, shift, residual,
+                         block_m=block_m, block_n=block_n)
+
+
+def _apply_fwd(y, scale, shift, residual, block_m, block_n):
+    out = _apply_pallas(y, scale, shift, residual,
+                        block_m=block_m, block_n=block_n)
+    # a scalar stands in for the residual: bwd needs its presence and
+    # dtype, not its values
+    like = None if residual is None else jnp.zeros((), residual.dtype)
+    return out, (y, scale, shift, out, like)
+
+
+def _apply_bwd(block_m, block_n, res, dout):
+    y, scale, shift, out, like = res
+    dz = jnp.where(out > 0, dout.astype(jnp.float32), 0.0)  # relu mask
+    dy = dz * scale.astype(jnp.float32)[None, :]
+    dscale = jnp.sum(dz * y.astype(jnp.float32), axis=0)
+    dshift = jnp.sum(dz, axis=0)
+    dres = None if like is None else dz.astype(like.dtype)
+    return (dy.astype(y.dtype), dscale.astype(scale.dtype),
+            dshift.astype(shift.dtype), dres)
+
+
+_apply.defvjp(_apply_fwd, _apply_bwd)
 
 
 def bn_apply_relu(y, scale, shift, residual=None, *,
@@ -254,9 +322,15 @@ def bn_apply_relu(y, scale, shift, residual=None, *,
     read of the residual, one write of the output — the guaranteed
     2-pass schedule of the module doc.  y ``[M, Cout]``, scale/shift
     ``[Cout]`` (f32 math), residual optional ``[M, Cout]``.
+    Differentiable in y, scale, shift and the residual.
     """
-    return _bn_apply(y, scale, shift, residual,
-                     block_m=block_m, block_n=block_n)
+    y, scale, shift = jnp.asarray(y), jnp.asarray(scale), jnp.asarray(shift)
+    if residual is not None:
+        residual = jnp.asarray(residual)
+    cfg = _bn_apply.resolve(y, scale, shift, residual,
+                            block_m=block_m, block_n=block_n)
+    return _apply(y, scale, shift, residual,
+                  int(cfg["block_m"]), int(cfg["block_n"]))
 
 
 def conv1x1_bn_relu(x, w, gamma, beta, *, epsilon: float = 1e-5,
@@ -299,8 +373,11 @@ def conv1x1_bn_relu(x, w, gamma, beta, *, epsilon: float = 1e-5,
     if running_mean is not None:
         n = jnp.asarray(M, jnp.float32)
         unbiased = var * n / jnp.maximum(n - 1, 1)
+        # f32 math, returned in the buffers' own dtype (bf16 under
+        # net.astype("bfloat16")): a scan-chained train step carries them
         running_mean = (momentum * running_mean.astype(jnp.float32)
-                        + (1 - momentum) * mean)
+                        + (1 - momentum) * mean).astype(running_mean.dtype)
         running_var = (momentum * running_var.astype(jnp.float32)
-                       + (1 - momentum) * unbiased)
+                       + (1 - momentum) * unbiased
+                       ).astype(running_var.dtype)
     return out, running_mean, running_var

@@ -38,9 +38,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
@@ -77,17 +75,17 @@ def _ln_res_pallas(x, r, g, b, epsilon, block_m):
     g2 = g.reshape(1, D)
     b2 = b.reshape(1, D)
 
-    interpret = jax.default_backend() != "tpu"
-    row = lambda i: (i, 0)  # noqa: E731
+    row = lambda i: (i, _at.I0)  # noqa: E731
+    whole = lambda i: (_at.I0, _at.I0)  # noqa: E731
     s, y, mean, rstd = pl.pallas_call(
         functools.partial(_kernel, epsilon=epsilon),
-        interpret=interpret,
+        interpret=not _device.on_tpu(),
         grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, D), row),
             pl.BlockSpec((bm, D), row),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
+            pl.BlockSpec((1, D), whole),
+            pl.BlockSpec((1, D), whole),
         ],
         out_specs=[
             pl.BlockSpec((bm, D), row),

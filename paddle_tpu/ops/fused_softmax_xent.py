@@ -32,9 +32,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
@@ -98,11 +96,10 @@ def _sxent_pallas(logits, labels, block_m, block_v):
     if Mp != M:
         lab = jnp.pad(lab, ((0, Mp - M), (0, 0)))
 
-    interpret = jax.default_backend() != "tpu"
-    row = lambda i, j: (i, 0)  # noqa: E731
+    row = lambda i, j: (i, _at.I0)  # noqa: E731
     loss, lse = pl.pallas_call(
         functools.partial(_kernel, V=V, block_v=bv),
-        interpret=interpret,
+        interpret=not _device.on_tpu(),
         grid=(Mp // bm, Vp // bv),  # vocab minor: sequential online sweep
         in_specs=[
             pl.BlockSpec((bm, bv), lambda i, j: (i, j)),

@@ -37,9 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
@@ -49,7 +47,7 @@ __all__ = ["grouped_matmul"]
 def _kernel(gs_ref, x_ref, w_ref, o_ref):
     i = pl.program_id(1)
     bm, bn = o_ref.shape[1], o_ref.shape[2]
-    gs = gs_ref[0, 0]
+    gs = gs_ref[pl.program_id(0)]  # [E] i32 in SMEM (scalar prefetch)
     acc = jnp.dot(x_ref[0].astype(jnp.float32),
                   w_ref[0].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
@@ -73,23 +71,26 @@ def _gmm_pallas(x, w, group_sizes, block_m, block_n):
         x = jnp.pad(x, ((0, 0), (0, Cp - C), (0, 0)))
     if Fp != F:
         w = jnp.pad(w, ((0, 0), (0, 0), (0, Fp - F)))
-    gs2 = group_sizes.reshape(E, 1).astype(jnp.int32)
-
-    interpret = jax.default_backend() != "tpu"
+    # the per-expert fill counts are scalars the kernel only compares
+    # against: they ride SMEM via scalar prefetch — a (1, 1) VMEM block of
+    # an [E, 1] operand is not (8, 128)-tileable
     out = pl.pallas_call(
         _kernel,
-        interpret=interpret,
-        grid=(E, Cp // bm, Fp // bn),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda e, i, j: (e, 0)),
-            pl.BlockSpec((1, bm, D), lambda e, i, j: (e, i, 0)),
-            pl.BlockSpec((1, D, bn), lambda e, i, j: (e, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j: (e, i, j)),
+        interpret=not _device.on_tpu(),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, Cp // bm, Fp // bn),
+            in_specs=[
+                pl.BlockSpec((1, bm, D), lambda e, i, j, gs: (e, i, _at.I0)),
+                pl.BlockSpec((1, D, bn), lambda e, i, j, gs: (e, _at.I0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda e, i, j, gs: (e, i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((E, Cp, Fp), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(gs2, x, w)
+    )(group_sizes.astype(jnp.int32), x, w)
     return out[:, :C, :F]
 
 

@@ -35,7 +35,7 @@ Equivalence: same math as the gather-then-attend reference modulo
 float reassociation (online softmax accumulates in f32); the reference
 path stays the bit-identical CPU/fallback — ``paged_flash_eligible``
 gates dispatch exactly like ``fused_epilogues_eligible`` does for the
-other epilogues (TPU backend, no model/sep sharding, aligned dims).
+other epilogues (TPU backend, one-device mesh, aligned dims).
 
 Tile parameters resolve through ``ops.autotune`` (kernel name
 ``"paged_decode"``): ``block_h`` — heads per grid step — trades grid
@@ -55,16 +55,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from ..framework.flags import flag
 from . import autotune as _at
 
 __all__ = ["paged_flash_decode", "paged_flash_eligible"]
 
-_NEG = -1e30  # mask fill; exp(_NEG - m) underflows to exactly 0.0 in f32
+# mask fill; exp(_NEG - m) underflows to exactly 0.0 in f32.  Typed f32:
+# under the package's global x64 a bare Python float reaches ``jnp.where``
+# as an f64 scalar, which Mosaic cannot legalize.
+_NEG = np.float32(-1e30)
+_ZERO = np.float32(0.0)
 
 
 def _kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *refs,
@@ -84,20 +86,22 @@ def _kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *refs,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    mask = mask_ref[0, :, 0, :]  # [Tp, page] 0/1 f32
+    mask = mask_ref[0, 0]  # [Tp, page] 0/1 f32
+    h0 = pl.program_id(1) * block_h  # first head of this block (i32)
     for h in range(block_h):  # static unroll: 2-D MXU dots per head
         q = q_ref[0, h].astype(jnp.float32)   # [Tp, hd]
         k = k_ref[0, h].astype(jnp.float32)   # [page, hd]
         v = v_ref[0, h].astype(jnp.float32)
-        if quantized:
-            # fused dequant: one multiplier per (page entry, head),
-            # applied to the block already resident in VMEM — the f32
-            # K/V never exists outside this register window
-            k = k * ks_ref[0, h][:, None]
-            v = v * vs_ref[0, h][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [Tp, page]
+        if quantized:
+            # fused dequant: one multiplier per (page entry, head).  The
+            # page axis is the LANE axis of the score tile, so the K
+            # scales fold in as a [1, page] row on s and the V scales as
+            # one on p — q·(k·ks)ᵀ = (q·kᵀ)·ks and p·(v·vs) = (p·vs)·v —
+            # and the f32 K/V never exists outside this register window
+            s = s * ks_ref[0, pl.ds(h0 + h, 1), :]
         s = jnp.where(mask > 0, s, _NEG)
 
         m_prev = m_s[h]                       # [Tp, LANE], lanes equal
@@ -105,6 +109,8 @@ def _kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *refs,
         alpha = jnp.exp(m_prev - m_new)       # [Tp, LANE]
         p = jnp.exp(s - m_new[:, :1]) * mask  # masked/padded entries -> 0
         l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, pl.ds(h0 + h, 1), :]
         acc_s[h] = (acc_s[h] * alpha[:, :1]
                     + jax.lax.dot_general(
                         p, v, (((1,), (0,)), ((), ())),
@@ -115,7 +121,7 @@ def _kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *refs,
     def _flush():
         for h in range(block_h):
             l = l_s[h][:, :1]  # fully-masked rows (query padding): l == 0
-            out = jnp.where(l > 0, acc_s[h] / jnp.maximum(l, 1e-30), 0.0)
+            out = jnp.where(l > 0, acc_s[h] / jnp.maximum(l, 1e-30), _ZERO)
             o_ref[0, h] = out.astype(o_ref.dtype)
 
 
@@ -137,7 +143,7 @@ def _space(q, k_pool, v_pool, tables, mask, k_scale, v_scale):
                     + Tp * page * 4                  # mask block
                     + bh * Tp * (2 * _at.LANE + hd) * 4)  # m/l/acc scratch
         if k_scale is not None:
-            resident += 2 * bh * page * 4
+            resident += 2 * H * page * 4  # scale rows ride whole-H blocks
         if _at.vmem_fits(resident):
             out.append({"block_h": bh})
     return out
@@ -175,31 +181,37 @@ def _paged_decode(q, k_pool, v_pool, tables, mask, k_scale, v_scale, *,
     maskf = mask.astype(jnp.float32)
     if Tp != T:
         maskf = jnp.pad(maskf, ((0, 0), (0, Tp - T), (0, 0)))
-    maskf = maskf.reshape(B, Tp, G, page)
+    # page-major so one page's [Tp, page] mask tile is the block's two
+    # minor dims IN FULL — Mosaic tiles the last two dims (8, 128) and a
+    # (1, page) slice of a [G, page] minor pair is not a legal block
+    maskf = maskf.reshape(B, Tp, G, page).transpose(0, 2, 1, 3)
     tab = tables.astype(jnp.int32)  # [B, G] SMEM table for the index maps
 
     def qmap(b, h, g, t):
-        return (b, h, 0, 0)
+        return (b, h, _at.I0, _at.I0)
 
     def kvmap(b, h, g, t):
-        return (t[b, g], h, 0, 0)
+        return (t[b, g], h, _at.I0, _at.I0)
 
     def scmap(b, h, g, t):
-        return (t[b, g], h, 0)
+        return (t[b, g], _at.I0, _at.I0)
 
     def mmap(b, h, g, t):
-        return (b, 0, g, 0)
+        return (b, g, _at.I0, _at.I0)
 
     in_specs = [
         pl.BlockSpec((1, bh, Tp, hd), qmap),
         pl.BlockSpec((1, bh, page, hd), kvmap),
         pl.BlockSpec((1, bh, page, hd), kvmap),
-        pl.BlockSpec((1, Tp, 1, page), mmap),
+        pl.BlockSpec((1, 1, Tp, page), mmap),
     ]
     operands = [qp, k_pool, v_pool, maskf]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bh, page), scmap),
-                     pl.BlockSpec((1, bh, page), scmap)]
+        # a page's scales for ALL heads: (H, page) is the operand's whole
+        # minor pair (a bh-row slice of it is not (8, 128)-tileable); the
+        # kernel picks its heads' rows by dynamic sublane index
+        in_specs += [pl.BlockSpec((1, H, page), scmap),
+                     pl.BlockSpec((1, H, page), scmap)]
         operands += [k_scale, v_scale]
 
     kern = functools.partial(_kernel, block_h=bh, sm_scale=sm_scale,
@@ -220,7 +232,7 @@ def _paged_decode(q, k_pool, v_pool, tables, mask, k_scale, v_scale, *,
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=jax.default_backend() != "tpu",
+        interpret=not _device.on_tpu(),
     )(tab, *operands)
     return out[:, :, :T, :]
 
@@ -258,20 +270,16 @@ def paged_flash_eligible(head_dim: Optional[int] = None,
     """Should ``forward_paged`` dispatch to the Pallas kernel?  Mirrors
     ``fused_epilogues_eligible``: a real TPU backend (interpret mode
     loses; the gather path is the bit-identical CPU reference), Mosaic-
-    friendly head/page dims, and no model/sep sharding — ``pallas_call``
-    has no GSPMD partitioning rule.  ``backend`` overrides the backend
+    friendly head/page dims, and a one-device mesh
+    (``autotune.mesh_admits_kernels``).  ``backend`` overrides the backend
     check so CI on CPU can assert the would-dispatch-on-TPU decision
     (tools/gen_smoke.py / quant_smoke.py)."""
     if not flag("paged_flash"):
         return False
-    if (backend or jax.default_backend()) != "tpu":
+    if not (backend == "tpu" if backend else _device.on_tpu()):
         return False
     if head_dim is not None and head_dim % _at.SUBLANE != 0:
         return False
     if page_size is not None and page_size % _at.SUBLANE != 0:
         return False
-    from ..distributed.mesh import get_mesh
-
-    mesh = get_mesh()
-    return (mesh.shape.get("model", 1) == 1
-            and mesh.shape.get("sep", 1) == 1)
+    return _at.mesh_admits_kernels()

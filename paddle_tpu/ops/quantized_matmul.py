@@ -21,7 +21,7 @@ as (32, 128) on Mosaic — row blocks are multiples of 32, column blocks
 of 128, and the whole contraction dim rides in VMEM zero-padded to a
 lane multiple (exact: padded products are zero).
 
-Off-TPU (and under model/sep sharding — ``pallas_call`` has no GSPMD
+Off-TPU (and on any multi-device mesh — ``pallas_call`` has no GSPMD
 partitioning rule) the same math runs as a plain XLA ``dot_general``
 with the identical quantize → accumulate → rescale structure, so tokens
 do not depend on which backend executed the layer.  Inference only: no
@@ -38,9 +38,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 spells it TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
+from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
@@ -90,16 +88,15 @@ def _qmm_pallas(xq, wq, scale, bias, block_m, block_n):
     s2 = scale.reshape(1, Np).astype(jnp.float32)
     b2 = bias.reshape(1, Np).astype(jnp.float32)
 
-    interpret = jax.default_backend() != "tpu"
     out = pl.pallas_call(
         _kernel,
-        interpret=interpret,
+        interpret=not _device.on_tpu(),
         grid=(Mp // bm, Np // bn),
         in_specs=[
-            pl.BlockSpec((bm, Kp), lambda i, j: (i, 0)),
-            pl.BlockSpec((Kp, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, Kp), lambda i, j: (i, _at.I0)),
+            pl.BlockSpec((Kp, bn), lambda i, j: (_at.I0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (_at.I0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (_at.I0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
@@ -154,7 +151,7 @@ def quantize_activations(x, mode: str):
 
 def _use_pallas(n_features: int) -> bool:
     # same gate as the other fused epilogues: real TPU, lane-aligned
-    # output features, no model/sep sharding (pallas_call cannot be
+    # output features, a one-device mesh (pallas_call cannot be
     # GSPMD-partitioned).  Interpret-mode pallas would only slow the
     # CPU test path down; the XLA fallback is numerically identical.
     return _at.fused_epilogues_eligible(feature_dim=n_features)

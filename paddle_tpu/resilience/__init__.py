@@ -1,13 +1,13 @@
 """paddle_tpu.resilience — fault tolerance as a first-class subsystem.
 
 The reference framework treats failure as API surface (the typed enforce
-taxonomy of paddle/fluid/platform/enforce.h, auto-checkpoint preemption
+hierarchy of paddle/fluid/platform/enforce.h, auto-checkpoint preemption
 resume, chief-side heartbeat monitoring); this package is where those
 islands become a system:
 
 * :mod:`~paddle_tpu.resilience.retry` — :class:`RetryPolicy`:
   deadline-aware exponential backoff with seeded jitter over the
-  transient/fatal taxonomy (``framework.errors.is_transient``); used by
+  transient/fatal classification (``framework.errors.is_transient``); used by
   the checkpoint async writer, ``Executor.run`` dispatch and serving
   batch execution.
 * :mod:`~paddle_tpu.resilience.faults` — deterministic fault injection:

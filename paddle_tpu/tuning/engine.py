@@ -125,8 +125,9 @@ def cache_path() -> Optional[str]:
     if val.lower() in ("0", "off", "none", "false", "disabled"):
         return None
     if not val:
-        return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                            "kernel_tuning.json")
+        from ..sysconfig import cache_root
+
+        return os.path.join(cache_root(), "kernel_tuning.json")
     return val
 
 
@@ -361,9 +362,11 @@ def resolve(space: str, name: str, key: str, *,
     be in the list so the search can never do worse than the hand-set
     config.  ``measure(cand) -> ms`` times one candidate (lower is
     better; raise :class:`CandidateError` to reject it).  ``heuristic``
-    is the untimed default used off-backend or when every candidate
-    fails.  ``prefilter(cand) -> bool`` drops invalid candidates before
-    any compile.  ``details`` (optional dict) is filled with the search
+    is the untimed default used off-backend.  A search in which EVERY
+    measured candidate raises re-raises the first candidate's error (a
+    loser among winners is skipped and counted in ``search_failures``).
+    ``prefilter(cand) -> bool`` drops invalid candidates before any
+    compile.  ``details`` (optional dict) is filled with the search
     outcome (event, best_ms, default_ms, per-candidate timings) for
     gates that assert on measurements."""
     if not space or "|" in space:
@@ -415,7 +418,7 @@ def resolve(space: str, name: str, key: str, *,
         candidates() if callable(candidates) else candidates, default)
     dsig = tuple(sorted((k, repr(v)) for k, v in default.items()))
     best_cfg, best_ms, default_ms = dict(default), math.inf, None
-    timed, dropped, timings = 0, 0, []
+    timed, dropped, timings, first_error = 0, 0, [], None
     with profiler.RecordEvent(f"measured_search/{space}/{name}"):
         for cand in cands:
             if prefilter is not None and not prefilter(cand):
@@ -425,7 +428,9 @@ def resolve(space: str, name: str, key: str, *,
                 continue
             try:
                 ms = float(measure(cand))
-            except Exception:  # fails to lower / violates a budget: skip
+            except Exception as e:  # a loser among winners: skip + count
+                if first_error is None:
+                    first_error = e
                 with _lock:
                     _bump(name, "search_failures")
                 timings.append({"config": dict(cand), "ms": None})
@@ -436,11 +441,18 @@ def resolve(space: str, name: str, key: str, *,
                 default_ms = ms
             if ms < best_ms:
                 best_cfg, best_ms = dict(cand), ms
-    if timed == 0:  # nothing measured — fall back, don't poison caches
+    if timed == 0 and first_error is not None:
+        # EVERY candidate failed to compile or run: that is a broken
+        # kernel (or a space whose filters admit nothing the compiler
+        # takes), not a tuning outcome.  Returning the heuristic here
+        # would only move the same failure into the caller's jit, far
+        # from its cause.
+        raise first_error
+    if timed == 0:  # the prefilter dropped everything: untimed default
         with _lock:
             _bump(name, "heuristic")
         _publish(space, name, "heuristic", key, default,
-                 note="all candidates failed")
+                 note="all candidates prefiltered")
         note(event="heuristic", config=dict(default),
              n_candidates=len(cands), n_prefiltered=dropped,
              timings=timings)

@@ -118,6 +118,49 @@ class TestResolution:
         _probe.config(x)
         assert autotune.get_counters("test_probe")["hits"] == 1
 
+    def test_search_inside_a_jit_trace_measures_real_executions(
+            self, monkeypatch):
+        # models resolve configs while THEIR step is being traced; the
+        # candidates must still compile and run for real, not be staged
+        # into the outer trace (where "timing" reads tracing time)
+        set_flags({"kernel_autotune": "force", "kernel_tuning_cache": "off"})
+        seen = []
+        real = autotune.measure_ms
+
+        def spy(fn, args=(), repeats=3):
+            seen.append(any(isinstance(a, jax.core.Tracer) for a in args))
+            return real(fn, args, repeats)
+
+        monkeypatch.setattr(autotune, "measure_ms", spy)
+
+        @jax.jit
+        def step(x):
+            return _probe(x)
+
+        np.testing.assert_allclose(np.asarray(step(_arr(32, 32))),
+                                   np.asarray(_arr(32, 32) * 2))
+        assert seen == [False, False]  # both candidates, concrete inputs
+
+    def test_every_candidate_failing_raises_the_first_error(self):
+        # an all-failed search is a broken kernel, not a tuning outcome:
+        # it must not quietly hand back the heuristic
+        set_flags({"kernel_autotune": "force", "kernel_tuning_cache": "off"})
+
+        def boom(x, *, block):
+            raise RuntimeError(f"refused block={block}")
+
+        broken = autotune.TunedKernel(
+            boom, "test_broken", ("block",),
+            lambda x: [{"block": 8}, {"block": 16}],
+            lambda x: {"block": 8})
+        try:
+            with pytest.raises(RuntimeError, match="refused block=8"):
+                broken.config(_arr(32, 32))
+            c = autotune.get_counters("test_broken")
+            assert c["search_failures"] == 2 and c["searches"] == 0
+        finally:
+            autotune._REGISTRY.pop("test_broken", None)
+
     def test_off_mode_never_searches(self):
         set_flags({"kernel_autotune": "off"})
         assert _probe.config(_arr(32, 32)) == {"block": 8}
@@ -153,9 +196,9 @@ class TestResolution:
         set_flags({"kernel_tuning_cache": str(tmp_path / "t.json")})
         assert autotune.cache_path() == str(tmp_path / "t.json")
         set_flags({"kernel_tuning_cache": ""})
-        assert autotune.cache_path().endswith(
-            os.path.join(".cache", "paddle_tpu", "kernel_tuning.json"))
         from paddle_tpu import sysconfig
+        assert autotune.cache_path() == os.path.join(
+            sysconfig.cache_root(), "kernel_tuning.json")
         assert sysconfig.kernel_tuning_cache_path() == autotune.cache_path()
 
     def test_events_published(self):

@@ -166,3 +166,76 @@ class TestBnApplyRelu:
         # the fused tail updated bn3's running stats like the plain one
         np.testing.assert_allclose(np.asarray(blk.bn3._mean.value),
                                    rm_ref, rtol=1e-4, atol=1e-6)
+
+
+def test_fused_tail_keeps_the_buffers_dtype():
+    # net.astype("bfloat16") makes bn3's running stats bf16; the kernel's
+    # stats are f32.  Handing them back as f32 changed the carry type of a
+    # scan-chained train step (Executor.run_steps) — the ResNet-50 program
+    # did not even trace on the chip.  conv1x1_bn_relu returns running
+    # stats in the dtype they came in.
+    import paddle_tpu.nn as nn
+    import paddle_tpu.ops.autotune as at
+    from paddle_tpu.vision.models.resnet import BottleneckBlock
+
+    blk = BottleneckBlock(
+        256, 64, data_format="NHWC",
+        norm_layer=lambda c: nn.BatchNorm2D(c, data_format="NHWC"),
+    ).astype("bfloat16")
+    x = jnp.ones((2, 8, 8, 256), jnp.bfloat16)
+    orig = at.fused_epilogues_eligible
+    at.fused_epilogues_eligible = lambda feature_dim=None: True
+    try:
+        assert blk._fused_tail(x[..., :64], x) is not None
+    finally:
+        at.fused_epilogues_eligible = orig
+    assert blk.bn3._mean.value.dtype == jnp.bfloat16
+    assert blk.bn3._variance.value.dtype == jnp.bfloat16
+
+
+class TestGradients:
+    """The ResNet training step differentiates through both kernels;
+    ``pallas_call`` has no transpose rule, so each carries a closed-form
+    VJP — checked here against autodiff of the plain jnp formulation."""
+
+    @pytest.mark.parametrize("fused_epilogue", [False, True])
+    def test_grads_match_unfused_reference(self, fused_epilogue):
+        rng = np.random.RandomState(7)
+        M, K, N = 77, 32, 128  # ragged M: padded rows must not leak grads
+        x = jnp.asarray(rng.randn(M, K).astype(np.float32))
+        w = jnp.asarray(rng.randn(K, N).astype(np.float32) * 0.2)
+        g = jnp.asarray(rng.rand(N).astype(np.float32) + 0.5)
+        b = jnp.asarray(rng.randn(N).astype(np.float32))
+        res = jnp.asarray(rng.randn(M, N).astype(np.float32))
+        cot = jnp.asarray(rng.randn(M, N).astype(np.float32))
+
+        def fused(x, w, g, b, res):
+            out, _, _ = conv1x1_bn_relu(x, w, g, b, residual=res,
+                                        fused_epilogue=fused_epilogue)
+            return (out * cot).sum()
+
+        def plain(x, w, g, b, res):
+            y = x @ w
+            mean = y.mean(0)
+            var = ((y - mean) ** 2).mean(0)
+            out = g * (y - mean) * jax.lax.rsqrt(var + 1e-5) + b + res
+            return (jnp.maximum(out, 0.0) * cot).sum()
+
+        got = jax.grad(fused, argnums=(0, 1, 2, 3, 4))(x, w, g, b, res)
+        want = jax.grad(plain, argnums=(0, 1, 2, 3, 4))(x, w, g, b, res)
+        for name, a, e in zip("x w gamma beta residual".split(), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       rtol=2e-3, atol=2e-3, err_msg=name)
+
+    def test_bn_apply_without_residual_is_differentiable(self):
+        rng = np.random.RandomState(8)
+        y = jnp.asarray(rng.randn(40, 128).astype(np.float32))
+        sc = jnp.asarray(rng.rand(128).astype(np.float32) + 0.5)
+        sh = jnp.asarray(rng.randn(128).astype(np.float32))
+        got = jax.grad(lambda *a: bn_apply_relu(*a).sum(),
+                       argnums=(0, 1, 2))(y, sc, sh)
+        want = jax.grad(lambda y, a, b: jnp.maximum(y * a + b, 0.0).sum(),
+                        argnums=(0, 1, 2))(y, sc, sh)
+        for a, e in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       rtol=1e-5, atol=1e-5)

@@ -194,11 +194,20 @@ class TestEligibility:
         assert jax.default_backend() != "tpu"
         assert not paged_flash_eligible(head_dim=64, page_size=16)
 
-    def test_tpu_override_would_dispatch(self):
+    def test_tpu_override_would_dispatch(self, use_mesh):
+        use_mesh(jax.devices()[:1])
         assert paged_flash_eligible(head_dim=64, page_size=16,
                                     backend="tpu")
 
-    def test_alignment_and_flag_gate(self):
+    def test_multi_device_mesh_refuses(self, use_mesh):
+        # JAX will not lower a Mosaic kernel inside a jit that spans more
+        # than one device, whichever axis is sharded — here data=2
+        use_mesh(jax.devices()[:2])
+        assert not paged_flash_eligible(head_dim=64, page_size=16,
+                                        backend="tpu")
+
+    def test_alignment_and_flag_gate(self, use_mesh):
+        use_mesh(jax.devices()[:1])
         assert not paged_flash_eligible(head_dim=12, backend="tpu")
         assert not paged_flash_eligible(page_size=12, backend="tpu")
         set_flags({"paged_flash": False})

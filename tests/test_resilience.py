@@ -69,9 +69,9 @@ def _clean_resilience_state():
 
 
 # ---------------------------------------------------------------------------
-# error taxonomy
+# error classification
 # ---------------------------------------------------------------------------
-class TestTransientTaxonomy:
+class TestTransientClassification:
     def test_typed_classification(self):
         assert is_transient(TransientDeviceError("x"))
         assert is_transient(UnavailableError("x"))
@@ -79,9 +79,35 @@ class TestTransientTaxonomy:
         assert not is_transient(ValueError("x"))
 
     def test_runtime_message_patterns(self):
-        assert is_transient(RuntimeError("RESOURCE_EXHAUSTED: hbm oom"))
+        assert is_transient(RuntimeError("RESOURCE_EXHAUSTED: too many "
+                                         "concurrent requests"))
         assert is_transient(OSError("Connection reset by peer"))
         assert not is_transient(RuntimeError("INVALID_ARGUMENT: bad shape"))
+
+    @pytest.mark.parametrize("msg", [
+        # what XLA:TPU says when a program does not fit the chip's HBM
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 16.17G of 15.75G hbm. Exceeded "
+        "hbm capacity by 428.41M.",
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 1.20G. That was not possible. There are 410.2M free.",
+        "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+        "123456 bytes.",
+    ])
+    def test_out_of_memory_is_not_retried(self, msg):
+        # the same program on the same chip fails the same way: a retry
+        # only re-pays the compile before failing again
+        assert not is_transient(RuntimeError(msg))
+        policy = RetryPolicy(max_attempts=3, backoff_ms=0.0)
+        calls = []
+
+        def oom():
+            calls.append(1)
+            raise RuntimeError(msg)
+
+        with pytest.raises(RuntimeError):
+            policy.call(oom)
+        assert len(calls) == 1
 
     def test_wrap_transient_chains_cause(self):
         src = RuntimeError("UNAVAILABLE: socket closed")

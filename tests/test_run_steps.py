@@ -335,6 +335,66 @@ class TestCompileCache:
         assert not [d for d in mon.diagnostics() if d.rule == "R402"]
 
 
+class TestOneChipPlacement:
+    def test_second_window_does_not_recompile_on_a_one_device_mesh(
+            self, use_mesh):
+        """On one chip a meta_parallel model's ``constrain`` calls say
+        nothing — but used to hand the chained step's outputs back under a
+        mesh sharding its single-device inputs did not have, so the second
+        window paid a second, placement-specialised XLA compile (minutes at
+        BERT-base width).  jax.monitoring sees what the trace counter
+        cannot."""
+        import jax
+
+        import paddle_tpu as paddle
+        from paddle_tpu import optimizer as popt
+        from paddle_tpu.models import BertForPretraining, bert_tiny
+        from paddle_tpu.static.builders import layer_op
+        from paddle_tpu.static.graph import record_call
+
+        compiled = []
+
+        def on_compile(name, secs, **kw):
+            if name.endswith("backend_compile_duration"):
+                compiled.append(kw.get("fun_name"))
+
+        use_mesh(jax.devices()[:1])
+        jax.monitoring.register_event_duration_secs_listener(on_compile)
+        try:
+            paddle.seed(0)
+            cfg = bert_tiny()
+            net = BertForPretraining(cfg)
+            main, startup = fluid.Program(), fluid.Program()
+            with fluid.program_guard(main, startup):
+                ids_v = fluid.data("input_ids", [4, 8], "int32")
+                mlm_y = fluid.data("mlm_labels", [4, 8], "int32")
+                nsp_y = fluid.data("nsp_labels", [4, 1], "int32")
+                mlm, nsp = layer_op(net, ids_v, prefix="bert")
+                loss = record_call(net.loss, mlm, nsp, mlm_y, nsp_y,
+                                   prefix="loss")
+                popt.SGD(learning_rate=0.1).minimize(loss)
+            rng = np.random.RandomState(0)
+            ids = rng.randint(0, cfg.vocab_size, (4, 8)).astype(np.int32)
+            feeds = {"input_ids": ids, "mlm_labels": ids,
+                     "nsp_labels": rng.randint(0, 2, (4, 1)).astype(np.int32)}
+            exe = fluid.Executor()
+            exe.run(startup)
+
+            def window():
+                return exe.run_steps(main, feed=feeds, fetch_list=[loss],
+                                     iterations=2,
+                                     constant_feeds=tuple(feeds))
+
+            window()
+            assert "jit(chain)" in compiled
+            del compiled[:]
+            window()
+            window()
+            assert "jit(chain)" not in compiled, compiled
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_compile)
+
+
 class TestDataLoaderSuperbatch:
     def test_superbatch_stacks_k_batches(self):
         from paddle_tpu.io import DataLoader

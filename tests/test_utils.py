@@ -84,3 +84,83 @@ class TestCompatSysconfig:
         assert any(f.endswith(".cc") for f in os.listdir(inc))
         lib = paddle.sysconfig.get_lib()
         assert os.path.isdir(lib)  # must exist even before any native build
+        # everything the program generates lives under the one fixed root
+        assert lib == os.path.join(paddle.sysconfig.cache_root(), "native")
+
+
+class TestCompilationCachePlacement:
+    """Where the persistent XLA cache lives (sysconfig): the deployment's
+    ``JAX_COMPILATION_CACHE_DIR`` when set — and then NO directory is set
+    from code — else one fixed path inside the checkout."""
+
+    @pytest.fixture
+    def restore_cache_config(self):
+        import jax
+
+        from paddle_tpu import sysconfig
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes")
+        prev = {k: getattr(jax.config, k) for k in keys}
+        prev_enabled = sysconfig._pcc_enabled
+        yield
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        sysconfig._pcc_enabled = prev_enabled
+        from jax.experimental.compilation_cache import (
+            compilation_cache as cc)
+
+        cc.reset_cache()  # do not leave the suite writing a disk cache
+
+    def test_env_var_set_means_no_directory_from_code(
+            self, monkeypatch, tmp_path, restore_cache_config):
+        import jax
+
+        from paddle_tpu import sysconfig
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert sysconfig.enable_persistent_compilation_cache() == str(
+            tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+    def test_unset_is_the_fixed_in_checkout_path(
+            self, monkeypatch, restore_cache_config):
+        import os
+        import subprocess
+        import sys
+
+        import jax
+
+        from paddle_tpu import sysconfig
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".cache", "xla")
+        assert sysconfig.enable_persistent_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        # ... and identical in another process (the directory is part of
+        # JAX's cache key: a path that moved would never hit)
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["JAX_PLATFORMS"] = "cpu"
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import sys; sys.path.insert(0, sys.argv[1]); "
+             "from paddle_tpu import sysconfig; "
+             "print(sysconfig.enable_persistent_compilation_cache())",
+             repo],
+            env=env, capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(repo))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == want
+
+    def test_cache_dirs_are_git_ignored(self):
+        import os
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, ".gitignore")) as f:
+            ignored = {line.strip() for line in f}
+        assert ".cache/" in ignored
