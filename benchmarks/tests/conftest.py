@@ -1,0 +1,10 @@
+"""The benchmark's own tests run on the CPU (``python -m pytest
+benchmarks/tests -q``); the platform is pinned here, before jax is
+imported, the way tests/conftest.py pins it for the repo's tests."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
