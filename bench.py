@@ -491,14 +491,14 @@ def bench_gpt_generate():
         ) as eng:
             eng.warmup()
             stats = _replay(eng, trace)
-            return stats["tokens_per_sec"], stats["mean_ms"], eng.stats()
+            return stats["tokens_per_sec"], stats["mean_ms"]
 
-    legacy_tps, legacy_lat, _ = run(False)
-    tps, lat_ms, _ = run(True)
+    legacy_tps, legacy_lat = run(False)
+    tps, lat_ms = run(True)
     # paged KV + speculative decoding on the identical workload (default
     # pool = the same HBM the dense ring uses; no shared prefixes here,
     # so this isolates the paging/speculation overhead-vs-win alone)
-    paged_tps, paged_lat, psnap = run(True, paged=True)
+    paged_tps, paged_lat = run(True, paged=True)
     return _emit("gpt_generate_tokens_per_sec", round(tps, 1), "tok/s",
                  tps / legacy_tps,
                  legacy_tokens_per_sec=round(legacy_tps, 1),
@@ -506,14 +506,6 @@ def bench_gpt_generate():
                  mean_latency_ms=round(float(lat_ms), 1),
                  legacy_mean_latency_ms=round(float(legacy_lat), 1),
                  paged_mean_latency_ms=round(float(paged_lat), 1),
-                 # last-step latency breakdown (serving/metrics.py gauges:
-                 # measured step wall time split by the engine's
-                 # bandwidth-roofline attention share) — the number the
-                 # paged-flash kernel moves on TPU
-                 paged_decode_attn_ms=round(
-                     float(psnap.get("decode_attn_ms", 0.0)), 3),
-                 paged_decode_rest_ms=round(
-                     float(psnap.get("decode_rest_ms", 0.0)), 3),
                  requests=len(trace), new_tokens=trace.total_new_tokens,
                  method="continuous_batching_vs_legacy")
 

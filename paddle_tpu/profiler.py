@@ -63,18 +63,21 @@ def register_summary_section(render_fn, on_reset=None) -> None:
 
 class RecordEvent:
     """Annotate a region: shows up named in the device trace and in the
-    host event table.  Context manager or decorator.
+    host event table.  Context manager or decorator.  Keyword arguments
+    become the trace event's metadata; the host table keys on ``name``
+    alone, so keep what varies (a bucket, a count) out of the name.
 
     Parity: platform/profiler.h:121 RecordEvent.
     """
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **args):
         self.name = name
+        self._args = args
         self._ann = None
         self._t0 = 0.0
 
     def __enter__(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self._args)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -102,7 +105,7 @@ class RecordEvent:
 
     def __call__(self, fn):
         def wrapped(*a, **k):
-            with RecordEvent(self.name):
+            with RecordEvent(self.name, **self._args):
                 return fn(*a, **k)
 
         return wrapped

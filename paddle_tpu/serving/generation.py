@@ -85,9 +85,10 @@ from ..resilience import CircuitBreaker, RetryPolicy
 from ..resilience import retry as _retry_mod
 from ..resilience.faults import fault_point
 from .batcher import MicroBatcher, Request
-from .metrics import (HANDOFF_COUNTERS, LORA_COUNTERS, MOE_COUNTERS,
-                      PAGED_COUNTERS, QUANT_COUNTERS, ServingMetrics,
-                      SLOT_COUNTERS, TENANCY_COUNTERS)
+from .metrics import (HANDOFF_COUNTERS, LOOP_COUNTERS, LORA_COUNTERS,
+                      MOE_COUNTERS, PAGED_COUNTERS, QUANT_COUNTERS,
+                      SLOT_COUNTERS, TENANCY_COUNTERS, LoopClock,
+                      ServingMetrics)
 from .paging import PagePool
 
 __all__ = ["GenerationEngine", "KVHandoff"]
@@ -301,7 +302,7 @@ class GenerationEngine:
                 "tenancy requires paged KV (budget preemption rides the "
                 "deterministic paged-pool release path)")
         extra = (SLOT_COUNTERS + PAGED_COUNTERS + HANDOFF_COUNTERS
-                 if self._paged else SLOT_COUNTERS)
+                 + LOOP_COUNTERS if self._paged else SLOT_COUNTERS)
         if self._moe_experts:
             extra = extra + MOE_COUNTERS
         if self._quantized:
@@ -524,7 +525,11 @@ class GenerationEngine:
         (the extra ``[B, 1]`` no-draft fast trace), ``+ 1`` legacy.
         Role-specialized engines add exactly one more: the page-export
         trace (``role='prefill'``) or the page-import trace
-        (``role='decode'``); default-role engines trace neither."""
+        (``role='decode'``); default-role engines trace neither.  On a
+        global mesh of several devices the continuous and paged counts are
+        one higher: a step's outputs carry the mesh's sharding, so the
+        host-built fresh state of ``_init_state``/``_init_pool`` is another
+        abstract input and the step traces once more for it."""
         B = self._batch
         if self._paged:
             # placement discipline as below: ids/positions/pos_map/table
@@ -682,30 +687,6 @@ class GenerationEngine:
         self._traces.update(snap)
         plan_space.apply_decode_schedule(winner)
         self._overlap_schedule = winner
-
-    def _decode_attn_frac(self) -> float:
-        """Attention's share of one decode step, from the bandwidth
-        roofline: bytes attention must move per step (every live slot's
-        logical KV view, plus the f32 scale planes on quantized pools)
-        over those plus the weight bytes the rest of the step streams.
-        Decode is memory-bound, so the byte ratio tracks the time ratio
-        well enough to split the measured step wall time into the
-        ``decode_attn_ms`` / ``decode_rest_ms`` gauges.  Computed once —
-        pool geometry and weights are fixed after warmup."""
-        frac = getattr(self, "_attn_frac", None)
-        if frac is None:
-            cfg = self._model.gpt.cfg
-            H = cfg.num_heads
-            hd = cfg.hidden_size // H
-            qdtype = self._kv_qdtype()
-            per_entry = hd * np.dtype(qdtype or np.float32).itemsize
-            if qdtype is not None:
-                per_entry += 4  # the per-(token, head) f32 dequant scale
-            kv = cfg.num_layers * 2 * self._batch * H * self._C * per_entry
-            w = sum(int(x.nbytes)
-                    for x in jax.tree_util.tree_leaves(self._params))
-            frac = self._attn_frac = kv / max(kv + w, 1)
-        return frac
 
     # -- MoE routing-health tap --------------------------------------------
     def _moe_tap(self, fn):
@@ -1118,9 +1099,16 @@ class GenerationEngine:
         accepted tokens (per-slot trailing acceptance) clear break-even —
         on accelerators the two traces cost about the same so the bar is
         ~0; on a compute-bound host the loop turns selective by itself.
+
+        On the record: a :class:`LoopClock` cuts every iteration into the
+        ``serve/*`` phases of ``metrics.LOOP_PHASES`` (trace spans on this
+        thread and ``loop_us_*`` counters), ``ph.counts`` gathers the
+        iteration's work counts for the one ``metrics.add`` of its
+        ``flush``, each counted once its dispatch has returned.
         """
         q = self._batcher
         B, C, page = self._batch, self._C, self._page
+        G = C // page
         k_max, eos = self._spec_k, self._eos
         T = 1 + k_max
         max_restarts = (max(int(flag("transient_max_retries")) - 1, 0)
@@ -1169,9 +1157,22 @@ class GenerationEngine:
             self.metrics.incr("preempted")
             return v
 
+        def poll(n, wait_s):
+            # a blocking poll (nothing live, nothing waiting) is idle time
+            if wait_s > 0:
+                ph.to("wait")
+            got = [(r, 0) for r in q.poll(n, wait_s=wait_s)]
+            if wait_s > 0:
+                ph.to("sched")
+            return got
+
+        ph = LoopClock(self.metrics)
+        cnt = ph.counts
         try:
             while True:
                 try:
+                    ph.to("sched")
+                    force_pub = False
                     closing = q.closing
                     if closing and not q.drain_on_close:
                         err = UnavailableError(
@@ -1238,8 +1239,7 @@ class GenerationEngine:
                                 wait = (0.05 if not live and not cand
                                         else 0.0)
                                 blocked_wait = wait > 0
-                                cand += [(r, 0)
-                                         for r in q.poll(want, wait_s=wait)]
+                                cand += poll(want, wait)
                         else:
                             # weighted-fair admission considers ALL waiting
                             # requests (carry + a widened queue window) so
@@ -1260,8 +1260,7 @@ class GenerationEngine:
                             wait = (0.05 if not live and not cand else 0.0)
                             blocked_wait = wait > 0
                             if want > 0:
-                                cand += [(r, 0)
-                                         for r in q.poll(want, wait_s=wait)]
+                                cand += poll(want, wait)
                             cand, deferred = ten.schedule(
                                 cand,
                                 tenant_of=lambda rc: self._tenant_of(rc[0]),
@@ -1302,6 +1301,7 @@ class GenerationEngine:
                             budget_pages -= need
                     n_adopted = 0
                     if take:
+                        ph.to("admit.host", engine=self.name, rows=len(take))
                         if cache is None:
                             cache = self._init_pool()
                         now = time.monotonic()
@@ -1358,10 +1358,8 @@ class GenerationEngine:
                                 pos[i] = -1
                                 aidsv[i] = -1
                                 n_adevicted += 1
-                        if n_adopted:
-                            self.metrics.incr("admitted", n_adopted)
-                        if n_adevicted:
-                            self.metrics.incr("evicted", n_adevicted)
+                        cnt["admitted"] += n_adopted
+                        cnt["evicted"] += n_adevicted
                     if take and pre:
                         Sb = self._buckets[max(r.bucket
                                                for (r, _), _ in pre)]
@@ -1397,16 +1395,17 @@ class GenerationEngine:
                                 to_register.append((key, i, prompt[:plen]))
                         dispatch_cow(cow_pairs)
                         fault_point("serving.decode")
-                        with profiler.RecordEvent(
-                                f"{self.name}/admit[{Sb}]"):
-                            first, cache = self._padmit(
-                                self._params, self._buffers,
-                                jnp.asarray(ids), jnp.asarray(pp),
-                                jnp.asarray(pool.pos_map.copy()),
-                                jnp.asarray(pool.table.copy()),
-                                jnp.asarray(lens), cache,
-                                self._aids_arg(aidsv))
-                            host_first = np.asarray(first)  # serial harvest
+                        ph.to("admit.device", engine=self.name, bucket=Sb,
+                              rows=len(admitted))
+                        first, cache = self._padmit(
+                            self._params, self._buffers,
+                            jnp.asarray(ids), jnp.asarray(pp),
+                            jnp.asarray(pool.pos_map.copy()),
+                            jnp.asarray(pool.table.copy()),
+                            jnp.asarray(lens), cache,
+                            self._aids_arg(aidsv))
+                        host_first = np.asarray(first)  # serial harvest
+                        ph.to("admit.host", engine=self.name)
                         tr = _tracing._active
                         if tr is not None:
                             adm_ms = (time.monotonic() - now) * 1e3
@@ -1427,9 +1426,13 @@ class GenerationEngine:
                             pool.register_prefix(key, i, toks)
                         now = time.monotonic()
                         n_evicted = 0
-                        for _, i in admitted:
+                        for r, i in admitted:
                             s = slots[i]
                             t = int(host_first[i])
+                            cnt["admit_tokens"] += int(lens[i])
+                            cnt["queue_wait_us"] += int(
+                                (s["t0"] - r.enqueue_t) * 1e6)
+                            cnt["ttft_us"] += int((now - r.enqueue_t) * 1e6)
                             if s.get("handoff"):
                                 # produce: export the prompt's pages while
                                 # they are still mapped and resolve with
@@ -1474,10 +1477,10 @@ class GenerationEngine:
                                 pos[i] = -1
                                 aidsv[i] = -1
                                 n_evicted += 1
-                        self.metrics.incr("admitted", len(admitted))
-                        self.metrics.incr("batches")
-                        if n_evicted:
-                            self.metrics.incr("evicted", n_evicted)
+                        cnt.update(admitted=len(admitted), batches=1,
+                                   evicted=n_evicted, admit_steps=1,
+                                   admit_rows=len(admitted),
+                                   admit_token_slots=B * Sb)
                     if take:
                         live = [i for i in range(B) if slots[i] is not None]
                         if ten is not None:
@@ -1526,6 +1529,7 @@ class GenerationEngine:
                     # ---- unified decode/verify step (serialized) ----
                     dispatched = bool(take)
                     if live:
+                        ph.to("decode.pack")
                         # pass 1 — propose: drafts only while the ring has
                         # spare slots (once positions reach C, every slot
                         # holds a live window position, and a multi-token
@@ -1615,33 +1619,27 @@ class GenerationEngine:
                         Td = (T if any(slots[i] is not None
                                        and slots[i].get("_prop")
                                        for i in live) else 1)
-                        t_step = time.monotonic()
-                        with profiler.RecordEvent(
-                                f"{self.name}/decode.step"):
-                            out, cache = self._step(
-                                self._params, self._buffers,
-                                self._pack_step(
-                                    ids[:, :Td], pp[:, :Td],
-                                    pool.pos_map, pool.table,
-                                    aidsv), cache)
-                            host = np.asarray(out)  # serial harvest
-                        dt = (time.monotonic() - t_step) * 1e3
+                        packed = self._pack_step(ids[:, :Td], pp[:, :Td],
+                                                 pool.pos_map, pool.table,
+                                                 aidsv)
+                        # only live slots map pages: release() clears a row
+                        n_pages = int(np.count_nonzero(pool.table >= 0))
+                        ph.to("decode.device", engine=self.name,
+                              live=len(live), columns=Td)
+                        out, cache = self._step(self._params, self._buffers,
+                                                packed, cache)
+                        host = np.asarray(out)  # serial harvest
+                        dt = ph.to("harvest") / 1e6
+                        cnt.update(decode_steps=1, live_slot_steps=len(live),
+                                   kv_pages_live_steps=n_pages,
+                                   kv_page_slots_steps=B * G)
                         if Td == 1:
                             it_fast = (dt if it_fast is None
                                        else 0.8 * it_fast + 0.2 * dt)
                         else:
                             it_wide = (dt if it_wide is None
                                        else 0.8 * it_wide + 0.2 * dt)
-                        # per-step attention-vs-rest breakdown gauges on
-                        # the ("serving", ·) bus — the paged-flash-decode
-                        # kernel's win shows up in Prometheus/profiler
-                        # dashboards, not just bench (see ServingMetrics)
-                        frac = self._decode_attn_frac()
                         self.metrics.set_gauge("decode_step_ms", dt)
-                        self.metrics.set_gauge("decode_attn_ms", dt * frac)
-                        self.metrics.set_gauge("decode_rest_ms",
-                                               dt * (1.0 - frac))
-                        self.metrics.incr("decode_steps")
                         self._note_quant_step()
                         self.metrics.observe_occupancy(len(live) / B)
                         if self._lora_cap:
@@ -1666,9 +1664,8 @@ class GenerationEngine:
                             for j in range(a + 1, len(prop) + 1):
                                 pool.pos_map[i, (p + j) % C] = -1
                             if prop:
-                                self.metrics.incr("spec_drafted",
-                                                  len(prop))
-                                self.metrics.incr("spec_accepted", a)
+                                cnt["spec_drafted"] += len(prop)
+                                cnt["spec_accepted"] += a
                                 # trailing acceptance estimate feeding
                                 # the wide-step break-even decision
                                 s["spec_ema"] = (
@@ -1715,15 +1712,18 @@ class GenerationEngine:
                                     tr.record("slot/evict", ctx, now,
                                               ev_ms, kind="evict",
                                               args={"engine": self.name})
-                            self.metrics.incr("evicted", n_evicted)
-                            self.metrics.publish()
+                            cnt["evicted"] += n_evicted
+                            force_pub = True
                         dispatched = True
 
                     if not dispatched and not blocked_wait:
+                        ph.to("wait")
                         time.sleep(0.002)  # deferred/idle: don't spin hot
 
+                    ph.to("publish")
                     now = time.monotonic()
-                    if now - last_pub >= 0.1:
+                    due = now - last_pub >= 0.1
+                    if due:
                         last_pub = now
                         nlive = sum(1 for s in slots if s is not None)
                         age = q.oldest_wait_ms()
@@ -1747,8 +1747,11 @@ class GenerationEngine:
                         self.metrics.set_counter("compiles",
                                                  self.compile_count)
                         self._emit_tenancy(carry)
+                    ph.flush()  # counters first: the snapshot holds them
+                    if due or force_pub:
                         self.metrics.publish()
                 except Exception as e:
+                    ph.flush()
                     # Device failure mid-flight: same restart contract as
                     # the dense loop, plus fresh page accounting — the
                     # pool metadata and device pool are rebuilt together
@@ -1776,6 +1779,8 @@ class GenerationEngine:
                         self.metrics.incr("restarts")
                     self.metrics.publish()
         finally:
+            ph.to(None)
+            ph.flush()
             q.consumer_done()
 
     def _slot_loop(self):
@@ -1788,6 +1793,9 @@ class GenerationEngine:
         flight while the host books the previous one (double buffering).
         Free slots ride along as position ``-1`` rows: they write nothing,
         attend to nothing, and their argmax garbage is never harvested.
+
+        Not on the record as :meth:`_paged_loop` is (no benchmark cell
+        runs this loop): no phases, no ``LOOP_COUNTERS``.
         """
         q = self._batcher
         B = self._batch

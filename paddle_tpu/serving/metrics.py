@@ -19,13 +19,18 @@ starved_steps_after_warm`` plus per-step gauges (``set_gauge``) such as
 ``queue_age_ms`` (age of the oldest queued request).  Rule S603 reads
 the starvation counters.
 
-The paged decode loop also publishes the per-step latency-breakdown
-gauges ``decode_step_ms`` (measured step wall time), ``decode_attn_ms``
-and ``decode_rest_ms`` — the measured step time split by the engine's
-bandwidth-roofline attention share (KV bytes vs weight bytes; see
-``GenerationEngine._decode_attn_frac``), so the paged-flash-decode
-kernel's win is visible on Prometheus/profiler dashboards, not just in
-bench lines.
+The paged decode loop publishes the gauge ``decode_step_ms`` (the last
+step's measured device call) and the ``LOOP_COUNTERS`` family: its wall
+time by phase in integer microseconds (``loop_us_<phase>``; the phases
+tile every iteration and sum to ``loop_us_total``), the work it
+dispatched (admission rows/tokens against the ``[B, bucket]`` token
+slots of the prefill program, live slots and live page-table entries
+against ``B`` and ``B x G`` per decode step) and the summed queue wait
+and time to first token of the requests it admitted.  Beside each sum,
+``loop_max_us_<phase>`` is the phase's longest single interval since the
+engine started, so that a stall shows as one call of one phase and not
+as a mean that crept.  The same phases are ``serve/<phase>`` spans on
+the loop's thread in a profiler trace (:class:`LoopClock`).
 
 Paged-KV engines (``FLAGS_paged_kv``) add the page-accounting family:
 counters ``cow_copies`` (copy-on-write page copies), ``spec_drafted`` /
@@ -52,12 +57,14 @@ from __future__ import annotations
 import collections
 import math
 import threading
-from typing import Deque, Dict, Optional, Sequence
+import time
+from typing import Deque, Dict, Mapping, Optional, Sequence
 
+from .. import profiler
 from ..framework import trace_events
 from ..framework.locking import OrderedLock
 
-__all__ = ["ServingMetrics"]
+__all__ = ["ServingMetrics", "LoopClock"]
 
 #: counter keys every snapshot carries (zero-initialized)
 _COUNTERS = ("requests", "completed", "shed", "expired", "errors",
@@ -67,6 +74,34 @@ _COUNTERS = ("requests", "completed", "shed", "expired", "errors",
 #: slot-scheduler counters (continuous batching; see ``extra_counters``)
 SLOT_COUNTERS = ("admitted", "evicted", "decode_steps", "restarts",
                  "starved_steps", "starved_steps_after_warm")
+
+#: the phases that tile one iteration of the paged decode loop, in the
+#: order they run: ``sched`` (close check, tenancy, expiry, queue poll,
+#: choosing what to admit), ``admit.host`` (page accounting, prefill
+#: inputs, CoW dispatch, first-token book-keeping), ``admit.device`` (the
+#: prefill call until its first tokens are on the host), ``decode.pack``
+#: (drafts, page growth, step inputs), ``decode.device`` (the step call
+#: until its tokens are on the host), ``harvest`` (accept/finish per
+#: slot), ``publish``, ``wait`` (sleeps and blocking polls with nothing
+#: live)
+LOOP_PHASES = ("sched", "admit.host", "admit.device", "decode.pack",
+               "decode.device", "harvest", "publish", "wait")
+_PHASE_KEY = {p: "loop_us_" + p.replace(".", "_")
+              for p in (*LOOP_PHASES, "total")}
+
+_MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
+
+#: paged-decode-loop counters (see the module docstring): wall time by
+#: phase and each phase's longest interval, work counted once its
+#: dispatch has returned (``admit_token_slots`` is ``B x bucket`` per
+#: prefill call and ``kv_page_slots_steps`` ``B x G`` per decode step:
+#: the denominators of the useful shares), and the summed per-request
+#: times whose count is ``admit_rows``
+LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
+                 "admit_steps", "admit_rows", "admit_tokens",
+                 "admit_token_slots", "live_slot_steps",
+                 "kv_pages_live_steps", "kv_page_slots_steps",
+                 "queue_wait_us", "ttft_us")
 
 #: page-accounting counters (paged KV mode; see ``extra_counters``)
 PAGED_COUNTERS = ("cow_copies", "spec_drafted", "spec_accepted",
@@ -146,6 +181,14 @@ class ServingMetrics:
     def incr(self, key: str, n: int = 1):
         with self._lock:
             self._counters[key] = self._counters.get(key, 0) + n
+
+    def add(self, counts: Mapping[str, int]):
+        """Advance several counters under one lock acquisition (the
+        decode loop's per-iteration batch)."""
+        with self._lock:
+            c = self._counters
+            for key, n in counts.items():
+                c[key] = c.get(key, 0) + int(n)
 
     def set_counter(self, key: str, value: int):
         with self._lock:
@@ -267,3 +310,67 @@ class ServingMetrics:
         if extra:
             snap.update(extra)
         trace_events.notify(("serving", self.name), snap)
+
+
+class LoopClock:
+    """Cuts a serving loop's iterations into contiguous phases.
+
+    :meth:`to` closes the open phase and opens the next with ONE clock
+    read, so the phases tile the loop's time.  Each phase is a
+    ``profiler.RecordEvent`` named ``serve/<phase>`` (a
+    ``TraceAnnotation``: on the device trace's clock, on the loop's
+    thread; keyword arguments become the event's metadata) and the same
+    interval under ``loop_us_<phase>``; ``loop_us_total`` is the time any
+    phase was open.  :meth:`flush`, once an iteration, adds them and
+    whatever the loop put into ``counts`` to the metrics under one lock;
+    the open phase stays open across it.  Sub-microsecond remainders
+    carry over, so the integer counters do not drift from the clock.
+    ``loop_max_us_<phase>`` is the phase's longest single interval so far
+    (a flush does not cut it); the counter advances by what that record
+    rose, so its delta over a window says how far an interval inside the
+    window outlasted every one before it.  A request's future resolves
+    inside an iteration, so the counters trail it by the rest of that
+    iteration."""
+
+    def __init__(self, metrics: "ServingMetrics"):
+        self._metrics = metrics
+        self.counts: Dict[str, int] = collections.Counter()
+        self._ns = dict.fromkeys(_PHASE_KEY, 0)
+        self._open_ns = 0  # the open phase's interval up to the last flush
+        self._max_us = dict.fromkeys(LOOP_PHASES, 0)
+        self._phase: Optional[str] = None
+        self._span: Optional[profiler.RecordEvent] = None
+        self._t = time.monotonic_ns()
+
+    def _lap(self) -> int:
+        now = time.monotonic_ns()
+        dt, self._t = now - self._t, now
+        if self._phase is not None:
+            self._ns[self._phase] += dt
+            self._ns["total"] += dt
+        return dt
+
+    def to(self, phase: Optional[str], **args) -> int:
+        """Open ``phase`` (``None``: only close the open one); returns the
+        nanoseconds since the last phase change or flush."""
+        dt = self._lap()
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            us = (self._open_ns + dt) // 1000
+            if us > self._max_us[self._phase]:
+                self.counts[_MAX_KEY[self._phase]] += \
+                    us - self._max_us[self._phase]
+                self._max_us[self._phase] = us
+        self._phase, self._span, self._open_ns = phase, None, 0
+        if phase is not None:
+            self._span = profiler.RecordEvent("serve/" + phase, **args)
+            self._span.__enter__()
+        return dt
+
+    def flush(self):
+        self._open_ns += self._lap()
+        c = self.counts
+        for p, ns in self._ns.items():
+            c[_PHASE_KEY[p]], self._ns[p] = divmod(ns, 1000)
+        self._metrics.add(c)
+        c.clear()

@@ -1,0 +1,279 @@
+"""The paged decode loop on the record (serving/generation.py
+``_paged_loop``, serving/metrics.py ``LOOP_COUNTERS``/``LoopClock``): the
+``loop_us_*`` phases tile the loop's time and ``loop_max_us_*`` keep each
+phase's longest interval, the work counters count what was dispatched, the
+summed request times bracket what the caller saw, and in a profiler trace
+the fixed ``serve/*`` span names lie on the engine's thread.  Served tokens
+stay the uncached greedy reference's.
+"""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.serving import GenerationEngine
+from paddle_tpu.serving.metrics import (LOOP_COUNTERS, LOOP_PHASES, LoopClock,
+                                        ServingMetrics)
+
+B, BUCKET, PAGE, CACHE = 2, 16, 8, 64
+PROMPTS = [(np.arange(10) * 5 + 2) % 97, np.arange(3) % 97,
+           (np.arange(6) * 3) % 97, (np.arange(4) * 7 + 1) % 97,
+           (np.arange(12) * 11 + 3) % 97]
+BUDGETS = [14, 3, 4, 5, 3]
+PHASE_KEYS = [k for k in LOOP_COUNTERS
+              if k.startswith("loop_us_") and k != "loop_us_total"]
+CLOCK_KEYS = tuple(k for k in LOOP_COUNTERS if k.startswith("loop_"))
+
+
+@pytest.fixture(scope="module")
+def model():
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    pt.seed(4321)
+    m = GPTForCausalLM(GPTConfig(vocab_size=97, hidden_size=32, num_layers=2,
+                                 num_heads=4, max_position=CACHE,
+                                 dropout=0.0))
+    m.eval()
+    return m
+
+
+def _ref_greedy(model, prompt, n):
+    import jax.numpy as jnp
+
+    # the existing paged tests' uncached forward, at ONE padded shape: the
+    # model is causal, so what follows position len(ids) - 1 cannot move it
+    ids, outs = list(map(int, prompt)), []
+    for _ in range(n):
+        padded = np.zeros((1, 32), np.int32)
+        padded[0, :len(ids)] = ids
+        logits = np.asarray(model(jnp.asarray(padded)))[0]
+        outs.append(int(np.argmax(logits[len(ids) - 1])))
+        ids.append(outs[-1])
+    return outs
+
+
+def _settled(eng, n_evicted):
+    """The engine's snapshot once the loop has flushed the iteration that
+    finished the last request (counters trail a resolved future by the
+    rest of its iteration)."""
+    for _ in range(500):
+        snap = eng.metrics.snapshot()
+        if snap["evicted"] >= n_evicted:
+            return snap
+        time.sleep(0.01)
+    raise AssertionError("the loop never flushed its last iteration")
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
+                          cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                          speculative_k=0, name="loopctr") as eng:
+        yield eng
+
+
+@pytest.fixture(scope="module")
+def served(engine):
+    """One staggered run, no shared prefix: more requests than slots, so
+    admissions fall between decode steps."""
+    before = engine.metrics.snapshot()
+    engine.warmup()
+    sent, done = [], {}
+
+    def submit(k):
+        sent.append(time.monotonic())
+        f = engine.submit(PROMPTS[k], BUDGETS[k])
+        f.add_done_callback(lambda _: done.setdefault(k, time.monotonic()))
+        return f
+
+    futs = [submit(0), submit(1)]
+    for k in range(2, len(PROMPTS)):
+        time.sleep(0.02)
+        futs.append(submit(k))
+    outs = [f.result(120) for f in futs]
+    return {"before": before, "snap": _settled(engine, len(futs)),
+            "latency_us": sum((done[k] - t) * 1e6
+                              for k, t in enumerate(sent)),
+            "outs": outs}
+
+
+def test_every_loop_counter_is_a_zero_int_before_the_first_request(served):
+    for k in LOOP_COUNTERS:
+        assert type(served["before"][k]) is int, k
+    assert all(served["before"][k] == 0 for k in LOOP_COUNTERS
+               if k not in CLOCK_KEYS)
+    assert all(type(served["snap"][k]) is int for k in LOOP_COUNTERS)
+
+
+def test_phases_sum_to_the_total_within_one_percent(served):
+    s = served["snap"]
+    assert s["loop_us_total"] > 0
+    assert abs(sum(s[k] for k in PHASE_KEYS) - s["loop_us_total"]) \
+        <= 0.01 * s["loop_us_total"]
+    for k in ("loop_us_admit_device", "loop_us_decode_device",
+              "loop_us_harvest", "loop_us_wait"):
+        assert s[k] > 0, k
+
+
+def test_served_tokens_are_the_uncached_greedy_reference(model, served):
+    for out, p, b in zip(served["outs"], PROMPTS, BUDGETS):
+        assert out.tolist() == _ref_greedy(model, p, b)
+
+
+def test_a_phases_longest_interval_lies_between_its_mean_and_its_sum(served):
+    s = served["snap"]
+    calls = {"admit_device": s["admit_steps"],
+             "decode_device": s["decode_steps"]}
+    for k in PHASE_KEYS:
+        longest = s[k.replace("loop_us_", "loop_max_us_")]
+        assert 0 <= longest <= s[k], k
+        n = calls.get(k[len("loop_us_"):])
+        if n:  # one interval a call: the longest is no shorter than the mean
+            assert longest >= s[k] // n, k
+
+
+def test_live_slot_steps_is_bounded_by_the_slots(served):
+    s = served["snap"]
+    assert 0 < s["decode_steps"] <= s["live_slot_steps"] \
+        <= B * s["decode_steps"]
+    # every token but a request's first comes out of a decode step
+    assert s["live_slot_steps"] == sum(BUDGETS) - len(BUDGETS)
+
+
+def test_admission_counts_rows_tokens_and_token_slots(served):
+    s = served["snap"]
+    assert s["admit_rows"] == s["admitted"] == len(PROMPTS)
+    assert s["admit_tokens"] == sum(len(p) for p in PROMPTS)
+    assert 2 <= s["admit_steps"] == s["batches"] <= len(PROMPTS)
+    assert s["admit_token_slots"] == B * BUCKET * s["admit_steps"]
+
+
+def test_live_pages_are_counted_against_the_page_table(served):
+    s = served["snap"]
+    assert s["kv_page_slots_steps"] == B * (CACHE // PAGE) * s["decode_steps"]
+    assert 0 < s["kv_pages_live_steps"] <= s["kv_page_slots_steps"]
+    # a live slot maps at least one page
+    assert s["kv_pages_live_steps"] >= s["live_slot_steps"]
+
+
+def test_request_times_are_ordered_and_inside_what_the_caller_saw(served):
+    s = served["snap"]
+    # a request waits, is prefilled, and only then can complete: summed
+    # over the requests, queue wait < time to first token <= latency
+    assert 0 < s["queue_wait_us"] < s["ttft_us"] <= served["latency_us"]
+    # the prefill call lies between admission and the first token
+    assert s["ttft_us"] - s["queue_wait_us"] >= s["loop_us_admit_device"]
+
+
+def test_a_failed_dispatch_counts_no_work(model):
+    from paddle_tpu.resilience.faults import FaultPlan
+
+    with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
+                          cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                          speculative_k=0, circuit_breaker=False,
+                          name="loopctr-fault") as eng:
+        eng.warmup()
+        with FaultPlan.parse(
+                "site=serving.decode,nth=1,error=TransientDeviceError"):
+            out = eng.submit(PROMPTS[1], 3).result(120)
+        s = _settled(eng, 1)
+    assert out.tolist() == _ref_greedy(model, PROMPTS[1], 3)
+    # the admission that failed before its dispatch left nothing behind:
+    # one row, its prompt's tokens and one wait are counted, once
+    assert s["restarts"] == 1
+    assert (s["admit_rows"], s["admit_steps"]) == (1, 1)
+    assert s["admit_tokens"] == len(PROMPTS[1])
+    assert s["live_slot_steps"] == s["decode_steps"] == 2
+
+
+def test_dense_loop_is_not_on_the_record(model):
+    with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
+                          continuous=True, name="loopctr-dense") as eng:
+        assert not set(LOOP_COUNTERS) & set(eng.metrics.snapshot())
+        assert len(eng.submit(PROMPTS[1], 3).result(120)) == 3
+
+
+def test_span_names_are_fixed_and_lie_on_the_engines_thread(
+        engine, served, tmp_path):
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("test/main_thread"):
+            futs = [engine.submit(p, 4) for p in PROMPTS[:3]]
+            for f in futs:
+                f.result(120)
+            time.sleep(0.12)  # an idle iteration: serve/wait, serve/publish
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    lines = {}  # line index -> names on it
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for n, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("serve/", "test/", engine.name)):
+                    lines.setdefault(n, set()).add(e.name)
+    on = [n for n, names in lines.items()
+          if any(x.startswith("serve/") for x in names)]
+    assert len(on) == 1, lines
+    assert lines[on[0]] == {"serve/" + p for p in LOOP_PHASES}
+    assert all("test/main_thread" not in lines[n] for n in on)
+    assert any("test/main_thread" in names for names in lines.values())
+
+
+def test_add_advances_many_counters_under_one_call():
+    m = ServingMetrics("m", extra_counters=("a",))
+    m.add({"a": 2, "b": 3})
+    m.add({"a": 5})
+    snap = m.snapshot()
+    assert (snap["a"], snap["b"]) == (7, 3)
+    assert type(snap["a"]) is int
+
+
+def test_loop_clock_tiles_time_and_carries_remainders():
+    m = ServingMetrics("m")
+    ph = LoopClock(m)
+    ph.counts["work"] += 1
+    for _ in range(50):
+        ph.to("sched")
+        ph.to("decode.device", live=1)
+        assert ph.to("harvest") >= 0
+        ph.flush()
+    assert not ph.counts  # handed over
+    ph.to(None)
+    ph.flush()
+    s = m.snapshot()
+    phases = sum(s.get("loop_us_" + p.replace(".", "_"), 0)
+                 for p in LOOP_PHASES)
+    # fifty iterations of a few microseconds: only carried remainders keep
+    # the integer sums within a microsecond a phase of the clock
+    assert abs(phases - s["loop_us_total"]) <= len(LOOP_PHASES)
+    assert s["loop_us_total"] > 0 and s["work"] == 1
+
+
+def test_loop_clock_keeps_each_phases_longest_interval_across_flushes():
+    m = ServingMetrics("m")
+    ph = LoopClock(m)
+    ph.to("decode.device")
+    time.sleep(0.002)
+    ph.to("wait")
+    time.sleep(0.02)
+    ph.flush()  # a flush does not cut the open interval
+    time.sleep(0.02)
+    ph.to("decode.device")  # a shorter second interval: the record stands
+    ph.to(None)
+    ph.flush()
+    s = m.snapshot()
+    assert s["loop_max_us_wait"] >= 40_000
+    assert s["loop_max_us_wait"] == s["loop_us_wait"]
+    assert 2_000 <= s["loop_max_us_decode_device"] \
+        <= s["loop_us_decode_device"]
