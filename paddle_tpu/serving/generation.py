@@ -49,15 +49,26 @@ of static width ``1 + FLAGS_speculative_k``: an n-gram proposer
 prefix matching the model's own argmax is accepted — token-identical to
 plain greedy, up to k+1 tokens per step when text repeats.  The loop
 runs serialized (each step harvested before the next dispatch) because
-drafting and page accounting depend on the previous step's tokens.  The
-paged compile set is closed and traced in :meth:`warmup`:
-``len(prompt_buckets) + 3`` with speculation (per-bucket admission, the
-unified step, its ``[B, 1]`` no-draft fast trace, the page-copy op) or
-``+ 2`` without.  The loop self-measures both step variants and drafts
-only when the predicted accepted tokens out-earn the wide step's extra
-cost, with per-slot exponential backoff after zero-accept verifies — on
-compute-bound hosts speculation turns itself off instead of losing
-throughput.
+drafting and page accounting depend on the previous step's tokens.
+
+The paged admission program is ``[R, bucket]``, not ``[B, bucket]``: ``R =
+min(batch_size, _ADMIT_ROWS)`` is a small fixed row chunk.  A row reaches
+the pool only through its own page-table and position-map rows, so the
+loop packs the requests an iteration admits densely into chunks of R rows
+(each padded to its widest row's bucket, unused rows inert), dispatches
+one admission call per chunk back to back, each threading the pool to the
+next, and waits for the first tokens once, after the last — the prefill
+computes the rows admitted, not every slot.
+
+The paged compile set is closed and traced in :meth:`warmup`, and the row
+chunk leaves its arithmetic as it was (the same count of executables,
+each smaller): ``len(prompt_buckets) + 3`` with speculation (per-bucket
+``[R, bucket]`` admission, the unified step, its ``[B, 1]`` no-draft fast
+trace, the page-copy op) or ``+ 2`` without.  The loop self-measures both
+step variants and drafts only when the predicted accepted tokens
+out-earn the wide step's extra cost, with per-slot exponential backoff
+after zero-accept verifies — on compute-bound hosts speculation turns
+itself off instead of losing throughput.
 """
 from __future__ import annotations
 
@@ -94,6 +105,20 @@ from .paging import PagePool
 __all__ = ["GenerationEngine", "KVHandoff"]
 
 _gen_counter = [0]
+
+#: rows of the paged admission program (``R0``).  A loop iteration admits
+#: a request or two between decode steps, not a batch, and the prefill
+#: costs what its rows x bucket cost whether they hold a prompt or not,
+#: so the program is traced at ``[min(batch_size, R0), bucket]`` and an
+#: iteration that admits more dispatches it once per chunk of R0 rows.
+#: One size, not a ladder of them: every further row size would add
+#: ``len(prompt_buckets)`` executables to the warm-up.  Swept on a
+#: TPU v5 lite over {1, 2, 4, 8} with GPT-2-small at 32 slots (PERF.md,
+#: PR 25): a call costs about 45 ms whatever its rows and 5-13 ms a row,
+#: so 1 pays the call too often under a closed loop that admits 1.5 rows
+#: an iteration, 4 and 8 pay for rows nothing was admitted into, and 2
+#: was best or tied in both cells.
+_ADMIT_ROWS = 2
 
 
 class KVHandoff(NamedTuple):
@@ -235,6 +260,7 @@ class GenerationEngine:
                 f"prompt_buckets must be positive lengths, got "
                 f"{prompt_buckets!r}")
         self._batch = int(batch_size)
+        self._admit_rows = min(self._batch, _ADMIT_ROWS)
         self._cache_len = cache_len
         self._eos = eos_token_id
         self._continuous = bool(flag("continuous_batching")
@@ -510,7 +536,9 @@ class GenerationEngine:
     @property
     def compile_count(self) -> int:
         """Traced executables so far: one per warmed prompt bucket (the
-        prefill or slot-admission executable) plus the shared decode step,
+        prefill or slot-admission executable; in paged mode the
+        ``[R, bucket]`` row-chunk admission, still one per bucket) plus
+        the shared decode step,
         plus — continuous mode — the slot-eviction op, or — paged mode —
         the page-copy (CoW) op and, when speculation is on, the ``[B, 1]``
         no-draft fast trace of the decode/verify step; paged eviction is a
@@ -522,7 +550,10 @@ class GenerationEngine:
         pays compile latency.  Returns the (closed) compile count:
         ``len(prompt_buckets) + 2`` continuous (or paged without
         speculation), ``len(prompt_buckets) + 3`` paged with speculation
-        (the extra ``[B, 1]`` no-draft fast trace), ``+ 1`` legacy.
+        (the extra ``[B, 1]`` no-draft fast trace), ``+ 1`` legacy.  The
+        paged admission is traced at its row chunk, ``[R, bucket]`` with
+        ``R = min(batch_size, _ADMIT_ROWS)``, once per bucket: the
+        arithmetic above is unchanged by it.
         Role-specialized engines add exactly one more: the page-export
         trace (``role='prefill'``) or the page-import trace
         (``role='decode'``); default-role engines trace neither.  On a
@@ -534,10 +565,12 @@ class GenerationEngine:
         if self._paged:
             # placement discipline as below: ids/positions/pos_map/table
             # always enter as host transfers, the pool as a jit output —
-            # _init_pool covers the one fresh-pool placement.
+            # _init_pool covers the one fresh-pool placement.  Admission
+            # is traced at its row chunk, [R, bucket], not [B, bucket].
             G = self._C // self._page
-            pm0 = jnp.asarray(np.full((B, self._C), -1, np.int32))
-            tb0 = jnp.asarray(np.full((B, G), -1, np.int32))
+            R = self._admit_rows
+            pm0 = jnp.asarray(np.full((R, self._C), -1, np.int32))
+            tb0 = jnp.asarray(np.full((R, G), -1, np.int32))
             cache = self._init_pool()
             # sharded decode only: measured search over the collective
             # overlap schedule, BEFORE the production traces below (they
@@ -546,13 +579,13 @@ class GenerationEngine:
             # winner from the tuning cache with zero searches)
             self._tune_overlap_schedule(cache)
             for sb in self._buckets:
-                ids = jnp.asarray(np.zeros((B, sb), np.int32))
+                ids = jnp.asarray(np.zeros((R, sb), np.int32))
                 pos = jnp.asarray(np.broadcast_to(
-                    np.arange(sb, dtype=np.int32), (B, sb)))
-                lens = jnp.asarray(np.full((B,), sb, np.int32))
-                _, cache = self._padmit(self._params, self._buffers, ids,
-                                        pos, pm0, tb0, lens, cache,
-                                        self._aids_arg())
+                    np.arange(sb, dtype=np.int32), (R, sb)))
+                lens = jnp.asarray(np.full((R,), sb, np.int32))
+                _, cache = self._padmit(
+                    self._params, self._buffers, ids, pos, pm0, tb0, lens,
+                    cache, self._aids_arg(np.full((R,), -1, np.int32)))
             T = 1 + self._spec_k
             _, cache = self._step(
                 self._params, self._buffers,
@@ -1109,6 +1142,7 @@ class GenerationEngine:
         q = self._batcher
         B, C, page = self._batch, self._C, self._page
         G = C // page
+        R = self._admit_rows
         k_max, eos = self._spec_k, self._eos
         T = 1 + k_max
         max_restarts = (max(int(flag("transient_max_retries")) - 1, 0)
@@ -1361,11 +1395,6 @@ class GenerationEngine:
                         cnt["admitted"] += n_adopted
                         cnt["evicted"] += n_adevicted
                     if take and pre:
-                        Sb = self._buckets[max(r.bucket
-                                               for (r, _), _ in pre)]
-                        ids = np.zeros((B, Sb), np.int32)
-                        pp = np.full((B, Sb), -1, np.int32)
-                        lens = np.ones((B,), np.int32)
                         cow_pairs: List[tuple] = []
                         to_register: List[tuple] = []
                         admitted: List[tuple] = []
@@ -1374,11 +1403,7 @@ class GenerationEngine:
                                 self._unpack_paged(r)
                             pairs, shared = pool.admit(i, prompt, key)
                             cow_pairs += [(s_, d_, i) for s_, d_ in pairs]
-                            L = len(prompt)
-                            ids[i, :L - shared] = prompt[shared:]
-                            pp[i, :L - shared] = np.arange(shared, L)
-                            lens[i] = L - shared
-                            pos[i] = L
+                            pos[i] = len(prompt)
                             aidsv[i] = aid
                             slots[i] = {"req": r, "budget": budget,
                                         "out": [], "t0": now,
@@ -1386,30 +1411,64 @@ class GenerationEngine:
                                         "tenant": tenant,
                                         "handoff": hand is True,
                                         "hist": [int(t) for t in prompt]}
-                            admitted.append((r, i))
+                            admitted.append((r, i, shared, prompt))
                             if key is not None and plen > 0:
-                                # registered AFTER this prefill lands, so
+                                # registered AFTER the last chunk lands, so
                                 # same-batch siblings never map pages whose
                                 # boundary CoW would copy data not yet
                                 # written
                                 to_register.append((key, i, prompt[:plen]))
+                        # the admitted rows, packed densely in admission
+                        # order, R to a chunk: a chunk's program runs over
+                        # its own rows' page-table and position-map rows
+                        # and nothing else, padded to its widest row's
+                        # bucket; rows a last chunk does not fill are inert
+                        # (position -1, table -1: they write to the drop
+                        # page, as warm-up's rows do)
+                        chunks: List[tuple] = []
+                        for c0 in range(0, len(admitted), R):
+                            part = admitted[c0:c0 + R]
+                            Sb = self._buckets[max(r.bucket
+                                                   for r, _, _, _ in part)]
+                            ids = np.zeros((R, Sb), np.int32)
+                            pp = np.full((R, Sb), -1, np.int32)
+                            lens = np.ones((R,), np.int32)
+                            sl = [i for _, i, _, _ in part]
+                            pm = np.full((R, C), -1, np.int32)
+                            tb = np.full((R, G), -1, np.int32)
+                            ra = np.full((R,), -1, np.int32)
+                            pm[:len(sl)] = pool.pos_map[sl]
+                            tb[:len(sl)] = pool.table[sl]
+                            ra[:len(sl)] = aidsv[sl]
+                            for j, (_, _, shared, prompt) in enumerate(part):
+                                L = len(prompt)
+                                ids[j, :L - shared] = prompt[shared:]
+                                pp[j, :L - shared] = np.arange(shared, L)
+                                lens[j] = L - shared
+                            chunks.append((ids, pp, pm, tb, lens, ra))
                         dispatch_cow(cow_pairs)
                         fault_point("serving.decode")
-                        ph.to("admit.device", engine=self.name, bucket=Sb,
+                        ph.to("admit.device", engine=self.name,
+                              bucket=max(c[0].shape[1] for c in chunks),
                               rows=len(admitted))
-                        first, cache = self._padmit(
-                            self._params, self._buffers,
-                            jnp.asarray(ids), jnp.asarray(pp),
-                            jnp.asarray(pool.pos_map.copy()),
-                            jnp.asarray(pool.table.copy()),
-                            jnp.asarray(lens), cache,
-                            self._aids_arg(aidsv))
-                        host_first = np.asarray(first)  # serial harvest
+                        # back to back, each threading the pool to the
+                        # next; the host waits once, for the last
+                        firsts = []
+                        for ids, pp, pm, tb, lens, ra in chunks:
+                            first, cache = self._padmit(
+                                self._params, self._buffers,
+                                jnp.asarray(ids), jnp.asarray(pp),
+                                jnp.asarray(pm), jnp.asarray(tb),
+                                jnp.asarray(lens), cache,
+                                self._aids_arg(ra))
+                            firsts.append(first)
+                        # serial harvest; row c * R + j is admitted[c * R + j]
+                        host_first = np.concatenate(jax.device_get(firsts))
                         ph.to("admit.host", engine=self.name)
                         tr = _tracing._active
                         if tr is not None:
                             adm_ms = (time.monotonic() - now) * 1e3
-                            for r, i in admitted:
+                            for j, (r, i, _, _) in enumerate(admitted):
                                 if r.trace is None:
                                     continue
                                 tr.record("batcher/queue", r.trace,
@@ -1421,15 +1480,16 @@ class GenerationEngine:
                                 tr.record("slot/admit", r.trace, now,
                                           adm_ms, kind="prefill",
                                           args={"engine": self.name,
-                                                "slot": i, "bucket": Sb})
+                                                "slot": i, "bucket":
+                                                chunks[j // R][0].shape[1]})
                         for key, i, toks in to_register:
                             pool.register_prefix(key, i, toks)
                         now = time.monotonic()
                         n_evicted = 0
-                        for r, i in admitted:
+                        for j, (r, i, shared, prompt) in enumerate(admitted):
                             s = slots[i]
-                            t = int(host_first[i])
-                            cnt["admit_tokens"] += int(lens[i])
+                            t = int(host_first[j])
+                            cnt["admit_tokens"] += len(prompt) - shared
                             cnt["queue_wait_us"] += int(
                                 (s["t0"] - r.enqueue_t) * 1e6)
                             cnt["ttft_us"] += int((now - r.enqueue_t) * 1e6)
@@ -1478,9 +1538,11 @@ class GenerationEngine:
                                 aidsv[i] = -1
                                 n_evicted += 1
                         cnt.update(admitted=len(admitted), batches=1,
-                                   evicted=n_evicted, admit_steps=1,
+                                   evicted=n_evicted,
+                                   admit_steps=len(chunks),
                                    admit_rows=len(admitted),
-                                   admit_token_slots=B * Sb)
+                                   admit_token_slots=sum(
+                                       c[0].size for c in chunks))
                     if take:
                         live = [i for i in range(B) if slots[i] is not None]
                         if ten is not None:

@@ -23,10 +23,11 @@ The paged decode loop publishes the gauge ``decode_step_ms`` (the last
 step's measured device call) and the ``LOOP_COUNTERS`` family: its wall
 time by phase in integer microseconds (``loop_us_<phase>``; the phases
 tile every iteration and sum to ``loop_us_total``), the work it
-dispatched (admission rows/tokens against the ``[B, bucket]`` token
-slots of the prefill program, live slots and live page-table entries
-against ``B`` and ``B x G`` per decode step) and the summed queue wait
-and time to first token of the requests it admitted.  Beside each sum,
+dispatched (admission rows/tokens against the ``[R, bucket]`` token
+slots of each prefill call, ``R`` the engine's admission row chunk;
+live slots and live page-table entries against ``B`` and ``B x G`` per
+decode step) and the summed queue wait and time to first token of the
+requests it admitted.  Beside each sum,
 ``loop_max_us_<phase>`` is the phase's longest single interval since the
 engine started, so that a stall shows as one call of one phase and not
 as a mean that crept.  The same phases are ``serve/<phase>`` spans on
@@ -79,7 +80,8 @@ SLOT_COUNTERS = ("admitted", "evicted", "decode_steps", "restarts",
 #: order they run: ``sched`` (close check, tenancy, expiry, queue poll,
 #: choosing what to admit), ``admit.host`` (page accounting, prefill
 #: inputs, CoW dispatch, first-token book-keeping), ``admit.device`` (the
-#: prefill call until its first tokens are on the host), ``decode.pack``
+#: prefill calls, one per row chunk, until the last one's first tokens
+#: are on the host), ``decode.pack``
 #: (drafts, page growth, step inputs), ``decode.device`` (the step call
 #: until its tokens are on the host), ``harvest`` (accept/finish per
 #: slot), ``publish``, ``wait`` (sleeps and blocking polls with nothing
@@ -93,8 +95,9 @@ _MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
 
 #: paged-decode-loop counters (see the module docstring): wall time by
 #: phase and each phase's longest interval, work counted once its
-#: dispatch has returned (``admit_token_slots`` is ``B x bucket`` per
-#: prefill call and ``kv_page_slots_steps`` ``B x G`` per decode step:
+#: dispatch has returned (``admit_steps`` counts admission device calls,
+#: one per chunk of ``R`` rows; ``admit_token_slots`` is ``R x bucket``
+#: per such call and ``kv_page_slots_steps`` ``B x G`` per decode step:
 #: the denominators of the useful shares), and the summed per-request
 #: times whose count is ``admit_rows``
 LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),

@@ -15,10 +15,12 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.serving import GenerationEngine
+from paddle_tpu.serving.generation import _ADMIT_ROWS
 from paddle_tpu.serving.metrics import (LOOP_COUNTERS, LOOP_PHASES, LoopClock,
                                         ServingMetrics)
 
 B, BUCKET, PAGE, CACHE = 2, 16, 8, 64
+R = min(B, _ADMIT_ROWS)  # rows of the admission program
 PROMPTS = [(np.arange(10) * 5 + 2) % 97, np.arange(3) % 97,
            (np.arange(6) * 3) % 97, (np.arange(4) * 7 + 1) % 97,
            (np.arange(12) * 11 + 3) % 97]
@@ -125,13 +127,15 @@ def test_served_tokens_are_the_uncached_greedy_reference(model, served):
 
 def test_a_phases_longest_interval_lies_between_its_mean_and_its_sum(served):
     s = served["snap"]
-    calls = {"admit_device": s["admit_steps"],
-             "decode_device": s["decode_steps"]}
+    # one interval a decode step, and one an admitting iteration: all of
+    # its chunks' calls are dispatched inside the one admit.device phase
+    intervals = {"admit_device": s["batches"],
+                 "decode_device": s["decode_steps"]}
     for k in PHASE_KEYS:
         longest = s[k.replace("loop_us_", "loop_max_us_")]
         assert 0 <= longest <= s[k], k
-        n = calls.get(k[len("loop_us_"):])
-        if n:  # one interval a call: the longest is no shorter than the mean
+        n = intervals.get(k[len("loop_us_"):])
+        if n:  # the longest interval is no shorter than the mean
             assert longest >= s[k] // n, k
 
 
@@ -147,8 +151,12 @@ def test_admission_counts_rows_tokens_and_token_slots(served):
     s = served["snap"]
     assert s["admit_rows"] == s["admitted"] == len(PROMPTS)
     assert s["admit_tokens"] == sum(len(p) for p in PROMPTS)
-    assert 2 <= s["admit_steps"] == s["batches"] <= len(PROMPTS)
-    assert s["admit_token_slots"] == B * BUCKET * s["admit_steps"]
+    # an iteration that admits n rows dispatches ceil(n / R) programs of
+    # [R, bucket], and is one batch
+    assert 2 <= s["batches"] <= s["admit_steps"] <= len(PROMPTS)
+    assert -(-s["admit_rows"] // R) <= s["admit_steps"] \
+        <= -(-B // R) * s["batches"]
+    assert s["admit_token_slots"] == R * BUCKET * s["admit_steps"]
 
 
 def test_live_pages_are_counted_against_the_page_table(served):

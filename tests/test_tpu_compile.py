@@ -177,3 +177,18 @@ def test_paged_flash_decode_gpt2_small(chip, pool, page, T):
     if pool == i8:
         shapes += [((pages, H, page), f32)] * 2
     _compiles_with_kernel(chip, paged_flash_decode, *shapes)
+
+
+def test_paged_flash_admission_prefill_gpt2_small(chip):
+    # the paged admission program's attention: the same kernel over the
+    # engine's row chunk x the widest prompt bucket the chip benchmark
+    # serves (docs_closed, 768), float pages of 16
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+    from paddle_tpu.serving.generation import _ADMIT_ROWS
+
+    R, H, hd, page, T = _ADMIT_ROWS, 12, 64, 16, 768
+    G, pages = 1024 // page, 2048 + 1
+    _compiles_with_kernel(
+        chip, paged_flash_decode, ((R, H, T, hd), f32),
+        ((pages, H, page, hd), f32), ((pages, H, page, hd), f32),
+        ((R, G), i32), ((R, T, G * page), jnp.bool_))
