@@ -10,6 +10,11 @@ from .gpt import (  # noqa: F401
     gpt_tiny,
     gpt_small,
 )
+from .latent_moe import (  # noqa: F401
+    LatentMoEConfig,
+    LatentMoEModel,
+    LatentMoEForCausalLM,
+)
 from .wide_deep import (  # noqa: F401
     WideDeep,
     wide_deep_tiny,

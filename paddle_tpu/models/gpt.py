@@ -749,6 +749,49 @@ class GPTForCausalLM(Layer):
         super().__init__()
         self.gpt = GPTModel(cfg)
 
+    # -- the serving-model protocol (serving/generation.py): what an engine
+    # asks of a model, answered here by the decoder under ``.gpt`` ---------
+    max_position = property(lambda self: self.gpt.cfg.max_position)
+    moe_experts = property(
+        lambda self: int(getattr(self.gpt.cfg, "moe_experts", 0) or 0))
+    lora_capacity = property(
+        lambda self: int(getattr(self.gpt.cfg, "lora_capacity", 0) or 0))
+
+    def init_cache(self, batch_size, cache_len=None):
+        return self.gpt.init_cache(batch_size, cache_len)
+
+    def write_slots(self, cache, src, slot_mask):
+        return self.gpt.write_slots(cache, src, slot_mask)
+
+    def reset_slots(self, cache, slot_mask):
+        return self.gpt.reset_slots(cache, slot_mask)
+
+    def init_paged_cache(self, num_pages, page_size, dtype=None):
+        return self.gpt.init_paged_cache(num_pages, page_size, dtype=dtype)
+
+    def copy_pages(self, cache, src, dst):
+        return self.gpt.copy_pages(cache, src, dst)
+
+    def gather_pages(self, cache, idx):
+        return self.gpt.gather_pages(cache, idx)
+
+    def scatter_pages(self, cache, kv, dst):
+        return self.gpt.scatter_pages(cache, kv, dst)
+
+    def handoff_zero(self, num_pages, page_size, dtype=None):
+        """Zeros in the shape :meth:`gather_pages` exports: one ``[L, 2,
+        K, H, page, hd]`` array, or for a quantized pool (``dtype`` int8 /
+        fp8) the ``(pages, scales)`` pair."""
+        import numpy as np
+
+        cfg = self.gpt.cfg
+        shape = (cfg.num_layers, 2, int(num_pages), cfg.num_heads,
+                 int(page_size), cfg.hidden_size // cfg.num_heads)
+        if dtype is None:
+            return np.zeros(shape, cfg.dtype)
+        return (np.zeros(shape, np.dtype(dtype)),
+                np.zeros(shape[:-1], np.float32))
+
     def forward(self, input_ids, attn_mask=None):
         if getattr(self.gpt.cfg, "moe_experts", 0):
             # collect the blocks' load-balance losses; loss() consumes

@@ -2,6 +2,9 @@
 
 * ``MoELayer`` — GShard-style top-k routed expert FFN, a drop-in for the
   dense ``ParallelMLP`` behind ``GPTConfig.moe_experts`` (layer.py);
+* ``DroplessMoE`` — sigmoid-routed experts with no capacity and shared
+  experts: sort-by-expert ragged dispatch into one grouped gated-MLP
+  kernel (layer.py, ``ops/grouped_matmul.py``);
 * ``stats`` — the trace-scoped collector carrying each layer's load-
   balance loss and routed/dropped counters to whoever owns the trace
   (stats.py).
@@ -11,4 +14,4 @@ Expert weights shard over the ``expert`` mesh axis
 capacity-bucketed one-hot einsums that GSPMD lowers to all-to-alls.
 """
 from . import stats  # noqa: F401
-from .layer import MoELayer  # noqa: F401
+from .layer import DroplessMoE, MoELayer  # noqa: F401

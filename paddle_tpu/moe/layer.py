@@ -54,7 +54,7 @@ from ..nn import initializer as I
 from ..nn.layer_base import Layer, current_rng_key
 from . import stats as moe_stats
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "DroplessMoE", "gated_mlp"]
 
 
 class MoELayer(Layer):
@@ -181,3 +181,106 @@ class MoELayer(Layer):
         moe_stats.record(aux, routed, (selected - routed).astype(jnp.int32))
 
         return self.drop(y.reshape(*lead, D))
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """``(silu(x W_gate) * (x W_up)) W_down``: matmuls accumulate in
+    float32, the product is rounded to ``x``'s dtype before ``W_down`` and
+    the result is float32."""
+    f32 = jnp.float32
+    g = jnp.dot(x, jnp.asarray(w_gate), preferred_element_type=f32)
+    u = jnp.dot(x, jnp.asarray(w_up), preferred_element_type=f32)
+    return jnp.dot((g * jax.nn.sigmoid(g) * u).astype(x.dtype),
+                   jnp.asarray(w_down), preferred_element_type=f32)
+
+
+class DroplessMoE(Layer):
+    """Sigmoid-routed experts with no capacity, plus shared experts.
+
+    ``s = sigmoid(x W_g)`` in float32; the ``top_k`` experts are chosen by
+    ``s + b`` (``score_bias``, a per-expert correction that steers load
+    and never enters the weights), weighted by ``s`` of the chosen,
+    normalised to sum 1 and scaled by ``routed_scale``.  Every expert is a
+    gated-SiLU MLP ``(silu(x W_gate) * (x W_up)) W_down``; the shared
+    expert (``shared_experts`` of them fused into one of that many widths)
+    sees every token once.  Dispatch sorts the (token, choice) pairs by
+    expert into ``ops.grouped_matmul.ragged_layout``'s tile-aligned rows
+    and ``ragged_gated_mlp`` runs only the tiles in use, so no token is
+    dropped whatever the routing and an expert nobody chose costs nothing.
+    Per-expert routed counts go to :mod:`paddle_tpu.moe.stats` (dropped is
+    0 by construction)."""
+
+    #: pairs per expert under which a call is decode-like: the small row
+    #: tile keeps the padding of one-token groups low
+    _SMALL_ROWS = 16
+
+    def __init__(self, hidden_size, expert_width, num_experts, top_k,
+                 shared_experts=1, routed_scale=1.0, norm_topk=True,
+                 dtype="float32", init_std=0.02):
+        super().__init__()
+        D, F, E = int(hidden_size), int(expert_width), int(num_experts)
+        self.num_experts, self.top_k = E, int(top_k)
+        self.routed_scale, self.norm_topk = float(routed_scale), norm_topk
+        init = I.Normal(std=init_std)
+
+        def p(shape, dt=dtype, spec=None):
+            w = self.create_parameter(shape, dtype=dt,
+                                      default_initializer=init)
+            w.partition_spec = spec
+            return w
+
+        self.router = p((D, E))
+        self.score_bias = p((E,), "float32")
+        ex = ("expert", None, None)
+        self.expert_gate = p((E, D, F), spec=ex)
+        self.expert_up = p((E, D, F), spec=ex)
+        self.expert_down = p((E, F, D), spec=ex)
+        Fs = F * int(shared_experts)
+        self.shared_gate = p((D, Fs)) if Fs else None
+        self.shared_up = p((D, Fs)) if Fs else None
+        self.shared_down = p((Fs, D)) if Fs else None
+
+    def route(self, xf):
+        """``[N, D]`` -> (expert ids ``[N, k]`` int32, weights ``[N, k]``
+        float32)."""
+        f32 = jnp.float32
+        s = jax.nn.sigmoid(jnp.dot(
+            xf.astype(f32), self.router.value.astype(f32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, top_e = jax.lax.top_k(s + self.score_bias.value.astype(f32),
+                                 self.top_k)
+        w = jnp.take_along_axis(s, top_e, axis=-1)
+        if self.norm_topk:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return top_e.astype(jnp.int32), w * self.routed_scale
+
+    def forward(self, x):
+        from ..ops.grouped_matmul import ragged_gated_mlp, ragged_layout
+
+        x = jnp.asarray(x)
+        lead, D = x.shape[:-1], x.shape[-1]
+        xf = x.reshape(-1, D)
+        N, k, E = xf.shape[0], self.top_k, self.num_experts
+        with jax.named_scope("moe"):
+            top_e, w = self.route(xf)
+            A = N * k
+            tm = 16 if A < self._SMALL_ROWS * E else 128
+            lay = ragged_layout(top_e.reshape(-1), E, tm)
+            rows = lay["tiles"] * tm
+            # row r of the sorted layout reads token src[r]; N is a zero row
+            src = jnp.full((rows,), N, jnp.int32).at[lay["dest"]].set(
+                jnp.arange(A, dtype=jnp.int32) // k)
+            xs = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[src]
+            ys = ragged_gated_mlp(xs, self.expert_gate.value,
+                                  self.expert_up.value,
+                                  self.expert_down.value, lay)
+            y = jnp.einsum("nkd,nk->nd",
+                           ys[lay["dest"]].reshape(N, k, D).astype(
+                               jnp.float32), w)
+            if self.shared_gate is not None:
+                y = y + gated_mlp(xf, self.shared_gate.value,
+                                  self.shared_up.value,
+                                  self.shared_down.value)
+            moe_stats.record(jnp.zeros((), jnp.float32), lay["counts"],
+                             jnp.zeros((E,), jnp.int32))
+        return y.astype(x.dtype).reshape(*lead, D)

@@ -65,6 +65,15 @@ class MoEStats:
             dropped = dropped + d
         return jnp.stack([routed, dropped])
 
+    def touched(self, num_experts: int):
+        """``[E]`` int32 — in how many of the recorded layers each expert
+        got at least one token; its sum over ``len(entries)`` is the mean
+        count of experts a layer touched."""
+        out = jnp.zeros((num_experts,), jnp.int32)
+        for _, r, _ in self.entries:
+            out = out + (r > 0).astype(jnp.int32)
+        return out
+
 
 class collect:
     """``with collect() as ms:`` — capture MoE records from the model

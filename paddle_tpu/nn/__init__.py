@@ -7,6 +7,7 @@ from .layer_base import (  # noqa: F401
     Layer,
     Parameter,
     Buffer,
+    abstract_parameters,
     functional_call,
     current_rng_key,
     rng_scope,

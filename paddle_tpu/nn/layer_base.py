@@ -174,6 +174,25 @@ def current_rng_key() -> jax.Array:
 # ---------------------------------------------------------------------------
 # Layer
 # ---------------------------------------------------------------------------
+_abstract_init = threading.local()
+
+
+@contextlib.contextmanager
+def abstract_parameters():
+    """Build layers without materializing their parameters: inside the
+    scope every new :class:`Parameter` box holds a
+    ``jax.ShapeDtypeStruct`` (shape and dtype, no memory, no initializer
+    run).  For models whose weights arrive from elsewhere and are too
+    large to hold twice; the caller fills every box (``p.value = w``)
+    before the first forward."""
+    prev = getattr(_abstract_init, "on", False)
+    _abstract_init.on = True
+    try:
+        yield
+    finally:
+        _abstract_init.on = prev
+
+
 def build_parameter(shape, dtype=None, attr=None, is_bias=False,
                     default_initializer=None) -> "Parameter":
     """Create a Parameter box from ParamAttr semantics — shared by
@@ -194,6 +213,11 @@ def build_parameter(shape, dtype=None, attr=None, is_bias=False,
     if init is None:
         init = default_initializer or (
             I.Constant(0.0) if is_bias else I.XavierNormal())
+    if getattr(_abstract_init, "on", False):
+        p = Parameter(jnp.zeros((), dtype), name=name or "",
+                      trainable=trainable)
+        p.value = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+        return p
     value = init(tuple(shape), dtype, key=_random.default_generator().next_key())
     return Parameter(value, name=name or "", trainable=trainable)
 

@@ -24,7 +24,7 @@ from .layer_base import Layer
 
 __all__ = [
     "BatchNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
-    "LayerNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
+    "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
     "InstanceNorm3D", "LocalResponseNorm", "SpectralNorm",
 ]
 
@@ -215,6 +215,25 @@ class LayerNorm(Layer):
                             self.weight.value if self.weight is not None else None,
                             self.bias.value if self.bias is not None else None,
                             self.epsilon)
+
+
+class RMSNorm(Layer):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, computed
+    in float32 and returned in ``x``'s dtype (Zhang & Sennrich 2019): no
+    mean subtraction, no bias."""
+
+    def __init__(self, size, epsilon=1e-6, dtype=None):
+        super().__init__()
+        self.epsilon = epsilon
+        self.weight = self.create_parameter(
+            (int(size),), dtype=dtype, default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        x = jnp.asarray(x)
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + self.epsilon)
+        return (y * self.weight.value.astype(jnp.float32)).astype(x.dtype)
 
 
 class GroupNorm(Layer):

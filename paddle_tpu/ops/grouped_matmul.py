@@ -25,6 +25,14 @@ batch over E and fuse fine; no second custom kernel needed):
 Tile sizes come from ``ops.autotune`` (kernel name "grouped_matmul");
 the contraction dim D stays whole per block, so eligibility on real
 TPUs wants ``D % 128 == 0`` (same shape class as the other epilogues).
+
+The DROPLESS layout (``ragged_layout`` / ``ragged_gated_mlp``) has no
+capacity: the (token, choice) pairs are sorted by expert and each
+expert's rows start at a multiple of the row tile, so every tile of
+``tile_m`` rows belongs to ONE expert.  The kernel walks only the tiles
+in use and fetches a tile's expert by a scalar-prefetched index: an
+expert nobody chose owns no tile and its weights are never read from
+HBM, which is what bounds a decode step over hundreds of experts.
 """
 from __future__ import annotations
 
@@ -41,7 +49,7 @@ from ..framework import device as _device
 from ..framework.errors import InvalidArgumentError
 from . import autotune as _at
 
-__all__ = ["grouped_matmul"]
+__all__ = ["grouped_matmul", "ragged_layout", "ragged_gated_mlp"]
 
 
 def _kernel(gs_ref, x_ref, w_ref, o_ref):
@@ -178,3 +186,124 @@ def grouped_matmul(x, w, group_sizes, *, block_m: Optional[int] = None,
         block_m = cfg["block_m"] if block_m is None else block_m
         block_n = cfg["block_n"] if block_n is None else block_n
     return _gmm(x, w, group_sizes, int(block_m), int(block_n))
+
+
+# -- dropless ragged groups: tile-aligned rows sorted by expert --------------
+def ragged_tiles(num_rows: int, num_groups: int, tile_m: int) -> int:
+    """Static upper bound on the tiles ``ragged_layout`` can use:
+    ``sum_e ceil(c_e / tile_m) <= num_rows // tile_m + num_groups``."""
+    return min(num_rows // tile_m + num_groups, num_rows)
+
+
+def ragged_layout(group_ids, num_groups: int, tile_m: int):
+    """Sort ``[A]`` group ids (one per (token, choice) pair) into a
+    tile-aligned row layout.  Returns a dict of int32 arrays:
+
+    ``dest`` [A]: the row of pair ``a`` in the sorted layout;
+    ``counts`` [E]: pairs per group; ``tile_group`` [NT]: the group of
+    each tile (tiles past the last one in use repeat it, so a kernel's
+    block index does not move there); ``tile_index`` [NT]: ``min(t,
+    used - 1)``; ``used`` [1]: tiles in use.  The layout has ``NT *
+    tile_m`` rows; a group's rows are contiguous from a tile boundary and
+    the rows that pad its last tile belong to nobody."""
+    i32 = jnp.int32
+    ids = jnp.asarray(group_ids, i32)
+    A, E, tm = ids.shape[0], int(num_groups), int(tile_m)
+    NT = ragged_tiles(A, E, tm)
+    counts = jnp.sum(ids[:, None] == jnp.arange(E, dtype=i32)[None, :],
+                     axis=0, dtype=i32)
+    order = jnp.argsort(ids, stable=True).astype(i32)
+    sorted_ids = ids[order]
+    tiles = (counts + (tm - 1)) // tm
+    tile_end = jnp.cumsum(tiles, dtype=i32)
+    tile_start = tile_end - tiles
+    group_start = jnp.cumsum(counts, dtype=i32) - counts
+    rank = jnp.arange(A, dtype=i32) - group_start[sorted_ids]
+    dest_sorted = tile_start[sorted_ids] * tm + rank
+    dest = jnp.zeros((A,), i32).at[order].set(dest_sorted)
+    used = tile_end[-1]
+    tile_index = jnp.minimum(jnp.arange(NT, dtype=i32), used - 1)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, tile_index, side="right").astype(i32),
+        E - 1)
+    return {"dest": dest, "counts": counts, "tile_group": tile_group,
+            "tile_index": tile_index, "used": used.reshape(1),
+            "tiles": NT, "tile_m": tm}
+
+
+def _gated_mlp_kernel(tg_ref, ti_ref, used_ref, x_ref, wg_ref, wu_ref,
+                      wd_ref, o_ref):
+    del tg_ref, ti_ref  # consumed by the index maps
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        o_ref[...] = jnp.dot(
+            h, wd_ref[0],
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _gated_mlp_pallas(xs, w_gate, w_up, w_down, lay):
+    NT, tm = lay["tiles"], lay["tile_m"]
+    E, D, F = w_gate.shape
+    item = np.dtype(xs.dtype).itemsize
+    # x and out tiles, the three weight blocks (all double-buffered) and
+    # the f32 intermediates of one tile
+    need = 2 * (2 * tm * D + 3 * D * F) * item + 4 * tm * (3 * F + D)
+    return pl.pallas_call(
+        _gated_mlp_kernel,
+        name=f"moe_gated_mlp_tm{tm}",
+        interpret=not _device.on_tpu(),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(NT,),
+            in_specs=[
+                pl.BlockSpec((tm, D), lambda t, tg, ti, u: (ti[t], _at.I0)),
+                pl.BlockSpec((1, D, F),
+                             lambda t, tg, ti, u: (tg[t], _at.I0, _at.I0)),
+                pl.BlockSpec((1, D, F),
+                             lambda t, tg, ti, u: (tg[t], _at.I0, _at.I0)),
+                pl.BlockSpec((1, F, D),
+                             lambda t, tg, ti, u: (tg[t], _at.I0, _at.I0)),
+            ],
+            out_specs=pl.BlockSpec((tm, D),
+                                   lambda t, tg, ti, u: (ti[t], _at.I0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((NT * tm, D), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(min(max(need * 5 // 4, 16 << 20),
+                                     100 << 20))),
+    )(lay["tile_group"], lay["tile_index"], lay["used"], xs, w_gate, w_up,
+      w_down)
+
+
+def _gated_mlp_xla(xs, w_gate, w_up, w_down, lay):
+    """The same function without a kernel (CPU, several-device meshes):
+    ``lax.ragged_dot`` over the groups' padded row counts."""
+    tm = lay["tile_m"]
+    sizes = (lay["counts"] + (tm - 1)) // tm * tm
+    f32 = jnp.float32
+    g = jax.lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
+    u = jax.lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
+    h = (g * jax.nn.sigmoid(g) * u).astype(xs.dtype)
+    return jax.lax.ragged_dot(h, w_down, sizes,
+                              preferred_element_type=f32).astype(xs.dtype)
+
+
+def ragged_gated_mlp(xs, w_gate, w_up, w_down, layout, *, kernel=None):
+    """``(silu(x W_gate[e]) * (x W_up[e])) W_down[e]`` for every row of
+    ``xs`` ``[NT * tile_m, D]`` laid out by :func:`ragged_layout`; ``e`` is
+    the row's tile's group.  Weights ``[E, D, F]``, ``[E, D, F]``, ``[E,
+    F, D]``; matmuls accumulate in float32 and round to ``xs.dtype``
+    between them.  Rows of tiles not in use are not written.  ``kernel``
+    None asks the gate (a TPU, lane-aligned D and F, a one-device mesh)."""
+    D, F = w_gate.shape[1], w_gate.shape[2]
+    if kernel is None:
+        kernel = (_at.fused_epilogues_eligible(D)
+                  and _at.fused_epilogues_eligible(F))
+    fn = _gated_mlp_pallas if kernel else _gated_mlp_xla
+    return fn(xs, w_gate, w_up, w_down, layout)

@@ -270,7 +270,7 @@ class GenerationEngine:
             raise InvalidArgumentError(
                 "paged_kv requires continuous batching (the legacy "
                 "run-batch path owns no persistent device state to page)")
-        self._C = int(cache_len or model.gpt.cfg.max_position)
+        self._C = int(cache_len or model.max_position)
         self._page = int(flag("kv_page_size")
                          if kv_page_size is None else kv_page_size)
         self._spec_k = max(int(flag("speculative_k")
@@ -307,18 +307,15 @@ class GenerationEngine:
         # bodies below collect [2, E] routed/dropped counts inside the
         # trace and a wrapper pops them off the jit output (_moe_tap) —
         # a 0-expert config builds the exact same executables as before
-        self._moe_experts = int(getattr(
-            getattr(getattr(model, "gpt", None), "cfg", None),
-            "moe_experts", 0) or 0)
+        self._moe_experts = int(getattr(model, "moe_experts", 0) or 0)
         self._moe_pending = None
+        self._moe_layers = 0  # expert layers a decode step runs (set at trace)
         self._moe_routed_cum = np.zeros(max(self._moe_experts, 1), np.int64)
         # batched multi-LoRA: capacity > 0 threads a per-slot adapter-id
         # column through every executable (warmup traces it with all -1,
         # so the compile set closes exactly as without LoRA; adapter hot
         # add/remove edits buffer leaves only)
-        self._lora_cap = int(getattr(
-            getattr(getattr(model, "gpt", None), "cfg", None),
-            "lora_capacity", 0) or 0)
+        self._lora_cap = int(getattr(model, "lora_capacity", 0) or 0)
         self._adapters: Dict[int, str] = {}       # slot -> adapter name
         self._adapter_hits = np.zeros(max(self._lora_cap, 1), np.int64)
         self._tenancy_steps = 0  # post-warm decode steps (S607 denominator)
@@ -369,7 +366,7 @@ class GenerationEngine:
                             adapter_ids=aids if lora_on else None)
                     return (jnp.argmax(logits[:, 0],
                                        axis=-1).astype(jnp.int32),
-                            cache, ms.counts(self._moe_experts))
+                            cache, self._moe_sample(ms))
                 logits, cache = mdl.forward_cached(
                     tok[:, None], pos[:, None], cache,
                     adapter_ids=aids if lora_on else None)
@@ -389,13 +386,13 @@ class GenerationEngine:
             # the legacy prefill (token identity).
             def body(ids, positions, lens, mask, cache, tok, aids):
                 traces["admit"] += 1
-                fresh = mdl.gpt.init_cache(ids.shape[0], self._cache_len)
+                fresh = mdl.init_cache(ids.shape[0], self._cache_len)
                 logits, fresh = mdl.forward_cached(
                     ids, positions, fresh, gather_last=lens,
                     adapter_ids=aids if lora_on else None)
                 first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (jnp.where(mask, first, tok),
-                        mdl.gpt.write_slots(cache, fresh, mask))
+                        mdl.write_slots(cache, fresh, mask))
             return functional_call(mdl, params, ids, positions, lens, mask,
                                    cache, tok, aids, buffers=buffers,
                                    training=False, call=body)
@@ -403,7 +400,7 @@ class GenerationEngine:
         def evict(tok, cache, mask):
             traces["evict"] += 1
             return (jnp.where(mask, jnp.int32(0), tok),
-                    mdl.gpt.reset_slots(cache, mask))
+                    mdl.reset_slots(cache, mask))
 
         # -- paged-mode executables (see serving/paging.py).  Admission
         # prefills STRAIGHT into the shared pool: each slot writes only
@@ -451,7 +448,7 @@ class GenerationEngine:
                             packed[:, 2 * Tp:2 * Tp + C], tab, cache,
                             adapter_ids=aids)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            cache, ms.counts(self._moe_experts))
+                            cache, self._moe_sample(ms))
                 logits, cache = mdl.forward_paged(
                     packed[:, :Tp], packed[:, Tp:2 * Tp],
                     packed[:, 2 * Tp:2 * Tp + C], tab, cache,
@@ -463,7 +460,7 @@ class GenerationEngine:
 
         def cow(cache, src, dst):
             traces["cow"] += 1
-            return mdl.gpt.copy_pages(cache, src, dst)
+            return mdl.copy_pages(cache, src, dst)
 
         # hand-off seam (prefill/decode disaggregation): export gathers a
         # slot's prompt pages into one host-bound array, import scatters
@@ -472,25 +469,31 @@ class GenerationEngine:
         # a default-role engine's compile set is unchanged.
         def pexport(cache, idx):
             traces["export"] += 1
-            return mdl.gpt.gather_pages(cache, idx)
+            return mdl.gather_pages(cache, idx)
 
         def pimport(cache, kv, dst):
             traces["import"] += 1
-            return mdl.gpt.scatter_pages(cache, kv, dst)
+            return mdl.scatter_pages(cache, kv, dst)
 
         self._prefill = jax.jit(prefill)
         self._decode = jax.jit(decode)
         self._admit = jax.jit(admit)
         self._evict = jax.jit(evict)
-        self._padmit = jax.jit(padmit)
+        # the paged programs DONATE the pool they are handed: each returns
+        # the pool it was given, updated in place, so a burst of calls in
+        # flight holds one pool and not one output pool per call.  Every
+        # call site threads the returned pool on and never reads its
+        # argument again.
+        self._padmit = jax.jit(padmit, donate_argnames=("cache",))
         self._pstep = pstep  # raw fn: the overlap-schedule search re-jits
-        self._step = jax.jit(pstep)
+        self._step = jax.jit(pstep, donate_argnames=("cache",))
+        self._step_jit = self._step  # under the MoE tap, for lowering
         if self._moe_experts:
             self._decode = self._moe_tap(self._decode)
             self._step = self._moe_tap(self._step)
-        self._cow = jax.jit(cow)
+        self._cow = jax.jit(cow, donate_argnames=("cache",))
         self._export = jax.jit(pexport)
-        self._import = jax.jit(pimport)
+        self._import = jax.jit(pimport, donate_argnames=("cache",))
         self.breaker = (CircuitBreaker(name) if circuit_breaker else None)
         self._retry_transient = bool(retry_transient)
         if self._continuous:
@@ -621,7 +624,7 @@ class GenerationEngine:
                     timed[key] = best
                 self._it_wide0, self._it_fast0 = timed["wide"], timed["fast"]
             neg = jnp.asarray(np.full((B,), -1, np.int32))
-            self._cow(cache, neg, neg)
+            cache = self._cow(cache, neg, neg)
             # role-gated hand-off traces: a prefill replica exports, a
             # decode replica imports — default-role engines trace NEITHER
             # (their compile set is byte-for-byte the pre-disaggregation
@@ -661,7 +664,7 @@ class GenerationEngine:
                 pos = jnp.broadcast_to(jnp.arange(sb, dtype=jnp.int32),
                                        (B, sb))
                 lens = jnp.full((B,), sb, jnp.int32)
-                cache = self._model.gpt.init_cache(B, self._cache_len)
+                cache = self._model.init_cache(B, self._cache_len)
                 tok, cache = self._prefill(self._params, self._buffers,
                                            ids, pos, lens, cache,
                                            self._aids_arg())
@@ -721,10 +724,59 @@ class GenerationEngine:
         plan_space.apply_decode_schedule(winner)
         self._overlap_schedule = winner
 
+    def compiled_programs(self) -> Dict[str, str]:
+        """The optimized HLO text of the paged executables as warm-up
+        compiled them (``"step"``, ``"admit[<bucket>]"``): instruction
+        names as a device trace prints them, each with the
+        ``jax.named_scope`` path of the code it came from in its
+        ``op_name`` metadata, which the trace itself does not carry.
+        Lowers the same abstract calls again (with the persistent compile
+        cache on, a read); trace counters are left as they were."""
+        if not self._paged:
+            raise InvalidArgumentError("compiled_programs: paged engines")
+        B, R, G = self._batch, self._admit_rows, self._C // self._page
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        pool = jax.eval_shape(lambda: self._model.init_paged_cache(
+            self._kv_pages, self._page, dtype=self._kv_qdtype()))
+        aids = i32(R) if self._lora_cap else None
+        width = 2 * (1 + self._spec_k) + self._C + G + (
+            1 if self._lora_cap else 0)
+        snap = dict(self._traces)
+        try:
+            out = {"step": self._step_jit.lower(
+                self._params, self._buffers, i32(B, width),
+                pool).compile().as_text()}
+            for sb in self._buckets:
+                out[f"admit[{sb}]"] = self._padmit.lower(
+                    self._params, self._buffers, i32(R, sb), i32(R, sb),
+                    i32(R, self._C), i32(R, G), i32(R), pool,
+                    aids).compile().as_text()
+        finally:
+            self._traces.clear()
+            self._traces.update(snap)
+        return out
+
     # -- MoE routing-health tap --------------------------------------------
+    def _moe_sample(self, ms):
+        """``[3, E]`` int32 of one traced decode step: per-expert routed
+        and dropped tokens summed over the expert layers, and in how many
+        of those layers the expert got at least one token."""
+        E = self._moe_experts
+        self._moe_layers = len(ms.entries)  # static: read at trace time
+        return jnp.concatenate([ms.counts(E), ms.touched(E)[None]])
+
+    def expert_counts(self) -> np.ndarray:
+        """Tokens routed to each expert by the decode steps harvested so
+        far, summed over the expert layers (a copy)."""
+        return self._moe_routed_cum.copy()
+
     def _moe_tap(self, fn):
         """Wrap a jitted decode-step callable whose body returns a
-        trailing ``[2, E]`` per-expert (routed, dropped) counts array:
+        trailing ``[3, E]`` per-expert (routed, dropped, layers touched)
+        counts array (:meth:`_moe_sample`):
         pop it off the output so every call site keeps its original
         arity, and harvest the PREVIOUS call's counts — the one-step
         deferral means the ``np.asarray`` sync always lands on an array
@@ -753,6 +805,8 @@ class GenerationEngine:
         m = self.metrics
         m.incr("moe_routed_tokens", routed)
         m.incr("moe_dropped_tokens", dropped)
+        m.incr("moe_experts_touched", int(c[2].sum()))
+        m.incr("moe_layer_steps", self._moe_layers)
         if self._warm:
             m.incr("moe_sampled_steps_after_warm")
             if dropped > 0:
@@ -789,12 +843,8 @@ class GenerationEngine:
         a plain array for float pools, a ``(pages, scales)`` pair for
         quantized ones (warmup's import trace must see the live pytree
         structure or adoption would retrace on first use)."""
-        shape = self._handoff_shape()
-        qdt = self._kv_qdtype()
-        if qdt is None:
-            return np.zeros(shape, self._model.gpt.cfg.dtype)
-        return (np.zeros(shape, np.dtype(qdt)),
-                np.zeros(shape[:-1], np.float32))
+        return self._model.handoff_zero(self._Gh, self._page,
+                                        self._kv_qdtype())
 
     def _note_quant_step(self):
         """Per-decode-step fallback bookkeeping for quantized engines: a
@@ -886,7 +936,7 @@ class GenerationEngine:
         return self._decode(self._params, self._buffers,
                             jnp.asarray(np.zeros((B,), np.int32)),
                             jnp.asarray(np.full((B,), -1, np.int32)),
-                            self._model.gpt.init_cache(B, self._cache_len),
+                            self._model.init_cache(B, self._cache_len),
                             self._aids_arg())
 
     def _expire_carry(self, carry: List[tuple]) -> List[tuple]:
@@ -947,14 +997,6 @@ class GenerationEngine:
     def _new_pool(self) -> PagePool:
         return PagePool(self._batch, self._kv_pages, self._page, self._C)
 
-    def _handoff_shape(self):
-        """Static shape of a :class:`KVHandoff` payload: whole pages for
-        the largest prompt bucket, every layer's k and v stacked into one
-        array so the hand-off is a single host transfer each way."""
-        cfg = self._model.gpt.cfg
-        hd = cfg.hidden_size // cfg.num_heads
-        return (cfg.num_layers, 2, self._Gh, cfg.num_heads, self._page, hd)
-
     def _init_pool(self):
         """Fresh empty page pool for the paged decode loop, pushed through
         one inert unified step (every row position ``-1``) — same
@@ -967,7 +1009,7 @@ class GenerationEngine:
             self._params, self._buffers,
             self._pack_step(np.zeros((B, T), np.int32),
                             np.full((B, T), -1, np.int32)),
-            self._model.gpt.init_paged_cache(self._kv_pages, self._page,
+            self._model.init_paged_cache(self._kv_pages, self._page,
                                              dtype=self._kv_qdtype()))
         return cache
 
@@ -2117,7 +2159,7 @@ class GenerationEngine:
             aidsv[i] = int(r.meta[2])
 
         t0 = time.monotonic()
-        cache = self._model.gpt.init_cache(B, self._cache_len)
+        cache = self._model.init_cache(B, self._cache_len)
         with profiler.RecordEvent(f"{self.name}/prefill[{Sb}]"):
             tok, cache = self._prefill(
                 self._params, self._buffers, jnp.asarray(ids),

@@ -121,6 +121,7 @@ HANDOFF_COUNTERS = ("handoffs_out", "handoffs_in")
 #: ``moe_dead_experts`` gauges these are rule S606's signal (sustained
 #: post-warmup expert overflow, or experts that never receive a token).
 MOE_COUNTERS = ("moe_routed_tokens", "moe_dropped_tokens",
+                "moe_experts_touched", "moe_layer_steps",
                 "moe_sampled_steps_after_warm",
                 "moe_overflow_steps_after_warm")
 

@@ -849,6 +849,9 @@ class Router:
                 except TypeError:
                     close()
         self._publish()
+        # a closed router is not a live one: it leaves the profiler's
+        # summary now, not whenever the collector gets to it
+        _routers.discard(self)
 
     def __enter__(self) -> "Router":
         return self

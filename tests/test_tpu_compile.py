@@ -192,3 +192,48 @@ def test_paged_flash_admission_prefill_gpt2_small(chip):
         chip, paged_flash_decode, ((R, H, T, hd), f32),
         ((pages, H, page, hd), f32), ((pages, H, page, hd), f32),
         ((R, G), i32), ((R, T, G * page), jnp.bool_))
+
+
+@pytest.mark.parametrize("rows,tile", [(32 * 8, 16), (2 * 4096 * 8, 128)],
+                         ids=["decode", "admit_2x4096"])
+def test_ragged_gated_mlp_256_experts_of_768(chip, rows, tile):
+    # the routed experts of benchmarks/configs/joyai_flash_serve.json: 256
+    # experts of 2048 x 768, top-8; a decode step's 32 slots and an
+    # admission chunk of 2 rows x the 4096 bucket.  The three weight
+    # blocks of one expert (9.4 MB, double-buffered) need more VMEM than
+    # the compiler's default scope: the kernel asks for it
+    from paddle_tpu.ops.grouped_matmul import (ragged_gated_mlp,
+                                               ragged_layout)
+
+    E, D, F = 256, 2048, 768
+
+    def fn(x, ids, wg, wu, wd):
+        lay = ragged_layout(ids, E, tile)
+        src = jnp.full((lay["tiles"] * tile,), rows, i32).at[
+            lay["dest"]].set(jnp.arange(rows, dtype=i32))
+        xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[src]
+        return ragged_gated_mlp(xs, wg, wu, wd, lay)[lay["dest"]]
+
+    _compiles_with_kernel(chip, fn, ((rows, D), bf16), ((rows,), i32),
+                          ((E, D, F), bf16), ((E, D, F), bf16),
+                          ((E, F, D), bf16))
+
+
+@pytest.mark.parametrize("T", [1536, 4096])
+def test_latent_prefill_attention_32_heads_of_192(chip, T):
+    # an admission chunk of the joyai_flash cell: 2 rows x a prompt bucket
+    # against a slot's 4608-position view, 32 heads of 128 + 64 | 128
+    from paddle_tpu.ops.latent_attention import (latent_prefill_attention,
+                                                 latent_prefill_eligible)
+
+    B, H, C = 2, 32, 4608
+    assert latent_prefill_eligible(128, 64, 128, T, C)
+
+    def fn(qn, qr, kn, kr, v, qp, kp):
+        return latent_prefill_attention(qn, qr, kn, kr, v, qp, kp, C,
+                                        192 ** -0.5)
+
+    _compiles_with_kernel(
+        chip, fn, ((B, H, T, 128), bf16), ((B, H, T, 64), bf16),
+        ((B, H, C, 128), bf16), ((B, C, 64), bf16), ((B, H, C, 128), bf16),
+        ((B, T), i32), ((B, C), i32))
