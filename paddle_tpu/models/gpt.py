@@ -164,14 +164,19 @@ class ParallelAttention(Layer):
 
         return get_mesh().shape.get("sep", 1)
 
-    def _heads(self, x):
-        """qkv projection → per-head ``[B,H,S,hd]`` triples."""
+    def _qkv(self, x):
+        """qkv projection → token-major ``[B,S,H,hd]`` triples (a token's
+        heads side by side, as the projection wrote them)."""
         B, S, D = x.shape
         qkv = self.qkv(x)  # [B,S,3D] sharded on last dim
         qkv = qkv.reshape(B, S, 3, self.num_heads, self.head_dim)
         # heads inherit the model sharding of the projection output
         qkv = constrain(qkv, None, None, None, "model", None)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,S,H,hd]
+        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+    def _heads(self, x):
+        """qkv projection → per-head ``[B,H,S,hd]`` triples."""
+        q, k, v = self._qkv(x)
         return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3))
 
@@ -276,67 +281,69 @@ class ParallelAttention(Layer):
         """One attention step over a PAGED KV pool — the paged serving
         decode path (see :meth:`GPTModel.init_paged_cache`).
 
-        The new tokens' K/V are scattered into the shared page pool at
-        host-resolved physical coordinates (``write_page``/``write_off``,
-        flattened ``[B*T]``; the pool's last page is the write-drop page
-        for padding), then each slot's logical cache view is gathered
-        back through its page-table row (``gather_tab`` ``[B,G]``,
-        entries pre-clipped to valid pages) and attention runs over the
-        gathered ``[B,H,C,hd]`` view exactly as the dense ring path does
-        — same einsums, same mask semantics, so tokens stay
-        bit-identical.  ``mask``: ``[B,T,C]`` attention validity computed
-        from the host-owned slot→position map.
+        The pool stores one token's K (or V) for ALL heads as one
+        contiguous row: ``[P+1, page, H*hd]``.  The new tokens' rows come
+        from the fused projection before any head split and are scattered
+        at host-resolved physical coordinates (``write_page``/
+        ``write_off``, flattened ``[B*T]``; the pool's last page is the
+        write-drop page for padding).  On the TPU the ``paged_decode``
+        kernel then reads the pool in that same order; elsewhere each
+        slot's logical cache view is gathered back through its page-table
+        row (``gather_tab`` ``[B,G]``, entries pre-clipped to valid pages)
+        and attention runs over the gathered ``[B,H,C,hd]`` view exactly
+        as the dense ring path does — same einsums, same mask semantics,
+        so tokens stay bit-identical.  ``mask``: ``[B,T,C]`` attention
+        validity computed from the host-owned slot→position map.
         """
         B, T, D = x.shape
         H, hd = self.num_heads, self.head_dim
-        q, k, v = self._heads(x)  # [B,H,T,hd]
-        kw = k.transpose(0, 2, 1, 3).reshape(B * T, H, hd)
-        vw = v.transpose(0, 2, 1, 3).reshape(B * T, H, hd)
+        q, k, v = self._qkv(x)  # [B,T,H,hd]: a token's heads side by side
+        q = q.transpose(0, 2, 1, 3)  # [B,H,T,hd]
         quantized = "k_scale" in kv  # static: pool dtype fixed at init
-        if quantized:
-            (kw, ks), (vw, vs) = (_quantize_kv(kw, kv["k"].dtype),
-                                  _quantize_kv(vw, kv["v"].dtype))
-            new_ks = kv["k_scale"].at[write_page, :, write_off].set(ks)
-            new_vs = kv["v_scale"].at[write_page, :, write_off].set(vs)
-        new_k = kv["k"].at[write_page, :, write_off].set(
-            kw.astype(kv["k"].dtype))
-        new_v = kv["v"].at[write_page, :, write_off].set(
-            vw.astype(kv["v"].dtype))
-        G, page = gather_tab.shape[1], kv["k"].shape[2]
-        out = {"k": new_k, "v": new_v}
-        if quantized:
-            out["k_scale"], out["v_scale"] = new_ks, new_vs
+        out = {}
+        for name, rows in (("k", k), ("v", v)):
+            rows = rows.reshape(B * T, H, hd)
+            if quantized:
+                rows, scale = _quantize_kv(rows, kv[name].dtype)
+                out[name + "_scale"] = kv[name + "_scale"].at[
+                    write_page, write_off].set(scale)
+            # a token is one row of the pool: [B*T, H*hd] lands at
+            # (page, offset) whole, no per-head pieces
+            out[name] = kv[name].at[write_page, write_off].set(
+                rows.reshape(B * T, H * hd).astype(kv[name].dtype))
+        new_k, new_v = out["k"], out["v"]
+        G, page = gather_tab.shape[1], new_k.shape[1]
         if _paged_flash(hd, page):
             # TPU hot path: page-table walk + dequant + online softmax in
-            # ONE Pallas kernel over the post-scatter pool — the [B,H,C,hd]
-            # float KV view is never materialized (ops/paged_attention.py).
-            # The scatter above is identical on both paths, so the cache
-            # state (and the CPU fallback below) stays bit-identical.
+            # ONE Pallas kernel over the post-scatter pool, read in its
+            # stored order — the [B,H,C,hd] float KV view is never
+            # materialized (ops/paged_attention.py).  The scatter above is
+            # identical on both paths, so the cache state (and the CPU
+            # fallback below) stays bit-identical.
             from ..ops.paged_attention import paged_flash_decode
 
             ctx = paged_flash_decode(
                 q, new_k, new_v, gather_tab, mask,
-                new_ks if quantized else None,
-                new_vs if quantized else None)  # [B,H,T,hd]
+                out.get("k_scale"), out.get("v_scale"))  # [B,H,T,hd]
             ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
             ctx = constrain(ctx, None, None, "model")
             return self.out(ctx), out
-        kview = jnp.take(new_k, gather_tab, axis=0)  # [B,G,H,page,hd]
-        vview = jnp.take(new_v, gather_tab, axis=0)
-        kview = kview.transpose(0, 2, 1, 3, 4).reshape(B, H, G * page, hd)
-        vview = vview.transpose(0, 2, 1, 3, 4).reshape(B, H, G * page, hd)
+
+        def view(pool, *tail):
+            # [P+1, page, *] pages → the slots' logical [B, H, C, *tail]
+            t = jnp.take(pool, gather_tab, axis=0)  # [B,G,page,H*...]
+            t = t.reshape(B, G * page, H, *tail)
+            return jnp.moveaxis(t, 2, 1)
+
+        kview, vview = view(new_k, hd), view(new_v, hd)
         if quantized:
             # dequantize the gathered view: one multiplier per (page
             # entry, head), broadcast over hd — drop-page entries carry
             # scale 0 and are masked out below anyway
-            ksview = jnp.take(new_ks, gather_tab, axis=0)  # [B,G,H,page]
-            vsview = jnp.take(new_vs, gather_tab, axis=0)
-            ksview = ksview.transpose(0, 2, 1, 3).reshape(B, H, G * page)
-            vsview = vsview.transpose(0, 2, 1, 3).reshape(B, H, G * page)
             kview = (kview.astype(jnp.float32)
-                     * ksview[..., None]).astype(q.dtype)
+                     * view(out["k_scale"])[..., None]).astype(q.dtype)
             vview = (vview.astype(jnp.float32)
-                     * vsview[..., None]).astype(q.dtype)
+                     * view(out["v_scale"])[..., None]).astype(q.dtype)
         scores = jnp.einsum("bhqd,bhcd->bhqc", q, kview) / math.sqrt(hd)
         scores = jnp.where(mask[:, None], scores,
                            jnp.finfo(scores.dtype).min)
@@ -536,8 +543,14 @@ class GPTModel(Layer):
 
     # -- paged KV cache (vLLM-style PagedAttention; Kwon et al. 2023) -------
     def init_paged_cache(self, num_pages: int, page_size: int, dtype=None):
-        """Preallocate a paged KV pool: per-layer ``[P+1, H, page, hd]``
-        K/V page arrays shared by ALL slots.  Which physical page holds
+        """Preallocate a paged KV pool: per-layer ``[P+1, page, H*hd]``
+        K/V page arrays shared by ALL slots.  A token's K (or V) for all
+        heads is ONE contiguous row, a page is ``page`` such rows: the one
+        stored order that the scatter of :meth:`forward_paged`, the paged
+        programs' entry and exit and the ``paged_decode`` kernel's blocks
+        all use as it is, so no program re-orders the pool (with ``hd``
+        minor, half a lane tile for GPT-2, every program copied all of it
+        to another order and back).  Which physical page holds
         which slot's tokens is decided per call by a host-owned page
         table (see :meth:`forward_paged`) — the indirection that lets
         pages be allocated on demand, shared copy-on-write between slots
@@ -548,25 +561,24 @@ class GPTModel(Layer):
 
         ``dtype=int8`` (or ``float8_e4m3fn``) switches the pool to
         QUANTIZED KV pages: each layer additionally holds per-entry
-        ``k_scale``/``v_scale`` ``[P+1, H, page]`` float32 tensors (one
-        scale per written token per head), K/V quantize on write in
-        :meth:`forward_paged`'s scatter and dequantize on gather in
-        attention — the same HBM budget holds ~2-4× the tokens, and the
-        host-side page table / CoW machinery is untouched (table edits
-        are dtype-blind)."""
+        ``k_scale``/``v_scale`` ``[P+1, page, H]`` float32 tensors (one
+        scale per written token per head, indexed like the values), K/V
+        quantize on write in :meth:`forward_paged`'s scatter and
+        dequantize on gather in attention — the same HBM budget holds
+        ~2-4× the tokens, and the host-side page table / CoW machinery is
+        untouched (table edits are dtype-blind)."""
         cfg = self.cfg
-        hd = cfg.hidden_size // cfg.num_heads
         dt = dtype or cfg.dtype
         P, pg = int(num_pages), int(page_size)
         quantized = str(jnp.dtype(dt)) in ("int8", "float8_e4m3fn")
 
         def layer():
-            l = {"k": jnp.zeros((P + 1, cfg.num_heads, pg, hd), dt),
-                 "v": jnp.zeros((P + 1, cfg.num_heads, pg, hd), dt)}
+            l = {"k": jnp.zeros((P + 1, pg, cfg.hidden_size), dt),
+                 "v": jnp.zeros((P + 1, pg, cfg.hidden_size), dt)}
             if quantized:
-                l["k_scale"] = jnp.zeros((P + 1, cfg.num_heads, pg),
+                l["k_scale"] = jnp.zeros((P + 1, pg, cfg.num_heads),
                                          jnp.float32)
-                l["v_scale"] = jnp.zeros((P + 1, cfg.num_heads, pg),
+                l["v_scale"] = jnp.zeros((P + 1, pg, cfg.num_heads),
                                          jnp.float32)
             return l
 
@@ -598,12 +610,13 @@ class GPTModel(Layer):
         prefill→decode KV hand-off (serving/pool.py): ``idx`` is a
         fixed-size ``[K]`` int32 vector of physical page numbers (``-1``
         reads the all-zero write-drop page, so the op always runs at one
-        static shape).  Returns one stacked ``[L, 2, K, H, page, hd]``
-        array (layer-major, k/v interleaved) so the hand-off rides a
-        single host transfer instead of ``2L`` small ones.
+        static shape).  Returns one stacked ``[L, 2, K, page, H*hd]``
+        array (layer-major, k/v interleaved, pages in the pool's stored
+        order) so the hand-off rides a single host transfer instead of
+        ``2L`` small ones.
 
         Quantized pools return ``(pages, scales)`` — the quantized
-        ``[L, 2, K, H, page, hd]`` stack plus its ``[L, 2, K, H, page]``
+        ``[L, 2, K, page, H*hd]`` stack plus its ``[L, 2, K, page, H]``
         float32 scale stack — so a hand-off never round-trips through
         float (the adopting engine's pool stores the exact same bits)."""
         P = cache["layers"][0]["k"].shape[0] - 1
@@ -620,7 +633,7 @@ class GPTModel(Layer):
 
     def scatter_pages(self, cache, kv, dst):
         """Write :meth:`gather_pages` payloads into the pool — the import
-        half of the KV hand-off: ``kv`` is the ``[L, 2, K, H, page, hd]``
+        half of the KV hand-off: ``kv`` is the ``[L, 2, K, page, H*hd]``
         export and ``dst`` the ``[K]`` int32 target pages the adopting
         host allocated (``-1`` lands in the write-drop page).  Same
         static-shape contract as :meth:`copy_pages`, so the adopting
@@ -672,7 +685,7 @@ class GPTModel(Layer):
         pos_map = jnp.asarray(pos_map, jnp.int32)
         table = jnp.asarray(table, jnp.int32)
         P = cache["layers"][0]["k"].shape[0] - 1
-        page = cache["layers"][0]["k"].shape[2]
+        page = cache["layers"][0]["k"].shape[1]
         G = table.shape[1]
         C = G * page
         x = self.wte(input_ids) + self.wpe(jnp.maximum(positions, 0))
@@ -780,17 +793,16 @@ class GPTForCausalLM(Layer):
 
     def handoff_zero(self, num_pages, page_size, dtype=None):
         """Zeros in the shape :meth:`gather_pages` exports: one ``[L, 2,
-        K, H, page, hd]`` array, or for a quantized pool (``dtype`` int8 /
+        K, page, H*hd]`` array, or for a quantized pool (``dtype`` int8 /
         fp8) the ``(pages, scales)`` pair."""
         import numpy as np
 
         cfg = self.gpt.cfg
-        shape = (cfg.num_layers, 2, int(num_pages), cfg.num_heads,
-                 int(page_size), cfg.hidden_size // cfg.num_heads)
+        shape = (cfg.num_layers, 2, int(num_pages), int(page_size))
         if dtype is None:
-            return np.zeros(shape, cfg.dtype)
-        return (np.zeros(shape, np.dtype(dtype)),
-                np.zeros(shape[:-1], np.float32))
+            return np.zeros(shape + (cfg.hidden_size,), cfg.dtype)
+        return (np.zeros(shape + (cfg.hidden_size,), np.dtype(dtype)),
+                np.zeros(shape + (cfg.num_heads,), np.float32))
 
     def forward(self, input_ids, attn_mask=None):
         if getattr(self.gpt.cfg, "moe_experts", 0):

@@ -138,8 +138,9 @@ class KVHandoff(NamedTuple):
 
     prompt: np.ndarray    # [length] int32 prompt tokens
     first_token: int      # greedy token from the prompt's last logit
-    kv: object            # [layers, 2, K, heads, page, hd] exported pages
-    #                       (array), or a (pages, scales) pair when the
+    kv: object            # exported pages in the donor pool's stored
+    #                       order (GPTModel: [layers, 2, K, page,
+    #                       heads*hd]), or a (pages, scales) pair when the
     #                       donor pool is quantized — both engines must
     #                       share the same `quantized` mode
     length: int           # resident KV covers positions 0..length-1

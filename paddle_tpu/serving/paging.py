@@ -2,7 +2,8 @@
 
 vLLM-style PagedAttention bookkeeping (Kwon et al., SOSP 2023): the
 device holds one flat page pool per layer (``GPTModel.init_paged_cache``
-— ``[P+1, H, page, hd]`` with the last page as a write-drop page), and
+— ``[P+1, page, H*hd]``, a token's heads side by side in one row, with
+the last page as a write-drop page), and
 *everything else lives here on the host*: per-slot page tables, the
 slot→absolute-position map, per-page refcounts, the free list, and the
 shared-prefix registry.  The device never sees an allocation decision —

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.framework.errors import InvalidArgumentError
 from paddle_tpu.framework.flags import set_flags
-from paddle_tpu.ops.paged_attention import (_paged_decode,
+from paddle_tpu.ops.paged_attention import (_head_blocks, _paged_decode,
                                             paged_flash_decode,
                                             paged_flash_eligible)
 
@@ -26,16 +26,18 @@ def _ref_attend(q, k_pool, v_pool, tables, mask, k_scale=None, v_scale=None):
     a uniform -1e30 row — garbage by construction — so callers compare
     valid rows only."""
     B, H, T, hd = q.shape
-    page = k_pool.shape[2]
+    page = k_pool.shape[1]
     tab = np.maximum(np.asarray(tables), 0)
-    k = np.asarray(k_pool, np.float32)[tab]  # [B, G, H, page, hd]
-    v = np.asarray(v_pool, np.float32)[tab]
-    if k_scale is not None:
+    B_, G = tab.shape
+    # the pool's stored order: [P+1, page, H*hd], a token's heads side by
+    # side in one row -> [B, G, page, H, hd]
+    k = np.asarray(k_pool, np.float32)[tab].reshape(B, G, page, H, hd)
+    v = np.asarray(v_pool, np.float32)[tab].reshape(B, G, page, H, hd)
+    if k_scale is not None:  # [P+1, page, H], indexed like the values
         k = k * np.asarray(k_scale, np.float32)[tab][..., None]
         v = v * np.asarray(v_scale, np.float32)[tab][..., None]
-    B_, G = tab.shape
-    k = k.transpose(0, 2, 1, 3, 4).reshape(B, H, G * page, hd)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(B, H, G * page, hd)
+    k = k.transpose(0, 3, 1, 2, 4).reshape(B, H, G * page, hd)
+    v = v.transpose(0, 3, 1, 2, 4).reshape(B, H, G * page, hd)
     s = np.einsum("bhtd,bhcd->bhtc", np.asarray(q, np.float32),
                   k) / np.sqrt(hd)
     s = np.where(np.asarray(mask)[:, None], s, -1e30)
@@ -45,12 +47,13 @@ def _ref_attend(q, k_pool, v_pool, tables, mask, k_scale=None, v_scale=None):
                      p / np.maximum(p.sum(-1, keepdims=True), 1e-30), v)
 
 
-def _geometry(rng, B=3, H=4, hd=16, page=16, G=4, T=1, dtype=np.float32):
+def _geometry(rng, B=3, H=4, hd=64, page=16, G=4, T=1, dtype=np.float32):
     """A ragged paged layout: slot b holds ``lengths[b]`` tokens across
-    its first ceil(len/page) table entries; the rest are unmapped (-1)."""
+    its first ceil(len/page) table entries; the rest are unmapped (-1).
+    Heads of 64 (GPT-2's, half a lane tile): a head block is 2 or 4."""
     P = B * G  # enough physical pages for a 1:1 mapping + 1 drop page
-    k_pool = rng.randn(P + 1, H, page, hd).astype(dtype)
-    v_pool = rng.randn(P + 1, H, page, hd).astype(dtype)
+    k_pool = rng.randn(P + 1, page, H * hd).astype(dtype)
+    v_pool = rng.randn(P + 1, page, H * hd).astype(dtype)
     lengths = [G * page - 1 - 3 * b for b in range(B)]  # ragged, >= T
     tables = np.full((B, G), -1, np.int32)
     nxt = 0
@@ -68,19 +71,21 @@ def _geometry(rng, B=3, H=4, hd=16, page=16, G=4, T=1, dtype=np.float32):
     return q, k_pool, v_pool, tables, mask
 
 
-def _quantize(pool, dtype):
+def _quantize(pool, dtype, H=4):
     """Per-(page entry, head) abs-max quantization, the serving layout:
-    scale [P+1, H, page] f32 applied over hd."""
+    scale [P+1, page, H] f32 applied over each head's hd lanes."""
+    flat = pool.shape
+    pool = pool.reshape(flat[0], flat[1], H, flat[2] // H)
     amax = np.abs(pool).max(-1)
     if dtype == "int8":
         scale = amax / 127.0
         qp = np.clip(np.round(pool / np.maximum(scale, 1e-30)[..., None]),
                      -127, 127).astype(np.int8)
-        qp = jnp.asarray(qp)
+        qp = jnp.asarray(qp.reshape(flat))
     else:  # fp8-e4m3
         scale = amax / 448.0
-        qp = jnp.asarray(pool / np.maximum(scale, 1e-30)[..., None]
-                         ).astype(jnp.float8_e4m3fn)
+        qp = jnp.asarray((pool / np.maximum(scale, 1e-30)[..., None]
+                          ).reshape(flat)).astype(jnp.float8_e4m3fn)
     return qp, jnp.asarray(scale.astype(np.float32))
 
 
@@ -93,7 +98,9 @@ class TestEquivalence:
         rng = np.random.RandomState(0)
         q, kp, vp, tab, mask = _geometry(rng)
         cands = _paged_decode.candidates(q, kp, vp, tab, mask, None, None)
-        assert len(cands) >= 2  # H=4 -> at least block_h 1, 2, 4
+        # H=4 heads of 64: a block of 4 (the whole row) or 2 (one lane
+        # tile); one head alone is half a tile and is not offered
+        assert sorted(c["block_h"] for c in cands) == [2, 4]
         want = _ref_attend(q, kp, vp, tab, mask)
         for cfg in cands:
             out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
@@ -101,6 +108,36 @@ class TestEquivalence:
                                      jnp.asarray(mask), **cfg)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=2e-4, atol=2e-5)
+
+    def test_decode_width_sweeps_the_heads_as_one_block_diagonal_head(self):
+        # q [B, H, 1, hd] over float pages with no block asked for: the
+        # heads become the rows of one head as wide as a pool row; same
+        # products, so the per-head form agrees to rounding, fully
+        # masked slots (free slots of a decode step) emit zeros, and the
+        # other heads' lanes never leak into a head's context
+        rng = np.random.RandomState(9)
+        q, kp, vp, tab, mask = _geometry(rng, H=12)  # GPT-2's 12 x 64
+        mask[2] = False
+        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+                _clipped(tab), jnp.asarray(mask))
+        jaxpr = str(jax.make_jaxpr(paged_flash_decode)(*args))
+        assert "f32[3,1,16,768]" in jaxpr  # 12 heads -> 16 rows of 768
+        out = np.asarray(paged_flash_decode(*args))
+        assert out.shape == q.shape
+        np.testing.assert_array_equal(out[2], 0.0)
+        want = _ref_attend(q, kp, vp, tab, mask)
+        np.testing.assert_allclose(out[:2], want[:2], rtol=2e-4, atol=2e-5)
+        per_head = np.asarray(paged_flash_decode(*args, block_h=12))
+        np.testing.assert_allclose(out, per_head, rtol=1e-5, atol=1e-6)
+        # poison one head's lanes of every page: only that head moves
+        kp2, vp2 = kp.copy(), vp.copy()
+        kp2[:, :, 3 * 64:4 * 64] += 5.0
+        vp2[:, :, 3 * 64:4 * 64] -= 7.0
+        out2 = np.asarray(paged_flash_decode(
+            args[0], jnp.asarray(kp2), jnp.asarray(vp2), *args[3:]))
+        others = [h for h in range(12) if h != 3]
+        np.testing.assert_array_equal(out2[:, others], out[:, others])
+        assert np.abs(out2[:2, 3] - out[:2, 3]).max() > 1.0
 
     @pytest.mark.parametrize("qdtype", ["int8", "fp8"])
     def test_quantized_all_candidates(self, qdtype):
@@ -177,6 +214,32 @@ class TestEquivalence:
                            np.asarray(vb, np.float32), tab, mask)
         np.testing.assert_allclose(np.asarray(out, np.float32), want,
                                    rtol=2e-2, atol=2e-2)
+
+    def test_head_blocks_are_whole_lane_tiles_or_the_row(self):
+        assert _head_blocks(12, 64) == [12, 6, 4, 2]   # GPT-2-small
+        assert _head_blocks(20, 64) == [20, 10, 4, 2]  # GPT-2-large
+        assert _head_blocks(8, 128) == [8, 4, 2, 1]
+        assert _head_blocks(4, 8) == [4]  # tiny models: the row whole
+        # a block the row cannot be cut into runs as the whole row
+        rng = np.random.RandomState(7)
+        q, kp, vp, tab, mask = _geometry(rng)
+        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+                _clipped(tab), jnp.asarray(mask))
+        np.testing.assert_array_equal(
+            np.asarray(paged_flash_decode(*args, block_h=1)),
+            np.asarray(paged_flash_decode(*args, block_h=4)))
+
+    def test_small_heads_and_wide_pages(self):
+        # hd=16 (a row of 64 lanes, one block) and pages of 128
+        rng = np.random.RandomState(8)
+        for kw in (dict(hd=16), dict(hd=16, page=128, G=2)):
+            q, kp, vp, tab, mask = _geometry(rng, **kw)
+            out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
+                                     jnp.asarray(vp), _clipped(tab),
+                                     jnp.asarray(mask))
+            np.testing.assert_allclose(np.asarray(out),
+                                       _ref_attend(q, kp, vp, tab, mask),
+                                       rtol=2e-4, atol=2e-5)
 
     def test_scale_pair_enforced(self):
         rng = np.random.RandomState(6)

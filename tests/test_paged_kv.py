@@ -163,6 +163,91 @@ class TestPagedGeneration(unittest.TestCase):
                              eng._pool.num_pages)  # all returned
             self.assertEqual(st["kv_pages_leaked"], 0)
 
+    def test_pool_is_stored_as_token_rows_of_all_heads(self):
+        # ONE stored order: a layer's K (and V) is [P+1, page, H*hd], a
+        # token's heads side by side in one row; scale planes of a
+        # quantized pool are indexed the same way.  A direct forward
+        # writes token t of slot 0 at (page, offset) as the row the
+        # fused projection produced, before any head split
+        import jax.numpy as jnp
+        gpt, cfg = self.model.gpt, self.cfg
+        pool = gpt.init_paged_cache(6, 8)
+        for l in pool["layers"]:
+            self.assertEqual(sorted(l), ["k", "v"])
+            self.assertEqual(l["k"].shape, (7, 8, cfg.hidden_size))
+            self.assertEqual(l["v"].shape, (7, 8, cfg.hidden_size))
+        qpool = gpt.init_paged_cache(6, 8, dtype=jnp.int8)
+        self.assertEqual(qpool["layers"][0]["k"].shape,
+                         (7, 8, cfg.hidden_size))
+        self.assertEqual(qpool["layers"][1]["v_scale"].shape,
+                         (7, 8, cfg.num_heads))
+        ids = np.asarray([[3, 14, 15, 9, 2, 6, 5, 35, 8, 0]], np.int32)
+        T = ids.shape[1]
+        pos = np.arange(T, dtype=np.int32)[None]
+        pos_map = np.full((1, 16), -1, np.int32)
+        pos_map[0, :T] = np.arange(T)
+        table = np.asarray([[4, 1]], np.int32)  # logical page 0 -> 4, 1 -> 1
+        _, new = gpt.forward_paged(ids, pos, pos_map, table, pool)
+        blk = gpt.blocks[0]
+        x = gpt.wte(jnp.asarray(ids)) + gpt.wpe(jnp.asarray(pos))
+        qkv = np.asarray(blk.attn.qkv(blk.ln1(x)))[0]  # [T, 3D]
+        D = cfg.hidden_size
+        k0, v0 = np.asarray(new["layers"][0]["k"]), np.asarray(
+            new["layers"][0]["v"])
+        np.testing.assert_array_equal(k0[4], qkv[:8, D:2 * D])
+        np.testing.assert_array_equal(k0[1, :2], qkv[8:, D:2 * D])
+        np.testing.assert_array_equal(v0[4], qkv[:8, 2 * D:])
+        untouched = [p for p in range(7) if p not in (4, 1)]
+        self.assertFalse(k0[untouched].any() or k0[1, 2:].any())
+
+    def test_handoff_payload_rides_in_the_stored_order(self):
+        # a hand-off exported by one engine and adopted by another: the
+        # payload is [L, 2, K, page, H*hd], pages exactly as the donor's
+        # pool stores them (every row a token's K or V for all heads),
+        # and the adopter reproduces the donor's tokens
+        from paddle_tpu.serving import KVHandoff
+        cfg = self.cfg
+        prompts = [(np.arange(11) * 7 + 3) % 97, (np.arange(5) * 3 + 1) % 97]
+        budgets = [6, 4]
+        refs = [self._ref_greedy(p, b) for p, b in zip(prompts, budgets)]
+
+        def eng(role, name):
+            return GenerationEngine(self.model, prompt_buckets=[8, 16],
+                                    batch_size=2, paged=True,
+                                    kv_page_size=8, speculative_k=0,
+                                    role=role, name=name)
+
+        with eng("prefill", "ho-pre") as pre, eng("decode", "ho-dec") as dec:
+            pre.warmup()
+            dec.warmup()
+            for p, b, ref in zip(prompts, budgets, refs):
+                p = p.astype(np.int32)
+                h = pre.submit(p, b, handoff=True).result(120)
+                self.assertIsInstance(h, KVHandoff)
+                kv = np.asarray(h.kv)
+                # widest bucket 16 / page 8 = 2 pages whatever the prompt
+                self.assertEqual(kv.shape, (cfg.num_layers, 2, 2, 8,
+                                            cfg.hidden_size))
+                self.assertEqual((h.length, h.first_token),
+                                 (len(p), ref[0]))
+                rows = kv.reshape(cfg.num_layers, 2, 16, cfg.hidden_size)
+                self.assertTrue(rows[:, :, :len(p)].any(axis=-1).all())
+                # layer 0's K rows ARE the projection's rows, token-major
+                gpt = self.model.gpt
+                import jax.numpy as jnp
+                x = (gpt.wte(jnp.asarray(p[None]))
+                     + gpt.wpe(jnp.arange(len(p))[None]))
+                qkv = np.asarray(gpt.blocks[0].attn.qkv(
+                    gpt.blocks[0].ln1(x)))[0]
+                D = cfg.hidden_size
+                np.testing.assert_allclose(rows[0, 0, :len(p)],
+                                           qkv[:, D:2 * D], rtol=1e-5,
+                                           atol=1e-6)
+                got = dec.submit(p, b, handoff=h).result(120)
+                self.assertEqual(np.asarray(got).tolist(), ref)
+            self.assertEqual(dec.metrics.snapshot()["handoffs_in"], 2)
+            self.assertEqual(pre.metrics.snapshot()["handoffs_out"], 2)
+
     def test_cow_prefix_sharing_isolation(self):
         # four requests share a system prompt under one prefix_key; the
         # prefix prefills once, siblings CoW the boundary page, and

@@ -66,19 +66,26 @@ class TestQuantizedKVPages:
         pool_a = gpt.init_paged_cache(4, PAGE, dtype=jnp.int8)
         kv = jnp.asarray(rng.randn(PAGE, 4, 8).astype(np.float32))
         q, s = _quantize_kv(kv, jnp.int8)
+        # the stored order: a page is PAGE token rows of H*hd values,
+        # its scale plane [PAGE, H] is indexed the same way
+        assert pool_a["layers"][0]["k"].shape == (5, PAGE, 4 * 8)
+        assert pool_a["layers"][0]["k_scale"].shape == (5, PAGE, 4)
+        rows = q.reshape(PAGE, 4 * 8)
         layers = []
         for l in pool_a["layers"]:
             layers.append({
-                "k": l["k"].at[1].set(jnp.transpose(q, (1, 0, 2))),
-                "v": l["v"].at[1].set(jnp.transpose(q, (1, 0, 2))),
-                "k_scale": l["k_scale"].at[1].set(jnp.transpose(s)),
-                "v_scale": l["v_scale"].at[1].set(jnp.transpose(s)),
+                "k": l["k"].at[1].set(rows),
+                "v": l["v"].at[1].set(rows),
+                "k_scale": l["k_scale"].at[1].set(s),
+                "v_scale": l["v_scale"].at[1].set(s),
             })
         pool_a = {"layers": layers}
         exported = gpt.gather_pages(pool_a, jnp.asarray([1], jnp.int32))
         assert isinstance(exported, tuple)  # (pages, scales) pair
         pages, scales = exported
         assert pages.dtype == jnp.int8 and scales.dtype == jnp.float32
+        assert pages.shape == (2, 2, 1, PAGE, 4 * 8)
+        assert scales.shape == (2, 2, 1, PAGE, 4)
         pool_b = gpt.init_paged_cache(4, PAGE, dtype=jnp.int8)
         pool_b = gpt.scatter_pages(pool_b, exported,
                                    jnp.asarray([2], jnp.int32))
@@ -92,7 +99,7 @@ class TestQuantizedKVPages:
     def test_scatter_quantized_pool_requires_scales(self):
         gpt = _model().gpt
         pool = gpt.init_paged_cache(4, PAGE, dtype=jnp.int8)
-        bare = jnp.zeros((2, 2, 1, 4, PAGE, 8), jnp.int8)
+        bare = jnp.zeros((2, 2, 1, PAGE, 4 * 8), jnp.int8)
         with pytest.raises(ValueError):
             gpt.scatter_pages(pool, bare, jnp.asarray([0], jnp.int32))
 

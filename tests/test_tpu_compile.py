@@ -164,18 +164,19 @@ def test_grouped_matmul_moe_experts(chip, bwd):
 @pytest.mark.parametrize("page", [16, 128])
 @pytest.mark.parametrize("pool", [f32, i8], ids=["float", "int8"])
 def test_paged_flash_decode_gpt2_small(chip, pool, page, T):
-    # GPT-2-small decode: 12 heads x 64, cache 1024, 4 slots
+    # GPT-2-small decode: 12 heads x 64, cache 1024, 4 slots; the pool in
+    # its stored order, a token's heads side by side in one 768-wide row
     from paddle_tpu.models.gpt import _paged_flash
     from paddle_tpu.ops.paged_attention import paged_flash_decode
 
     assert _paged_flash(64, page)  # the model's gate selects the kernel
     B, H, hd, G = 4, 12, 64, 1024 // page
     pages = B * G + 1
-    shapes = [((B, H, T, hd), f32), ((pages, H, page, hd), pool),
-              ((pages, H, page, hd), pool), ((B, G), i32),
+    shapes = [((B, H, T, hd), f32), ((pages, page, H * hd), pool),
+              ((pages, page, H * hd), pool), ((B, G), i32),
               ((B, T, G * page), jnp.bool_)]
     if pool == i8:
-        shapes += [((pages, H, page), f32)] * 2
+        shapes += [((pages, page, H), f32)] * 2
     _compiles_with_kernel(chip, paged_flash_decode, *shapes)
 
 
@@ -190,8 +191,136 @@ def test_paged_flash_admission_prefill_gpt2_small(chip):
     G, pages = 1024 // page, 2048 + 1
     _compiles_with_kernel(
         chip, paged_flash_decode, ((R, H, T, hd), f32),
-        ((pages, H, page, hd), f32), ((pages, H, page, hd), f32),
+        ((pages, page, H * hd), f32), ((pages, page, H * hd), f32),
         ((R, G), i32), ((R, T, G * page), jnp.bool_))
+
+
+# -- the stored order of GPT-2's page pool ------------------------------------
+@pytest.fixture(scope="module")
+def gpt2_small_engine():
+    # the engine of the chip benchmark's GPT-2 cells
+    # (benchmarks/configs/gpt2_small_serve.json, traffic docs_closed) with
+    # weights that are shapes only: nothing runs, its jitted programs are
+    # lowered for the described chip
+    from paddle_tpu import nn
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    with nn.abstract_parameters():
+        model = GPTForCausalLM(GPTConfig(dropout=0.0))
+    eng = GenerationEngine(
+        model, prompt_buckets=[512, 640, 768], batch_size=32, paged=True,
+        continuous=True, kv_page_size=16, speculative_k=0,
+        eos_token_id=None, name="compile-only")
+    yield eng
+    eng.close()
+
+
+def _stray_pool_results(text, pool_elems):
+    """Instructions of an optimized HLO program whose result is an array
+    of exactly ``pool_elems`` elements (one K or V pool, in any shape or
+    order) and that are NOT one of: the
+    program's parameter, the in-place write (``scatter`` /
+    ``dynamic-update-slice``, or a fusion whose body holds one), the
+    Pallas kernel, or the plumbing around them (tuples, bitcasts).
+    Returns ``(strays, kinds seen)``."""
+    import math
+    import re
+
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(%?[\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    stray, kinds = [], set()
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = [a-z0-9]+\[([0-9,]+)\]\S* ([a-z\-]+)\("
+            r"([^\n]*)", text, re.M):
+        name, dims, op, rest = m.groups()
+        if math.prod(int(d) for d in dims.split(",")) != pool_elems:
+            continue
+        kinds.add(op)
+        if op in ("parameter", "scatter", "dynamic-update-slice", "tuple",
+                  "get-tuple-element", "bitcast"):
+            continue
+        if op == "custom-call" and "tpu_custom_call" in rest:
+            continue
+        if op == "fusion":
+            body = bodies.get(re.search(r"calls=(%?[\w.\-]+)", rest).group(1))
+            if body and re.search(r" (scatter|dynamic-update-slice)\(", body):
+                continue
+        stray.append((op, name))
+    return stray, kinds
+
+
+def test_stray_pool_results_sees_a_copy():
+    text = """HloModule m
+%fused_computation.1 (p: f32[9,4,8]) -> f32[9,4,8] {
+  %p = f32[9,4,8]{2,1,0} parameter(0)
+  ROOT %s = f32[9,4,8]{2,1,0} scatter(%p, %p, %p), to_apply=%x
+}
+%fused_computation.2 (p: f32[9,4,8]) -> f32[9,4,8] {
+  %p = f32[9,4,8]{2,1,0} parameter(0)
+  ROOT %c = f32[9,4,8]{0,2,1} copy(%p)
+}
+ENTRY %main (a: f32[9,4,8]) -> f32[9,4,8] {
+  %a = f32[9,4,8]{2,1,0} parameter(0)
+  %f1 = f32[9,4,8]{2,1,0} fusion(%a), kind=kCustom, calls=%fused_computation.1
+  %b = f32[36,8]{1,0} bitcast(%f1)
+  %f2 = f32[9,4,8]{0,2,1} fusion(%f1), kind=kLoop, calls=%fused_computation.2
+  %small = f32[4,8]{1,0} copy(%a)
+  ROOT %copy.7 = f32[9,4,8]{2,1,0} copy(%f2)
+}
+"""
+    stray, kinds = _stray_pool_results(text, 9 * 4 * 8)
+    assert sorted(stray) == [("copy", "%c"), ("copy", "%copy.7"),
+                             ("fusion", "%f2")]
+    assert {"parameter", "scatter", "fusion", "bitcast", "copy"} == kinds
+
+
+@pytest.mark.parametrize("program", ["step", "admit512", "admit768"])
+def test_gpt2_paged_programs_never_copy_the_pool(chip, gpt2_small_engine,
+                                                 program):
+    # 24 K/V arrays of [2049, 16, 768] float32 (100 MB each) go in, are
+    # scattered into in place, read by the kernel and handed back: no
+    # instruction of the optimized program may produce another such
+    # array (a `copy` to another order cost 40 ms in every execution
+    # while the pool was stored [P+1, H, page, hd])
+    eng = gpt2_small_engine
+    one = SingleDeviceSharding(chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+    B, R, C, page, pages = 32, 2, 1024, 16, 2048
+    G = C // page
+    assert (eng._batch, eng._admit_rows, eng._C, eng._page,
+            eng._kv_pages) == (B, R, C, page, pages)
+    pool = on_chip(jax.eval_shape(
+        lambda: eng._model.init_paged_cache(pages, page)))
+    assert pool["layers"][0]["k"].shape == (pages + 1, page, 768)
+    params, buffers = on_chip(eng._params), on_chip(eng._buffers)
+    if program == "step":
+        lowered = eng._step_jit.lower(params, buffers,
+                                      ints(B, 2 + C + G), pool)
+    else:
+        T = int(program[len("admit"):])
+        lowered = eng._padmit.lower(params, buffers, ints(R, T), ints(R, T),
+                                    ints(R, C), ints(R, G), ints(R), pool,
+                                    None)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 12  # the kernel, every layer
+    stray, kinds = _stray_pool_results(text, (pages + 1) * page * 768)
+    assert "parameter" in kinds and kinds & {"scatter",
+                                             "dynamic-update-slice"}
+    assert not stray, f"whole-pool results outside the allowed kinds: {stray}"
+    # the pool is donated: the program's outputs alias its arguments
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 24 * (pages + 1) * page * 768 * 4
+    assert mem.temp_size_in_bytes < 100 * 2 ** 20  # no second pool array
 
 
 @pytest.mark.parametrize("rows,tile", [(32 * 8, 16), (2 * 4096 * 8, 128)],

@@ -233,13 +233,13 @@ def gate_flash_dispatch(model):
     H, P = cfg.num_heads, INT8_PAGES
     rng = np.random.RandomState(23)
     q = jnp.asarray(rng.randn(SLOTS, H, 1, hd), jnp.float32)
-    pool = jnp.asarray(rng.randint(-127, 128, (P + 1, H, PAGE, hd)),
+    pool = jnp.asarray(rng.randint(-127, 128, (P + 1, PAGE, H * hd)),
                        jnp.int8)
-    scale = jnp.asarray(rng.rand(P + 1, H, PAGE), jnp.float32)
+    scale = jnp.asarray(rng.rand(P + 1, PAGE, H), jnp.float32)
     tables = jnp.zeros((SLOTS, CACHE // PAGE), jnp.int32)
     mask = jnp.ones((SLOTS, 1, CACHE), bool)
     jaxpr = jax.make_jaxpr(
-        lambda *a: paged_flash_decode(*a, block_h=1))(
+        lambda *a: paged_flash_decode(*a, block_h=H))(
             q, pool, pool, tables, mask, scale, scale)
     pool_shape = tuple(pool.shape)
     full_dequants = _count_eqns(
