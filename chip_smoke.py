@@ -14,7 +14,7 @@ would call, at the published width of models the repo ships, on ONE chip:
   Momentum) through the same ``run_steps`` path: one warm window and one
   more, loss finite, the conv1x1+BN kernels dispatched.
 * **serve**  — GPT-2-small (768 x 12 x 12, vocab 50304, max_position 1024)
-  behind ``serving.GenerationEngine(paged=True, continuous=True)``:
+  behind ``serving.GenerationEngine``:
   ``warmup()``, eight concurrent ragged requests, every token checked
   against the uncached greedy forward (teacher-forced; a flip is allowed
   only where the reference's top-2 logit margin is within ``MARGIN_K`` x
@@ -150,7 +150,7 @@ def device_phase(need):
 
 # -- train: BERT-base through Executor.run_steps ------------------------------
 def _bert_program(batch, seq, max_pred, cfg):
-    """The program bench.py's BERT config builds."""
+    """BERT-base masked-LM + NSP pretraining as a ``fluid.Program``."""
     import paddle_tpu as paddle
     import paddle_tpu.fluid as fluid
     from paddle_tpu import optimizer as popt
@@ -374,8 +374,7 @@ def _serve(model, prompts, new_tokens, mon, **engine_kw):
     from paddle_tpu.serving import GenerationEngine
 
     t0 = time.perf_counter()
-    with GenerationEngine(model, paged=True, continuous=True,
-                          **engine_kw) as eng:
+    with GenerationEngine(model, **engine_kw) as eng:
         compiled = eng.warmup()
         warm_s = time.perf_counter() - t0
         snap = mon.snapshot()

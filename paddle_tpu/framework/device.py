@@ -109,8 +109,8 @@ def on_tpu() -> bool:
 #: Peak dense bf16 matmul rate per chip in TFLOP/s, keyed by
 #: ``jax.Device.device_kind``.  Source: Google Cloud documentation, "TPU
 #: v5e" system architecture (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
-#: A kind that is not listed has no peak here: bench.py treats that as an
-#: error and steptrace reports no MFU — there is no default.
+#: A kind that is not listed has no peak here and steptrace reports no
+#: MFU — there is no default.
 PEAK_BF16_TFLOPS = {
     "TPU v5 lite": 197.0,
 }

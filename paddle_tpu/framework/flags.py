@@ -181,32 +181,13 @@ define_flag("circuit_cooldown_ms", 1000.0,
 define_flag("circuit_half_open_probes", 1,
             "Probe batches admitted in the half-open state; all must "
             "succeed to close the circuit, any failure re-opens it.")
-define_flag("continuous_batching", True,
-            "GenerationEngine decode scheduling (serving/generation.py): "
-            "on (default), requests are admitted into and evicted from "
-            "individual decode slots at decode-step granularity against "
-            "the preallocated ring KV cache (Orca-style iteration-level "
-            "scheduling — a stalled long request holds one slot, never "
-            "the batch). Off falls back to the legacy run-batch-to-"
-            "completion path. Per-engine override: "
-            "GenerationEngine(continuous=...).")
-define_flag("paged_kv", False,
-            "GenerationEngine KV-cache layout (serving/generation.py): on, "
-            "the continuous-batching decode loop stores KV in fixed-size "
-            "pages behind a slot→page-table indirection (vLLM-style "
-            "PagedAttention) instead of one dense ring region per slot — "
-            "pages are allocated on demand, shared copy-on-write across "
-            "slots with a common prefix, and returned to a free list at "
-            "eviction, so the same HBM budget holds strictly more "
-            "resident slots. Tokens stay bit-identical to the dense "
-            "path. Requires continuous batching. Per-engine override: "
-            "GenerationEngine(paged=...).")
 define_flag("kv_page_size", 16,
-            "Tokens per KV page in paged mode. Smaller pages waste less "
+            "Tokens per KV page of GenerationEngine's page pool "
+            "(serving/generation.py). Smaller pages waste less "
             "memory on the last partial page per sequence but grow the "
             "page table; must divide the engine's max_len.")
 define_flag("speculative_k", 4,
-            "Speculative decoding draft length in paged mode: an n-gram "
+            "GenerationEngine's speculative decoding draft length: an n-gram "
             "proposer (prompt-lookup) drafts up to k tokens per slot and "
             "one batched verify step accepts the longest matching prefix "
             "— token-identical to plain greedy, up to k+1 tokens per "
@@ -222,8 +203,7 @@ define_flag("metrics_jsonl", "",
             "Base path of the periodic JSONL metrics sink; written as "
             "<base>.p<process_index>.jsonl (one file per host process — "
             "observability.merge_jsonl collates them). Empty (default) "
-            "disables the sink. bench.py also emits its per-config "
-            "results through this lane when set.")
+            "disables the sink.")
 define_flag("metrics_jsonl_interval_s", 10.0,
             "Seconds between JSONL metric snapshots (plus one final "
             "snapshot at close).")

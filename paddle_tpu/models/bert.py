@@ -2,7 +2,8 @@
 
 Reference workload parity: the reference ships transformer encoder layers
 (python/paddle/nn/layer/transformer.py TransformerEncoder) and BERT-class
-training is the BASELINE.json north-star benchmark (BERT-base seq/sec/chip).
+training is the north-star benchmark (``benchmarks/run.py --workload
+bert_base.pretrain_s128``: BERT-base samples/s on one chip).
 Reuses the GPT parallel blocks (same megatron column/row sharding) with a
 bidirectional mask and BERT's token-type embeddings + pooler + MLM/NSP heads.
 """
@@ -255,7 +256,7 @@ class BertForPretraining(Layer):
 
 class BertForQuestionAnswering(Layer):
     """Extractive-QA (SQuAD) head: per-token start/end logits over the
-    encoder states — BASELINE config 3 (BERT-base SQuAD fine-tune)."""
+    encoder states (BERT-base SQuAD fine-tune)."""
 
     def __init__(self, cfg: BertConfig):
         super().__init__()

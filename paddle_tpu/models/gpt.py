@@ -2,8 +2,8 @@
 
 The reference has no GPT (its transformer surface is the seq2seq
 paddle.nn.Transformer, python/paddle/nn/layer/transformer.py); a decoder LM
-is the flagship workload for the TPU framework's distributed story
-(BASELINE.json north star: BERT-class encoder + LM training throughput).
+is the flagship workload for the TPU framework's distributed story, and
+GPT-2-small is the benchmark's serving model (``benchmarks/run.py``).
 
 Every projection is a meta_parallel layer: on a mesh with ``model`` axis
 size 1 they degenerate to plain Linears (zero overhead single-chip); with
@@ -111,7 +111,7 @@ class GPTConfig:
         #: > 0 registers fixed-capacity batched multi-LoRA adapter
         #: tables on every block projection (``lora.enable_lora``) —
         #: that many hot-swappable adapter slots per linear; per-slot
-        #: adapter ids flow through ``forward_cached``/``forward_paged``
+        #: adapter ids flow through ``forward_paged``
         #: and id -1 is bitwise the base model.  0 = no LoRA.
         if int(lora_capacity) < 0:
             raise ValueError(
@@ -245,41 +245,13 @@ class ParallelAttention(Layer):
 
         return shard_map(local, mesh, (spec, spec, spec), spec)(q, k, v)
 
-    def forward_cached(self, x, kv, hit, mask):
-        """One attention step over a preallocated ring KV cache — the
-        serving decode path (paddle_tpu/serving/generation.py).
-
-        The new tokens' K/V are scattered into fixed ``[B,H,C,hd]`` cache
-        buffers (one-hot ``hit``), then attention runs over the WHOLE
-        cache under ``mask`` — every decode step has the same shapes, so
-        the jitted step never retraces and costs O(C) instead of
-        re-running the O(S²) prefix.  Dense path only (no flash/SP —
-        decode is bandwidth-bound at T=1); attention-prob dropout is
-        skipped (decode is inference).
-
-        x: ``[B,T,D]`` new-token activations; kv: ``{"k","v"}`` cache
-        buffers; hit: ``[B,T,C]`` bool one-hot slot writes; mask:
-        ``[B,T,C]`` attention validity.  Returns ``(out, new_kv)``.
-        """
-        B, T, D = x.shape
-        q, k, v = self._heads(x)  # [B,H,T,hd]
-        write = hit.any(axis=1)[:, None, :, None]  # [B,1,C,1]
-        h = hit.astype(x.dtype)
-        new_k = jnp.where(write, jnp.einsum("btc,bhtd->bhcd", h, k), kv["k"])
-        new_v = jnp.where(write, jnp.einsum("btc,bhtd->bhcd", h, v), kv["v"])
-        scores = jnp.einsum("bhqd,bhcd->bhqc", q, new_k) / math.sqrt(
-            self.head_dim)
-        scores = jnp.where(mask[:, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqc,bhcd->bhqd", probs, new_v)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
-        ctx = constrain(ctx, None, None, "model")
-        return self.out(ctx), {"k": new_k, "v": new_v}
-
     def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask):
-        """One attention step over a PAGED KV pool — the paged serving
-        decode path (see :meth:`GPTModel.init_paged_cache`).
+        """One attention step over a PAGED KV pool — the serving decode
+        path (paddle_tpu/serving/generation.py; see
+        :meth:`GPTModel.init_paged_cache`).  Every step has the same
+        shapes, so the jitted step never retraces and costs O(C) instead
+        of re-running the O(S²) prefix; attention-prob dropout is skipped
+        (decode is inference).
 
         The pool stores one token's K (or V) for ALL heads as one
         contiguous row: ``[P+1, page, H*hd]``.  The new tokens' rows come
@@ -290,10 +262,9 @@ class ParallelAttention(Layer):
         kernel then reads the pool in that same order; elsewhere each
         slot's logical cache view is gathered back through its page-table
         row (``gather_tab`` ``[B,G]``, entries pre-clipped to valid pages)
-        and attention runs over the gathered ``[B,H,C,hd]`` view exactly
-        as the dense ring path does — same einsums, same mask semantics,
-        so tokens stay bit-identical.  ``mask``: ``[B,T,C]`` attention
-        validity computed from the host-owned slot→position map.
+        and plain masked attention runs over the gathered ``[B,H,C,hd]``
+        view.  ``mask``: ``[B,T,C]`` attention validity computed from the
+        host-owned slot→position map.
         """
         B, T, D = x.shape
         H, hd = self.num_heads, self.head_dim
@@ -398,12 +369,6 @@ class GPTBlock(Layer):
         x = x + self.mlp(self.ln2(x))
         return x
 
-    def forward_cached(self, x, kv, hit, mask):
-        a, new_kv = self.attn.forward_cached(self.ln1(x), kv, hit, mask)
-        x = x + a
-        x = x + self.mlp(self.ln2(x))
-        return x, new_kv
-
     def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask):
         from ..distributed.collective import (
             get_overlap_schedule,
@@ -489,59 +454,8 @@ class GPTModel(Layer):
                 x = blk(x, attn_mask)
         return self.ln_f(x)
 
-    # -- KV-cache decode path (paddle_tpu.serving) --------------------------
-    def init_cache(self, batch_size: int, cache_len: Optional[int] = None,
-                   dtype=None):
-        """Preallocate a ring KV cache: per-layer ``[B,H,C,hd]`` K/V
-        buffers plus one shared ``[B,C]`` slot→absolute-position map
-        (``-1`` = empty).  Every decode step reads and writes arrays of
-        exactly these shapes, so the jitted step compiles once.  While the
-        absolute position stays below ``C`` attention is exact; past it
-        the ring overwrites the oldest entries (sliding-window decode)."""
-        cfg = self.cfg
-        C = int(cache_len or cfg.max_position)
-        hd = cfg.hidden_size // cfg.num_heads
-        dt = dtype or cfg.dtype
-        return {
-            "pos": jnp.full((batch_size, C), -1, jnp.int32),
-            "layers": [
-                {"k": jnp.zeros((batch_size, cfg.num_heads, C, hd), dt),
-                 "v": jnp.zeros((batch_size, cfg.num_heads, C, hd), dt)}
-                for _ in range(cfg.num_layers)
-            ],
-        }
-
-    def reset_slots(self, cache, slot_mask):
-        """Evict batch slots from a live cache: the slot→position map rows
-        of masked slots become ``-1`` (= empty; nothing attends to them),
-        unmasked rows pass through bit-identical.  K/V payloads stay —
-        attention visibility is decided solely by ``pos``, so clearing the
-        map is the whole eviction.  ``slot_mask``: ``[B]`` bool."""
-        mask = jnp.asarray(slot_mask, bool)[:, None]  # [B,1]
-        return {"pos": jnp.where(mask, jnp.int32(-1), cache["pos"]),
-                "layers": cache["layers"]}
-
-    def write_slots(self, cache, src, slot_mask):
-        """Scatter whole cache rows of ``src`` into ``cache`` where
-        ``slot_mask`` is set — the admission op of slot-level continuous
-        batching: a prompt is prefilled into a FRESH cache (only its slot
-        rows populated, everything else ``-1``/zeros) and this merges those
-        rows into the live cache.  Unmasked slots pass through
-        bit-identical, so admission never perturbs other requests' KV
-        state.  ``slot_mask``: ``[B]`` bool; ``src`` has the same
-        structure/shapes as ``cache``."""
-        m1 = jnp.asarray(slot_mask, bool)
-        m4 = m1[:, None, None, None]  # broadcast over [B,H,C,hd]
-        return {
-            "pos": jnp.where(m1[:, None], src["pos"], cache["pos"]),
-            "layers": [
-                {"k": jnp.where(m4, s["k"], d["k"]),
-                 "v": jnp.where(m4, s["v"], d["v"])}
-                for s, d in zip(src["layers"], cache["layers"])
-            ],
-        }
-
-    # -- paged KV cache (vLLM-style PagedAttention; Kwon et al. 2023) -------
+    # -- the serving decode path (paddle_tpu.serving): a paged KV cache
+    # (vLLM-style PagedAttention; Kwon et al. 2023) ------------------------
     def init_paged_cache(self, num_pages: int, page_size: int, dtype=None):
         """Preallocate a paged KV pool: per-layer ``[P+1, page, H*hd]``
         K/V page arrays shared by ALL slots.  A token's K (or V) for all
@@ -669,11 +583,17 @@ class GPTModel(Layer):
                       adapter_ids=None):
         """Prefill/decode forward over :meth:`init_paged_cache` state.
 
-        Same contract as :meth:`forward_cached` — ``input_ids`` /
-        ``positions`` are ``[B,T]`` with absolute positions and ``-1`` =
-        padding — but the cache metadata is HOST-owned and passed per
-        call: ``table`` ``[B,G]`` maps each slot's logical pages to
-        physical pool pages (``-1`` = unmapped), and ``pos_map``
+        ``input_ids``/``positions`` are ``[B,T]`` — ``T`` is the prompt
+        bucket length for prefill, 1 (or ``1 + k`` draft columns) for a
+        decode step.  ``positions`` are ABSOLUTE token positions per
+        sequence (``-1`` marks padding: the token writes nothing and
+        attends to nothing), so ragged right-padded prompts and
+        per-sequence decode offsets batch together.  A key is visible iff
+        its slot holds a real token, causally before (or at) the query,
+        and within the last ``C`` positions (past ``C`` a slot's window
+        slides).  The cache metadata is HOST-owned and passed per call:
+        ``table`` ``[B,G]`` maps each slot's logical pages to physical
+        pool pages (``-1`` = unmapped), and ``pos_map``
         ``[B,C]`` (``C = G*page``) is the slot→absolute-position map
         *after this call's writes* (the host knows exactly which
         positions it is writing, so it marks them up front; stale or
@@ -722,38 +642,6 @@ class GPTModel(Layer):
 
         return adapter_scope(adapter_ids)
 
-    def forward_cached(self, input_ids, positions, cache, adapter_ids=None):
-        """Prefill/decode forward over :meth:`init_cache` state.
-
-        ``input_ids``/``positions`` are ``[B,T]`` — ``T`` is the prompt
-        bucket length for prefill, 1 for a decode step.  ``positions``
-        are ABSOLUTE token positions per sequence (``-1`` marks padding:
-        the token writes nothing and attends to nothing), so ragged
-        right-padded prompts and per-sequence decode offsets batch
-        together.  Returns ``(hidden [B,T,D], new_cache)``.
-        """
-        positions = jnp.asarray(positions, jnp.int32)
-        C = cache["pos"].shape[1]
-        x = self.wte(input_ids) + self.wpe(jnp.maximum(positions, 0))
-        x = self.drop(x)
-        slots = jnp.where(positions >= 0, positions % C, -1)
-        hit = slots[:, :, None] == jnp.arange(C)[None, None, :]  # [B,T,C]
-        written = hit.any(axis=1)  # [B,C]
-        new_pos = jnp.where(
-            written,
-            jnp.einsum("btc,bt->bc", hit.astype(jnp.int32), positions),
-            cache["pos"])
-        # a key is visible iff its slot holds a real token, causally
-        # before (or at) the query, and not yet evicted by the ring
-        kp, qp = new_pos[:, None, :], positions[:, :, None]
-        mask = (kp >= 0) & (kp <= qp) & (kp > qp - C)  # [B,T,C]
-        new_layers = []
-        with self._lora_scope(adapter_ids):
-            for blk, kv in zip(self.blocks, cache["layers"]):
-                x, kv = blk.forward_cached(x, kv, hit, mask)
-                new_layers.append(kv)
-        return self.ln_f(x), {"pos": new_pos, "layers": new_layers}
-
 
 class GPTForCausalLM(Layer):
     """LM head ties the (vocab-sharded) input embedding."""
@@ -769,15 +657,6 @@ class GPTForCausalLM(Layer):
         lambda self: int(getattr(self.gpt.cfg, "moe_experts", 0) or 0))
     lora_capacity = property(
         lambda self: int(getattr(self.gpt.cfg, "lora_capacity", 0) or 0))
-
-    def init_cache(self, batch_size, cache_len=None):
-        return self.gpt.init_cache(batch_size, cache_len)
-
-    def write_slots(self, cache, src, slot_mask):
-        return self.gpt.write_slots(cache, src, slot_mask)
-
-    def reset_slots(self, cache, slot_mask):
-        return self.gpt.reset_slots(cache, slot_mask)
 
     def init_paged_cache(self, num_pages, page_size, dtype=None):
         return self.gpt.init_paged_cache(num_pages, page_size, dtype=dtype)
@@ -819,35 +698,16 @@ class GPTForCausalLM(Layer):
         logits = jnp.einsum("bsd,vd->bsv", h, jnp.asarray(self.gpt.wte.weight))
         return constrain(logits, None, None, None)
 
-    def forward_cached(self, input_ids, positions, cache, gather_last=None,
-                       adapter_ids=None):
-        """KV-cache forward (see :meth:`GPTModel.forward_cached`).
+    def forward_paged(self, input_ids, positions, pos_map, table, cache,
+                      gather_last=None, adapter_ids=None):
+        """Paged KV forward (see :meth:`GPTModel.forward_paged`).
 
         With ``gather_last`` (per-sequence prompt lengths ``[B]``), only
         the hidden state at position ``length-1`` is projected to logits
         — the prefill path needs just the next-token distribution, and
         skipping the ``[B,S,V]`` projection is the bulk of the prefill
         FLOPs for large vocabularies.  Returns ``(logits, new_cache)``
-        with logits ``[B,T,V]`` (or ``[B,V]`` under ``gather_last``).
-        """
-        h, cache = self.gpt.forward_cached(input_ids, positions, cache,
-                                           adapter_ids=adapter_ids)
-        if gather_last is not None:
-            idx = jnp.maximum(jnp.asarray(gather_last, jnp.int32) - 1, 0)
-            h = jnp.take_along_axis(
-                h, idx[:, None, None], axis=1)[:, 0]  # [B,D]
-            logits = jnp.einsum("bd,vd->bv", h,
-                                jnp.asarray(self.gpt.wte.weight))
-            return constrain(logits, None, None), cache
-        logits = jnp.einsum("bsd,vd->bsv", h,
-                            jnp.asarray(self.gpt.wte.weight))
-        return constrain(logits, None, None, None), cache
-
-    def forward_paged(self, input_ids, positions, pos_map, table, cache,
-                      gather_last=None, adapter_ids=None):
-        """Paged KV forward (see :meth:`GPTModel.forward_paged`).  Same
-        ``gather_last`` contract as :meth:`forward_cached`: per-sequence
-        prompt lengths ``[B]`` project only the last hidden state."""
+        with logits ``[B,T,V]`` (or ``[B,V]`` under ``gather_last``)."""
         h, cache = self.gpt.forward_paged(input_ids, positions, pos_map,
                                           table, cache,
                                           adapter_ids=adapter_ids)

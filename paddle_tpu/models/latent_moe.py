@@ -422,8 +422,7 @@ class LatentMoEModel(Layer):
 class LatentMoEForCausalLM(Layer):
     """The decoder with its untied head; answers the serving-model
     protocol (``max_position``, ``moe_experts``, ``lora_capacity``, the
-    paged cache and its page ops, ``forward_paged``).  It has no dense
-    ring cache: serve it with ``paged=True``."""
+    paged cache and its page ops, ``forward_paged``)."""
 
     def __init__(self, cfg: LatentMoEConfig):
         super().__init__()

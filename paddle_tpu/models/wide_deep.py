@@ -1,7 +1,7 @@
 """Wide&Deep CTR model over mesh-sharded embedding tables.
 
 This is the TPU-native replacement for the reference's parameter-server CTR
-story (BASELINE config 5): where the reference shards `large_scale_kv`
+story: where the reference shards `large_scale_kv`
 embedding tables across PS nodes and routes lookups through the
 DistributeTranspiler's send/recv fabric
 (python/paddle/fluid/transpiler/distribute_transpiler.py:256,

@@ -29,7 +29,6 @@ from . import exporters, metrics, slo, steptrace, tracing  # noqa: F401
 from .exporters import (  # noqa: F401
     JsonlSink,
     PrometheusExporter,
-    append_jsonl_record,
     merge_jsonl,
     render_prometheus,
 )
@@ -50,7 +49,7 @@ __all__ = [
     "MetricRegistry", "Counter", "Gauge", "Histogram",
     "DEFAULT_MS_BUCKETS", "default_registry", "render_prometheus",
     "PrometheusExporter", "JsonlSink", "merge_jsonl",
-    "append_jsonl_record", "install_bridge", "uninstall_bridge",
+    "install_bridge", "uninstall_bridge",
     "enable", "disable", "enabled", "status", "maybe_enable_from_flags",
     "Objective", "ScaleSignal", "SloEngine", "TraceContext", "Tracer",
     "metrics", "exporters", "slo", "steptrace", "tracing",

@@ -23,7 +23,7 @@ from .metrics import MetricRegistry, default_registry
 
 __all__ = [
     "render_prometheus", "PrometheusExporter", "JsonlSink",
-    "process_jsonl_path", "merge_jsonl", "append_jsonl_record",
+    "process_jsonl_path", "merge_jsonl",
 ]
 
 
@@ -231,24 +231,3 @@ def merge_jsonl(base_or_paths, out_path: Optional[str] = None) -> List[dict]:
             for r in records:
                 f.write(json.dumps(r) + "\n")
     return records
-
-
-def append_jsonl_record(record: dict, path: Optional[str] = None) -> bool:
-    """Best-effort one-off record through the JSONL lane (``bench.py``
-    emits its per-config results here).  ``path`` defaults to
-    ``FLAGS_metrics_jsonl``; empty flag → no-op.  Returns whether a line
-    was written."""
-    if path is None:
-        from ..framework.flags import flag
-
-        path = flag("metrics_jsonl")
-    if not path:
-        return False
-    target = process_jsonl_path(path)
-    parent = os.path.dirname(os.path.abspath(target))
-    os.makedirs(parent, exist_ok=True)
-    line = json.dumps({"ts": time.time(),
-                       "process_index": _process_index(), **record})
-    with open(target, "a") as f:
-        f.write(line + "\n")
-    return True

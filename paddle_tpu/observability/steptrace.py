@@ -71,8 +71,8 @@ def estimate_flops(jitted, *args, **kwargs) -> Optional[float]:
 
 
 def _peak_flops() -> Optional[float]:
-    """Peak chip FLOP/s for the MFU denominator, from the one table
-    bench.py also reads (``framework.device.PEAK_BF16_TFLOPS``).  None for
+    """Peak chip FLOP/s for the MFU denominator, from the package's one
+    table (``framework.device.PEAK_BF16_TFLOPS``).  None for
     a device kind the table does not list (the CPU, an unknown chip):
     then MFU is not reported — there is no default peak to divide by."""
     from ..framework.device import peak_bf16_tflops

@@ -14,19 +14,19 @@ KV-cache model paths into an online engine:
   AOT predictors over an exported ``save_inference_model`` artifact, with
   hot weight-swap from a ``.pdiparams`` side-file.
 * :mod:`~paddle_tpu.serving.generation` — :class:`GenerationEngine`:
-  prefill/decode greedy generation for ``models.GPTForCausalLM`` over a
-  preallocated ring KV cache (one decode executable total).  By default
-  (``FLAGS_continuous_batching``) it runs slot-level continuous
-  batching: a persistent decode loop admits/evicts individual requests
-  at decode-step granularity, so a stalled long request holds one slot,
-  never the batch.  With ``FLAGS_paged_kv`` the per-slot ring regions
-  become one shared page pool behind a slot→page-table indirection
-  (PagedAttention): pages allocate on demand, shared-prefix pages are
-  reused copy-on-write, eviction is a host table edit, and an n-gram
-  proposer drives speculative decoding — all bit-identical to dense
-  greedy on the same closed compile set.
+  prefill/decode greedy generation for a paged causal LM
+  (``models.GPTForCausalLM``, ``models.LatentMoEForCausalLM``; one
+  decode executable total) under slot-level continuous batching: a
+  persistent decode loop admits/evicts individual requests at
+  decode-step granularity, so a stalled long request holds one slot,
+  never the batch.  The KV state is one shared page pool behind a
+  slot→page-table indirection (PagedAttention): pages allocate on
+  demand, shared-prefix pages are reused copy-on-write, eviction is a
+  host table edit, and an n-gram proposer drives speculative decoding —
+  all token-identical to uncached greedy on the same closed compile
+  set.
 * :mod:`~paddle_tpu.serving.paging` — :class:`PagePool`: the host-side
-  page accounting behind paged mode — refcounts, the free list, CoW
+  page accounting behind the engine — refcounts, the free list, CoW
   copy scheduling and the shared-prefix registry.
 * :mod:`~paddle_tpu.serving.metrics` — :class:`ServingMetrics`: queue
   depth, batch occupancy, p50/p99 latency, tokens/s, the continuous

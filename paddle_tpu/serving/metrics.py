@@ -12,14 +12,14 @@ bucket_misses, fallback_runs, compiles, batches, circuit_shed,
 queue_depth, batch_occupancy, p50_ms, p99_ms, queue_p50_ms,
 queue_p99_ms, execute_p50_ms, execute_p99_ms, tokens, tokens_per_s``.
 
-Continuous-batching engines add the slot-scheduler family: counters
+:class:`GenerationEngine` adds the slot-scheduler family: counters
 ``admitted, evicted, decode_steps, restarts, starved_steps,
 starved_steps_after_warm`` plus per-step gauges (``set_gauge``) such as
 ``slot_occupancy`` (live slots / batch), ``slots_free`` and
 ``queue_age_ms`` (age of the oldest queued request).  Rule S603 reads
 the starvation counters.
 
-The paged decode loop publishes the gauge ``decode_step_ms`` (the last
+The decode loop publishes the gauge ``decode_step_ms`` (the last
 step's measured device call) and the ``LOOP_COUNTERS`` family: its wall
 time by phase in integer microseconds (``loop_us_<phase>``; the phases
 tile every iteration and sum to ``loop_us_total``), the work it
@@ -33,7 +33,7 @@ engine started, so that a stall shows as one call of one phase and not
 as a mean that crept.  The same phases are ``serve/<phase>`` spans on
 the loop's thread in a profiler trace (:class:`LoopClock`).
 
-Paged-KV engines (``FLAGS_paged_kv``) add the page-accounting family:
+With them comes the page-accounting family:
 counters ``cow_copies`` (copy-on-write page copies), ``spec_drafted`` /
 ``spec_accepted`` (speculative-decoding draft economics) and
 ``preempted`` (slots evicted to reclaim pages), plus gauges
@@ -106,11 +106,11 @@ LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
                  "kv_pages_live_steps", "kv_page_slots_steps",
                  "queue_wait_us", "ttft_us")
 
-#: page-accounting counters (paged KV mode; see ``extra_counters``)
+#: page-accounting counters (see ``extra_counters``)
 PAGED_COUNTERS = ("cow_copies", "spec_drafted", "spec_accepted",
                   "preempted")
 
-#: prefill/decode disaggregation counters (paged KV mode): hand-offs a
+#: prefill/decode disaggregation counters: hand-offs a
 #: prefill-role engine exported (``handoffs_out``) and a decode-role
 #: engine adopted (``handoffs_in``)
 HANDOFF_COUNTERS = ("handoffs_out", "handoffs_in")

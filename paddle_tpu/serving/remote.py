@@ -102,7 +102,7 @@ class EngineServer:
         prefix = f"req.{self.name}."
         try:
             names = sorted(n for n in os.listdir(self.root)
-                           if n.startswith(prefix) and not n.endswith(".tmp"))
+                           if n.startswith(prefix) and ".tmp." not in n)
         except OSError:
             return 0
         n = 0

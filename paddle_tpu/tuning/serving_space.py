@@ -16,8 +16,7 @@ A candidate config is JSON-plain and maps onto
 ``GenerationEngine.from_tuned`` / ``InferenceEngine.from_tuned``::
 
     {"buckets": [16, 48], "batch_size": 8, "max_queue_delay_ms": 1.0,
-     "kv_page_size": 64, "speculative_k": 4, "paged": 1,
-     "quantization": "int8"}
+     "kv_page_size": 64, "speculative_k": 4, "quantization": "int8"}
 
 Winners persist in the shared tuning cache keyed
 ``serving | tag | trace digest | mesh | device_kind`` — a tuned config

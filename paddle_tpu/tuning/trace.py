@@ -9,10 +9,9 @@ submission order.  This module is that workload as a value:
   requests with a stable content digest (:meth:`RequestTrace.key`) that
   lands in the measured-search cache key, so a tuned winner is bound to
   the trace it was measured on;
-* :meth:`RequestTrace.synthetic` — the fixed-seed mixed-length sweep
-  ``bench.py`` has always used (RandomState(17), prompts 4..48, outputs
-  4..64), reproduced draw-for-draw so benches before and after this
-  module see bit-identical requests;
+* :meth:`RequestTrace.synthetic` — a fixed-seed mixed-length sweep
+  (RandomState(17), prompts 4..48, outputs 4..64), the same requests
+  draw-for-draw in every process;
 * :class:`TraceRecorder` — capture live submissions (wrap an engine's
   ``submit``) and save them for offline tuning against production
   shapes;
@@ -59,12 +58,10 @@ class RequestTrace:
     def synthetic(cls, n: int = 48, *, seed: int = 17, vocab: int = 8192,
                   prompt_range: Tuple[int, int] = (4, 49),
                   new_range: Tuple[int, int] = (4, 65)) -> "RequestTrace":
-        """The fixed-seed mixed-length sweep: ragged on both axes, the
-        spread a run-batch-to-completion scheduler pays head-of-line
-        blocking on.  Draw order matches the historical ``bench.py``
-        inline generation exactly (lengths first, then output counts,
-        then per-request tokens), so default-args output is bit-identical
-        to every recorded bench number."""
+        """The fixed-seed mixed-length sweep: ragged on both axes.  The
+        draw order is fixed (lengths first, then output counts, then
+        per-request tokens): a tuned winner is keyed by the trace's
+        digest, so the same arguments must give the same requests."""
         rng = np.random.RandomState(seed)
         lens = rng.randint(prompt_range[0], prompt_range[1], size=n)
         news = rng.randint(new_range[0], new_range[1], size=n)

@@ -1,6 +1,6 @@
 """LeNet (parity: python/paddle/vision/models/lenet.py:21).
 
-The BASELINE config-1 smoke model: MNIST digits, 1×28×28 input.
+The smoke model: MNIST digits, 1×28×28 input.
 """
 from __future__ import annotations
 

@@ -288,7 +288,7 @@ def resnet34(pretrained=False, **kwargs):
 
 def resnet50(pretrained=False, **kwargs):
     """ResNet-50 (reference surface: vision/models/resnet.py:312) — the
-    BASELINE.json flagship CNN (configs 2 and 4)."""
+    flagship CNN."""
     return _resnet("resnet50", BottleneckBlock, 50, pretrained, **kwargs)
 
 

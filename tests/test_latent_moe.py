@@ -340,7 +340,7 @@ def test_the_engine_serves_the_model_with_a_closed_compile_set():
     cfg = tiny_cfg(cache_len=128)
     m, w = build(cfg)
     eng = GenerationEngine(m, prompt_buckets=[16, 32], batch_size=4,
-                           paged=True, continuous=True, kv_page_size=8,
+                           kv_page_size=8,
                            speculative_k=0, eos_token_id=None, name="lm")
     try:
         warm = eng.warmup()
@@ -396,7 +396,7 @@ def test_gpt_through_the_engine_is_bit_identical_to_before_the_protocol():
                                  max_position=128, dropout=0.0))
     m.eval()
     eng = GenerationEngine(m, prompt_buckets=[16, 32], batch_size=4,
-                           paged=True, continuous=True, kv_page_size=8,
+                           kv_page_size=8,
                            speculative_k=0, eos_token_id=None, name="g")
     try:
         # as at ed5b0fe: 4 on one device, one more on the suite's

@@ -154,7 +154,7 @@ class TestHotSwap(unittest.TestCase):
         model = _tiny_model(capacity=2, rank=4)
         p = (np.arange(6) * 9 + 4) % 97
         with GenerationEngine(model, prompt_buckets=[8], batch_size=2,
-                              cache_len=48, paged=True, kv_page_size=8,
+                              cache_len=48, kv_page_size=8,
                               name="lora-hot") as eng:
             n_tr = eng.warmup()
             base = eng.generate(p, 8, timeout=120).tolist()
@@ -185,7 +185,7 @@ class TestHotSwap(unittest.TestCase):
     def test_submit_validates_adapter_id(self):
         model = _tiny_model(capacity=2)
         with GenerationEngine(model, prompt_buckets=[8], batch_size=2,
-                              cache_len=48, paged=True, kv_page_size=8,
+                              cache_len=48, kv_page_size=8,
                               name="lora-val") as eng:
             eng.warmup()
             with self.assertRaises(InvalidArgumentError):

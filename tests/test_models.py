@@ -123,7 +123,7 @@ class TestBert:
         assert l1 < l0, (l0, l1)
 
     def test_question_answering_finetunes(self):
-        """BASELINE config 3 (SQuAD fine-tune shape): the QA head learns to
+        """SQuAD fine-tune shape: the QA head learns to
         point start/end at a marker token's span."""
         from paddle_tpu.models import BertForQuestionAnswering
 

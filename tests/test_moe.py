@@ -245,7 +245,7 @@ class TestExpertShardedDecode(unittest.TestCase):
         prompts = [np.random.RandomState(k).randint(1, 61, size=3 + k)
                    .astype(np.int32) for k in range(3)]
         with GenerationEngine(model, prompt_buckets=[8], batch_size=2,
-                              continuous=True, name="moe-t-dense") as eng:
+                              name="moe-t-dense") as eng:
             eng.warmup()
             outs = [eng.submit(p, 6).result(300).tolist() for p in prompts]
             st = eng.stats()
@@ -261,7 +261,7 @@ class TestExpertShardedDecode(unittest.TestCase):
         prompts = [np.random.RandomState(k).randint(1, 61, size=3 + k)
                    .astype(np.int32) for k in range(3)]
         with GenerationEngine(model, prompt_buckets=[8], batch_size=2,
-                              continuous=True, name="moe-t-routed") as eng:
+                              name="moe-t-routed") as eng:
             eng.warmup()
             compiles0 = eng.compile_count
             outs = [eng.submit(p, 6).result(300).tolist() for p in prompts]

@@ -57,7 +57,7 @@ def _ref_greedy(model, prompt, n):
 
 def _engine(model, name, **kw):
     return GenerationEngine(model, prompt_buckets=BUCKETS, batch_size=B,
-                            cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                            cache_len=CACHE, kv_page_size=PAGE,
                             speculative_k=0, name=name, **kw)
 
 
@@ -110,7 +110,7 @@ def engine(model):
 def test_the_row_chunk_is_derived_from_the_batch(model, engine):
     assert engine._admit_rows == R < B
     with GenerationEngine(model, prompt_buckets=[8], batch_size=1,
-                          cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                          cache_len=CACHE, kv_page_size=PAGE,
                           speculative_k=0, name="chunks-b1") as one:
         assert one._admit_rows == 1
 

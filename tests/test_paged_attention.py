@@ -4,7 +4,8 @@ Per-candidate numerical equivalence against the gather-then-attend
 reference (the serving path's bit-identical CPU fallback) across float,
 int8 and fp8-e4m3 pools, drop-page masking, ragged page counts and the
 speculative ``1+k`` verify width — all on the CPU interpreter.  The
-performance question lives on the real chip (bench.py gpt_generate).
+performance question lives on the real chip (``benchmarks/run.py``, the
+``gpt2_small`` cells).
 """
 import numpy as np
 import pytest

@@ -1,25 +1,29 @@
 """Paged KV cache + copy-on-write prefix sharing + speculative decoding
-(serving/paging.py, serving/generation.py paged mode, models/gpt.py
+(serving/paging.py, serving/generation.py, models/gpt.py
 ``forward_paged``/``init_paged_cache``/``copy_pages``).
 
-Covers the paged scheduler's contract: token identity with uncached
-greedy AND the dense ring path under staggered mid-decode admission; the
-closed paged compile set (``len(prompt_buckets) + 3`` with speculation
-on — the extra trace is the ``[B, 1]`` no-draft fast step — zero
-post-warmup retraces); CoW isolation (a sibling's divergent write never perturbs a
+Covers the page pool's side of the scheduler's contract (staggered
+admission and restart are in tests/test_continuous_batching.py): the
+closed compile set (``len(prompt_buckets) + 3`` with speculation on —
+the extra trace is the ``[B, 1]`` no-draft fast step — zero post-warmup
+retraces); CoW isolation (a sibling's divergent write never perturbs a
 shared prefix page); speculative accept/reject bit-identity vs plain
-greedy (including past the ring-wrap point where drafting disables);
-pool-exhaustion preemption; ``PagePool`` accounting invariants; and
-analysis rule S604 (admission starved by a page leak).
+greedy and vs the uncached forward under a sliding-window mask
+(including past the wrap point where drafting disables); pool-exhaustion
+preemption; ``PagePool`` accounting invariants; the keywords and flags
+of the removed schedulers; and analysis rule S604 (admission starved by
+a page leak).
 """
 import time
 import unittest
 
 import jax
 import numpy as np
+import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.framework.errors import InvalidArgumentError, UnavailableError
+from paddle_tpu.framework.errors import (InvalidArgumentError, NotFoundError,
+                                         UnavailableError)
 from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.serving import GenerationEngine, PagePool
 
@@ -126,43 +130,6 @@ class TestPagedGeneration(unittest.TestCase):
                 break
         return outs
 
-    def test_token_identity_staggered_admission(self):
-        # the continuous-batching interleavings, paged: a long request
-        # pins a slot while shorts churn through the other as pages
-        # allocate and free underneath — every output must match
-        # uncached greedy
-        prompts = [(np.arange(10) * 5 + 2) % 97, np.arange(3) % 97,
-                   (np.arange(6) * 3) % 97, (np.arange(4) * 7 + 1) % 97,
-                   (np.arange(5) * 11 + 3) % 97]
-        budgets = [14, 3, 4, 5, 3]
-        refs = [self._ref_greedy(p, b) for p, b in zip(prompts, budgets)]
-        with GenerationEngine(self.model, prompt_buckets=[8, 16],
-                              batch_size=2, paged=True, kv_page_size=8,
-                              speculative_k=3,
-                              name="pg-stagger") as eng:
-            # 2 admits + unified step + its [B, 1] fast trace + CoW op
-            # + the fresh-pool trace of the step (on the suite's 8-device
-            # mesh a step's output pool carries the mesh's sharding, so
-            # _init_pool's host-built pool is another abstract input and
-            # the step traces once more for it: FRESH_TRACE);
-            # eviction is a host table edit with no executable
-            self.assertEqual(eng.warmup(), 5 + FRESH_TRACE)
-            futs = [eng.submit(prompts[0], budgets[0]),
-                    eng.submit(prompts[1], budgets[1])]
-            for p, b in zip(prompts[2:], budgets[2:]):
-                time.sleep(0.02)
-                futs.append(eng.submit(p, b))
-            gens = [f.result(120) for f in futs]
-            for g, ref in zip(gens, refs):
-                self.assertEqual(g.tolist(), ref)
-            # page churn never reopened the compile set
-            self.assertEqual(eng.compile_count, 5 + FRESH_TRACE)
-            st = eng.stats()
-            self.assertTrue(st["paged"])
-            self.assertEqual(st["kv_pages_free"],
-                             eng._pool.num_pages)  # all returned
-            self.assertEqual(st["kv_pages_leaked"], 0)
-
     def test_pool_is_stored_as_token_rows_of_all_heads(self):
         # ONE stored order: a layer's K (and V) is [P+1, page, H*hd], a
         # token's heads side by side in one row; scale planes of a
@@ -213,8 +180,8 @@ class TestPagedGeneration(unittest.TestCase):
 
         def eng(role, name):
             return GenerationEngine(self.model, prompt_buckets=[8, 16],
-                                    batch_size=2, paged=True,
-                                    kv_page_size=8, speculative_k=0,
+                                    batch_size=2, kv_page_size=8,
+                                    speculative_k=0,
                                     role=role, name=name)
 
         with eng("prefill", "ho-pre") as pre, eng("decode", "ho-dec") as dec:
@@ -260,7 +227,7 @@ class TestPagedGeneration(unittest.TestCase):
         budgets = [6, 5, 8, 7]
         refs = [self._ref_greedy(p, b) for p, b in zip(prompts, budgets)]
         with GenerationEngine(self.model, prompt_buckets=[16],
-                              batch_size=2, cache_len=64, paged=True,
+                              batch_size=2, cache_len=64,
                               kv_page_size=8, speculative_k=2,
                               name="pg-cow") as eng:
             eng.warmup()
@@ -279,28 +246,58 @@ class TestPagedGeneration(unittest.TestCase):
             # 1 admit + step + fast step + cow + the fresh-pool trace
             self.assertEqual(eng.compile_count, 4 + FRESH_TRACE)
 
+    def _next_tokens(self, seq, window=None):
+        """The uncached forward's argmax after every prefix of ``seq``,
+        in one teacher-forced pass; with ``window``, under a causal mask
+        banded to it: a query at position q sees keys q-window+1..q in
+        every layer — sliding-window attention written without a cache.
+        Greedy output ``out`` of ``prompt`` is exact iff it equals
+        ``_next_tokens(prompt + out)[len(prompt) - 1:-1]``."""
+        import jax.numpy as jnp
+        S = len(seq)
+        band = None
+        if window is not None:
+            q, k = np.arange(S)[:, None], np.arange(S)[None, :]
+            band = jnp.asarray(np.where(
+                k > q - window, 0.0,
+                np.finfo(np.float32).min).astype(np.float32))
+        logits = np.asarray(self.model(
+            jnp.asarray([list(map(int, seq))], jnp.int32), band))[0]
+        return np.argmax(logits, axis=-1).tolist()
+
     def test_speculative_bit_identity_and_ring_wrap(self):
         # repetitive continuations make the n-gram proposer hit; accepted
-        # AND rejected drafts must leave tokens bit-identical to the
-        # dense ring engine — including past position C where drafting
+        # AND rejected drafts must leave tokens bit-identical to plain
+        # greedy on the same engine AND to the uncached forward under the
+        # sliding window — including past position C where drafting
         # disables and the window slides
-        p = (np.arange(6) * 9 + 4) % 97
-        with GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                              cache_len=32, paged=True, kv_page_size=8,
-                              speculative_k=3, name="pg-spec") as eng, \
-             GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                              cache_len=32, paged=False,
-                              name="pg-spec-dense") as dense:
-            eng.warmup()
-            dense.warmup()
-            ref = dense.generate(p, 45, timeout=120).tolist()
-            out = eng.generate(p, 45, timeout=120).tolist()
-            self.assertEqual(out, ref)
-            st = eng.stats()
+        p = ((np.arange(6) * 9 + 4) % 97).tolist()
+        C, n = 32, 45
+
+        def eng(k, name):
+            return GenerationEngine(self.model, prompt_buckets=[8],
+                                    batch_size=2, cache_len=C,
+                                    kv_page_size=8, speculative_k=k,
+                                    name=name)
+
+        with eng(3, "pg-spec") as spec, eng(0, "pg-spec-k0") as plain:
+            spec.warmup()
+            plain.warmup()
+            out = spec.generate(p, n, timeout=120).tolist()
+            self.assertEqual(plain.generate(p, n, timeout=120).tolist(), out)
+            self.assertEqual(
+                self._next_tokens(p + out, window=C)[len(p) - 1:-1], out)
+            # the window only matters past C: the plain causal forward
+            # gives the same tokens up to there and other ones after
+            full = self._next_tokens(p + out)[len(p) - 1:-1]
+            self.assertEqual(full[:C - len(p) + 1], out[:C - len(p) + 1])
+            self.assertNotEqual(full, out)
+            st = spec.stats()
             self.assertGreater(st["spec_drafted"], 0)
             self.assertGreaterEqual(st["spec_drafted"], st["spec_accepted"])
             # speculation paid off: fewer steps than tokens decoded
-            self.assertLess(st["decode_steps"], 45)
+            self.assertLess(st["decode_steps"], n)
+            self.assertEqual(plain.stats()["spec_drafted"], 0)
 
     def test_pool_exhaustion_preempts_and_recovers(self):
         # a pool too small for both requests' full decode: the newest
@@ -310,7 +307,7 @@ class TestPagedGeneration(unittest.TestCase):
         pb = (np.arange(4) * 5 + 2) % 97
         refs = [self._ref_greedy(pa, 26), self._ref_greedy(pb, 26)]
         with GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                              cache_len=32, paged=True, kv_page_size=4,
+                              cache_len=32, kv_page_size=4,
                               kv_pages=9, speculative_k=0,
                               circuit_breaker=False,
                               name="pg-preempt") as eng:
@@ -324,50 +321,28 @@ class TestPagedGeneration(unittest.TestCase):
             self.assertEqual(st["kv_pages_leaked"], 0)
             self.assertEqual(st["kv_pages_free"], 9)
 
-    def test_transient_failure_restarts_rebuild_pool(self):
-        from paddle_tpu.resilience.faults import FaultPlan
-        with GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                              paged=True, kv_page_size=8, speculative_k=2,
-                              circuit_breaker=False,
-                              name="pg-restart") as eng:
-            eng.warmup()
-            p = (np.arange(5) * 9 + 4) % 97
-            ref = self._ref_greedy(p, 6)
-            self.assertEqual(eng.generate(p, 6, timeout=120).tolist(), ref)
-            plan = FaultPlan.parse(
-                "site=serving.decode,nth=1,error=TransientDeviceError")
-            with plan:
-                self.assertEqual(
-                    eng.generate(p, 6, timeout=120).tolist(), ref)
-            self.assertEqual(plan.stats()["serving.decode"]["fired"], 1)
-            st = eng.stats()
-            self.assertGreaterEqual(st["restarts"], 1)
-            # the rebuilt pool starts clean
-            self.assertEqual(st["kv_pages_leaked"], 0)
-
     def test_flag_and_mode_validation(self):
-        set_flags({"paged_kv": True})
-        try:
-            eng = GenerationEngine(self.model, prompt_buckets=[8],
-                                   batch_size=1, name="pg-flag")
-            try:
-                self.assertTrue(eng.stats()["paged"])
-                p = np.arange(3) % 97
+        # nothing selects a scheduler: the default-constructed engine runs
+        # the paged loop, the vestigial keywords accept None and True
+        p = np.arange(3) % 97
+        for kw in ({}, {"paged": True, "continuous": True}):
+            with GenerationEngine(self.model, prompt_buckets=[8],
+                                  batch_size=1, name="pg-flag", **kw) as eng:
+                self.assertEqual(eng._thread._target, eng._paged_loop)
+                st = eng.stats()
+                self.assertTrue(st["paged"] and st["continuous"])
+                self.assertIn("kv_pages_free", st)
                 self.assertEqual(eng.generate(p, 3, timeout=120).tolist(),
                                  self._ref_greedy(p, 3))
-            finally:
-                eng.close()
-        finally:
-            set_flags({"paged_kv": False})
         with self.assertRaises(InvalidArgumentError):
             GenerationEngine(self.model, prompt_buckets=[8], batch_size=1,
-                             paged=True, continuous=False, name="pg-bad")
+                             paged=False, continuous=False, name="pg-bad")
 
     def test_s604_fires_on_page_leak(self):
         from paddle_tpu.analysis import RetraceMonitor
         with RetraceMonitor(budget=8) as mon:
             eng = GenerationEngine(self.model, prompt_buckets=[8],
-                                   batch_size=1, cache_len=32, paged=True,
+                                   batch_size=1, cache_len=32,
                                    kv_page_size=8, name="pg-leak")
             try:
                 eng.warmup()
@@ -395,6 +370,43 @@ class TestPagedGeneration(unittest.TestCase):
                 eng.close(drain=False, timeout=10)
             self.assertIsInstance(fut.exception(timeout=5),
                                   UnavailableError)
+
+
+@pytest.mark.parametrize("engine_kw,extra", [
+    ({"speculative_k": 0}, 2), ({"speculative_k": 2}, 3),
+    ({"speculative_k": 0, "role": "prefill"}, 3)],
+    ids=["k0", "k2", "k0_prefill_role"])
+def test_warmup_returns_the_count_its_docstring_states(engine_kw, extra):
+    # len(prompt_buckets) + 2 (per-bucket admission, the step, the page
+    # copy), + 1 with speculation (the [B, 1] fast trace), + 1 for a
+    # hand-off role, + 1 on a mesh of several devices (FRESH_TRACE)
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    pt.seed(4321)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=97, hidden_size=32, num_layers=1, num_heads=4,
+        max_position=32, dropout=0.0))
+    buckets = [8, 16, 24]
+    with GenerationEngine(model, prompt_buckets=buckets, batch_size=2,
+                          kv_page_size=8, name="pg-count",
+                          **engine_kw) as eng:
+        assert eng.warmup() == len(buckets) + extra + FRESH_TRACE
+        assert eng.compile_count == len(buckets) + extra + FRESH_TRACE
+        assert eng.stats()["compile_count"] == eng.compile_count
+
+
+@pytest.mark.parametrize("keyword", ["paged", "continuous"])
+def test_removed_scheduler_keyword_is_refused(keyword):
+    # the dense ring (paged=False) and run-to-completion
+    # (continuous=False) schedulers are gone; asking for one says so
+    # before the model is touched
+    with pytest.raises(InvalidArgumentError, match="removed in PR 29"):
+        GenerationEngine(None, prompt_buckets=[8], **{keyword: False})
+
+
+@pytest.mark.parametrize("name", ["paged_kv", "continuous_batching"])
+def test_removed_scheduler_flag_is_unknown(name):
+    with pytest.raises(NotFoundError):
+        set_flags({name: True})
 
 
 if __name__ == "__main__":

@@ -140,7 +140,7 @@ class TestQuantizedEngine:
             donor, os.path.join(str(tmp_path), "donor"), mode="int8")
         prompt = np.arange(1, 9, dtype=np.int32)
         with GenerationEngine(_model(), prompt_buckets=[16], batch_size=2,
-                              cache_len=CACHE, continuous=True,
+                              cache_len=CACHE,
                               speculative_k=0, quantized="int8",
                               name="tq-swap") as eng:
             eng.warmup()
@@ -158,7 +158,7 @@ class TestQuantizedEngine:
         artifact = slim.export_quantized(
             donor, os.path.join(str(tmp_path), "donor8"), mode="fp8")
         with GenerationEngine(_model(), prompt_buckets=[16], batch_size=2,
-                              cache_len=CACHE, continuous=True,
+                              cache_len=CACHE,
                               speculative_k=0, quantized="int8",
                               name="tq-mismatch") as eng:
             with pytest.raises(InvalidArgumentError):

@@ -61,6 +61,21 @@ class TestRemoteEngine:
             assert syn[0].shape == (1, 2)
             proxy.close()
 
+    def test_server_leaves_a_request_still_being_written_alone(self, tmp_path):
+        # a client's write is `<req>.tmp.<pid>` until its atomic rename;
+        # a server that claimed that file made the rename fail
+        # (FileNotFoundError in the client: the suite's flake under load)
+        import os
+        import pickle
+        srv = EngineServer(_FakeEngine(), str(tmp_path), name="e0")
+        half = tmp_path / f"req.e0.77-1.tmp.{os.getpid()}"
+        half.write_bytes(pickle.dumps(([np.zeros((1, 2), np.float32)], {})))
+        assert srv.serve_once() == 0
+        assert half.exists()
+        os.replace(half, tmp_path / "req.e0.77-1")
+        assert srv.serve_once() == 1
+        assert (tmp_path / "rsp.e0.77-1").exists()
+
     def test_server_exception_travels_to_client(self, tmp_path):
         with EngineServer(_FakeEngine(fail=True), str(tmp_path), name="e0"):
             proxy = RemoteEngineProxy(str(tmp_path), "e0", timeout_s=10.0,

@@ -498,8 +498,7 @@ class PoolEndToEndSlowTest(unittest.TestCase):
 
         def eng(role, name):
             return GenerationEngine(model, prompt_buckets=[8, 16],
-                                    batch_size=2, continuous=True,
-                                    paged=True, kv_page_size=16,
+                                    batch_size=2, kv_page_size=16,
                                     role=role, name=name)
 
         colo = eng("any", "e2e-colo")
@@ -528,8 +527,7 @@ class PoolEndToEndSlowTest(unittest.TestCase):
 
         def factory():
             e = GenerationEngine(model, prompt_buckets=[8, 16],
-                                 batch_size=2, continuous=True, paged=True,
-                                 kv_page_size=16,
+                                 batch_size=2, kv_page_size=16,
                                  name=f"e2e-g{len(made)}")
             made.append(e)
             return e

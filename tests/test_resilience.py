@@ -10,6 +10,7 @@ import os
 import signal
 import sys
 import textwrap
+import threading
 import time
 
 import numpy as np
@@ -476,10 +477,16 @@ class TestCheckpointWriterResilience:
             model, str(tmp_path),
             retry=RetryPolicy(max_attempts=2, backoff_ms=1,
                               name="ckpt-latch", sleep=lambda s: None))
+        # the writer is held until all three are queued: a save() called
+        # after snapshot 1 has failed raises the latched error itself
+        # (the next test), and under load the worker used to win that race
+        queued, write = threading.Event(), acp._write
+        acp._write = lambda snap: (queued.wait(30), write(snap))[1]
         with plan:
             acp.save(epoch=0)
             acp.save(epoch=1)
             acp.save(epoch=2)
+            queued.set()
             with pytest.raises(InvalidArgumentError, match="injected"):
                 acp.close()
         assert len(acp.committed_dirs()) == 2

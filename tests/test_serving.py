@@ -291,13 +291,17 @@ class TestGenerationEngine(unittest.TestCase):
         return outs
 
     def test_token_identical_and_closed_compile_set(self):
-        # continuous=False pins the legacy run-batch-to-completion path;
-        # the continuous scheduler has its own suite
-        # (test_continuous_batching.py)
+        # more requests than slots, all submitted at once; the scheduler
+        # has its own suite (test_continuous_batching.py, which also holds
+        # the EOS cases)
+        import jax
         with GenerationEngine(self.model, prompt_buckets=[8, 16],
-                              batch_size=2, max_queue_delay_ms=2.0,
-                              continuous=False) as eng:
-            self.assertEqual(eng.warmup(), 3)  # 2 prefill buckets + 1 decode
+                              batch_size=2, max_queue_delay_ms=2.0) as eng:
+            # 2 admission buckets + the verify step + its [B, 1] fast
+            # trace + the page copy, and on a mesh of several devices the
+            # fresh-pool trace of the step (warmup's docstring)
+            closed = 5 + int(len(jax.devices()) > 1)
+            self.assertEqual(eng.warmup(), closed)
             prompts = [np.arange(5) % 97, (np.arange(7) * 3) % 97,
                        (np.arange(11) * 5 + 2) % 97]
             futs = [eng.submit(p, max_new_tokens=5) for p in prompts]
@@ -305,23 +309,10 @@ class TestGenerationEngine(unittest.TestCase):
             for p, g in zip(prompts, gens):
                 self.assertEqual(g.tolist(), self._ref_greedy(p, 5))
             # ragged prompts + many decode steps never reopened the set
-            self.assertEqual(eng.compile_count, 3)
+            self.assertEqual(eng.compile_count, closed)
             st = eng.stats()
             self.assertEqual(st["tokens"], 15)
             self.assertGreater(st["tokens_per_s"], 0.0)
-
-    def test_eos_stops_early(self):
-        probe = self._ref_greedy(np.arange(4) % 97, 8)
-        eos = probe[1]  # stop at this token's FIRST occurrence
-        expect = probe[: probe.index(eos) + 1]
-        self.assertLess(len(expect), 8)
-        with GenerationEngine(self.model, prompt_buckets=[8], batch_size=1,
-                              max_queue_delay_ms=1.0, continuous=False,
-                              eos_token_id=eos) as eng:
-            gen = eng.generate(np.arange(4) % 97, max_new_tokens=8,
-                               timeout=120)
-            self.assertEqual(gen.tolist(), expect)
-            self.assertEqual(gen[-1], eos)
 
     def test_prompt_over_largest_bucket_is_a_miss(self):
         with GenerationEngine(self.model, prompt_buckets=[8],

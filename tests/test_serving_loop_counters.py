@@ -72,7 +72,7 @@ def _settled(eng, n_evicted):
 @pytest.fixture(scope="module")
 def engine(model):
     with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
-                          cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                          cache_len=CACHE, kv_page_size=PAGE,
                           speculative_k=0, name="loopctr") as eng:
         yield eng
 
@@ -180,7 +180,7 @@ def test_a_failed_dispatch_counts_no_work(model):
     from paddle_tpu.resilience.faults import FaultPlan
 
     with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
-                          cache_len=CACHE, paged=True, kv_page_size=PAGE,
+                          cache_len=CACHE, kv_page_size=PAGE,
                           speculative_k=0, circuit_breaker=False,
                           name="loopctr-fault") as eng:
         eng.warmup()
@@ -195,13 +195,6 @@ def test_a_failed_dispatch_counts_no_work(model):
     assert (s["admit_rows"], s["admit_steps"]) == (1, 1)
     assert s["admit_tokens"] == len(PROMPTS[1])
     assert s["live_slot_steps"] == s["decode_steps"] == 2
-
-
-def test_dense_loop_is_not_on_the_record(model):
-    with GenerationEngine(model, prompt_buckets=[BUCKET], batch_size=B,
-                          continuous=True, name="loopctr-dense") as eng:
-        assert not set(LOOP_COUNTERS) & set(eng.metrics.snapshot())
-        assert len(eng.submit(PROMPTS[1], 3).result(120)) == 3
 
 
 def test_span_names_are_fixed_and_lie_on_the_engines_thread(

@@ -108,7 +108,7 @@ class TestEngineTenancy(unittest.TestCase):
 
         def build(name, tenancy=None):
             eng = GenerationEngine(self.model, prompt_buckets=[8],
-                                   batch_size=2, cache_len=48, paged=True,
+                                   batch_size=2, cache_len=48,
                                    kv_page_size=8, tenancy=tenancy,
                                    name=name)
             eng.install_adapter(0, a0)
@@ -145,7 +145,7 @@ class TestEngineTenancy(unittest.TestCase):
             TenantSpec("metered", token_budget=50, refill_per_s=500.0)])
         p = (np.arange(6) * 9 + 4) % 97
         with GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                              cache_len=48, paged=True, kv_page_size=8,
+                              cache_len=48, kv_page_size=8,
                               tenancy=ten, name="ten-preempt") as eng:
             eng.warmup()
             ref = eng.generate(p, 20, timeout=120).tolist()  # untagged
@@ -163,13 +163,6 @@ class TestEngineTenancy(unittest.TestCase):
             self.assertGreaterEqual(ten.snapshot()["metered"]["preempted"],
                                     1)
             self.assertEqual(st["kv_pages_leaked"], 0)
-
-    def test_tenancy_requires_paged(self):
-        ten = TenantScheduler([TenantSpec("a")])
-        with self.assertRaises(InvalidArgumentError):
-            GenerationEngine(self.model, prompt_buckets=[8], batch_size=2,
-                             continuous=True, paged=False, tenancy=ten,
-                             name="ten-dense")
 
 
 class TestS607(unittest.TestCase):

@@ -209,11 +209,33 @@ def gpt2_small_engine():
     with nn.abstract_parameters():
         model = GPTForCausalLM(GPTConfig(dropout=0.0))
     eng = GenerationEngine(
-        model, prompt_buckets=[512, 640, 768], batch_size=32, paged=True,
-        continuous=True, kv_page_size=16, speculative_k=0,
+        model, prompt_buckets=[512, 640, 768], batch_size=32,
+        kv_page_size=16, speculative_k=0,
         eos_token_id=None, name="compile-only")
     yield eng
     eng.close()
+
+
+def test_gpt2_engine_speaks_the_paged_protocol_only(gpt2_small_engine):
+    # one cache form: the model answers forward_paged and the page ops and
+    # has no dense ring verbs left, and the engine holds the paged programs
+    # and no others (a program that is not built cannot be compiled late)
+    from paddle_tpu.models.gpt import GPTForCausalLM, GPTModel
+
+    for cls in (GPTForCausalLM, GPTModel):
+        for verb in ("forward_cached", "init_cache", "write_slots",
+                     "reset_slots"):
+            assert not hasattr(cls, verb), (cls.__name__, verb)
+        for verb in ("forward_paged", "init_paged_cache", "copy_pages",
+                     "gather_pages", "scatter_pages"):
+            assert callable(getattr(cls, verb))
+    eng = gpt2_small_engine
+    assert sorted(eng._traces) == ["admit", "cow", "decode", "export",
+                                   "import"]
+    for gone in ("_prefill", "_decode", "_admit", "_evict", "_slot_loop",
+                 "_run_batch", "_init_state"):
+        assert not hasattr(eng, gone), gone
+    assert eng._thread._target == eng._paged_loop
 
 
 def _stray_pool_results(text, pool_elems):

@@ -23,7 +23,7 @@ from paddle_tpu.vision.transforms import functional as TF
 # --- models -----------------------------------------------------------------
 
 def test_resnet_nhwc_matches_nchw():
-    # data_format="NHWC" is the TPU-preferred layout (bench.py uses it);
+    # data_format="NHWC" is the TPU-preferred layout (chip_smoke.py uses it);
     # same state_dict must produce identical outputs on transposed input
     paddle.seed(0)
     m1 = M.resnet18(num_classes=10)
@@ -253,7 +253,7 @@ def test_image_folder(tmp_path):
 # --- convergence gate (book-test style) -------------------------------------
 
 def test_resnet50_amp_dp_plan():
-    """BASELINE configs 2+4: ResNet-50 trains AMP-O1 under an 8-device
+    """ResNet-50 trains AMP-O1 under an 8-device
     data-parallel fleet plan (batch sharded over the mesh, momentum with
     f32 master weights)."""
     from paddle_tpu import optimizer as popt
@@ -309,7 +309,7 @@ def test_resnet50_amp_dp_plan():
 
 def test_lenet_convergence_synthetic_digits():
     """Train LeNet on a synthetic separable 10-class image problem and
-    assert the loss drops and accuracy rises — the BASELINE config-1 gate
+    assert the loss drops and accuracy rises
     (ref: tests/book/test_recognize_digits.py asserts acc within a run)."""
     from paddle_tpu import optimizer as popt
     from paddle_tpu.metric import Accuracy

@@ -4,7 +4,7 @@ One-command proof of the MoE serving contracts (paddle_tpu/moe):
 
 1. **Closed compile set, tokens exact** — a 4-expert top-2 GPT behind the
    continuous-batching engine decodes with the per-step router INSIDE the
-   jitted step: ``compile_count`` stays at ``len(prompt_buckets) + 2`` and
+   jitted step: ``compile_count`` stays at ``len(prompt_buckets) + 3`` and
    zero post-warmup XLA compile requests fire.  With ample expert capacity
    (``moe_capacity_factor >= num_experts`` ⇒ no token ever dropped) the
    generated tokens are bit-identical to the eager greedy reference —
@@ -83,7 +83,7 @@ def _drive(model, name):
                for k in range(REQS)]
     refs = [_ref(model, p, NEW_TOKENS) for p in prompts]
     with GenerationEngine(model, prompt_buckets=BUCKETS, batch_size=2,
-                          continuous=True, name=name) as eng:
+                          name=name) as eng:
         warm = eng.warmup()
         xla0 = _XLA_COMPILES[0]
         futs = [eng.submit(p, NEW_TOKENS) for p in prompts]
@@ -107,7 +107,7 @@ def gate_moe():
     return {
         "token_identical": bool(r["outs"] == r["refs"]),
         "warmup_compiles": r["warm"],
-        "closed_compile_set": (r["compiles"] == len(BUCKETS) + 2
+        "closed_compile_set": (r["compiles"] == len(BUCKETS) + 3
                                and r["xla"] == 0),
         "xla_recompiles_post_warmup": r["xla"],
         "moe_routed_tokens": routed,
@@ -128,7 +128,7 @@ def gate_dense():
     moe_keys = [k for k in r["stats"] if k.startswith("moe_")]
     return {
         "token_identical": bool(r["outs"] == r["refs"]),
-        "closed_compile_set": (r["compiles"] == len(BUCKETS) + 2
+        "closed_compile_set": (r["compiles"] == len(BUCKETS) + 3
                                and r["xla"] == 0),
         "no_moe_keys": not moe_keys,
         "moe_keys": moe_keys,

@@ -87,7 +87,7 @@ def _prompts(rng, n, lo, hi):
 def _run_engine(model, quantized, pages, prompts, name):
     """Serve the workload; return (best_wall_s, outputs, peak_slots)."""
     eng = GenerationEngine(model, prompt_buckets=[48], batch_size=SLOTS,
-                           cache_len=CACHE, continuous=True, paged=True,
+                           cache_len=CACHE,
                            kv_pages=pages, kv_page_size=PAGE,
                            speculative_k=0, quantized=quantized, name=name)
     with eng:
@@ -270,8 +270,7 @@ def gate_rolling_swap(model):
     rng = np.random.RandomState(23)
     prompts = _prompts(rng, 4, 17, 24)
     engines = [GenerationEngine(model, prompt_buckets=[48], batch_size=2,
-                                cache_len=CACHE, continuous=True,
-                                paged=True, kv_pages=INT8_PAGES,
+                                cache_len=CACHE, kv_pages=INT8_PAGES,
                                 kv_page_size=PAGE, speculative_k=0,
                                 quantized="int8", name=f"quant-smoke-r{i}")
                for i in range(2)]

@@ -70,7 +70,7 @@ def gate_lifecycle():
 
     def factory():
         eng = GenerationEngine(model, prompt_buckets=[8, 16], batch_size=2,
-                               continuous=True, paged=True, kv_page_size=16,
+                               kv_page_size=16,
                                name=f"scn-g{len(made)}")
         made.append(eng)
         return eng
@@ -181,7 +181,7 @@ def gate_disagg():
 
     def eng(role, name):
         return GenerationEngine(model, prompt_buckets=buckets, batch_size=2,
-                                continuous=True, paged=True, kv_page_size=16,
+                                kv_page_size=16,
                                 role=role, name=name)
 
     # decode-class victims: short prompts with LONG budgets, arriving

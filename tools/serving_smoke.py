@@ -97,12 +97,10 @@ def gate_generation():
         identical = all(g.tolist() == ref(p, 5)
                         for p, g in zip(prompts, gens))
         st = eng.stats()
-        # continuous (default): per-bucket slot-admission prefill + decode
-        # + evict; legacy: per-bucket prefill + decode
-        expected = (len([8, 16]) + 2 if st["continuous"]
-                    else len([8, 16]) + 1)
+        # per-bucket admission + the verify step + its [B, 1] fast trace
+        # + the page copy (the default engine speculates)
+        expected = len([8, 16]) + 3
         return {"token_identical": bool(identical),
-                "continuous": bool(st["continuous"]),
                 "closed_compile_set": st["compile_count"] == expected,
                 "compile_count": st["compile_count"],
                 "tokens": st["tokens"],

@@ -156,7 +156,7 @@ def main():
     t0 = time.time()
     model = _model()
     engines = [GenerationEngine(model, prompt_buckets=BUCKETS, batch_size=2,
-                                continuous=True, name=f"slo-smoke-g{i}")
+                                name=f"slo-smoke-g{i}")
                for i in range(2)]
     router = Router(engines, name="slo-smoke-router", probe_interval_s=0.2)
     try:

@@ -83,7 +83,7 @@ def gate_mixed_and_hot_add(model):
 
     refs = {}
     with GenerationEngine(model, prompt_buckets=[16], batch_size=2,
-                          cache_len=48, paged=True, kv_page_size=8,
+                          cache_len=48, kv_page_size=8,
                           name="ten-smoke-serial") as ser:
         ser.install_adapter(0, a0)
         ser.install_adapter(1, a1)
@@ -98,7 +98,7 @@ def gate_mixed_and_hot_add(model):
                            TenantSpec("base", adapter_id=-1)])
     with RetraceMonitor(budget=8) as mon:
         with GenerationEngine(model, prompt_buckets=[16], batch_size=2,
-                              cache_len=48, paged=True, kv_page_size=8,
+                              cache_len=48, kv_page_size=8,
                               tenancy=ten, name="ten-smoke-mixed") as eng:
             eng.install_adapter(0, a0)  # adapter 1 hot-adds mid-traffic
             warm = eng.warmup()
@@ -151,7 +151,7 @@ def gate_noisy_neighbor(model):
             TenantSpec("initech", token_budget=FLOOD_BUDGET)])
         with GenerationEngine(model, prompt_buckets=[16],
                               batch_size=NOISY_SLOTS, cache_len=32,
-                              paged=True, kv_page_size=8, tenancy=ten,
+                              kv_page_size=8, tenancy=ten,
                               name="ten-smoke-noisy") as eng:
             eng.warmup()
             rep = run_scenario(eng, scenario, deadline_ms=8000.0,

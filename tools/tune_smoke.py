@@ -7,9 +7,9 @@ plan/serving twin of ``kernel_smoke.py``:
 1. **Cold process** — with a fresh cache file, a sharding-plan search
    times REAL fused train steps (``Executor.run_steps`` on a tiny MLP
    program) per candidate, and a serving-config search replays the
-   SAME deterministic fixed-seed request trace ``bench.py`` uses
-   against a real ``GenerationEngine`` per candidate under a p99
-   budget.  Both winners persist to disk (schema v2, space-tagged),
+   SAME deterministic fixed-seed request trace
+   (``RequestTrace.synthetic``) against a real ``GenerationEngine``
+   per candidate under a p99 budget.  Both winners persist to disk (schema v2, space-tagged),
    and — because the hand-set default is always in the running — the
    winner's measured score is no worse than the default's in the same
    search (tokens/s for serving, step time for the plan).
