@@ -30,6 +30,12 @@ from ..distributed.meta_parallel import (
 )
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
+from ..ops.paged_attention import (
+    key_visible,
+    paged_flash_decode,
+    paged_flash_eligible,
+    sweep_bound,
+)
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "gpt_tiny", "gpt_small"]
 
@@ -47,8 +53,6 @@ def _paged_flash(head_dim, page_size) -> bool:
     ``_fused_epilogues``: TPU backend, aligned dims, a one-device
     mesh).  Off-gate, ``forward_paged`` keeps the gather-then-attend
     path — the bit-identical CPU/fallback reference."""
-    from ..ops.paged_attention import paged_flash_eligible
-
     return paged_flash_eligible(head_dim, page_size)
 
 
@@ -245,7 +249,8 @@ class ParallelAttention(Layer):
 
         return shard_map(local, mesh, (spec, spec, spec), spec)(q, k, v)
 
-    def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask):
+    def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask,
+                      walk=None):
         """One attention step over a PAGED KV pool — the serving decode
         path (paddle_tpu/serving/generation.py; see
         :meth:`GPTModel.init_paged_cache`).  Every step has the same
@@ -259,7 +264,11 @@ class ParallelAttention(Layer):
         at host-resolved physical coordinates (``write_page``/
         ``write_off``, flattened ``[B*T]``; the pool's last page is the
         write-drop page for padding).  On the TPU the ``paged_decode``
-        kernel then reads the pool in that same order; elsewhere each
+        kernel then reads the pool in that same order, walking each slot's
+        pages up to its sweep bound (``walk``: ``(pos_map, positions,
+        bound)``, what the kernel rebuilds ``mask`` from and how far it
+        has to look; given by :meth:`GPTModel.forward_paged` when the
+        kernel's gate is open); elsewhere each
         slot's logical cache view is gathered back through its page-table
         row (``gather_tab`` ``[B,G]``, entries pre-clipped to valid pages)
         and plain masked attention runs over the gathered ``[B,H,C,hd]``
@@ -284,17 +293,15 @@ class ParallelAttention(Layer):
                 rows.reshape(B * T, H * hd).astype(kv[name].dtype))
         new_k, new_v = out["k"], out["v"]
         G, page = gather_tab.shape[1], new_k.shape[1]
-        if _paged_flash(hd, page):
+        if walk is not None:
             # TPU hot path: page-table walk + dequant + online softmax in
             # ONE Pallas kernel over the post-scatter pool, read in its
             # stored order — the [B,H,C,hd] float KV view is never
             # materialized (ops/paged_attention.py).  The scatter above is
             # identical on both paths, so the cache state (and the CPU
             # fallback below) stays bit-identical.
-            from ..ops.paged_attention import paged_flash_decode
-
             ctx = paged_flash_decode(
-                q, new_k, new_v, gather_tab, mask,
+                q, new_k, new_v, gather_tab, *walk,
                 out.get("k_scale"), out.get("v_scale"))  # [B,H,T,hd]
             ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
             ctx = constrain(ctx, None, None, "model")
@@ -369,14 +376,16 @@ class GPTBlock(Layer):
         x = x + self.mlp(self.ln2(x))
         return x
 
-    def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask):
+    def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask,
+                      walk=None):
         from ..distributed.collective import (
             get_overlap_schedule,
             overlap_schedule,
         )
 
         a, new_kv = self.attn.forward_paged(self.ln1(x), kv, write_page,
-                                            write_off, gather_tab, mask)
+                                            write_off, gather_tab, mask,
+                                            walk)
         x = x + a
         if get_overlap_schedule().get("mlp_collective_split"):
             # overlap dial: trace the MLP with its row-parallel reduce
@@ -618,14 +627,21 @@ class GPTModel(Layer):
         phys = jnp.where((slots >= 0) & (phys >= 0), phys, P)
         write_page = phys.reshape(-1)
         write_off = off.reshape(-1)
-        kp, qp = pos_map[:, None, :], positions[:, :, None]
-        mask = (kp >= 0) & (kp <= qp) & (kp > qp - C)  # [B,T,C]
+        mask = key_visible(pos_map[:, None, :], positions[:, :, None],
+                           C)  # [B,T,C]
         gather_tab = jnp.maximum(table, 0)  # unmapped → page 0; mask hides it
+        # the kernel's side of the same rule, once for all the layers: what
+        # it rebuilds the mask from, and how many key blocks of each slot
+        # hold a key that some row of this call can see (0: a free slot, a
+        # padding row; the whole window: a slot that has wrapped)
+        walk = None
+        if _paged_flash(self.cfg.hidden_size // self.cfg.num_heads, page):
+            walk = (pos_map, positions, sweep_bound(mask, page))
         new_layers = []
         with self._lora_scope(adapter_ids):
             for blk, kv in zip(self.blocks, cache["layers"]):
                 x, kv = blk.forward_paged(x, kv, write_page, write_off,
-                                          gather_tab, mask)
+                                          gather_tab, mask, walk)
                 new_layers.append(kv)
         return self.ln_f(x), {"layers": new_layers}
 

@@ -13,55 +13,75 @@ is that kernel for TPU, in the shape of the repo's other Pallas kernels:
 * the pool is read in its STORED order, ``[P+1, page, H*hd]``: one
   token's K (or V) for all heads is one contiguous lane-dense row, the
   same rows ``forward_paged``'s scatter writes and the paged programs
-  take in and hand back, so nothing re-orders the pool between them;
-* grid ``(B, H/bh, G)`` with the page dim innermost/sequential — each
-  grid step streams ONE physical page of K/V, the ``bh*hd`` lanes of
-  ``bh`` heads of it, straight from the pool into VMEM, located by a
-  scalar-prefetched i32 page table (``PrefetchScalarGridSpec`` — index
-  maps stay SMEM lookups, which Mosaic lowers directly; the
-  splash-attention pattern shared with flash_attention.py's triangle
-  grid).  Inside the block a head is a STATIC ``hd``-lane slice of the
-  ``[page, bh*hd]`` tile: the same per-head products as a per-head
-  block, every head, every key;
+  take in and hand back, so nothing re-orders the pool between them.  The
+  pools stay in HBM (``memory_space=pl.ANY``), as do a quantized pool's
+  scale planes (those padded to a lane tile first: a DMA moves whole
+  lane tiles); the kernel fetches what it reads itself;
+* grid ``(B, H/bh)``: one grid step is one slot's whole sweep for ``bh``
+  heads.  The page dimension is NOT in the grid (until PR 30 it was: one
+  ``[page, bh*hd]`` pair a grid step, whose fixed cost, not its bytes, was
+  the kernel's time, and every page of the window was swept whatever was
+  live).  Inside the step an ``lax.fori_loop`` walks the slot's KEY
+  BLOCKS: ``ceil(128 / page)`` logical pages, so that a block's keys are
+  one lane tile of scores (8 pages of 16, 1 of 128: fixed by the page
+  size, nothing to search).  A block's pages are located in the
+  scalar-prefetched i32 page table and copied into a two-slot VMEM buffer
+  by ``make_async_copy`` (the ``bh*hd`` lanes of this step's heads of each
+  page's rows), block ``i+1`` in flight while block ``i`` is multiplied;
+* the loop walks only the blocks of the slot's first ``bound[b]`` pages,
+  a second scalar-prefetched operand: the slot's SWEEP BOUND
+  (:func:`sweep_bound`), up to the last page that holds a key some query
+  row can see, in whole blocks; 0 for a free slot or a padding row.  ``GPTModel.forward_paged`` computes it once a program
+  from the validity mask it builds anyway and every layer shares it; it
+  is an upper bound only (a ring-wrapped slot gets the whole window):
+  every key inside a walked block is still tested by the model's rule;
+* that rule (:func:`key_visible`: a real token, causally at or before the
+  query, within the last ``C`` positions) is rebuilt in the kernel from
+  the slot's ``pos_map`` row and the rows' ``positions`` — three integer
+  comparisons on a ``[Tp, 128]`` tile, shared by the block's heads —
+  instead of moving a ``[B, T, C]`` float mask through HBM (at an
+  admission width the mask tile was four times the K/V bytes).  Causality,
+  ragged page counts, the write-drop page and the speculative ``1+k``
+  verify width all fold into it (the pool is scattered BEFORE attention,
+  so intra-step draft causality is just ``kp <= qp``).  Unmapped table
+  entries are pre-clipped to page 0 and their ``pos_map`` entries are
+  ``-1``; rows that see nothing (query padding, free slots) emit zeros;
+* inside the block a head is a STATIC ``hd``-lane slice of the
+  ``[128, bh*hd]`` tile: the same per-head products as a per-head block,
+  every head, every visible key;
 * at the decode width (one query token a slot, float pages) the heads
   ARE the query rows: the query is laid out block-diagonal, ``[H, H*hd]``
   with row h holding ``q_h`` in head h's own lanes and zeros elsewhere,
   and swept as ONE head of width ``H*hd`` — one ``[H, H*hd] x [H*hd,
-  page]`` product gives every head's scores (the other heads' lanes add
-  exact zeros), one ``[H, page] x [page, H*hd]`` product their contexts,
+  128]`` product gives every head's scores (the other heads' lanes add
+  exact zeros), one ``[H, 128] x [128, H*hd]`` product their contexts,
   of which row h keeps its own lanes.  Same kernel body, same products
-  in the same f32 accumulation; 2 products a page instead of 2H;
+  in the same f32 accumulation; 2 products a block instead of 2H;
 * flash-style online softmax: running max / normalizer / output
-  accumulator ride VMEM scratch across the sequential page sweep, so
-  attention memory is O(page), never O(C);
-* quantized pools (int8 / fp8-e4m3) dequantize PER PAGE inside the
-  inner loop — ``k_f32 = k_q * k_scale`` on the [page, hd] slice that
-  is already in VMEM.  A float KV view is never materialized in HBM;
-  the pool bytes crossing the memory bus per step are the quantized
-  bytes (the whole point of a quantized pool);
-* masking is the host-computed validity mask the gather path already
-  uses (causality, ragged page counts, the write-drop page, and the
-  speculative ``1+k`` verify width all fold into it — the pool is
-  scattered BEFORE attention, so intra-step draft causality is just
-  ``kp <= qp``).  Unmapped table entries are pre-clipped to page 0 and
-  carry mask 0; fully-masked rows (query padding) emit zeros.
+  accumulator ride VMEM scratch across the block loop, so attention
+  memory is O(block), never O(C);
+* quantized pools (int8 / fp8-e4m3) dequantize PER BLOCK inside the
+  loop — ``k_f32 = k_q * k_scale`` on the tile that is already in VMEM.
+  A float KV view is never materialized in HBM; the pool bytes crossing
+  the memory bus per step are the quantized bytes of the walked pages.
 
 Equivalence: same math as the gather-then-attend reference modulo
-float reassociation (online softmax accumulates in f32); the reference
-path stays the bit-identical CPU/fallback — ``paged_flash_eligible``
-gates dispatch exactly like ``fused_epilogues_eligible`` does for the
-other epilogues (TPU backend, one-device mesh, aligned dims).
+float reassociation (online softmax accumulates in f32, 128 keys a
+partial sum); the reference path stays the bit-identical CPU/fallback —
+``paged_flash_eligible`` gates dispatch exactly like
+``fused_epilogues_eligible`` does for the other epilogues (TPU backend,
+one-device mesh, aligned dims).
 
 Tile parameters resolve through ``ops.autotune`` (kernel name
 ``"paged_decode"``): ``block_h`` — heads per grid step, i.e. how many
-lanes of a page one step fetches — trades grid steps (and, below H,
-H/bh fetches of each page row) against VMEM residency, which the query
-width sets: a verify width takes all heads, so a page is fetched once;
-a 768-token admission block takes two (the decode width over float
-pages has nothing to tune, see above).  Candidates are the
-divisors of H whose ``bh*hd`` lanes are whole lane tiles (or all of
-``H*hd``) and that fit the VMEM budget; per-candidate equivalence is
-tested in tests/test_paged_attention.py.
+lanes of a page row one step fetches — trades grid steps against VMEM
+residency, which the query width sets: a verify width takes all heads; a
+768-token admission block takes two (the decode width over float pages
+has nothing to tune, see above).  The search times the sweep of the whole
+window (synthetic arguments carry no bound).  Candidates are the divisors
+of H whose ``bh*hd`` lanes are whole lane tiles (or all of ``H*hd``) and
+that fit the VMEM budget; per-candidate equivalence is tested in
+tests/test_paged_attention.py.
 """
 from __future__ import annotations
 
@@ -80,7 +100,8 @@ from ..framework.errors import InvalidArgumentError
 from ..framework.flags import flag
 from . import autotune as _at
 
-__all__ = ["paged_flash_decode", "paged_flash_eligible"]
+__all__ = ["paged_flash_decode", "paged_flash_eligible", "key_visible",
+           "block_pages", "sweep_bound"]
 
 # mask fill; exp(_NEG - m) underflows to exactly 0.0 in f32.  Typed f32:
 # under the package's global x64 a bare Python float reaches ``jnp.where``
@@ -89,67 +110,148 @@ _NEG = np.float32(-1e30)
 _ZERO = np.float32(0.0)
 
 
-def _kernel(tab_ref, q_ref, k_ref, v_ref, mask_ref, *refs,
-            block_h: int, sm_scale: float, quantized: bool):
-    """One (slot, head-block, page) step of the online-softmax sweep."""
+def key_visible(kp, qp, window):
+    """THE validity rule of the paged cache, for numpy and jax arrays
+    alike: the key at absolute position ``kp`` (``-1``: nothing written)
+    is visible to the query at ``qp`` (``-1``: padding) iff it holds a
+    real token, causally at or before the query, within the last
+    ``window`` positions.  ``forward_paged`` builds its mask with it, the
+    kernel applies it a tile at a time, the engine's loop counts with
+    it."""
+    return (kp >= 0) & (kp <= qp) & (kp > qp - window)
+
+
+def block_pages(page: int) -> int:
+    """Logical pages a key block of the sweep holds: as many as make the
+    block's keys one lane tile of scores (8 pages of 16, 1 page of 128)."""
+    return -(-_at.LANE // page)
+
+
+def sweep_bound(visible, page: int):
+    """Logical pages the sweep has to walk for each slot: up to the last
+    page that holds a key visible to ANY query row, rounded up to whole
+    key blocks (:func:`block_pages` pages; the window's end cuts the last
+    one), 0 where nothing is visible.  ``visible``: ``[B, T, C]`` bool
+    (numpy or jax), the mask of :func:`key_visible`; returns ``[B]``
+    int32.  A ring-wrapped slot's live pages are not a prefix of its
+    table: its bound is simply the whole window."""
+    B, _, C = visible.shape
+    G = C // page
+    live = visible.any(axis=1).reshape(B, G, page).any(axis=2)  # [B, G]
+    pages = (live * np.arange(1, G + 1, dtype=np.int32)).max(axis=1)
+    ppb = block_pages(page)
+    return ((pages + (ppb - 1)) // ppb * ppb).clip(0, G).astype(np.int32)
+
+
+def _kernel(tab_ref, bound_ref, q_ref, qp_ref, kp_ref, k_hbm, v_hbm, *refs,
+            block_h: int, window: int, sm_scale: float, quantized: bool):
+    """One (slot, head-block) step: the online-softmax sweep over the
+    slot's ``bound`` key blocks, each fetched by this step's own DMA."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = refs
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
+         m_s, l_s, acc_s) = refs
     else:
-        ks_ref = vs_ref = None
-        o_ref, m_s, l_s, acc_s = refs
-    g = pl.program_id(2)
-    g_steps = pl.num_programs(2)
+        o_ref, k_buf, v_buf, sem, m_s, l_s, acc_s = refs
+    # i32 constants are typed: under the package's global x64 a Python int
+    # next to a traced i32 becomes an i64, which Mosaic does not lower
+    i32 = np.int32
+    b, hb = pl.program_id(0), pl.program_id(1)
+    n = bound_ref[b]  # key blocks to walk
     hd = q_ref.shape[-1]
+    _, ppb, page, width = k_buf.shape  # two slots of ppb pages' lanes
+    bk = ppb * page
+    whole_row = width == k_hbm.shape[2]
+    lane0 = pl.multiple_of(hb * i32(width), width)  # this head block's lanes
 
-    @pl.when(g == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    def copies(i, slot):
+        """The DMAs of key block ``i`` into buffer ``slot``.  ``i`` None:
+        descriptors to WAIT with (a wait reads the destination and the
+        semaphore; its source only has to have the shape)."""
+        out = []
+        for j in range(ppb):
+            pg = i32(0) if i is None else tab_ref[b, i * i32(ppb) + i32(j)]
+            lanes = (slice(None) if whole_row else
+                     pl.ds(i32(0) if i is None else lane0, width))
+            for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                out.append(pltpu.make_async_copy(
+                    pool.at[pg, :, lanes], buf.at[slot, i32(j)],
+                    sem.at[slot]))
+            if quantized:  # a page's scales for all heads, [page, H]
+                for pool, buf in ((ks_hbm, ks_buf), (vs_hbm, vs_buf)):
+                    out.append(pltpu.make_async_copy(
+                        pool.at[pg], buf.at[slot, i32(j)], sem.at[slot]))
+        return out
 
-    mask = mask_ref[0, 0]  # [Tp, page] 0/1 f32
-    if quantized:
-        # a page's scales for all heads, [page, H], indexed like the
-        # values; this block's heads are picked out by lane below
-        ks_all, vs_all = ks_ref[0], vs_ref[0]
-        head_of = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 1)
-        h0 = pl.program_id(1) * block_h  # first head of this block (i32)
-    for h in range(block_h):  # static unroll: 2-D MXU dots per head
-        lanes = slice(h * hd, (h + 1) * hd)  # this head's lanes of a row
-        q = q_ref[0, h].astype(jnp.float32)          # [Tp, hd]
-        k = k_ref[0, :, lanes].astype(jnp.float32)   # [page, hd]
-        v = v_ref[0, :, lanes].astype(jnp.float32)
+    m_s[...] = jnp.full_like(m_s, _NEG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    qp = qp_ref[0]  # [Tp, 1] absolute positions of the query rows
+
+    def multiply(i, slot):
+        """Key block ``i``, landed in buffer ``slot``, into the running
+        max / sum / accumulator of each head."""
+        # the model's rule on this block's keys: [1, bk] against [Tp, 1]
+        valid = key_visible(kp_ref[0, i], qp, i32(window))
         if quantized:
-            # fused dequant: one multiplier per (page entry, head), a
-            # [page, 1] column over the head's [page, hd] tile — the f32
-            # K/V never exists outside this register window
-            mine = head_of == h0 + h
-            k = k * jnp.sum(jnp.where(mine, ks_all, _ZERO), axis=1,
-                            keepdims=True)
-            v = v * jnp.sum(jnp.where(mine, vs_all, _ZERO), axis=1,
-                            keepdims=True)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [Tp, page]
-        s = jnp.where(mask > 0, s, _NEG)
+            ks_all, vs_all = ks_buf[slot], vs_buf[slot]  # [ppb, page, H..]
+            head_of = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 2)
+            h0 = hb * i32(block_h)  # first head of this block (i32)
+        for h in range(block_h):  # static unroll: 2-D MXU dots per head
+            lanes = slice(h * hd, (h + 1) * hd)  # this head's lanes of a row
+            q = q_ref[0, h].astype(jnp.float32)              # [Tp, hd]
+            k = k_buf[slot, :, :, lanes].astype(jnp.float32)  # [ppb,page,hd]
+            v = v_buf[slot, :, :, lanes].astype(jnp.float32)
+            if quantized:
+                # fused dequant: one multiplier per (page entry, head), a
+                # column over the head's tile — the f32 K/V never exists
+                # outside this register window
+                mine = head_of == h0 + i32(h)
+                k = k * jnp.sum(jnp.where(mine, ks_all, _ZERO), axis=2,
+                                keepdims=True)
+                v = v * jnp.sum(jnp.where(mine, vs_all, _ZERO), axis=2,
+                                keepdims=True)
+            k, v = k.reshape(bk, hd), v.reshape(bk, hd)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [Tp, bk]
+            s = jnp.where(valid, s, _NEG)
 
-        m_prev = m_s[h]                       # [Tp, LANE], lanes equal
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)       # [Tp, LANE]
-        p = jnp.exp(s - m_new[:, :1]) * mask  # masked/padded entries -> 0
-        l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[h] = (acc_s[h] * alpha[:, :1]
-                    + jax.lax.dot_general(
-                        p, v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
-        m_s[h] = m_new
+            m_prev = m_s[h]                       # [Tp, LANE], lanes equal
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)       # [Tp, LANE]
+            # masked / padded keys -> 0 (a row with nothing yet: exp(0))
+            p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), _ZERO)
+            l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[h] = (acc_s[h] * alpha[:, :1]
+                        + jax.lax.dot_general(
+                            p, v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32))
+            m_s[h] = m_new
 
-    @pl.when(g == g_steps - 1)
-    def _flush():
-        for h in range(block_h):
-            l = l_s[h][:, :1]  # fully-masked rows (query padding): l == 0
-            out = jnp.where(l > 0, acc_s[h] / jnp.maximum(l, 1e-30), _ZERO)
-            o_ref[0, h] = out.astype(o_ref.dtype)
+    def step(i, carry):
+        """Iteration ``i`` of ``bound + 1``: start block ``i``'s copies,
+        then multiply block ``i - 1`` while they fly."""
+        @pl.when(i < n)
+        def _start():
+            for c in copies(i, jax.lax.rem(i, i32(2))):
+                c.start()
+
+        @pl.when(i > i32(0))
+        def _multiply():
+            slot = jax.lax.rem(i - i32(1), i32(2))
+            for c in copies(None, slot):
+                c.wait()
+            multiply(i - i32(1), slot)
+
+        return carry
+
+    # a bound of 0 (a free slot, a padding row): one empty iteration
+    jax.lax.fori_loop(i32(0), n + i32(1), step, i32(0))
+
+    for h in range(block_h):
+        l = l_s[h][:, :1]  # rows that saw nothing (query padding): l == 0
+        out = jnp.where(l > 0, acc_s[h] / jnp.maximum(l, 1e-30), _ZERO)
+        o_ref[0, h] = out.astype(o_ref.dtype)
 
 
 def _head_blocks(H: int, hd: int):
@@ -160,13 +262,16 @@ def _head_blocks(H: int, hd: int):
             if H % bh == 0 and (bh == H or (bh * hd) % _at.LANE == 0)]
 
 
-def _space(q, k_pool, v_pool, tables, mask, k_scale, v_scale):
+def _space(q, k_pool, v_pool, tables, pos_map, positions, k_scale, v_scale):
     """Candidate head-block sizes (:func:`_head_blocks`) whose resident
-    blocks fit the VMEM budget: the pipelined q/out/k/v/mask/scale blocks
-    twice (double-buffered), the three scratch accumulators once, minor
-    dims padded to whole lane tiles as VMEM holds them."""
+    blocks fit the VMEM budget: the pipelined q/out/position blocks twice
+    (double-buffered), the two-slot K/V (and scale) buffers and the three
+    scratch accumulators once, minor dims padded to whole tiles as VMEM
+    holds them."""
     B, H, T, hd = q.shape
     page = k_pool.shape[1]
+    ppb = block_pages(page)
+    bk, nblk = ppb * page, -(-tables.shape[1] // ppb)
     Tp = -(-T // _at.SUBLANE) * _at.SUBLANE
     kv_item = np.dtype(k_pool.dtype).itemsize
     q_item = np.dtype(q.dtype).itemsize
@@ -176,119 +281,132 @@ def _space(q, k_pool, v_pool, tables, mask, k_scale, v_scale):
 
     out = []
     for bh in _head_blocks(H, hd):
-        blocks = (2 * bh * Tp * lanes(hd) * q_item          # q + out
-                  + 2 * page * lanes(bh * hd) * kv_item     # k + v page
-                  + Tp * lanes(page) * 4)                   # mask
+        piped = (2 * bh * Tp * lanes(hd) * q_item      # q + out
+                 + Tp * _at.LANE * 4                   # positions column
+                 + nblk * _at.SUBLANE * lanes(bk) * 4)  # pos_map row
+        bufs = 2 * 2 * bk * lanes(bh * hd) * kv_item   # k + v, two slots
         if k_scale is not None:
-            blocks += 2 * page * lanes(H) * 4  # scale planes, all heads
+            bufs += 2 * 2 * bk * lanes(H) * 4  # scale planes, all heads
         scratch = bh * Tp * (2 * _at.LANE + lanes(hd)) * 4  # m/l/acc
-        if _at.vmem_fits(2 * blocks + scratch):
+        if _at.vmem_fits(2 * piped + bufs + scratch):
             out.append({"block_h": bh})
     return out
 
 
-def _heuristic(q, k_pool, v_pool, tables, mask, k_scale, v_scale):
+def _heuristic(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
+               v_scale):
     # the most heads that fit: the fewest grid steps, and each page row
-    # fetched the fewest times (once, at the decode width)
-    fits = _space(q, k_pool, v_pool, tables, mask, k_scale, v_scale)
+    # fetched in the fewest pieces (one, at the verify width)
+    fits = _space(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
+                  v_scale)
     H, hd = q.shape[1], q.shape[3]
     return fits[0] if fits else {"block_h": _head_blocks(H, hd)[-1]}
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "sm_scale"))
-def _sweep(q, k_pool, v_pool, tables, mask, k_scale, v_scale, *,
-           block_h: int, sm_scale: float):
+def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
+           v_scale, *, block_h: int, sm_scale: float):
     B, H, T, hd = q.shape
     P1, page, D = k_pool.shape
     G = tables.shape[1]
+    C = G * page
     if D != H * hd or v_pool.shape != k_pool.shape:
         raise InvalidArgumentError(
             f"paged_flash_decode: pool {k_pool.shape}/{v_pool.shape} vs "
             f"q {q.shape}")
-    if mask.shape != (B, T, G * page):
+    if pos_map.shape != (B, C) or positions.shape != (B, T):
         raise InvalidArgumentError(
-            f"paged_flash_decode: mask {mask.shape} != {(B, T, G * page)}")
+            f"paged_flash_decode: pos_map {pos_map.shape} != {(B, C)} or "
+            f"positions {positions.shape} != {(B, T)}")
     bh = block_h if block_h in _head_blocks(H, hd) else H
     quantized = k_scale is not None
+    ppb = block_pages(page)
+    nblk = -(-G // ppb)  # key blocks in a slot's window
+    bk = ppb * page
 
-    # pad the verify width to the sublane tile; padded rows carry mask 0
-    # everywhere, so they finalize to zeros and are sliced away below
+    # pad the verify width to the sublane tile; padded rows sit at
+    # position -1, see nothing, finalize to zeros and are sliced away
     Tp = -(-T // _at.SUBLANE) * _at.SUBLANE
     qp = q if Tp == T else jnp.pad(q, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
-    maskf = mask.astype(jnp.float32)
-    if Tp != T:
-        maskf = jnp.pad(maskf, ((0, 0), (0, Tp - T), (0, 0)))
-    # page-major so one page's [Tp, page] mask tile is the block's two
-    # minor dims IN FULL — Mosaic tiles the last two dims (8, 128) and a
-    # (1, page) slice of a [G, page] minor pair is not a legal block
-    maskf = maskf.reshape(B, Tp, G, page).transpose(0, 2, 1, 3)
-    tab = tables.astype(jnp.int32)  # [B, G] SMEM table for the index maps
+    qpos = jnp.pad(positions.astype(jnp.int32), ((0, 0), (0, Tp - T)),
+                   constant_values=-1)[:, :, None]           # [B, Tp, 1]
+    # a window that is not whole blocks: the last block's tail is page 0
+    # at position -1, like any unmapped entry
+    kpos = jnp.pad(pos_map.astype(jnp.int32), ((0, 0), (0, nblk * bk - C)),
+                   constant_values=-1).reshape(B, nblk, 1, bk)
+    tab = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, nblk * ppb - G)))
+    nb = (jnp.full((B,), nblk, jnp.int32) if bound is None  # pages -> blocks
+          else jnp.minimum(-(-bound.astype(jnp.int32) // ppb), nblk))
 
-    def qmap(b, h, g, t):
+    def qmap(b, h, tab, nb):
         return (b, h, _at.I0, _at.I0)
-
-    def kvmap(b, h, g, t):
-        return (t[b, g], _at.I0, h)
-
-    def scmap(b, h, g, t):
-        return (t[b, g], _at.I0, _at.I0)
-
-    def mmap(b, h, g, t):
-        return (b, g, _at.I0, _at.I0)
 
     in_specs = [
         pl.BlockSpec((1, bh, Tp, hd), qmap),
-        # one page of the pool as it is stored: `page` token rows, the
-        # bh*hd lanes of this head block (whole lane tiles, or the row)
-        pl.BlockSpec((1, page, bh * hd), kvmap),
-        pl.BlockSpec((1, page, bh * hd), kvmap),
-        pl.BlockSpec((1, 1, Tp, page), mmap),
+        pl.BlockSpec((1, Tp, 1), lambda b, h, tab, nb: (b, _at.I0, _at.I0)),
+        pl.BlockSpec((1, nblk, 1, bk),
+                     lambda b, h, tab, nb: (b, _at.I0, _at.I0, _at.I0)),
+        # the pools as they are stored, left in HBM: the kernel copies
+        # the pages it walks, the bh*hd lanes of this head block of each
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [qp, k_pool, v_pool, maskf]
+    operands = [qp, qpos, kpos, k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, ppb, page, bh * hd), k_pool.dtype),
+               pltpu.VMEM((2, ppb, page, bh * hd), v_pool.dtype)]
     if quantized:
-        # a page's scales for ALL heads: (page, H) is the operand's whole
-        # minor pair (a bh-lane slice of it is not (8, 128)-tileable); the
-        # kernel picks its heads' columns by lane
-        in_specs += [pl.BlockSpec((1, page, H), scmap),
-                     pl.BlockSpec((1, page, H), scmap)]
-        operands += [k_scale, v_scale]
+        # a page's scales for ALL heads, its [page, H] plane whole; the
+        # kernel picks its heads' columns by lane.  A DMA moves whole lane
+        # tiles, so the planes go in padded to one: a copy of the planes
+        # a layer (1/hd of the pool's values), the one thing here that
+        # is not read as stored
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        Hl = -(-H // _at.LANE) * _at.LANE
+        operands += [jnp.pad(s, ((0, 0), (0, 0), (0, Hl - H)))
+                     for s in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, ppb, page, Hl), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),                # one a buffer slot
+        pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running max
+        pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running sum
+        pltpu.VMEM((bh, Tp, hd), jnp.float32),        # out accum
+    ]
 
-    kern = functools.partial(_kernel, block_h=bh, sm_scale=sm_scale,
-                             quantized=quantized)
+    kern = functools.partial(_kernel, block_h=bh, window=C,
+                             sm_scale=sm_scale, quantized=quantized)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H // bh, G),  # page dim innermost: sequential sweep
+            num_scalar_prefetch=2,  # the page table and the sweep bounds
+            grid=(B, H // bh),  # the key blocks are the kernel's own loop
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bh, Tp, hd), qmap),
-            scratch_shapes=[
-                pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running max
-                pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running sum
-                pltpu.VMEM((bh, Tp, hd), jnp.float32),        # out accum
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=not _device.on_tpu(),
         name="paged_decode",
-    )(tab, *operands)
+    )(tab, nb, *operands)
     return out[:, :, :T, :]
 
 
 @_at.autotune("paged_decode", params=("block_h",), space=_space,
               heuristic=_heuristic)
-def _paged_decode(q, k_pool, v_pool, tables, mask, k_scale, v_scale, *,
-                  block_h: int):
-    return _sweep(q, k_pool, v_pool, tables, mask, k_scale, v_scale,
-                  block_h=block_h, sm_scale=1.0 / math.sqrt(q.shape[3]))
+def _paged_decode(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
+                  v_scale, *, block_h: int):
+    """The measurable unit of the ``block_h`` search: the sweep of every
+    slot's whole window (no bound)."""
+    return _sweep(q, k_pool, v_pool, tables, pos_map, positions, None,
+                  k_scale, v_scale, block_h=block_h,
+                  sm_scale=1.0 / math.sqrt(q.shape[3]))
 
 
-def _decode_width(q, k_pool, v_pool, tables, mask):
+def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound):
     """The decode width, ``q`` ``[B, H, 1, hd]`` over float pages: the
     heads become the query rows of ONE head as wide as a pool row (see
-    the module docstring), so a page costs two products, not 2H."""
+    the module docstring), so a block costs two products, not 2H."""
     B, H, _, hd = q.shape
     D = H * hd
     Hp = -(-H // _at.SUBLANE) * _at.SUBLANE
@@ -296,8 +414,8 @@ def _decode_width(q, k_pool, v_pool, tables, mask):
     qbd = (q[:, :, 0, None, :]
            * jnp.eye(H, dtype=q.dtype)[None, :, :, None]).reshape(B, H, D)
     qbd = jnp.pad(qbd, ((0, 0), (0, Hp - H), (0, 0)))[:, None]
-    out = _sweep(qbd, k_pool, v_pool, tables,
-                 jnp.broadcast_to(mask, (B, Hp, mask.shape[2])), None, None,
+    out = _sweep(qbd, k_pool, v_pool, tables, pos_map,
+                 jnp.broadcast_to(positions, (B, Hp)), bound, None, None,
                  block_h=1, sm_scale=1.0 / math.sqrt(hd))  # [B, 1, Hp, D]
     # row h's own lanes are head h's context; the rest is other heads'
     # values under head h's weights, dropped
@@ -305,8 +423,8 @@ def _decode_width(q, k_pool, v_pool, tables, mask):
     return jnp.einsum("bhhd->bhd", out)[:, :, None, :]
 
 
-def paged_flash_decode(q, k_pool, v_pool, tables, mask,
-                       k_scale=None, v_scale=None, *,
+def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
+                       bound=None, k_scale=None, v_scale=None, *,
                        block_h: Optional[int] = None):
     """Flash decode over a paged KV pool, page walk in-kernel.
 
@@ -316,8 +434,12 @@ def paged_flash_decode(q, k_pool, v_pool, tables, mask,
     by side in one row (float, int8 or fp8-e4m3; the last page is the
     write-drop page), ALREADY scattered with this step's K/V; tables:
     ``[B, G]`` i32 page-table rows with unmapped entries pre-clipped to
-    a valid page (``jnp.maximum(table, 0)`` — their mask is 0); mask:
-    ``[B, T, G*page]`` bool validity, identical to the gather path's;
+    a valid page (``jnp.maximum(table, 0)`` — their ``pos_map`` is -1);
+    pos_map: ``[B, G*page]`` i32, the absolute position each cache entry
+    holds (-1: none); positions: ``[B, T]`` i32, the query rows' absolute
+    positions (-1: padding) — validity is :func:`key_visible` of the two,
+    the gather path's mask; bound: ``[B]`` i32 logical pages to walk per
+    slot (:func:`sweep_bound` of that mask; None walks the whole window);
     k_scale/v_scale: ``[P+1, page, H]`` f32 dequant multipliers for
     quantized pools (both or neither).
 
@@ -333,9 +455,13 @@ def paged_flash_decode(q, k_pool, v_pool, tables, mask,
             "paged_flash_decode: pass k_scale and v_scale together "
             "(or neither)")
     if q.shape[2] == 1 and k_scale is None and block_h is None:
-        return _decode_width(q, k_pool, v_pool, tables, mask)
-    return _paged_decode(q, k_pool, v_pool, tables, mask, k_scale, v_scale,
-                         block_h=block_h)
+        return _decode_width(q, k_pool, v_pool, tables, pos_map, positions,
+                             bound)
+    cfg = _paged_decode.resolve(q, k_pool, v_pool, tables, pos_map,
+                                positions, k_scale, v_scale, block_h=block_h)
+    return _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound,
+                  k_scale, v_scale, sm_scale=1.0 / math.sqrt(q.shape[3]),
+                  **cfg)
 
 
 def paged_flash_eligible(head_dim: Optional[int] = None,

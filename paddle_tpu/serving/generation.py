@@ -71,6 +71,7 @@ from ..framework.errors import (
 )
 from ..framework.flags import flag
 from ..nn.layer_base import functional_call
+from ..ops.paged_attention import key_visible, sweep_bound
 from ..observability import tracing as _tracing
 from ..resilience import CircuitBreaker
 from ..resilience import retry as _retry_mod
@@ -1546,10 +1547,17 @@ class GenerationEngine:
                               live=len(live), columns=Td)
                         out, cache = self._step(self._params, self._buffers,
                                                 packed, cache)
+                        # while the device runs: the pages the paged_decode
+                        # kernel's sweep is bounded to, by the rule and
+                        # from the arrays the program was given
+                        n_swept = int(sweep_bound(key_visible(
+                            pool.pos_map[:, None, :], pp[:, :Td, None], C),
+                            self._page).sum())
                         host = np.asarray(out)  # serial harvest
                         dt = ph.to("harvest") / 1e6
                         cnt.update(decode_steps=1, live_slot_steps=len(live),
                                    kv_pages_live_steps=n_pages,
+                                   kv_pages_swept_steps=n_swept,
                                    kv_page_slots_steps=B * G)
                         if Td == 1:
                             it_fast = (dt if it_fast is None

@@ -98,12 +98,15 @@ _MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
 #: dispatch has returned (``admit_steps`` counts admission device calls,
 #: one per chunk of ``R`` rows; ``admit_token_slots`` is ``R x bucket``
 #: per such call and ``kv_page_slots_steps`` ``B x G`` per decode step:
-#: the denominators of the useful shares), and the summed per-request
+#: the denominators of the useful shares; ``kv_pages_swept_steps`` is the
+#: part of ``B x G`` inside the slots' sweep bounds, what the
+#: ``paged_decode`` kernel walks), and the summed per-request
 #: times whose count is ``admit_rows``
 LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
                  "admit_steps", "admit_rows", "admit_tokens",
                  "admit_token_slots", "live_slot_steps",
-                 "kv_pages_live_steps", "kv_page_slots_steps",
+                 "kv_pages_live_steps", "kv_pages_swept_steps",
+                 "kv_page_slots_steps",
                  "queue_wait_us", "ttft_us")
 
 #: page-accounting counters (see ``extra_counters``)

@@ -2,10 +2,10 @@
 
 Per-candidate numerical equivalence against the gather-then-attend
 reference (the serving path's bit-identical CPU fallback) across float,
-int8 and fp8-e4m3 pools, drop-page masking, ragged page counts and the
-speculative ``1+k`` verify width — all on the CPU interpreter.  The
-performance question lives on the real chip (``benchmarks/run.py``, the
-``gpt2_small`` cells).
+int8 and fp8-e4m3 pools, drop-page masking, ragged page counts, sweep
+bounds and the speculative ``1+k`` verify width — all on the CPU
+interpreter.  The performance question lives on the real chip
+(``benchmarks/run.py``, the ``gpt2_small`` cells).
 """
 import numpy as np
 import pytest
@@ -16,8 +16,10 @@ import jax.numpy as jnp
 from paddle_tpu.framework.errors import InvalidArgumentError
 from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.ops.paged_attention import (_head_blocks, _paged_decode,
+                                            block_pages, key_visible,
                                             paged_flash_decode,
-                                            paged_flash_eligible)
+                                            paged_flash_eligible,
+                                            sweep_bound)
 
 
 def _ref_attend(q, k_pool, v_pool, tables, mask, k_scale=None, v_scale=None):
@@ -48,14 +50,19 @@ def _ref_attend(q, k_pool, v_pool, tables, mask, k_scale=None, v_scale=None):
                      p / np.maximum(p.sum(-1, keepdims=True), 1e-30), v)
 
 
-def _geometry(rng, B=3, H=4, hd=64, page=16, G=4, T=1, dtype=np.float32):
+def _geometry(rng, B=3, H=4, hd=64, page=16, G=4, T=1, dtype=np.float32,
+              lengths=None):
     """A ragged paged layout: slot b holds ``lengths[b]`` tokens across
     its first ceil(len/page) table entries; the rest are unmapped (-1).
-    Heads of 64 (GPT-2's, half a lane tile): a head block is 2 or 4."""
+    The cache metadata is the serving loop's: ``pos_map`` [B, C] (the
+    position each mapped entry holds, -1 elsewhere) and the query rows'
+    ``positions`` [B, T], the last T of the slot.  Heads of 64 (GPT-2's,
+    half a lane tile): a head block is 2 or 4."""
     P = B * G  # enough physical pages for a 1:1 mapping + 1 drop page
     k_pool = rng.randn(P + 1, page, H * hd).astype(dtype)
     v_pool = rng.randn(P + 1, page, H * hd).astype(dtype)
-    lengths = [G * page - 1 - 3 * b for b in range(B)]  # ragged, >= T
+    if lengths is None:
+        lengths = [G * page - 1 - 3 * b for b in range(B)]  # ragged, >= T
     tables = np.full((B, G), -1, np.int32)
     nxt = 0
     for b in range(B):
@@ -63,13 +70,17 @@ def _geometry(rng, B=3, H=4, hd=64, page=16, G=4, T=1, dtype=np.float32):
             tables[b, g] = nxt
             nxt += 1
     q = rng.randn(B, H, T, hd).astype(np.float32)
-    kp = np.arange(G * page)
-    mask = np.zeros((B, T, G * page), bool)
-    for b in range(B):
-        mapped = np.repeat(tables[b] >= 0, page)
-        for t in range(T):
-            mask[b, t] = mapped & (kp <= lengths[b] - T + t)
-    return q, k_pool, v_pool, tables, mask
+    kp = np.arange(G * page, dtype=np.int32)
+    pos_map = np.where(np.repeat(tables >= 0, page, axis=1), kp[None], -1)
+    positions = np.stack([np.arange(n - T, n) if n else np.full(T, -1)
+                          for n in lengths]).astype(np.int32)
+    return q, k_pool, v_pool, tables, pos_map.astype(np.int32), positions
+
+
+def _mask(pos_map, positions):
+    """The model's validity mask, as ``forward_paged`` builds it."""
+    return key_visible(pos_map[:, None, :], positions[:, :, None],
+                       pos_map.shape[1])
 
 
 def _quantize(pool, dtype, H=4):
@@ -94,19 +105,22 @@ def _clipped(tables):
     return jnp.maximum(jnp.asarray(tables), 0)
 
 
+def _args(q, kp, vp, tab, pm, pos):
+    return (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), _clipped(tab),
+            jnp.asarray(pm), jnp.asarray(pos))
+
+
 class TestEquivalence:
     def test_float_all_candidates(self):
         rng = np.random.RandomState(0)
-        q, kp, vp, tab, mask = _geometry(rng)
-        cands = _paged_decode.candidates(q, kp, vp, tab, mask, None, None)
+        q, kp, vp, tab, pm, pos = _geometry(rng)
+        cands = _paged_decode.candidates(q, kp, vp, tab, pm, pos, None, None)
         # H=4 heads of 64: a block of 4 (the whole row) or 2 (one lane
         # tile); one head alone is half a tile and is not offered
         assert sorted(c["block_h"] for c in cands) == [2, 4]
-        want = _ref_attend(q, kp, vp, tab, mask)
+        want = _ref_attend(q, kp, vp, tab, _mask(pm, pos))
         for cfg in cands:
-            out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
-                                     jnp.asarray(vp), _clipped(tab),
-                                     jnp.asarray(mask), **cfg)
+            out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos), **cfg)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=2e-4, atol=2e-5)
 
@@ -117,16 +131,15 @@ class TestEquivalence:
         # masked slots (free slots of a decode step) emit zeros, and the
         # other heads' lanes never leak into a head's context
         rng = np.random.RandomState(9)
-        q, kp, vp, tab, mask = _geometry(rng, H=12)  # GPT-2's 12 x 64
-        mask[2] = False
-        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                _clipped(tab), jnp.asarray(mask))
+        q, kp, vp, tab, pm, pos = _geometry(rng, H=12)  # GPT-2's 12 x 64
+        pos[2] = -1
+        args = _args(q, kp, vp, tab, pm, pos)
         jaxpr = str(jax.make_jaxpr(paged_flash_decode)(*args))
         assert "f32[3,1,16,768]" in jaxpr  # 12 heads -> 16 rows of 768
         out = np.asarray(paged_flash_decode(*args))
         assert out.shape == q.shape
         np.testing.assert_array_equal(out[2], 0.0)
-        want = _ref_attend(q, kp, vp, tab, mask)
+        want = _ref_attend(q, kp, vp, tab, _mask(pm, pos))
         np.testing.assert_allclose(out[:2], want[:2], rtol=2e-4, atol=2e-5)
         per_head = np.asarray(paged_flash_decode(*args, block_h=12))
         np.testing.assert_allclose(out, per_head, rtol=1e-5, atol=1e-6)
@@ -143,16 +156,16 @@ class TestEquivalence:
     @pytest.mark.parametrize("qdtype", ["int8", "fp8"])
     def test_quantized_all_candidates(self, qdtype):
         rng = np.random.RandomState(1)
-        q, kp, vp, tab, mask = _geometry(rng)
+        q, kp, vp, tab, pm, pos = _geometry(rng)
         kq, ks = _quantize(kp, qdtype)
         vq, vs = _quantize(vp, qdtype)
         # the oracle attends over the SAME dequantized values, so the
         # comparison isolates the kernel, not the quantizer
-        want = _ref_attend(q, kq, vq, tab, mask, ks, vs)
-        cands = _paged_decode.candidates(q, kq, vq, tab, mask, ks, vs)
+        want = _ref_attend(q, kq, vq, tab, _mask(pm, pos), ks, vs)
+        cands = _paged_decode.candidates(q, kq, vq, tab, pm, pos, ks, vs)
         for cfg in cands:
-            out = paged_flash_decode(jnp.asarray(q), kq, vq, _clipped(tab),
-                                     jnp.asarray(mask), ks, vs, **cfg)
+            out = paged_flash_decode(*_args(q, kq, vq, tab, pm, pos),
+                                     k_scale=ks, v_scale=vs, **cfg)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=2e-4, atol=2e-4)
 
@@ -160,59 +173,55 @@ class TestEquivalence:
         # T = 1+k (k=4) pads to the sublane tile inside the kernel; all
         # T rows are valid queries at staggered causal positions
         rng = np.random.RandomState(2)
-        q, kp, vp, tab, mask = _geometry(rng, T=5)
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=5)
+        mask = _mask(pm, pos)
         assert mask.all(-1).sum() == 0  # staggered causality is live
+        assert len({int(r.sum()) for r in mask[0]}) == 5
         want = _ref_attend(q, kp, vp, tab, mask)
-        out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
-                                 jnp.asarray(vp), _clipped(tab),
-                                 jnp.asarray(mask))
+        out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos))
         np.testing.assert_allclose(np.asarray(out), want,
                                    rtol=2e-4, atol=2e-5)
 
     def test_drop_page_and_unmapped_pages_never_contribute(self):
         rng = np.random.RandomState(3)
-        q, kp, vp, tab, mask = _geometry(rng)
-        out0 = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
-                                  jnp.asarray(vp), _clipped(tab),
-                                  jnp.asarray(mask))
+        q, kp, vp, tab, pm, pos = _geometry(rng)
+        out0 = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos))
         # poison the write-drop page (last) AND every unmapped page: the
-        # mask (not the data) must be what excludes them
+        # rule (not the data) must be what excludes them
         kp2, vp2 = kp.copy(), vp.copy()
         kp2[-1] = vp2[-1] = 1e4
         used = set(tab[tab >= 0].ravel())
         for p in range(kp.shape[0] - 1):
             if p not in used:
                 kp2[p] = vp2[p] = -1e4
-        out1 = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp2),
-                                  jnp.asarray(vp2), _clipped(tab),
-                                  jnp.asarray(mask))
+        out1 = paged_flash_decode(*_args(q, kp2, vp2, tab, pm, pos))
         np.testing.assert_array_equal(np.asarray(out0), np.asarray(out1))
 
     def test_fully_masked_row_emits_zeros(self):
         rng = np.random.RandomState(4)
-        q, kp, vp, tab, mask = _geometry(rng, T=2)
-        mask[1, 0, :] = False  # e.g. a slot mid-admission: no valid kv yet
-        out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
-                                 jnp.asarray(vp), _clipped(tab),
-                                 jnp.asarray(mask))
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=2)
+        pos[1, 0] = -1  # e.g. a slot mid-admission: no valid kv yet
+        out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos))
         np.testing.assert_array_equal(np.asarray(out)[1, :, 0], 0.0)
+        mask = _mask(pm, pos)
         want = _ref_attend(q, kp, vp, tab, mask)
-        vb, vt = np.nonzero(np.asarray(mask).any(-1))  # valid rows only
+        vb, vt = np.nonzero(mask.any(-1))  # valid rows only
+        assert len(vb) == 5
         np.testing.assert_allclose(np.asarray(out)[vb, :, vt],
                                    want[vb, :, vt], rtol=2e-4, atol=2e-5)
 
     def test_bf16_query_pool(self):
         rng = np.random.RandomState(5)
-        q, kp, vp, tab, mask = _geometry(rng)
+        q, kp, vp, tab, pm, pos = _geometry(rng)
         qb = jnp.asarray(q, jnp.bfloat16)
         kb = jnp.asarray(kp, jnp.bfloat16)
         vb = jnp.asarray(vp, jnp.bfloat16)
         out = paged_flash_decode(qb, kb, vb, _clipped(tab),
-                                 jnp.asarray(mask))
+                                 jnp.asarray(pm), jnp.asarray(pos))
         assert out.dtype == jnp.bfloat16
         want = _ref_attend(np.asarray(qb, np.float32),
                            np.asarray(kb, np.float32),
-                           np.asarray(vb, np.float32), tab, mask)
+                           np.asarray(vb, np.float32), tab, _mask(pm, pos))
         np.testing.assert_allclose(np.asarray(out, np.float32), want,
                                    rtol=2e-2, atol=2e-2)
 
@@ -223,9 +232,7 @@ class TestEquivalence:
         assert _head_blocks(4, 8) == [4]  # tiny models: the row whole
         # a block the row cannot be cut into runs as the whole row
         rng = np.random.RandomState(7)
-        q, kp, vp, tab, mask = _geometry(rng)
-        args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                _clipped(tab), jnp.asarray(mask))
+        args = _args(*_geometry(rng))
         np.testing.assert_array_equal(
             np.asarray(paged_flash_decode(*args, block_h=1)),
             np.asarray(paged_flash_decode(*args, block_h=4)))
@@ -234,21 +241,197 @@ class TestEquivalence:
         # hd=16 (a row of 64 lanes, one block) and pages of 128
         rng = np.random.RandomState(8)
         for kw in (dict(hd=16), dict(hd=16, page=128, G=2)):
-            q, kp, vp, tab, mask = _geometry(rng, **kw)
-            out = paged_flash_decode(jnp.asarray(q), jnp.asarray(kp),
-                                     jnp.asarray(vp), _clipped(tab),
-                                     jnp.asarray(mask))
-            np.testing.assert_allclose(np.asarray(out),
-                                       _ref_attend(q, kp, vp, tab, mask),
-                                       rtol=2e-4, atol=2e-5)
+            q, kp, vp, tab, pm, pos = _geometry(rng, **kw)
+            out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos))
+            np.testing.assert_allclose(
+                np.asarray(out),
+                _ref_attend(q, kp, vp, tab, _mask(pm, pos)),
+                rtol=2e-4, atol=2e-5)
 
     def test_scale_pair_enforced(self):
         rng = np.random.RandomState(6)
-        q, kp, vp, tab, mask = _geometry(rng)
+        q, kp, vp, tab, pm, pos = _geometry(rng)
         kq, ks = _quantize(kp, "int8")
         with pytest.raises(InvalidArgumentError):
-            paged_flash_decode(jnp.asarray(q), kq, kq, _clipped(tab),
-                               jnp.asarray(mask), k_scale=ks)
+            paged_flash_decode(*_args(q, kq, kq, tab, pm, pos), k_scale=ks)
+
+
+def _wrapped(pm, pos, b, at):
+    """Slot ``b`` (fully mapped) has decoded past its window: its newest
+    token sits at ``at`` >= C, entry c holds the newest position that is
+    c modulo C, and the entries of its FIRST pages, not yet re-written
+    this lap, are blank — its live pages are not a prefix of its table."""
+    C, T = pm.shape[1], pos.shape[1]
+    c = np.arange(C)
+    lap = at - at % C + c
+    pm[b] = np.where(c <= at % C, lap, lap - C)
+    pm[b, :24] = -1
+    pos[b] = np.arange(at - T + 1, at + 1)
+
+
+#: one case a line: geometry, then what the case does to it
+_BOUNDED = {
+    # bounds of 2, 1, 0 blocks of 8 pages: slot 1 ends inside its first
+    # block (a live length that is no multiple of the block, nor of the
+    # page), slot 2 is free: zero iterations, zeros out
+    "ragged_bounds_one_slot_empty": dict(G=16, lengths=[255, 77, 0],
+                                         bounds=[16, 8, 0]),
+    "verify_width": dict(G=16, T=5, lengths=[200, 133, 5],
+                         bounds=[16, 16, 8]),
+    # an admission chunk of two rows, the second a padding row
+    "admission_width_one_padding_row": dict(B=2, G=16, T=40,
+                                            lengths=[40, 0], bounds=[8, 0]),
+    "wrapped_slot_gets_the_whole_window": dict(G=16, lengths=[256, 100, 9],
+                                               wrap=(0, 256 + 70),
+                                               bounds=[16, 8, 8]),
+    "int8_pool": dict(G=16, T=5, lengths=[255, 77, 0], quant="int8",
+                      bounds=[16, 8, 0]),
+    "fp8_pool": dict(G=16, lengths=[255, 77, 0], quant="fp8",
+                     bounds=[16, 8, 0]),
+    # one page a block
+    "page_128": dict(G=3, page=128, T=5, lengths=[384, 129, 0],
+                     bounds=[3, 2, 0]),
+    "decode_width_page_128": dict(G=3, page=128, lengths=[300, 128, 0],
+                                  bounds=[3, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BOUNDED))
+def test_bounded_sweep_matches_the_gather_path(case):
+    """The kernel given the model's sweep bound against the gather
+    reference over the whole window: no visible key is skipped, whatever
+    the bound cuts off."""
+    kw = dict(_BOUNDED[case])
+    bounds, quant, wrap = kw.pop("bounds"), kw.pop("quant", None), kw.pop(
+        "wrap", None)
+    rng = np.random.RandomState(sorted(_BOUNDED).index(case))
+    q, kp, vp, tab, pm, pos = _geometry(rng, **kw)
+    if wrap:
+        _wrapped(pm, pos, *wrap)
+    mask = _mask(pm, pos)
+    page = kp.shape[1]
+    bound = sweep_bound(mask, page)
+    assert bound.dtype == np.int32 and bound.tolist() == bounds
+    np.testing.assert_array_equal(  # numpy and jax: one rule
+        np.asarray(sweep_bound(jnp.asarray(mask), page)), bound)
+    scales = {}
+    if quant:
+        (kp, ks), (vp, vs) = _quantize(kp, quant), _quantize(vp, quant)
+        scales = dict(k_scale=ks, v_scale=vs)
+    want = _ref_attend(q, kp, vp, tab, mask, *scales.values())
+    args = _args(q, kp, vp, tab, pm, pos)
+    out = np.asarray(paged_flash_decode(*args, jnp.asarray(bound), **scales))
+    seen = mask.any(-1)  # [B, T]: rows with a visible key
+    np.testing.assert_array_equal(out[~seen[:, None].repeat(q.shape[1], 1)],
+                                  0.0)
+    vb, vt = np.nonzero(seen)
+    np.testing.assert_allclose(out[vb, :, vt], want[vb, :, vt],
+                               rtol=2e-4, atol=2e-4 if quant else 2e-5)
+    # the bound is an upper bound only: the whole window gives the same
+    whole = np.asarray(paged_flash_decode(*args, **scales))
+    np.testing.assert_allclose(whole, out, rtol=1e-6, atol=1e-6)
+
+
+def test_the_bound_is_what_the_kernel_walks():
+    # a bound one block short really stops the sweep there: the answer is
+    # the reference's over the first 128 keys alone (so a bound of 0 costs
+    # a slot nothing, and a wrong bound would be seen)
+    rng = np.random.RandomState(11)
+    q, kp, vp, tab, pm, pos = _geometry(rng, G=16, T=5,
+                                        lengths=[255, 250, 240])
+    assert block_pages(16) == 8 and block_pages(128) == 1
+    mask = _mask(pm, pos)
+    assert sweep_bound(mask, 16).tolist() == [16, 16, 16]
+    # a window that ends inside a block cuts the bound: 4 pages, not 8
+    small = _geometry(rng)
+    assert sweep_bound(_mask(*small[4:]), 16).tolist() == [4, 4, 4]
+    short = jnp.asarray([8, 16, 0], jnp.int32)
+    out = np.asarray(paged_flash_decode(*_args(q, kp, vp, tab, pm, pos),
+                                        short))
+    cut = mask.copy()
+    cut[0, :, 128:] = False
+    want = _ref_attend(q, kp, vp, tab, cut)
+    np.testing.assert_allclose(out[:2], want[:2], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(out[2], 0.0)
+    full = _ref_attend(q, kp, vp, tab, mask)
+    assert np.abs(out[0] - full[0]).max() > 1e-2
+
+
+@pytest.mark.parametrize("pool_dtype", [None, "int8"], ids=["float", "int8"])
+def test_forward_paged_gives_the_kernel_its_bound(monkeypatch, pool_dtype):
+    """``GPTModel.forward_paged`` with the kernel's gate open (interpret
+    mode here; the chip's branch, which no CPU run takes otherwise)
+    against the gather path: an admission call with a padding row, then a
+    verify-width step with a free slot, over a pool of shuffled pages.
+    Hidden states agree, the pools written are identical, and every layer
+    was handed the one bound computed from the call's mask."""
+    from paddle_tpu import nn  # noqa: F401  (registers layers)
+    from paddle_tpu.models import gpt as G
+    from paddle_tpu.ops import paged_attention as PA
+
+    cfg = G.gpt_tiny(hidden_size=64, max_position=512)  # 4 heads of 16
+    model = G.GPTModel(cfg)
+    page, Gp, B = 16, 16, 3
+    C = page * Gp  # two key blocks of 128
+    dt = jnp.int8 if pool_dtype else None
+    rng = np.random.RandomState(5)
+    table = np.full((B, Gp), -1, np.int32)
+    free = list(rng.permutation(B * Gp))
+    lengths = [150, 0, 37]
+    for b, n in enumerate(lengths):
+        for g in range(-(-(n + 5) // page)):
+            table[b, g] = free.pop()
+    pos_map = np.full((B, C), -1, np.int32)
+    Tb = 160
+    ids = rng.randint(0, cfg.vocab_size, (B, Tb)).astype(np.int32)
+    pos = np.full((B, Tb), -1, np.int32)
+    for b, n in enumerate(lengths):
+        pos[b, :n] = pos_map[b, :n] = np.arange(n)
+    step_ids = rng.randint(0, cfg.vocab_size, (B, 5)).astype(np.int32)
+    step_pos = np.full((B, 5), -1, np.int32)
+    step_map = pos_map.copy()
+    for b, n in enumerate(lengths):
+        if n:
+            step_pos[b] = step_map[b, n:n + 5] = np.arange(n, n + 5)
+
+    given = []
+    real = PA.paged_flash_decode
+
+    def spy(q, k, v, tab, pm, pp, bound, *scales, **kw):
+        given.append(np.asarray(bound))
+        return real(q, k, v, tab, pm, pp, bound, *scales, **kw)
+
+    def run(kernel):
+        monkeypatch.setattr(G, "_paged_flash", lambda hd, pg: kernel)
+        monkeypatch.setattr(G, "paged_flash_decode", spy)
+        pool = model.init_paged_cache(B * Gp, page, dtype=dt)
+        h0, pool = model.forward_paged(ids, pos, pos_map, table, pool)
+        h1, pool = model.forward_paged(step_ids, step_pos, step_map, table,
+                                       pool)
+        return np.asarray(h0), np.asarray(h1), pool
+
+    want0, want1, wpool = run(False)
+    assert not given
+    got0, got1, gpool = run(True)
+    assert len(given) == 2 * cfg.num_layers
+    for got_b, (pm, pp) in zip(given[::cfg.num_layers],
+                               ((pos_map, pos), (step_map, step_pos))):
+        mask = key_visible(pm[:, None, :], pp[:, :, None], C)
+        np.testing.assert_array_equal(got_b, sweep_bound(mask, page))
+    assert given[0].tolist() == [16, 0, 8] and given[-1].tolist() == [16, 0, 8]
+    for a, b in zip(given[:cfg.num_layers], given[1:cfg.num_layers]):
+        np.testing.assert_array_equal(a, b)  # one bound, every layer
+    live0, live1 = pos >= 0, step_pos >= 0
+    tol = dict(rtol=2e-2, atol=2e-2) if pool_dtype else dict(rtol=2e-4,
+                                                              atol=2e-4)
+    np.testing.assert_allclose(got0[live0], want0[live0], **tol)
+    np.testing.assert_allclose(got1[live1], want1[live1], **tol)
+    if not pool_dtype:  # the scatter is the same on both paths
+        for lw, lg in zip(wpool["layers"], gpool["layers"]):
+            used = table[table >= 0]
+            np.testing.assert_allclose(np.asarray(lg["k"])[used],
+                                       np.asarray(lw["k"])[used],
+                                       rtol=2e-4, atol=2e-4)
 
 
 class TestEligibility:

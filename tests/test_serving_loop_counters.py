@@ -167,6 +167,67 @@ def test_live_pages_are_counted_against_the_page_table(served):
     assert s["kv_pages_live_steps"] >= s["live_slot_steps"]
 
 
+def test_swept_pages_are_the_bounds_the_step_program_was_given():
+    # kv_pages_swept_steps: per decode step, the pages inside the slots'
+    # sweep bounds (ops/paged_attention.py: whole key blocks up to the
+    # last page with a visible key), summed.  Here a window of 4 blocks of
+    # 2 pages: a short request sweeps one block, the long one grows into
+    # its third, a free slot none.  Computed again from the very rows the
+    # step program was handed
+    from paddle_tpu.ops.paged_attention import (block_pages, key_visible,
+                                                sweep_bound)
+
+    page, cache, slots = 64, 512, 3
+    G, ppb = cache // page, block_pages(page)
+    assert (G, ppb) == (8, 2)
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    pt.seed(99)
+    wide = GPTForCausalLM(GPTConfig(vocab_size=97, hidden_size=32,
+                                    num_layers=1, num_heads=4,
+                                    max_position=cache, dropout=0.0))
+    wide.eval()
+    seen = []
+    with GenerationEngine(wide, prompt_buckets=[16, 288], batch_size=slots,
+                          cache_len=cache, kv_page_size=page,
+                          speculative_k=0, name="sweptctr") as eng:
+        eng.warmup()
+        step = eng._step
+
+        def spy(params, buffers, packed, pool):
+            seen.append(np.array(packed))
+            return step(params, buffers, packed, pool)
+
+        eng._step = spy
+        before = eng.metrics.snapshot()
+        futs = [eng.submit(np.arange(250) % 97, 12),
+                eng.submit(np.arange(5) % 97, 6)]
+        for f in futs:
+            f.result(120)
+        after = _settled(eng, before["evicted"] + len(futs))
+    d = {k: after[k] - before[k]
+         for k in (*LOOP_COUNTERS, "decode_steps")}
+    given, per_slot, steps = 0, set(), 0
+    for packed in seen:
+        T = (packed.shape[1] - cache - G) // 2
+        pos, pm = packed[:, T:2 * T], packed[:, 2 * T:2 * T + cache]
+        steps += bool((pos >= 0).any())  # not the pool's inert first call
+        pages = sweep_bound(
+            key_visible(pm[:, None, :], pos[:, :, None], cache), page)
+        given += int(pages.sum())
+        per_slot.update(pages.tolist())
+    assert steps == d["decode_steps"] > 0
+    assert d["kv_pages_swept_steps"] == given
+    assert per_slot == {0, 2, 4, 6}  # free; short; 250 + 12 crosses 256
+    assert d["kv_pages_swept_steps"] <= d["kv_page_slots_steps"] \
+        == slots * G * d["decode_steps"]
+    # whole blocks up to the newest written page: at most the page a slot
+    # has mapped ahead of its next write is outside
+    assert d["kv_pages_swept_steps"] >= (d["kv_pages_live_steps"]
+                                         - d["live_slot_steps"])
+    assert d["kv_pages_swept_steps"] < d["kv_page_slots_steps"]
+
+
 def test_request_times_are_ordered_and_inside_what_the_caller_saw(served):
     s = served["snap"]
     # a request waits, is prefilled, and only then can complete: summed

@@ -164,35 +164,40 @@ def test_grouped_matmul_moe_experts(chip, bwd):
 @pytest.mark.parametrize("page", [16, 128])
 @pytest.mark.parametrize("pool", [f32, i8], ids=["float", "int8"])
 def test_paged_flash_decode_gpt2_small(chip, pool, page, T):
-    # GPT-2-small decode: 12 heads x 64, cache 1024, 4 slots; the pool in
-    # its stored order, a token's heads side by side in one 768-wide row
+    # GPT-2-small decode as the chip benchmark's cells run it: 12 heads x
+    # 64, cache 1024, 32 slots over 2049 pages of 16 (257 of 128); the
+    # pool in its stored order, a token's heads side by side in one
+    # 768-wide row, left in HBM; the page table and the sweep bounds are
+    # the kernel's two scalar-prefetch operands
     from paddle_tpu.models.gpt import _paged_flash
     from paddle_tpu.ops.paged_attention import paged_flash_decode
 
     assert _paged_flash(64, page)  # the model's gate selects the kernel
-    B, H, hd, G = 4, 12, 64, 1024 // page
-    pages = B * G + 1
+    B, H, hd, G = 32, 12, 64, 1024 // page
+    pages = 2048 * 16 // page + 1
     shapes = [((B, H, T, hd), f32), ((pages, page, H * hd), pool),
               ((pages, page, H * hd), pool), ((B, G), i32),
-              ((B, T, G * page), jnp.bool_)]
+              ((B, G * page), i32), ((B, T), i32), ((B,), i32)]
     if pool == i8:
         shapes += [((pages, page, H), f32)] * 2
     _compiles_with_kernel(chip, paged_flash_decode, *shapes)
 
 
-def test_paged_flash_admission_prefill_gpt2_small(chip):
+@pytest.mark.parametrize("T", [64, 768])
+def test_paged_flash_admission_prefill_gpt2_small(chip, T):
     # the paged admission program's attention: the same kernel over the
-    # engine's row chunk x the widest prompt bucket the chip benchmark
-    # serves (docs_closed, 768), float pages of 16
+    # engine's row chunk x the narrowest and the widest prompt bucket the
+    # chip benchmark serves (chat_open 64, docs_closed 768), float pages
+    # of 16
     from paddle_tpu.ops.paged_attention import paged_flash_decode
     from paddle_tpu.serving.generation import _ADMIT_ROWS
 
-    R, H, hd, page, T = _ADMIT_ROWS, 12, 64, 16, 768
+    R, H, hd, page = _ADMIT_ROWS, 12, 64, 16
     G, pages = 1024 // page, 2048 + 1
     _compiles_with_kernel(
         chip, paged_flash_decode, ((R, H, T, hd), f32),
         ((pages, page, H * hd), f32), ((pages, page, H * hd), f32),
-        ((R, G), i32), ((R, T, G * page), jnp.bool_))
+        ((R, G), i32), ((R, G * page), i32), ((R, T), i32), ((R,), i32))
 
 
 # -- the stored order of GPT-2's page pool ------------------------------------

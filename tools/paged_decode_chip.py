@@ -1,0 +1,239 @@
+"""The ``paged_decode`` kernel on the chip, at the GPT-2 cells' own shapes.
+
+The kernel's dispatch is a TPU-only branch of ``GPTModel.forward_paged``
+that no CPU test takes (PR 26 was refused ``outputs_incorrect`` with the
+CPU suite green), so before a number is quoted:
+
+    chiprun -- python tools/paged_decode_chip.py
+
+1. ``agree``: one GPT-2-small layer's ``forward_paged`` with the kernel
+   against the same call on the gather path, over 2048 float pages of 16
+   filled at random: the decode width ``[32, 1]`` with ragged lengths and
+   one free slot, the verify width ``[32, 5]``, and an admission chunk
+   ``[2, T]`` for every bucket the cells serve, one row a padding row;
+   then the kernel alone against a plain gather for a slot that has
+   wrapped (positions the model's embedding table does not reach).
+2. ``kernel_ms``: the kernel alone, a layer, 12 dependent calls in one
+   program: the decode width at ``docs_closed``'s and ``chat_open``'s
+   occupancy, the admission chunk at its widest and narrowest bucket.
+
+``--trace <dir>`` instead reads a profiler trace a benchmark run left
+(``.cache/bench_trace/<cell>``) and prints, for each paged program in
+it, its mean time on the device and the mean time of one ``paged_decode``
+call inside it.  One JSON object, last line; exit 1 on a disagreement.
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+PAGE, PAGES, C, H, HD = 16, 2048, 1024, 12, 64
+G = C // PAGE
+BUCKETS = (64, 128, 256, 512, 640, 768)
+
+
+def _layout(rng, lengths, T):
+    """Page table, position map and the last ``T`` positions of slots
+    holding ``lengths`` tokens, pages drawn from the pool at random."""
+    B = len(lengths)
+    free = list(rng.permutation(PAGES))
+    table = np.full((B, G), -1, np.int32)
+    pos_map = np.full((B, C), -1, np.int32)
+    pos = np.full((B, T), -1, np.int32)
+    for b, n in enumerate(lengths):
+        for g in range(-(-n // PAGE)):
+            table[b, g] = free.pop()
+        pos_map[b, :n] = np.arange(n)
+        if n:
+            pos[b, -min(T, n):] = np.arange(n - min(T, n), n)
+    return table, pos_map, pos
+
+
+def agree():
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import gpt as G_
+    from paddle_tpu.models.gpt import GPTConfig, GPTModel
+    from paddle_tpu.ops.paged_attention import (key_visible,
+                                                paged_flash_decode,
+                                                sweep_bound)
+
+    pt.seed(30)
+    model = GPTModel(GPTConfig(num_layers=1, dropout=0.0))
+    model.eval()
+    rng = np.random.RandomState(30)
+    pool = {"layers": [{
+        n: jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
+                       jnp.float32) for n in ("k", "v")}]}
+    assert G_._paged_flash(HD, PAGE), "the kernel's gate is shut here"
+    gate = G_._paged_flash
+    cases = [("decode[32,1]", 1,
+              [0] + [int(n) for n in rng.randint(1, C, 31)]),
+             ("verify[32,5]", 5,
+              [0] + [int(n) for n in rng.randint(5, C, 31)])]
+    cases += [(f"admit[2,{T}]", T, [int(rng.randint(T // 2 + 1, T + 1)), 0])
+              for T in BUCKETS]
+    out, worst = {}, 0.0
+    for name, T, lengths in cases:
+        table, pos_map, pos = _layout(rng, lengths, T)
+        ids = rng.randint(0, 50257, pos.shape).astype(np.int32)
+        hidden = {}
+        for kernel in (True, False):
+            G_._paged_flash = gate if kernel else (lambda hd, pg: False)
+            try:
+                h, _ = jax.jit(model.forward_paged)(ids, pos, pos_map, table,
+                                                    pool)
+            finally:
+                G_._paged_flash = gate
+            hidden[kernel] = np.asarray(h)
+        live = pos >= 0
+        gap = float(np.abs(hidden[True][live] - hidden[False][live]).max())
+        scale = float(np.abs(hidden[False][live]).max())
+        bound = sweep_bound(key_visible(pos_map[:, None, :],
+                                        pos[:, :, None], C), PAGE)
+        out[name] = {"max_gap": gap, "ref_max": scale,
+                     "pages": int(bound.sum()), "rows": int(live.sum())}
+        worst = max(worst, gap / scale)
+    # a wrapped slot: the kernel against a plain gather at "highest"
+    table, pos_map, _ = _layout(rng, [C, C * 2 // 3, 0], 1)
+    at = C + 333
+    c = np.arange(C)
+    lap = at - at % C + c
+    pos_map[0] = np.where(c <= at % C, lap, lap - C)
+    pos_map[0, :40] = -1
+    pos = np.asarray([[at], [C * 2 // 3 - 1], [-1]], np.int32)
+    q = jnp.asarray(rng.standard_normal((3, H, 1, HD)), jnp.float32)
+    k, v = pool["layers"][0]["k"], pool["layers"][0]["v"]
+    tab = jnp.maximum(jnp.asarray(table), 0)
+    mask = key_visible(pos_map[:, None, :], pos[:, :, None], C)
+    bound = sweep_bound(mask, PAGE)
+    got = np.asarray(jax.jit(paged_flash_decode)(
+        q, k, v, tab, jnp.asarray(pos_map), jnp.asarray(pos),
+        jnp.asarray(bound)))
+    with jax.default_matmul_precision("highest"):
+        kv = [jnp.take(a, tab, axis=0).reshape(3, C, H, HD) for a in (k, v)]
+        s = jnp.einsum("bhqd,bchd->bhqc", q, kv[0]) / np.sqrt(HD)
+        s = jnp.where(jnp.asarray(mask)[:, None], s, -1e30)
+        want = np.asarray(jnp.einsum("bhqc,bchd->bhqd",
+                                     jax.nn.softmax(s, -1), kv[1]))
+    gap = float(np.abs(got[:2] - want[:2]).max())
+    out["wrapped[3,1]"] = {"max_gap": gap,
+                           "ref_max": float(np.abs(want[:2]).max()),
+                           "pages": bound.tolist(),
+                           "free_slot_zero": bool((got[2] == 0).all())}
+    # default matmul precision (bf16 passes) on both sides: 1e-2 relative
+    ok = (worst < 2e-2 and gap < 2e-2 * np.abs(want[:2]).max()
+          and out["wrapped[3,1]"]["free_slot_zero"])
+    return ok, out
+
+
+def kernel_ms():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.paged_attention import (key_visible,
+                                                paged_flash_decode,
+                                                sweep_bound)
+
+    rng = np.random.RandomState(31)
+    k = jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
+                    jnp.float32)
+    v = jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
+                    jnp.float32)
+    cases = {
+        "decode_docs_closed[32,1]": (1, [int(n) for n in
+                                         rng.randint(400, 800, 32)]),
+        "decode_chat_open[32,1]": (1, [200, 90, 330] + [0] * 29),
+        "decode_whole_window[32,1]": (1, [C] * 32),
+        "admit[2,768]_two_rows": (768, [768, 600]),
+        "admit[2,768]_one_row": (768, [700, 0]),
+        "admit[2,64]_one_row": (64, [50, 0]),
+    }
+    out = {}
+    for name, (T, lengths) in cases.items():
+        table, pos_map, pos = _layout(rng, lengths, T)
+        B = len(lengths)
+        bound = sweep_bound(key_visible(pos_map[:, None, :],
+                                        pos[:, :, None], C), PAGE)
+        q = jnp.asarray(rng.standard_normal((B, H, T, HD)), jnp.float32)
+        args = (jnp.maximum(jnp.asarray(table), 0), jnp.asarray(pos_map),
+                jnp.asarray(pos), jnp.asarray(bound))
+
+        @jax.jit
+        def twelve(q, k, v, *a):
+            for _ in range(12):  # each call needs the one before it
+                q = q + 1e-3 * paged_flash_decode(q, k, v, *a)
+            return q
+
+        twelve(q, k, v, *args).block_until_ready()
+        best = float("inf")
+        for _ in range(5):
+            t = time.perf_counter()
+            for _ in range(10):
+                r = twelve(q, k, v, *args)
+            r.block_until_ready()
+            best = min(best, (time.perf_counter() - t) / 120)
+        out[name] = {"ms_a_layer": best * 1e3, "pages": int(bound.sum()),
+                     "kv_mb": int(bound.sum()) * PAGE * 2 * H * HD * 4 / 1e6}
+    return out
+
+
+def read_trace(trace_dir):
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
+    from harness import trace_reduce as tr
+
+    dev = tr.load(tr.find_xplane(trace_dir))["devices"]
+    dev = dev[min(dev)]
+    # an op is named by its HLO text, operands included: the kernel is the
+    # instruction CALLED paged_decode.N, not every op that reads its result
+    kern = sorted((s, d) for n, s, d in dev["ops"]
+                  if tr.short_op_name(n).startswith("paged_decode"))
+    out = {}
+    for name, s, d in dev["modules"]:
+        mine = [kd for ks, kd in kern if s <= ks < s + d]
+        if not mine:
+            continue
+        row = out.setdefault(name, {"runs": 0, "ms": 0.0, "kernel_calls": 0,
+                                    "kernel_ms": 0.0})
+        row["runs"] += 1
+        row["ms"] += d / 1e6
+        row["kernel_calls"] += len(mine)
+        row["kernel_ms"] += sum(mine) / 1e6
+    for row in out.values():
+        row["ms_a_run"] = row.pop("ms") / row["runs"]
+        row["kernel_ms_a_call"] = row["kernel_ms"] / row["kernel_calls"]
+        row["kernel_ms_a_run"] = row.pop("kernel_ms") / row["runs"]
+    return out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--trace", help="read this trace directory instead")
+    a = ap.parse_args()
+    if a.trace:
+        print(json.dumps({"trace": a.trace, "programs": read_trace(a.trace)}))
+        return 0
+    import jax
+
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        print(json.dumps({"ok": False, "why": f"no TPU here: {d.platform}"}))
+        return 1
+    ok, rows = agree()
+    line = {"ok": ok, "device": d.device_kind, "agree": rows}
+    if ok:  # no timing of a kernel that disagrees
+        line["kernel_ms"] = kernel_ms()
+    print(json.dumps(line))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -237,10 +237,11 @@ def gate_flash_dispatch(model):
                        jnp.int8)
     scale = jnp.asarray(rng.rand(P + 1, PAGE, H), jnp.float32)
     tables = jnp.zeros((SLOTS, CACHE // PAGE), jnp.int32)
-    mask = jnp.ones((SLOTS, 1, CACHE), bool)
+    pos_map = jnp.tile(jnp.arange(CACHE, dtype=jnp.int32), (SLOTS, 1))
+    positions = jnp.full((SLOTS, 1), CACHE - 1, jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda *a: paged_flash_decode(*a, block_h=H))(
-            q, pool, pool, tables, mask, scale, scale)
+            q, pool, pool, tables, pos_map, positions, None, scale, scale)
     pool_shape = tuple(pool.shape)
     full_dequants = _count_eqns(
         jaxpr.jaxpr,
