@@ -15,6 +15,11 @@ from .latent_moe import (  # noqa: F401
     LatentMoEModel,
     LatentMoEForCausalLM,
 )
+from .hybrid import (  # noqa: F401
+    HybridConfig,
+    HybridModel,
+    HybridForCausalLM,
+)
 from .wide_deep import (  # noqa: F401
     WideDeep,
     wide_deep_tiny,

@@ -32,7 +32,7 @@ from ..nn import initializer as I
 from ..nn.layer_base import Layer
 from ..ops.paged_attention import (
     key_visible,
-    paged_flash_decode,
+    paged_attention,
     paged_flash_eligible,
     sweep_bound,
 )
@@ -292,41 +292,12 @@ class ParallelAttention(Layer):
             out[name] = kv[name].at[write_page, write_off].set(
                 rows.reshape(B * T, H * hd).astype(kv[name].dtype))
         new_k, new_v = out["k"], out["v"]
-        G, page = gather_tab.shape[1], new_k.shape[1]
-        if walk is not None:
-            # TPU hot path: page-table walk + dequant + online softmax in
-            # ONE Pallas kernel over the post-scatter pool, read in its
-            # stored order — the [B,H,C,hd] float KV view is never
-            # materialized (ops/paged_attention.py).  The scatter above is
-            # identical on both paths, so the cache state (and the CPU
-            # fallback below) stays bit-identical.
-            ctx = paged_flash_decode(
-                q, new_k, new_v, gather_tab, *walk,
-                out.get("k_scale"), out.get("v_scale"))  # [B,H,T,hd]
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
-            ctx = constrain(ctx, None, None, "model")
-            return self.out(ctx), out
-
-        def view(pool, *tail):
-            # [P+1, page, *] pages → the slots' logical [B, H, C, *tail]
-            t = jnp.take(pool, gather_tab, axis=0)  # [B,G,page,H*...]
-            t = t.reshape(B, G * page, H, *tail)
-            return jnp.moveaxis(t, 2, 1)
-
-        kview, vview = view(new_k, hd), view(new_v, hd)
-        if quantized:
-            # dequantize the gathered view: one multiplier per (page
-            # entry, head), broadcast over hd — drop-page entries carry
-            # scale 0 and are masked out below anyway
-            kview = (kview.astype(jnp.float32)
-                     * view(out["k_scale"])[..., None]).astype(q.dtype)
-            vview = (vview.astype(jnp.float32)
-                     * view(out["v_scale"])[..., None]).astype(q.dtype)
-        scores = jnp.einsum("bhqd,bhcd->bhqc", q, kview) / math.sqrt(hd)
-        scores = jnp.where(mask[:, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqc,bhcd->bhqd", probs, vview)
+        # the kernel on the TPU (``walk`` given), the gathered view elsewhere:
+        # ops.paged_attention.paged_attention, shared with models/hybrid.py.
+        # The scatter above is identical on both paths, so the cache state
+        # stays bit-identical.
+        ctx = paged_attention(q, new_k, new_v, gather_tab, mask, walk,
+                              out.get("k_scale"), out.get("v_scale"))
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, T, D)
         ctx = constrain(ctx, None, None, "model")
         return self.out(ctx), out
@@ -667,7 +638,11 @@ class GPTForCausalLM(Layer):
         self.gpt = GPTModel(cfg)
 
     # -- the serving-model protocol (serving/generation.py): what an engine
-    # asks of a model, answered here by the decoder under ``.gpt`` ---------
+    # asks of a model, answered here by the decoder under ``.gpt``.  A model
+    # with recurrent state per slot beside its pages also declares
+    # ``slot_state = True``, takes ``slots=`` in ``init_paged_cache`` and
+    # in an admission's ``forward_paged`` and answers ``slot_state_bytes()``
+    # (models/hybrid.py); this one has pages only ---------------------------
     max_position = property(lambda self: self.gpt.cfg.max_position)
     moe_experts = property(
         lambda self: int(getattr(self.gpt.cfg, "moe_experts", 0) or 0))

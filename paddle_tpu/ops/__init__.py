@@ -18,6 +18,10 @@ that beat the compiler, plus the autotuner that picks their tile sizes:
   with the page-table walk in-kernel and per-page int8/fp8 dequant
   fused into the online-softmax loop, the paged serving decode hot
   path (paged_attention.py);
+* ``gated_delta_chunk`` / ``gated_delta_step`` — the gated delta rule
+  (linear attention over a decaying matrix state): a prompt in chunks with
+  the state resident in VMEM, and one token a slot updating the stored
+  states in place (gated_delta.py);
 * ``quantized_matmul`` / ``fp8_matmul`` — int8×int8→int32 (and
   fp8-e4m3) matmul with the dequant + bias epilogue fused, the serving
   quantization hot path (quantized_matmul.py);
@@ -42,6 +46,10 @@ from .fused_conv1x1_bn import (  # noqa: F401
     conv1x1_bn_stats,
 )
 from .fused_layernorm import layernorm_residual  # noqa: F401
+from .gated_delta import (  # noqa: F401
+    gated_delta_chunk,
+    gated_delta_step,
+)
 from .grouped_matmul import grouped_matmul  # noqa: F401
 from .paged_attention import (  # noqa: F401
     paged_flash_decode,

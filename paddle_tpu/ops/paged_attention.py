@@ -100,8 +100,8 @@ from ..framework.errors import InvalidArgumentError
 from ..framework.flags import flag
 from . import autotune as _at
 
-__all__ = ["paged_flash_decode", "paged_flash_eligible", "key_visible",
-           "block_pages", "sweep_bound"]
+__all__ = ["paged_flash_decode", "paged_flash_eligible", "paged_attention",
+           "key_visible", "block_pages", "sweep_bound"]
 
 # mask fill; exp(_NEG - m) underflows to exactly 0.0 in f32.  Typed f32:
 # under the package's global x64 a bare Python float reaches ``jnp.where``
@@ -462,6 +462,48 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
     return _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound,
                   k_scale, v_scale, sm_scale=1.0 / math.sqrt(q.shape[3]),
                   **cfg)
+
+
+def paged_attention(q, k_pool, v_pool, gather_tab, mask, walk=None,
+                    k_scale=None, v_scale=None):
+    """The attention context ``[B, H, T, hd]`` of ``q`` ``[B, H, T, hd]``
+    over pools ALREADY scattered with this call's K/V (stored order ``[P+1,
+    page, H*hd]``), by either path of a paged model's ``forward_paged``:
+
+    * ``walk`` given (``(pos_map, positions, bound)``, the TPU hot path):
+      :func:`paged_flash_decode` — page-table walk, dequant and online
+      softmax in one kernel over the pool in its stored order; the float
+      ``[B, H, C, hd]`` view is never materialized;
+    * else: each slot's logical view is gathered through its page-table
+      row (``gather_tab`` ``[B, G]``, pre-clipped to valid pages) and
+      plain masked attention runs over it (``mask`` ``[B, T, C]``, the
+      rule of :func:`key_visible`) — the CPU path and the reference the
+      kernel is held to."""
+    if walk is not None:
+        return paged_flash_decode(q, k_pool, v_pool, gather_tab, *walk,
+                                  k_scale, v_scale)
+    B, H, _, hd = q.shape
+    G, page = gather_tab.shape[1], k_pool.shape[1]
+
+    def view(pool, *tail):
+        # [P+1, page, *] pages → the slots' logical [B, H, C, *tail]
+        t = jnp.take(pool, gather_tab, axis=0)  # [B,G,page,H*...]
+        t = t.reshape(B, G * page, H, *tail)
+        return jnp.moveaxis(t, 2, 1)
+
+    kview, vview = view(k_pool, hd), view(v_pool, hd)
+    if k_scale is not None:
+        # dequantize the gathered view: one multiplier per (page entry,
+        # head), broadcast over hd — drop-page entries carry scale 0 and
+        # are masked out below anyway
+        kview = (kview.astype(jnp.float32)
+                 * view(k_scale)[..., None]).astype(q.dtype)
+        vview = (vview.astype(jnp.float32)
+                 * view(v_scale)[..., None]).astype(q.dtype)
+    scores = jnp.einsum("bhqd,bhcd->bhqc", q, kview) / math.sqrt(hd)
+    scores = jnp.where(mask[:, None], scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqc,bhcd->bhqd", probs, vview)
 
 
 def paged_flash_eligible(head_dim: Optional[int] = None,

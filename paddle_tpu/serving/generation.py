@@ -1,5 +1,6 @@
 """Batched greedy generation for a paged causal LM (``models.GPTForCausalLM``,
-``models.LatentMoEForCausalLM``): one scheduler over one cache form.
+``models.LatentMoEForCausalLM``, ``models.HybridForCausalLM``): one
+scheduler over one cache form.
 
 A persistent decode loop (:meth:`GenerationEngine._paged_loop`) owns a
 ``B``-slot batch and schedules at decode-step granularity — Orca-style
@@ -42,6 +43,26 @@ one admission call per chunk back to back, each threading the pool to the
 next, and waits for the first tokens once, after the last — the prefill
 computes the rows admitted, not every slot.
 
+**Slot state beside the pages.**  A model may keep recurrent state per
+slot (a linear-attention layer's matrix state and conv window) that is no
+function of pages.  It says so by ``slot_state = True`` and the protocol
+grows by one keyword, ``slots``, on two verbs: ``init_paged_cache(...,
+slots=B)`` returns a cache whose per-slot leaves have ``B + 1`` rows (row
+``B`` is the write-drop row, as page ``P`` is the write-drop page), and
+every admission hands ``forward_paged(..., slots=[R])`` its rows' slot
+numbers (``-1``: an inert row).  An admitted row starts from the zero
+state and leaves its state in its slot; a decode row IS its slot (row
+``i`` of the step is slot ``i``; position ``-1`` leaves the slot as it
+was); ``slot_state_bytes()`` is what one slot holds.  Preemption and a
+restart re-prefill, which resets the state; sliding past ``cache_len``
+touches only the layers that have pages.  What has no meaning for such
+state is refused at construction or at ``submit``, by name: speculation (a
+rejected draft cannot be rolled back), ``role != 'any'`` and ``handoff=``
+(the payload carries pages), ``quantized=``; a ``prefix_key`` is served
+cold and counted (``prefix_unshared``: mapped pages would come without the
+state at the prefix's boundary).  A model without the attribute is handed
+neither keyword and builds the programs it always built.
+
 The compile set is closed and traced in :meth:`warmup`:
 ``len(prompt_buckets) + 3`` with speculation (per-bucket ``[R, bucket]``
 admission, the unified step, its ``[B, 1]`` no-draft fast trace, the
@@ -79,8 +100,8 @@ from ..resilience.faults import fault_point
 from .batcher import MicroBatcher, Request
 from .metrics import (HANDOFF_COUNTERS, LOOP_COUNTERS, LORA_COUNTERS,
                       MOE_COUNTERS, PAGED_COUNTERS, QUANT_COUNTERS,
-                      SLOT_COUNTERS, TENANCY_COUNTERS, LoopClock,
-                      ServingMetrics)
+                      SLOT_COUNTERS, STATE_COUNTERS, TENANCY_COUNTERS,
+                      LoopClock, ServingMetrics)
 from .paging import PagePool
 
 __all__ = ["GenerationEngine", "KVHandoff"]
@@ -255,6 +276,23 @@ class GenerationEngine:
             raise InvalidArgumentError(
                 f"role must be 'any', 'prefill' or 'decode', got {role!r}")
         self._role = role
+        # recurrent state per slot beside the K/V pages (the model owns the
+        # layout, see the module docstring).  What has no meaning for such
+        # state is refused here, in the open
+        self._slot_state = bool(getattr(model, "slot_state", False))
+        if self._slot_state:
+            for bad, why in (
+                    (self._spec_k > 0, f"speculative_k={self._spec_k}: a "
+                     f"rejected draft's write to the state cannot be rolled "
+                     f"back"),
+                    (role != "any", f"role={role!r}: the hand-off payload "
+                     f"carries pages, not slot state"),
+                    (quantized is not None, f"quantized={quantized!r}: its "
+                     f"page pools have no scale planes")):
+                if bad:
+                    raise InvalidArgumentError(
+                        f"{name}: {type(model).__name__} keeps recurrent "
+                        f"state per slot, which rules out {why}")
         if self._buckets[-1] > self._C:
             raise InvalidArgumentError(
                 f"largest prompt bucket ({self._buckets[-1]}) exceeds "
@@ -298,6 +336,8 @@ class GenerationEngine:
             extra = extra + LORA_COUNTERS
         if tenancy is not None:
             extra = extra + TENANCY_COUNTERS
+        if self._slot_state:
+            extra = extra + STATE_COUNTERS
         self.metrics = ServingMetrics(name, extra_counters=extra)
 
         mdl, traces = model, self._traces
@@ -305,22 +345,27 @@ class GenerationEngine:
         # tables — a 0-capacity engine's executables take aids=None and
         # trace byte-identically to before
         lora_on = bool(self._lora_cap)
+        # likewise the rows' slot numbers: handed to the model only when it
+        # declares slot state
+        state_on = self._slot_state
 
         # -- the executables (see serving/paging.py).  Admission prefills
         # STRAIGHT into the shared pool: each slot writes only its own
         # pages (padding rows scatter into the write-drop page), so live
         # slots' KV is untouched by construction.
         def padmit(params, buffers, ids, positions, pos_map, table, lens,
-                   cache, aids=None):
-            def body(ids, positions, pos_map, table, lens, cache, aids):
+                   cache, aids=None, slots=None):
+            def body(ids, positions, pos_map, table, lens, cache, aids,
+                     slots):
                 traces["admit"] += 1
                 logits, cache = mdl.forward_paged(
                     ids, positions, pos_map, table, cache, gather_last=lens,
-                    adapter_ids=aids if lora_on else None)
+                    adapter_ids=aids if lora_on else None,
+                    **({"slots": slots} if state_on else {}))
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
             return functional_call(mdl, params, ids, positions, pos_map,
-                                   table, lens, cache, aids, buffers=buffers,
-                                   training=False, call=body)
+                                   table, lens, cache, aids, slots,
+                                   buffers=buffers, training=False, call=body)
 
         def pstep(params, buffers, packed, cache):
             # the unified decode/verify step: T = 1 + speculative_k
@@ -468,7 +513,8 @@ class GenerationEngine:
             lens = jnp.asarray(np.full((R,), sb, np.int32))
             _, cache = self._padmit(
                 self._params, self._buffers, ids, pos, pm0, tb0, lens,
-                cache, self._aids_arg(np.full((R,), -1, np.int32)))
+                cache, self._aids_arg(np.full((R,), -1, np.int32)),
+                self._slots_arg(np.full((R,), -1, np.int32)))
         T = 1 + self._spec_k
         _, cache = self._step(
             self._params, self._buffers,
@@ -582,9 +628,9 @@ class GenerationEngine:
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-        pool = jax.eval_shape(lambda: self._model.init_paged_cache(
-            self._kv_pages, self._page, dtype=self._kv_qdtype()))
+        pool = jax.eval_shape(self._empty_pool)
         aids = i32(R) if self._lora_cap else None
+        slots = i32(R) if self._slot_state else None
         width = 2 * (1 + self._spec_k) + self._C + G + (
             1 if self._lora_cap else 0)
         snap = dict(self._traces)
@@ -596,7 +642,7 @@ class GenerationEngine:
                 out[f"admit[{sb}]"] = self._padmit.lower(
                     self._params, self._buffers, i32(R, sb), i32(R, sb),
                     i32(R, self._C), i32(R, G), i32(R), pool,
-                    aids).compile().as_text()
+                    aids, slots).compile().as_text()
         finally:
             self._traces.clear()
             self._traces.update(snap)
@@ -761,6 +807,14 @@ class GenerationEngine:
             return None
         return jnp.asarray(np.asarray(aidsv, np.int32).copy())
 
+    def _slots_arg(self, slots: np.ndarray):
+        """An admission chunk's slot numbers (``-1``: an inert row) as a
+        host transfer — ``None`` (not traced at all) unless the model
+        keeps state per slot, as :meth:`_aids_arg`."""
+        if not self._slot_state:
+            return None
+        return jnp.asarray(np.asarray(slots, np.int32))
+
     def _expire_carry(self, carry: List[tuple]) -> List[tuple]:
         """Deadline sweep for requests held outside the batcher queue
         (breaker-deferred admissions, restart re-admissions)."""
@@ -832,9 +886,15 @@ class GenerationEngine:
             self._params, self._buffers,
             self._pack_step(np.zeros((B, T), np.int32),
                             np.full((B, T), -1, np.int32)),
-            self._model.init_paged_cache(self._kv_pages, self._page,
-                                             dtype=self._kv_qdtype()))
+            self._empty_pool())
         return cache
+
+    def _empty_pool(self):
+        """The model's cache, zeroed: the page pools and, for a model with
+        slot state, its ``batch_size + 1`` rows a layer."""
+        return self._model.init_paged_cache(
+            self._kv_pages, self._page, dtype=self._kv_qdtype(),
+            **({"slots": self._batch} if self._slot_state else {}))
 
     def _pack_step(self, ids: np.ndarray, positions: np.ndarray,
                    pos_map: Optional[np.ndarray] = None,
@@ -1295,15 +1355,17 @@ class GenerationEngine:
                             pm = np.full((R, C), -1, np.int32)
                             tb = np.full((R, G), -1, np.int32)
                             ra = np.full((R,), -1, np.int32)
+                            rs = np.full((R,), -1, np.int32)
                             pm[:len(sl)] = pool.pos_map[sl]
                             tb[:len(sl)] = pool.table[sl]
                             ra[:len(sl)] = aidsv[sl]
+                            rs[:len(sl)] = sl
                             for j, (_, _, shared, prompt) in enumerate(part):
                                 L = len(prompt)
                                 ids[j, :L - shared] = prompt[shared:]
                                 pp[j, :L - shared] = np.arange(shared, L)
                                 lens[j] = L - shared
-                            chunks.append((ids, pp, pm, tb, lens, ra))
+                            chunks.append((ids, pp, pm, tb, lens, ra, rs))
                         dispatch_cow(cow_pairs)
                         fault_point("serving.decode")
                         ph.to("admit.device", engine=self.name,
@@ -1312,13 +1374,13 @@ class GenerationEngine:
                         # back to back, each threading the pool to the
                         # next; the host waits once, for the last
                         firsts = []
-                        for ids, pp, pm, tb, lens, ra in chunks:
+                        for ids, pp, pm, tb, lens, ra, rs in chunks:
                             first, cache = self._padmit(
                                 self._params, self._buffers,
                                 jnp.asarray(ids), jnp.asarray(pp),
                                 jnp.asarray(pm), jnp.asarray(tb),
                                 jnp.asarray(lens), cache,
-                                self._aids_arg(ra))
+                                self._aids_arg(ra), self._slots_arg(rs))
                             firsts.append(first)
                         # serial harvest; row c * R + j is admitted[c * R + j]
                         host_first = np.concatenate(jax.device_get(firsts))
@@ -1401,6 +1463,13 @@ class GenerationEngine:
                                    admit_rows=len(admitted),
                                    admit_token_slots=sum(
                                        c[0].size for c in chunks))
+                        if self._slot_state:
+                            cnt.update(
+                                state_slots_reset=len(admitted),
+                                gdn_prefill_tokens=sum(
+                                    len(p) for _, _, _, p in admitted),
+                                gdn_prefill_token_slots=sum(
+                                    c[0].size for c in chunks))
                     if take:
                         live = [i for i in range(B) if slots[i] is not None]
                         if ten is not None:
@@ -1559,6 +1628,10 @@ class GenerationEngine:
                                    kv_pages_live_steps=n_pages,
                                    kv_pages_swept_steps=n_swept,
                                    kv_page_slots_steps=B * G)
+                        if self._slot_state:
+                            # every slot's rows, live or not: in and out
+                            cnt["state_bytes_steps"] += (
+                                2 * B * self._model.slot_state_bytes())
                         if Td == 1:
                             it_fast = (dt if it_fast is None
                                        else 0.8 * it_fast + 0.2 * dt)
@@ -1765,6 +1838,17 @@ class GenerationEngine:
             aid = int(self._tenancy.adapter_id(tenant))
         else:
             aid = -1
+        if self._slot_state:
+            if handoff is not None:
+                raise InvalidArgumentError(
+                    f"{self.name}: handoff= with a model that keeps "
+                    f"recurrent state per slot: the payload carries pages, "
+                    f"not slot state")
+            if prefix_key is not None:
+                # pages mapped from a sibling would come without the state
+                # at the prefix's boundary: served cold, on the record
+                prefix_key = None
+                self.metrics.incr("prefix_unshared")
         if handoff is not None:
             if handoff is True:
                 if self._role != "prefill":

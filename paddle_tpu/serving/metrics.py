@@ -150,6 +150,20 @@ LORA_COUNTERS = ("adapter_installs", "adapter_removals")
 TENANCY_COUNTERS = ("tenant_preempted", "tenant_throttled_steps",
                     "tenant_starved_steps_after_warm")
 
+#: slot-state counters (a model that declares ``slot_state``: recurrent
+#: state per slot beside the K/V pages): admitted rows, each of which
+#: started from the zero state whatever its slot held
+#: (``state_slots_reset``); real and padded prompt tokens through the
+#: chunked scan, one admission call counted once whatever the number of
+#: layers (``gdn_prefill_tokens`` / ``gdn_prefill_token_slots``); bytes of
+#: slot state the decode steps read and wrote, every slot of every step
+#: (``state_bytes_steps``); requests that named a ``prefix_key`` and were
+#: served without sharing (``prefix_unshared``: a shared prefix would
+#: need a snapshot of the state at its boundary).
+STATE_COUNTERS = ("state_slots_reset", "gdn_prefill_tokens",
+                  "gdn_prefill_token_slots", "state_bytes_steps",
+                  "prefix_unshared")
+
 
 def _quantile(sorted_vals, q: float) -> float:
     """Nearest-rank quantile with the CEIL rank convention: the q-th

@@ -39,6 +39,12 @@ class PagePool:
     table row = unmapped; ``-1`` in ``pos_map`` = no valid KV at that
     cache slot (also how rejected speculative drafts are invalidated —
     the stale KV is simply never gathered and gets overwritten later).
+
+    A model with per-slot recurrent state (``slot_state``) keeps it in
+    rows numbered as the slots are here, so that cache needs no accounting
+    of its own: a row is reset by the admission that maps the slot's pages
+    and is dead once :meth:`release` has unmapped them.  What it cannot do
+    is share: the engine never hands such a model's pool a ``prefix_key``.
     """
 
     def __init__(self, num_slots: int, num_pages: int, page_size: int,

@@ -403,7 +403,7 @@ def test_forward_paged_gives_the_kernel_its_bound(monkeypatch, pool_dtype):
 
     def run(kernel):
         monkeypatch.setattr(G, "_paged_flash", lambda hd, pg: kernel)
-        monkeypatch.setattr(G, "paged_flash_decode", spy)
+        monkeypatch.setattr(PA, "paged_flash_decode", spy)
         pool = model.init_paged_cache(B * Gp, page, dtype=dt)
         h0, pool = model.forward_paged(ids, pos, pos_map, table, pool)
         h1, pool = model.forward_paged(step_ids, step_pos, step_map, table,

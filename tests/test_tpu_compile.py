@@ -393,3 +393,53 @@ def test_latent_prefill_attention_32_heads_of_192(chip, T):
         chip, fn, ((B, H, T, 128), bf16), ((B, H, T, 64), bf16),
         ((B, H, C, 128), bf16), ((B, C, 64), bf16), ((B, H, C, 128), bf16),
         ((B, T), i32), ((B, C), i32))
+
+
+# -- the hybrid decoder's kernels at the olmo_hybrid cell's shapes ------------
+@pytest.mark.parametrize("T", [1536, 4096])
+def test_gated_delta_chunk_30_heads_of_96_by_192(chip, T):
+    # an admission chunk of the olmo_hybrid cell: 2 rows x a prompt bucket,
+    # 30 heads with a [96, 192] float32 state, chunks of 64; the whole op,
+    # the WY operands in XLA and the walk over chunks in the kernel
+    from paddle_tpu.ops import gated_delta as gd
+
+    assert gd.gated_delta_eligible()
+    B, H, dk, dv = 2, 30, 96, 192
+    _compiles_with_kernel(
+        chip, gd.gated_delta_chunk, ((B, T, H, dk), f32), ((B, T, H, dk), f32),
+        ((B, T, H, dv), f32), ((B, T, H), f32), ((B, T, H), f32))
+
+
+def test_gated_delta_step_updates_17_rows_of_state_in_place(chip):
+    # a decode step of the cell: 16 slots of the 17 stored rows, donated, so
+    # that the kernel's alias is the program's
+    from paddle_tpu.ops import gated_delta as gd
+
+    B, H, dk, dv = 16, 30, 96, 192
+    one = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
+        (B, H, dk), (B, H, dk), (B, H, dv), (B, H), (B, H),
+        (B + 1, H, dk, dv))]
+    text = jax.jit(gd.gated_delta_step, donate_argnums=(5,)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text and "gated_delta_step" in text
+    # updated where it lies: the 37.6 MB of states are not copied around it
+    assert "input_output_alias" in text
+    assert not [l for l in text.splitlines()
+                if " copy(" in l and "f32[17,30,96,192]" in l.split("=")[1]
+                .split("copy(")[0]]
+
+
+def test_paged_decode_reads_30_heads_of_128_at_the_decode_width(chip):
+    # the hybrid model's four full layers decode through GPT-2's kernel as
+    # it is: 16 slots x 288 pages of 16, rows of 30 x 128 = 3840 lanes
+    from paddle_tpu.models.hybrid import _paged_flash
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    assert _paged_flash(128, 16)
+    B, H, hd, page, G = 16, 30, 128, 16, 288
+    pages = B * G + 1
+    _compiles_with_kernel(
+        chip, paged_flash_decode, ((B, H, 1, hd), bf16),
+        ((pages, page, H * hd), bf16), ((pages, page, H * hd), bf16),
+        ((B, G), i32), ((B, G * page), i32), ((B, 1), i32), ((B,), i32))
