@@ -34,12 +34,15 @@ step when text repeats.  The loop runs serialized (each step harvested
 before the next dispatch) because drafting and page accounting depend on
 the previous step's tokens.
 
-The admission program is ``[R, bucket]``, not ``[B, bucket]``: ``R =
-min(batch_size, _ADMIT_ROWS)`` is a small fixed row chunk.  A row reaches
+The admission program is ``[R(bucket), bucket]``, not ``[B, bucket]``: a
+few rows, as many as fit ``_ADMIT_TOKEN_SLOTS`` token slots and at most
+``_ADMIT_ROWS`` (:func:`admit_rows`: two up to a bucket of 1024, one where a
+row is thousands of tokens and dwarfs the call's fixed cost).  A row reaches
 the pool only through its own page-table and position-map rows, so the
-loop packs the requests an iteration admits densely into chunks of R rows
-(each padded to its widest row's bucket, unused rows inert), dispatches
-one admission call per chunk back to back, each threading the pool to the
+loop packs the requests an iteration admits, in admission order, into
+chunks (:func:`admit_chunks`: each padded to its widest row's bucket ``b``
+and holding at most ``R(b)`` rows, unused rows inert), dispatches one
+admission call per chunk back to back, each threading the pool to the
 next, and waits for the first tokens once, after the last — the prefill
 computes the rows admitted, not every slot.
 
@@ -64,8 +67,8 @@ state at the prefix's boundary).  A model without the attribute is handed
 neither keyword and builds the programs it always built.
 
 The compile set is closed and traced in :meth:`warmup`:
-``len(prompt_buckets) + 3`` with speculation (per-bucket ``[R, bucket]``
-admission, the unified step, its ``[B, 1]`` no-draft fast trace, the
+``len(prompt_buckets) + 3`` with speculation (per-bucket ``[R(bucket),
+bucket]`` admission, the unified step, its ``[B, 1]`` no-draft fast trace, the
 page-copy op) or ``+ 2`` without.  The loop self-measures both step
 variants and drafts only when the predicted accepted tokens out-earn the
 wide step's extra cost, with per-slot exponential backoff after
@@ -108,19 +111,70 @@ __all__ = ["GenerationEngine", "KVHandoff"]
 
 _gen_counter = [0]
 
-#: rows of the paged admission program (``R0``).  A loop iteration admits
-#: a request or two between decode steps, not a batch, and the prefill
-#: costs what its rows x bucket cost whether they hold a prompt or not,
-#: so the program is traced at ``[min(batch_size, R0), bucket]`` and an
-#: iteration that admits more dispatches it once per chunk of R0 rows.
-#: One size, not a ladder of them: every further row size would add
-#: ``len(prompt_buckets)`` executables to the warm-up.  Swept on a
-#: TPU v5 lite over {1, 2, 4, 8} with GPT-2-small at 32 slots (PERF.md,
-#: PR 25): a call costs about 45 ms whatever its rows and 5-13 ms a row,
-#: so 1 pays the call too often under a closed loop that admits 1.5 rows
-#: an iteration, 4 and 8 pay for rows nothing was admitted into, and 2
-#: was best or tied in both cells.
+#: the paged admission program's rows: ``R(bucket) = min(batch_size,
+#: _ADMIT_ROWS, max(1, _ADMIT_TOKEN_SLOTS // bucket))`` (:func:`admit_rows`).
+#: A loop iteration admits a request or two between decode steps, not a
+#: batch, and the prefill costs what its rows x bucket cost whether they
+#: hold a prompt or not, so the program is traced at ``[R(bucket), bucket]``
+#: and an iteration that admits more dispatches it once per chunk.  One
+#: row count a bucket, not a ladder of them: every further row size would
+#: add ``len(prompt_buckets)`` executables to the warm-up.
+#:
+#: Two rows pay where the calls carry more rows than the second row costs:
+#: with ``t1`` a ``[1, b]`` call and ``t2`` a ``[2, b]`` call (taken with
+#: one row real; both real cost 0-11 % more), a row costs ``t2 / n`` at
+#: ``n`` rows a call against ``t1``.  Swept on a TPU v5 lite at the serving
+#: cells' own sizes (``tools/admit_rows_chip.py``, PERF.md, PR 32; host
+#: clock, ms):
+#:
+#:   GPT-2-small, 32 slots      b   64    128   256   512   640   768
+#:                              t1  3.98  4.44  5.06  7.15  7.66  9.20
+#:                              t2  4.20  4.95  6.46  9.52 11.00 12.46
+#:   latent, 256 experts, 32 s. b   1536   2048   3072   4096
+#:                              t1  35.2   40.4   52.4   69.4
+#:                              t2  53.8   65.9   90.0  114.5
+#:   hybrid, 16 slots           t1  103.5  144.3  200.6  283.5
+#:                              t2  214.8  303.9  500.5  699.2
+#:
+#: About 2 ms of a call are its two ends on the host, whatever its rows.
+#: Up to 768 tokens that is a large part of the call: ``t2 / t1`` is
+#: 1.06-1.44, and a closed loop of short answers admits 1.56 rows a call
+#: (``docs_closed``), so two rows are a tenth cheaper a row; an open loop
+#: that admits one row a call pays 0.2-2.4 ms more a request for them, of
+#: a request of hundreds.  From 1536 tokens a row dwarfs the ends: ``t2 /
+#: t1`` is 1.53-1.72 (latent) and 2.07-2.49 (hybrid: two rows cost MORE
+#: than two calls of one), and a closed loop of long answers frees one
+#: slot at a time (1.03-1.06 rows a call), so the second row is paid for
+#: and empty.  The cap lies between: 2048 token slots a call keeps two
+#: rows up to a bucket of 1024 and gives one row past it.
 _ADMIT_ROWS = 2
+_ADMIT_TOKEN_SLOTS = 2048
+
+
+def admit_rows(bucket: int, batch_size: int) -> int:
+    """Rows of the admission program traced for ``bucket``: as many as the
+    call's token slots hold, at least one, at most ``_ADMIT_ROWS`` and the
+    engine's slots."""
+    return min(batch_size, _ADMIT_ROWS, max(1, _ADMIT_TOKEN_SLOTS // bucket))
+
+
+def admit_chunks(buckets: Sequence[int], rows_of) -> List[List[int]]:
+    """Pack admitted rows, given by their prompt buckets in admission
+    order, into admission calls: a chunk takes consecutive rows, is padded
+    to its widest row's bucket ``b`` and holds at most ``rows_of(b)`` rows.
+    Returns each chunk's row indices.  A chunk is closed as soon as the
+    next row would not fit beside the ones it holds, so a chunk in the
+    middle may be short of ``rows_of(b)`` (a wide row after a narrow one)."""
+    chunks: List[List[int]] = []
+    widest = 0
+    for j, b in enumerate(buckets):
+        if chunks and len(chunks[-1]) < rows_of(max(widest, b)):
+            chunks[-1].append(j)
+            widest = max(widest, b)
+        else:
+            chunks.append([j])
+            widest = b
+    return chunks
 
 
 class KVHandoff(NamedTuple):
@@ -264,7 +318,9 @@ class GenerationEngine:
                 f"prompt_buckets must be positive lengths, got "
                 f"{prompt_buckets!r}")
         self._batch = int(batch_size)
-        self._admit_rows = min(self._batch, _ADMIT_ROWS)
+        # rows of each bucket's admission program (admit_rows)
+        self._admit_rows = {b: admit_rows(b, self._batch)
+                            for b in self._buckets}
         self._eos = eos_token_id
         self._C = int(cache_len or model.max_position)
         self._page = int(flag("kv_page_size")
@@ -467,7 +523,8 @@ class GenerationEngine:
     @property
     def compile_count(self) -> int:
         """Traced executables so far: one per warmed prompt bucket (the
-        ``[R, bucket]`` row-chunk admission) plus the shared decode step,
+        ``[R(bucket), bucket]`` admission, :func:`admit_rows`) plus the
+        shared decode step,
         the page-copy (CoW) op and, when speculation is on, the ``[B, 1]``
         no-draft fast trace of the decode/verify step; eviction is a pure
         host table edit with no executable at all."""
@@ -477,10 +534,10 @@ class GenerationEngine:
         """Trace the full compile set on dummy data so live traffic never
         pays compile latency.  Returns the (closed) compile count:
         ``len(prompt_buckets) + 2`` without speculation (the admission
-        traced at its row chunk, ``[R, bucket]`` with ``R =
-        min(batch_size, _ADMIT_ROWS)``, once per bucket; the step; the
-        page-copy op), ``len(prompt_buckets) + 3`` with it (the extra
-        ``[B, 1]`` no-draft fast trace).
+        traced once per bucket at that bucket's rows, ``[R(bucket),
+        bucket]`` by :func:`admit_rows`; the step; the page-copy op),
+        ``len(prompt_buckets) + 3`` with it (the extra ``[B, 1]`` no-draft
+        fast trace).
         Role-specialized engines add exactly one more: the page-export
         trace (``role='prefill'``) or the page-import trace
         (``role='decode'``); default-role engines trace neither.  On a
@@ -493,12 +550,9 @@ class GenerationEngine:
         # placement mismatch is a silent XLA recompile the trace counter
         # can't see): ids/positions/pos_map/table always enter as host
         # transfers, the pool as a jit output — _init_pool covers the one
-        # fresh-pool placement.  Admission is traced at its row chunk,
-        # [R, bucket], not [B, bucket].
+        # fresh-pool placement.  Admission is traced at each bucket's own
+        # rows, [R(bucket), bucket], not [B, bucket].
         G = self._C // self._page
-        R = self._admit_rows
-        pm0 = jnp.asarray(np.full((R, self._C), -1, np.int32))
-        tb0 = jnp.asarray(np.full((R, G), -1, np.int32))
         cache = self._init_pool()
         # sharded decode only: measured search over the collective
         # overlap schedule, BEFORE the production traces below (they
@@ -507,6 +561,9 @@ class GenerationEngine:
         # winner from the tuning cache with zero searches)
         self._tune_overlap_schedule(cache)
         for sb in self._buckets:
+            R = self._admit_rows[sb]
+            pm0 = jnp.asarray(np.full((R, self._C), -1, np.int32))
+            tb0 = jnp.asarray(np.full((R, G), -1, np.int32))
             ids = jnp.asarray(np.zeros((R, sb), np.int32))
             pos = jnp.asarray(np.broadcast_to(
                 np.arange(sb, dtype=np.int32), (R, sb)))
@@ -621,16 +678,15 @@ class GenerationEngine:
         names as a device trace prints them, each with the
         ``jax.named_scope`` path of the code it came from in its
         ``op_name`` metadata, which the trace itself does not carry.
-        Lowers the same abstract calls again (with the persistent compile
-        cache on, a read); trace counters are left as they were."""
-        B, R, G = self._batch, self._admit_rows, self._C // self._page
+        Lowers the same abstract calls again (``admit[sb]`` at ``[R(sb),
+        sb]``; with the persistent compile cache on, a read); trace
+        counters are left as they were."""
+        B, G = self._batch, self._C // self._page
 
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
         pool = jax.eval_shape(self._empty_pool)
-        aids = i32(R) if self._lora_cap else None
-        slots = i32(R) if self._slot_state else None
         width = 2 * (1 + self._spec_k) + self._C + G + (
             1 if self._lora_cap else 0)
         snap = dict(self._traces)
@@ -639,10 +695,13 @@ class GenerationEngine:
                 self._params, self._buffers, i32(B, width),
                 pool).compile().as_text()}
             for sb in self._buckets:
+                R = self._admit_rows[sb]
                 out[f"admit[{sb}]"] = self._padmit.lower(
                     self._params, self._buffers, i32(R, sb), i32(R, sb),
                     i32(R, self._C), i32(R, G), i32(R), pool,
-                    aids, slots).compile().as_text()
+                    i32(R) if self._lora_cap else None,
+                    i32(R) if self._slot_state else None
+                ).compile().as_text()
         finally:
             self._traces.clear()
             self._traces.update(snap)
@@ -1061,7 +1120,6 @@ class GenerationEngine:
         q = self._batcher
         B, C, page = self._batch, self._C, self._page
         G = C // page
-        R = self._admit_rows
         k_max, eos = self._spec_k, self._eos
         T = 1 + k_max
         max_restarts = (max(int(flag("transient_max_retries")) - 1, 0)
@@ -1336,18 +1394,22 @@ class GenerationEngine:
                                 # boundary CoW would copy data not yet
                                 # written
                                 to_register.append((key, i, prompt[:plen]))
-                        # the admitted rows, packed densely in admission
-                        # order, R to a chunk: a chunk's program runs over
+                        # the admitted rows, packed in admission order
+                        # into chunks of at most R(bucket) rows
+                        # (admit_chunks): a chunk's program runs over
                         # its own rows' page-table and position-map rows
                         # and nothing else, padded to its widest row's
-                        # bucket; rows a last chunk does not fill are inert
+                        # bucket; rows a chunk does not fill are inert
                         # (position -1, table -1: they write to the drop
                         # page, as warm-up's rows do)
                         chunks: List[tuple] = []
-                        for c0 in range(0, len(admitted), R):
-                            part = admitted[c0:c0 + R]
-                            Sb = self._buckets[max(r.bucket
-                                                   for r, _, _, _ in part)]
+                        widths = [self._buckets[r.bucket]
+                                  for r, _, _, _ in admitted]
+                        for rows in admit_chunks(
+                                widths, self._admit_rows.__getitem__):
+                            part = [admitted[j] for j in rows]
+                            Sb = max(widths[j] for j in rows)
+                            R = self._admit_rows[Sb]
                             ids = np.zeros((R, Sb), np.int32)
                             pp = np.full((R, Sb), -1, np.int32)
                             lens = np.ones((R,), np.int32)
@@ -1365,7 +1427,8 @@ class GenerationEngine:
                                 ids[j, :L - shared] = prompt[shared:]
                                 pp[j, :L - shared] = np.arange(shared, L)
                                 lens[j] = L - shared
-                            chunks.append((ids, pp, pm, tb, lens, ra, rs))
+                            chunks.append((ids, pp, pm, tb, lens, ra, rs,
+                                           len(part)))
                         dispatch_cow(cow_pairs)
                         fault_point("serving.decode")
                         ph.to("admit.device", engine=self.name,
@@ -1374,7 +1437,7 @@ class GenerationEngine:
                         # back to back, each threading the pool to the
                         # next; the host waits once, for the last
                         firsts = []
-                        for ids, pp, pm, tb, lens, ra, rs in chunks:
+                        for ids, pp, pm, tb, lens, ra, rs, _ in chunks:
                             first, cache = self._padmit(
                                 self._params, self._buffers,
                                 jnp.asarray(ids), jnp.asarray(pp),
@@ -1382,12 +1445,17 @@ class GenerationEngine:
                                 jnp.asarray(lens), cache,
                                 self._aids_arg(ra), self._slots_arg(rs))
                             firsts.append(first)
-                        # serial harvest; row c * R + j is admitted[c * R + j]
-                        host_first = np.concatenate(jax.device_get(firsts))
+                        # serial harvest: a chunk's first n rows are the
+                        # next n of `admitted`, the rest of its rows inert
+                        host_first = np.concatenate([
+                            f[:c[-1]] for f, c in zip(
+                                jax.device_get(firsts), chunks)])
                         ph.to("admit.host", engine=self.name)
                         tr = _tracing._active
                         if tr is not None:
                             adm_ms = (time.monotonic() - now) * 1e3
+                            row_bucket = [c[0].shape[1] for c in chunks
+                                          for _ in range(c[-1])]
                             for j, (r, i, _, _) in enumerate(admitted):
                                 if r.trace is None:
                                     continue
@@ -1401,7 +1469,7 @@ class GenerationEngine:
                                           adm_ms, kind="prefill",
                                           args={"engine": self.name,
                                                 "slot": i, "bucket":
-                                                chunks[j // R][0].shape[1]})
+                                                row_bucket[j]})
                         for key, i, toks in to_register:
                             pool.register_prefix(key, i, toks)
                         now = time.monotonic()
@@ -1461,6 +1529,8 @@ class GenerationEngine:
                                    evicted=n_evicted,
                                    admit_steps=len(chunks),
                                    admit_rows=len(admitted),
+                                   admit_row_slots=sum(
+                                       c[0].shape[0] for c in chunks),
                                    admit_token_slots=sum(
                                        c[0].size for c in chunks))
                         if self._slot_state:

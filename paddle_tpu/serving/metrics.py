@@ -23,8 +23,9 @@ The decode loop publishes the gauge ``decode_step_ms`` (the last
 step's measured device call) and the ``LOOP_COUNTERS`` family: its wall
 time by phase in integer microseconds (``loop_us_<phase>``; the phases
 tile every iteration and sum to ``loop_us_total``), the work it
-dispatched (admission rows/tokens against the ``[R, bucket]`` token
-slots of each prefill call, ``R`` the engine's admission row chunk;
+dispatched (admission rows/tokens against the row slots and the
+``[R(bucket), bucket]`` token slots of each prefill call, ``R`` that
+bucket's rows by ``generation.admit_rows``;
 live slots and live page-table entries against ``B`` and ``B x G`` per
 decode step) and the summed queue wait and time to first token of the
 requests it admitted.  Beside each sum,
@@ -80,7 +81,7 @@ SLOT_COUNTERS = ("admitted", "evicted", "decode_steps", "restarts",
 #: order they run: ``sched`` (close check, tenancy, expiry, queue poll,
 #: choosing what to admit), ``admit.host`` (page accounting, prefill
 #: inputs, CoW dispatch, first-token book-keeping), ``admit.device`` (the
-#: prefill calls, one per row chunk, until the last one's first tokens
+#: prefill calls, one per chunk of rows, until the last one's first tokens
 #: are on the host), ``decode.pack``
 #: (drafts, page growth, step inputs), ``decode.device`` (the step call
 #: until its tokens are on the host), ``harvest`` (accept/finish per
@@ -96,15 +97,17 @@ _MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
 #: paged-decode-loop counters (see the module docstring): wall time by
 #: phase and each phase's longest interval, work counted once its
 #: dispatch has returned (``admit_steps`` counts admission device calls,
-#: one per chunk of ``R`` rows; ``admit_token_slots`` is ``R x bucket``
-#: per such call and ``kv_page_slots_steps`` ``B x G`` per decode step:
-#: the denominators of the useful shares; ``kv_pages_swept_steps`` is the
-#: part of ``B x G`` inside the slots' sweep bounds, what the
+#: one per chunk; ``admit_row_slots`` is the call's rows ``R(bucket)``
+#: (``generation.admit_rows``) and ``admit_token_slots`` ``R(bucket) x
+#: bucket`` per such call, so ``admit_rows / admit_row_slots`` is the rows
+#: filled apart from the bucket's padding; ``kv_page_slots_steps`` is
+#: ``B x G`` per decode step: the denominators of the useful shares;
+#: ``kv_pages_swept_steps`` is the part of ``B x G`` inside the slots' sweep bounds, what the
 #: ``paged_decode`` kernel walks), and the summed per-request
 #: times whose count is ``admit_rows``
 LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
-                 "admit_steps", "admit_rows", "admit_tokens",
-                 "admit_token_slots", "live_slot_steps",
+                 "admit_steps", "admit_rows", "admit_row_slots",
+                 "admit_tokens", "admit_token_slots", "live_slot_steps",
                  "kv_pages_live_steps", "kv_pages_swept_steps",
                  "kv_page_slots_steps",
                  "queue_wait_us", "ttft_us")

@@ -338,6 +338,38 @@ def test_chunks_of_one_admission_do_not_see_each_others_slots(tiny, alone):
         eng.close()
 
 
+def test_slot_state_follows_rows_into_chunks_of_unequal_rows(
+        tiny, alone, monkeypatch):
+    """Buckets on both sides of the admission cap (two rows of 16, one row
+    of 32): one iteration admits rows of 16, 32, 16, 32 as four calls, the
+    first and third with an inert second row (slot -1)."""
+    from paddle_tpu.serving import generation
+
+    m, _ = tiny
+    prompts, outs = alone
+    order = [0, 2, 1, 3]  # lengths 5, 20, 16, 31
+    monkeypatch.setattr(generation, "_ADMIT_TOKEN_SLOTS", 32)
+    eng = engine(m)
+    try:
+        assert eng._admit_rows == {16: 2, 32: 1}
+        warm = eng.warmup()
+        assert warm == 4
+        with eng._batcher._cv:  # the loop's poll sees none of them or all
+            futures = [eng.submit(prompts[k], 10) for k in order]
+        got = [np.asarray(f.result(timeout=300)).tolist() for f in futures]
+        eng.close()
+        st = eng.stats()
+        assert (st["batches"], st["admit_steps"], st["admit_rows"],
+                st["admit_row_slots"]) == (1, 4, 4, 2 + 1 + 2 + 1)
+        assert st["admit_token_slots"] == 2 * 16 + 32 + 2 * 16 + 32
+        assert st["gdn_prefill_token_slots"] == st["admit_token_slots"]
+        assert st["state_slots_reset"] == 4
+        assert eng.compile_count == warm
+    finally:
+        eng.close()
+    assert got == [outs[k] for k in order]
+
+
 def test_a_preempted_request_regenerates_the_same_tokens(tiny, alone):
     m, _ = tiny
     prompts, outs = alone
@@ -395,6 +427,46 @@ def _digest(eng):
     return hashlib.sha256("\n".join(
         _LOCATION.sub("", texts[k]) for k in sorted(texts)).encode()
     ).hexdigest()
+
+
+def test_gpt2s_benchmark_engine_lowers_the_two_row_programs():
+    """An engine with the GPT-2 cells' settings (32 slots; the buckets of
+    ``chat_open`` and ``docs_closed``, all under the admission cap) lowers,
+    bucket for bucket, the text of its admission program lowered by hand at
+    two rows, and the step's at 32."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    paddle.seed(1234)
+    m = GPTForCausalLM(GPTConfig(vocab_size=512, hidden_size=64,
+                                 num_layers=2, num_heads=4,
+                                 max_position=1024, dropout=0.0))
+    m.eval()
+    buckets, B, page = [64, 128, 256, 512, 640, 768], 32, 16
+    eng = GenerationEngine(m, prompt_buckets=buckets, batch_size=B,
+                           kv_page_size=page, speculative_k=0,
+                           eos_token_id=None, name="gpt2-settings")
+    try:
+        assert eng._admit_rows == {b: 2 for b in buckets}
+        texts = eng.compiled_programs()
+        C, G = 1024, 1024 // page
+        pool = jax.eval_shape(eng._empty_pool)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        want = {"step": eng._step_jit.lower(
+            eng._params, eng._buffers, i32(B, 2 + C + G), pool)}
+        for sb in buckets:
+            want[f"admit[{sb}]"] = eng._padmit.lower(
+                eng._params, eng._buffers, i32(2, sb), i32(2, sb), i32(2, C),
+                i32(2, G), i32(2), pool, None, None)
+        assert sorted(texts) == sorted(want)
+        for key, lowered in want.items():
+            assert _LOCATION.sub("", texts[key]) == _LOCATION.sub(
+                "", lowered.compile().as_text()), key
+    finally:
+        eng.close()
 
 
 def _gpt():

@@ -15,12 +15,12 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.serving import GenerationEngine
-from paddle_tpu.serving.generation import _ADMIT_ROWS
+from paddle_tpu.serving.generation import admit_rows
 from paddle_tpu.serving.metrics import (LOOP_COUNTERS, LOOP_PHASES, LoopClock,
                                         ServingMetrics)
 
 B, BUCKET, PAGE, CACHE = 2, 16, 8, 64
-R = min(B, _ADMIT_ROWS)  # rows of the admission program
+R = admit_rows(BUCKET, B)  # rows of the bucket's admission program
 PROMPTS = [(np.arange(10) * 5 + 2) % 97, np.arange(3) % 97,
            (np.arange(6) * 3) % 97, (np.arange(4) * 7 + 1) % 97,
            (np.arange(12) * 11 + 3) % 97]
@@ -152,10 +152,11 @@ def test_admission_counts_rows_tokens_and_token_slots(served):
     assert s["admit_rows"] == s["admitted"] == len(PROMPTS)
     assert s["admit_tokens"] == sum(len(p) for p in PROMPTS)
     # an iteration that admits n rows dispatches ceil(n / R) programs of
-    # [R, bucket], and is one batch
+    # [R(bucket), bucket], and is one batch
     assert 2 <= s["batches"] <= s["admit_steps"] <= len(PROMPTS)
     assert -(-s["admit_rows"] // R) <= s["admit_steps"] \
         <= -(-B // R) * s["batches"]
+    assert s["admit_row_slots"] == R * s["admit_steps"]
     assert s["admit_token_slots"] == R * BUCKET * s["admit_steps"]
 
 

@@ -186,13 +186,14 @@ def test_paged_flash_decode_gpt2_small(chip, pool, page, T):
 @pytest.mark.parametrize("T", [64, 768])
 def test_paged_flash_admission_prefill_gpt2_small(chip, T):
     # the paged admission program's attention: the same kernel over the
-    # engine's row chunk x the narrowest and the widest prompt bucket the
+    # engine's two rows x the narrowest and the widest prompt bucket the
     # chip benchmark serves (chat_open 64, docs_closed 768), float pages
     # of 16
     from paddle_tpu.ops.paged_attention import paged_flash_decode
-    from paddle_tpu.serving.generation import _ADMIT_ROWS
+    from paddle_tpu.serving.generation import admit_rows
 
-    R, H, hd, page = _ADMIT_ROWS, 12, 64, 16
+    R, H, hd, page = admit_rows(T, 32), 12, 64, 16
+    assert R == 2
     G, pages = 1024 // page, 2048 + 1
     _compiles_with_kernel(
         chip, paged_flash_decode, ((R, H, T, hd), f32),
@@ -324,7 +325,7 @@ def test_gpt2_paged_programs_never_copy_the_pool(chip, gpt2_small_engine,
     B, R, C, page, pages = 32, 2, 1024, 16, 2048
     G = C // page
     assert (eng._batch, eng._admit_rows, eng._C, eng._page,
-            eng._kv_pages) == (B, R, C, page, pages)
+            eng._kv_pages) == (B, {512: R, 640: R, 768: R}, C, page, pages)
     pool = on_chip(jax.eval_shape(
         lambda: eng._model.init_paged_cache(pages, page)))
     assert pool["layers"][0]["k"].shape == (pages + 1, page, 768)
@@ -443,3 +444,74 @@ def test_paged_decode_reads_30_heads_of_128_at_the_decode_width(chip):
         chip, paged_flash_decode, ((B, H, 1, hd), bf16),
         ((pages, page, H * hd), bf16), ((pages, page, H * hd), bf16),
         ((B, G), i32), ((B, G * page), i32), ((B, 1), i32), ((B,), i32))
+
+
+# -- a one-row admission of the two long-prompt models ------------------------
+@pytest.mark.parametrize("config,cls,layers,kernels", [
+    # the dense layer and one expert layer: prompt attention in both, the
+    # experts' 128-row tile in one
+    ("joyai_flash_serve", "LatentMoEForCausalLM", 2,
+     {"latent_prefill_attention": 2, "moe_gated_mlp_tm128": 1}),
+    # one period: the chunk walk in three linear layers, and the full
+    # layer's prompt attention (ops/flash_attention.py names no kernel)
+    ("olmo_hybrid_serve", "HybridForCausalLM", 4,
+     {"gated_delta_chunk": 3, "": 4}),
+], ids=["latent", "hybrid"])
+def test_a_one_row_admission_of_the_long_prompt_models(chip, config, cls,
+                                                       layers, kernels):
+    # the ragdocs_closed cells' engines (benchmarks/configs/<config>.json:
+    # published widths, a few of the layers, weights that are shapes only).
+    # Every bucket there is past the admission cap, so the program is
+    # [1, bucket]; lowered at the widest for the described chip, it takes
+    # the TPU-only branches of a one-row call that no CPU test runs (the
+    # kernel gates, the experts' row tile at 4096 x 8 routed rows, the
+    # chunked scan over one row)
+    import json
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.harness import loader
+    from paddle_tpu import nn
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    bench = os.path.join(repo, "benchmarks")
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": layers}
+    with open(os.path.join(bench, "traffic", "ragdocs_closed.json")) as f:
+        buckets = json.load(f)["prompt_buckets"]
+    fam = loader.load_module("families", cfg["family"], bench)
+    serve = cfg["serve"]
+    with nn.abstract_parameters():
+        model = getattr(fam, cls)(fam.model_config(cfg))
+    eng = GenerationEngine(
+        model, prompt_buckets=buckets, batch_size=serve["batch_size"],
+        cache_len=serve["cache_len"], kv_page_size=serve["kv_page_size"],
+        speculative_k=0, eos_token_id=None, name="compile-only-1row")
+    try:
+        assert eng._admit_rows == {b: 1 for b in buckets}
+        one = SingleDeviceSharding(chip)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+        T, C = buckets[-1], serve["cache_len"]
+        G = C // serve["kv_page_size"]
+        text = eng._padmit.lower(
+            on_chip(eng._params), on_chip(eng._buffers), ints(1, T),
+            ints(1, T), ints(1, C), ints(1, G), ints(1),
+            on_chip(jax.eval_shape(eng._empty_pool)), None,
+            ints(1) if eng._slot_state else None).compile().as_text()
+    finally:
+        eng.close()
+    # a kernel is a custom call whose instruction carries its name
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    for kernel, n in kernels.items():
+        assert sum(kernel in c for c in calls) == n, (kernel, calls)
