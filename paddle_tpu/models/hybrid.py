@@ -2,12 +2,33 @@
 list: ``linear_attention`` — the gated delta rule (``ops/gated_delta.py``)
 over a per-head float32 matrix state, behind a short causal depthwise
 convolution — and ``full_attention`` — causal softmax attention with
-QK-norm and no rotary (positions reach it through the recurrent layers).
+QK-norm.  ONE stack; what differs between the published decoders of this
+shape are options of :class:`HybridConfig`, each with the default that
+leaves the first of them (reordered norms, a dense MLP, no rotary, equal
+head counts) the program it was:
 
-Block, both kinds (the reordered norm): ``h = x + norm(Mix(x))``, ``y = h +
-norm(MLP(h))``, gated-SiLU MLP, final norm, untied head, no bias anywhere.
+* the block: ``block_norm="post"``, ``h = x + norm(Mix(x))``, ``y = h +
+  norm(FFN(h))`` (the reordered norm), or ``"pre"``, ``h = x +
+  Mix(norm(x))``, ``y = h + FFN(norm(h))``; ``zero_centered_norms`` stores
+  the block, QK and final gains as their distance from one, ``(1 + w)``;
+* the FFN of each layer, ``ffn_types``: ``dense`` (gated-SiLU MLP) or
+  ``moe`` (:class:`paddle_tpu.moe.DroplessMoE` built from ``moe``, its
+  keyword arguments: router kind, the experts HELD here, the shared
+  expert's gate);
+* full attention: ``num_kv_heads`` grouped K/V heads (query head h reads
+  K/V head ``h // (H / H_kv)``; the pools' row is ``H_kv * head_dim``
+  wide), ``head_dim`` other than ``hidden / heads``, ``qk_norm`` over the
+  whole ``"projection"`` or over each ``"head"``, ``rope_theta`` (None: no
+  rotary, positions reach the layer through the recurrent ones) with
+  rotate-half pairs on the first ``partial_rotary_factor`` of each head's
+  dims, ``attn_output_gate`` (the query projection is twice as wide, ``[q |
+  gate]`` per head, and the context is scaled by ``sigmoid(gate)``);
+* linear attention: ``linear_num_key_heads`` fewer than the value heads
+  (value head h reads key head ``h // rep``), ``allow_neg_eigval``.
 
-``linear_attention``, per head of ``d_k`` keys and ``d_v`` values:
+No bias anywhere, final norm, untied head.
+
+``linear_attention``, per value head of ``d_k`` keys and ``d_v`` values:
 
     [q~ | k~ | v~] = x W_qkv;  causal depthwise conv of K taps, then SiLU
     q = q' / |q'| * d_k^-1/2,  k = k' / |k'|
@@ -19,7 +40,7 @@ norm(MLP(h))``, gated-SiLU MLP, final norm, untied head, no bias anywhere.
 Two kinds of cache, so the model owns the layout of both and declares
 ``slot_state`` (the serving-model protocol, ``serving/generation.py``):
 
-* a full layer holds K and V page pools ``[P + 1, page, H * hd]`` in
+* a full layer holds K and V page pools ``[P + 1, page, H_kv * hd]`` in
   ``GPTModel``'s stored order, read by the same ``paged_decode`` kernel;
 * a linear layer holds, PER SLOT and not per page, ``state`` ``[B + 1, H,
   d_k, d_v]`` float32 and ``conv`` ``[B + 1, K - 1, conv_width]`` (the last
@@ -43,6 +64,7 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..framework.errors import InvalidArgumentError
+from ..moe import DroplessMoE
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
 from ..ops import autotune as _at
@@ -51,10 +73,12 @@ from ..ops.paged_attention import (key_visible, paged_attention,
                                    paged_flash_eligible, sweep_bound)
 from .latent_moe import GatedMLP, _mm
 
-__all__ = ["HybridConfig", "HybridModel", "HybridForCausalLM"]
+__all__ = ["HybridConfig", "HybridModel", "HybridForCausalLM",
+           "rope_rotate_half"]
 
 _F32 = jnp.float32
 LAYER_TYPES = ("linear_attention", "full_attention")
+FFN_TYPES = ("dense", "moe")
 
 
 def _kernels(head_dim=None) -> bool:
@@ -72,41 +96,102 @@ class HybridConfig:
                  layer_types, linear_num_heads, linear_key_head_dim,
                  linear_value_head_dim, linear_conv_kernel=4,
                  allow_neg_eigval=True, rms_norm_eps=1e-6, rope_theta=None,
-                 max_position=4096, dtype="bfloat16", init_std=0.02):
-        if rope_theta is not None:
-            raise InvalidArgumentError(
-                "HybridConfig: rope_theta must be None — the full-attention "
-                "layers carry no rotary, positions come from the recurrent "
-                "layers")
+                 max_position=4096, dtype="bfloat16", init_std=0.02,
+                 num_kv_heads=None, head_dim=None, partial_rotary_factor=1.0,
+                 qk_norm="projection", attn_output_gate=False,
+                 linear_num_key_heads=None, block_norm="post",
+                 zero_centered_norms=False, ffn_types=None, moe=None):
         bad = [t for t in layer_types if t not in LAYER_TYPES]
         if bad or not layer_types:
             raise InvalidArgumentError(
                 f"layer_types must be a non-empty list of {LAYER_TYPES}, "
                 f"got {bad or layer_types!r}")
+        ffn_types = tuple(ffn_types or ("dense",) * len(layer_types))
+        if (len(ffn_types) != len(layer_types)
+                or any(t not in FFN_TYPES for t in ffn_types)
+                or ("moe" in ffn_types) != bool(moe)):
+            raise InvalidArgumentError(
+                f"ffn_types must name one of {FFN_TYPES} a layer, and "
+                f"`moe` (DroplessMoE's keyword arguments) goes with a 'moe' "
+                f"among them: got {ffn_types!r}, moe={moe!r}")
+        if qk_norm not in ("projection", "head") or block_norm not in (
+                "post", "pre"):
+            raise InvalidArgumentError(
+                f"qk_norm is 'projection' or 'head' and block_norm 'post' "
+                f"or 'pre', got {qk_norm!r}, {block_norm!r}")
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.num_heads = int(num_heads)
+        self.num_kv_heads = int(num_kv_heads or num_heads)
+        self.head_dim = int(head_dim or self.hidden_size // self.num_heads)
         self.intermediate_size = int(intermediate_size)
         self.layer_types = tuple(layer_types)
-        self.linear_num_heads = int(linear_num_heads)
+        self.ffn_types = ffn_types
+        self.moe = dict(moe or {})
+        self.linear_num_heads = int(linear_num_heads)      # value heads
+        self.linear_num_key_heads = int(linear_num_key_heads
+                                        or linear_num_heads)
         self.linear_key_head_dim = int(linear_key_head_dim)
         self.linear_value_head_dim = int(linear_value_head_dim)
         self.linear_conv_kernel = int(linear_conv_kernel)
         self.allow_neg_eigval = bool(allow_neg_eigval)
         self.rms_norm_eps = float(rms_norm_eps)
-        self.rope_theta = None
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
+        self.rotary_dim = int(self.head_dim * float(partial_rotary_factor))
+        self.qk_norm, self.block_norm = qk_norm, block_norm
+        self.attn_output_gate = bool(attn_output_gate)
+        self.zero_centered_norms = bool(zero_centered_norms)
         self.max_position = int(max_position)
         self.dtype = dtype
         self.init_std = float(init_std)
+        if (self.num_heads % self.num_kv_heads
+                or self.linear_num_heads % self.linear_num_key_heads
+                or (self.rope_theta is not None and self.rotary_dim % 2)):
+            raise InvalidArgumentError(
+                f"{self.num_heads} query heads over {self.num_kv_heads} K/V "
+                f"heads, {self.linear_num_heads} value heads over "
+                f"{self.linear_num_key_heads} key heads, {self.rotary_dim} "
+                f"rotary dims: the groups must be whole and the rotary "
+                f"dims pairs")
 
     num_layers = property(lambda self: len(self.layer_types))
-    head_dim = property(lambda self: self.hidden_size // self.num_heads)
 
     @property
     def conv_width(self) -> int:
         """Channels under the convolution: ``[q~ | k~ | v~]``."""
-        return self.linear_num_heads * (2 * self.linear_key_head_dim
-                                        + self.linear_value_head_dim)
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_heads * self.linear_value_head_dim)
+
+    @property
+    def experts_held(self) -> int:
+        """Experts an expert layer holds here (0: no expert layer)."""
+        if not self.moe:
+            return 0
+        held = self.moe.get("held")
+        return int(held[1] if held else self.moe["num_experts"])
+
+
+def rope_rotate_half(x, positions, theta, dims):
+    """Rotary positions over the pairs ``(x[i], x[i + dims / 2])`` of the
+    first ``dims`` entries of the last axis (the rotate-half form), angle
+    ``pos * theta^(-2i / dims)``, in float32; the entries past ``dims`` pass
+    untouched.  ``positions`` broadcasts against ``x``'s leading axes;
+    negative (padding) positions rotate as position 0."""
+    half = dims // 2
+    inv = theta ** (-jnp.arange(half, dtype=_F32) * 2.0 / dims)
+    ang = jnp.maximum(positions, 0).astype(_F32)[..., None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(_F32)
+    a, b = xf[..., :half], xf[..., half:dims]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                            xf[..., dims:]], axis=-1).astype(x.dtype)
+
+
+def _norm(cfg, size, zero_centered=None):
+    """A gain of ``size``; the block, QK and final norms follow
+    ``zero_centered_norms``, the delta rule's output norm never does."""
+    return nn.RMSNorm(size, cfg.rms_norm_eps, cfg.dtype, zero_centered=(
+        cfg.zero_centered_norms if zero_centered is None else zero_centered))
 
 
 def _weight(layer, *shape, dtype=None, init=None):
@@ -134,8 +219,8 @@ class GatedDeltaNet(Layer):
         self.dt_bias = _weight(self, H, dtype="float32",
                                init=I.Assign(dt + jnp.log(-jnp.expm1(-dt))))
         self.conv = _weight(self, cfg.linear_conv_kernel, cfg.conv_width)
-        self.o_norm = nn.RMSNorm(cfg.linear_value_head_dim, cfg.rms_norm_eps,
-                                 cfg.dtype)
+        self.o_norm = _norm(cfg, cfg.linear_value_head_dim,
+                            zero_centered=False)
         self.out = _weight(self, H * cfg.linear_value_head_dim, D)
 
     # -- the parts both calls share ----------------------------------------
@@ -155,14 +240,15 @@ class GatedDeltaNet(Layer):
 
     def _qkv_heads(self, y):
         """Conv output ``[..., conv_width]`` (float32) -> SiLU, the head
-        split, and the l2 norms: ``q``, ``k`` ``[..., H, dk]``, ``v``
+        split, and the l2 norms: ``q``, ``k`` ``[..., H_k, dk]``, ``v``
         ``[..., H, dv]``, float32."""
         cfg = self.cfg
-        H, dk = cfg.linear_num_heads, cfg.linear_key_head_dim
+        H, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
         y = y * jax.nn.sigmoid(y)
         q = y[..., :H * dk].reshape(*y.shape[:-1], H, dk)
         k = y[..., H * dk:2 * H * dk].reshape(*y.shape[:-1], H, dk)
-        v = y[..., 2 * H * dk:].reshape(*y.shape[:-1], H, -1)
+        v = y[..., 2 * H * dk:].reshape(*y.shape[:-1], cfg.linear_num_heads,
+                                        -1)
 
         def unit(t):
             return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
@@ -239,37 +325,66 @@ class GatedDeltaNet(Layer):
 
 
 class FullAttention(Layer):
-    """The ``full_attention`` mixer: QK-norm over the whole projection,
-    heads of ``hidden / heads``, no rotary."""
+    """The ``full_attention`` mixer: ``H`` query heads over ``H_kv`` K/V
+    heads of ``head_dim``, QK-norm, and by the configuration's options
+    rotary on the leading dims of each head and an output gate."""
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
         self.cfg = cfg
-        D = cfg.hidden_size
-        self.qkv = _weight(self, D, 3 * D)
-        self.q_norm = nn.RMSNorm(D, cfg.rms_norm_eps, cfg.dtype)
-        self.k_norm = nn.RMSNorm(D, cfg.rms_norm_eps, cfg.dtype)
-        self.out = _weight(self, D, D)
+        D, hd = cfg.hidden_size, cfg.head_dim
+        self.q_width, self.kv_width = cfg.num_heads * hd, cfg.num_kv_heads * hd
+        # [W_q (per head [q | gate] with an output gate) | W_k | W_v]
+        self.qkv = _weight(self, D, (2 if cfg.attn_output_gate else 1)
+                           * self.q_width + 2 * self.kv_width)
+        per_head = cfg.qk_norm == "head"
+        self.q_norm = _norm(cfg, hd if per_head else self.q_width)
+        self.k_norm = _norm(cfg, hd if per_head else self.kv_width)
+        self.out = _weight(self, self.q_width, D)
 
-    def _qkv(self, x):
-        D = self.cfg.hidden_size
+    def _qkv(self, x, positions):
+        """``q`` ``[B, T, H * hd]``, ``k``, ``v`` ``[B, T, H_kv * hd]``
+        normed and rotated as the cache holds them, and the output gate
+        ``[B, T, H * hd]`` or None."""
+        cfg, (B, T, _) = self.cfg, x.shape
+        Q, KV, hd = self.q_width, self.kv_width, cfg.head_dim
         qkv = _mm(x, self.qkv.value)
-        return (self.q_norm(qkv[..., :D]), self.k_norm(qkv[..., D:2 * D]),
-                qkv[..., 2 * D:])
+        gate = None
+        if cfg.attn_output_gate:
+            qg = qkv[..., :2 * Q].reshape(B, T, cfg.num_heads, 2 * hd)
+            q, gate = (t.reshape(B, T, Q) for t in (qg[..., :hd],
+                                                    qg[..., hd:]))
+            k, v = qkv[..., 2 * Q:2 * Q + KV], qkv[..., 2 * Q + KV:]
+        else:
+            q, k, v = qkv[..., :Q], qkv[..., Q:Q + KV], qkv[..., Q + KV:]
+        if cfg.qk_norm == "head" or cfg.rope_theta is not None:
+            q, k = q.reshape(B, T, -1, hd), k.reshape(B, T, -1, hd)
+        q, k = self.q_norm(q), self.k_norm(k)
+        if cfg.rope_theta is not None:
+            q, k = (rope_rotate_half(t, positions[:, :, None],
+                                     cfg.rope_theta, cfg.rotary_dim)
+                    for t in (q, k))
+        return q.reshape(B, T, Q), k.reshape(B, T, KV), v, gate
 
     def _heads(self, t):
         B, T, _ = t.shape
-        return t.reshape(B, T, self.cfg.num_heads, -1).transpose(0, 2, 1, 3)
+        return t.reshape(B, T, -1, self.cfg.head_dim).transpose(0, 2, 1, 3)
 
-    def _merge(self, ctx):
+    def _merge(self, ctx, gate):
         B, _, T, _ = ctx.shape
-        return _mm(ctx.transpose(0, 2, 1, 3).reshape(B, T, -1).astype(
-            self.cfg.dtype), self.out.value)
+        y = ctx.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        if gate is not None:
+            y = y.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))
+        return _mm(y.astype(self.cfg.dtype), self.out.value)
 
     def forward(self, x, positions):
-        del positions  # a prompt from position 0: causal by row
+        """A prompt from position 0 (causal by row), no cache."""
         with jax.named_scope("attn"):
-            q, k, v = map(self._heads, self._qkv(x))
+            q, k, v, gate = self._qkv(x, positions)
+            q, k, v = map(self._heads, (q, k, v))
+            rep = q.shape[1] // k.shape[1]
+            if rep > 1:
+                k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
             T, hd = q.shape[2], q.shape[3]
             s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                            preferred_element_type=_F32) / math.sqrt(hd)
@@ -277,19 +392,19 @@ class FullAttention(Layer):
                           jnp.finfo(_F32).min)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             return self._merge(jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                                          preferred_element_type=_F32))
+                                          preferred_element_type=_F32), gate)
 
     def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask,
-                      walk, prompt):
+                      walk, prompt, positions):
         """K and V rows land in the pages as ``models.gpt`` lays them; a
         prompt (``prompt``: an admission, which starts at position 0) then
         attends to its own K/V by the flash kernel where that runs, a
         decode row to its slot's pages."""
         with jax.named_scope("attn"):
-            B, T, D = x.shape
-            q, k, v = self._qkv(x)
+            B, T, _ = x.shape
+            q, k, v, gate = self._qkv(x, positions)
             pools = {n: kv[n].at[write_page, write_off].set(
-                rows.reshape(B * T, D).astype(kv[n].dtype))
+                rows.reshape(B * T, self.kv_width).astype(kv[n].dtype))
                 for n, rows in (("k", k), ("v", v))}
             q = self._heads(q)
             if prompt and _kernels(self.cfg.head_dim):
@@ -300,28 +415,40 @@ class FullAttention(Layer):
             else:
                 ctx = paged_attention(q, pools["k"], pools["v"], gather_tab,
                                       mask, walk)  # no walk for a prompt
-            return self._merge(ctx), pools
+            return self._merge(ctx, gate), pools
 
 
 class HybridBlock(Layer):
-    def __init__(self, cfg: HybridConfig, kind: str):
+    def __init__(self, cfg: HybridConfig, kind: str, ffn: str = "dense"):
         super().__init__()
-        dt = cfg.dtype
         self.kind = kind
+        self.pre_norm = cfg.block_norm == "pre"
         self.mixer = (GatedDeltaNet(cfg) if kind == "linear_attention"
                       else FullAttention(cfg))
-        self.norm1 = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, dt)
-        self.mlp = GatedMLP(cfg.hidden_size, cfg.intermediate_size, dt,
-                            cfg.init_std)
-        self.norm2 = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps, dt)
+        self.norm1 = _norm(cfg, cfg.hidden_size)
+        if ffn == "moe":
+            self.mlp = DroplessMoE(cfg.hidden_size, dtype=cfg.dtype,
+                                   init_std=cfg.init_std, **cfg.moe)
+        else:
+            self.mlp = GatedMLP(cfg.hidden_size, cfg.intermediate_size,
+                                cfg.dtype, cfg.init_std)
+        self.norm2 = _norm(cfg, cfg.hidden_size)
+
+    def enter(self, x):
+        """What the mixer reads."""
+        return self.norm1(x) if self.pre_norm else x
 
     def finish(self, x, mixed):
-        """The block after its mixer: both reordered norms and the MLP."""
+        """The block after its mixer: the residuals, the other norm(s) and
+        the FFN."""
+        if self.pre_norm:
+            h = x + mixed
+            return h + self.mlp(self.norm2(h))
         h = x + self.norm1(mixed)
         return h + self.norm2(self.mlp(h))
 
     def forward(self, x, positions):
-        return self.finish(x, self.mixer(x, positions))
+        return self.finish(x, self.mixer(self.enter(x), positions))
 
 
 class HybridModel(Layer):
@@ -329,10 +456,10 @@ class HybridModel(Layer):
         super().__init__()
         self.cfg = cfg
         self.embed = _weight(self, cfg.vocab_size, cfg.hidden_size)
-        self.blocks = nn.LayerList([HybridBlock(cfg, kind)
-                                    for kind in cfg.layer_types])
-        self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                                 cfg.dtype)
+        self.blocks = nn.LayerList([
+            HybridBlock(cfg, kind, ffn)
+            for kind, ffn in zip(cfg.layer_types, cfg.ffn_types)])
+        self.norm_f = _norm(cfg, cfg.hidden_size)
 
     def forward(self, input_ids):
         """``[B, S]`` ids from position 0 -> ``[B, S, D]``, causal."""
@@ -347,7 +474,7 @@ class HybridModel(Layer):
     # -- the two caches: the model owns both layouts ---------------------------
     def init_paged_cache(self, num_pages: int, page_size: int, dtype=None,
                          slots=None):
-        """Per full layer K and V pools ``[P + 1, page, hidden]``; per
+        """Per full layer K and V pools ``[P + 1, page, H_kv * hd]``; per
         linear layer ``state`` ``[slots + 1, H, dk, dv]`` float32 and
         ``conv`` ``[slots + 1, K - 1, conv_width]``."""
         cfg = self.cfg
@@ -355,7 +482,8 @@ class HybridModel(Layer):
             raise InvalidArgumentError(
                 "a model with slot state needs init_paged_cache(slots=): "
                 "the engine's batch size")
-        pool = (int(num_pages) + 1, int(page_size), cfg.hidden_size)
+        pool = (int(num_pages) + 1, int(page_size),
+                cfg.num_kv_heads * cfg.head_dim)
         rows = int(slots) + 1
 
         def layer(kind):
@@ -413,7 +541,8 @@ class HybridModel(Layer):
         if not prompt and _paged_flash(cfg.head_dim, page):
             walk = (pos_map, positions, sweep_bound(mask, page))
         paged = (phys.reshape(-1), jnp.clip(ring % page, 0, page - 1)
-                 .reshape(-1), jnp.maximum(table, 0), mask, walk, prompt)
+                 .reshape(-1), jnp.maximum(table, 0), mask, walk, prompt,
+                 positions)
         if prompt:
             slots = jnp.asarray(slots, jnp.int32)
             drop = next(kv for kv in cache["layers"]
@@ -421,12 +550,13 @@ class HybridModel(Layer):
             rows = jnp.where(slots >= 0, slots, drop)
         layers = []
         for blk, kv in zip(self.blocks, cache["layers"]):
+            y = blk.enter(x)
             if blk.kind == "full_attention":
-                mixed, kv = blk.mixer.forward_paged(x, kv, *paged)
+                mixed, kv = blk.mixer.forward_paged(y, kv, *paged)
             elif prompt:
-                mixed, kv = blk.mixer.admit(x, positions, kv, rows)
+                mixed, kv = blk.mixer.admit(y, positions, kv, rows)
             else:
-                mixed, kv = blk.mixer.decode(x, positions, kv)
+                mixed, kv = blk.mixer.decode(y, positions, kv)
             x = blk.finish(x, mixed)
             layers.append(kv)
         return self.norm_f(x), {"layers": layers}
@@ -445,7 +575,8 @@ class HybridForCausalLM(Layer):
         self.head = _weight(self, cfg.hidden_size, cfg.vocab_size)
 
     max_position = property(lambda self: self.cfg.max_position)
-    moe_experts = 0
+    #: experts the engine counts routed tokens over: those HELD here
+    moe_experts = property(lambda self: self.cfg.experts_held)
     lora_capacity = 0
     slot_state = True
 

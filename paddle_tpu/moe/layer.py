@@ -178,7 +178,8 @@ class MoELayer(Layer):
         f = selected.astype(jnp.float32) / float(N * k)
         P = probs.mean(0)
         aux = float(E) * jnp.sum(f * P)
-        moe_stats.record(aux, routed, (selected - routed).astype(jnp.int32))
+        moe_stats.record(aux, routed, (selected - routed).astype(jnp.int32),
+                         pairs=N * k)
 
         return self.drop(y.reshape(*lead, D))
 
@@ -195,20 +196,36 @@ def gated_mlp(x, w_gate, w_up, w_down):
 
 
 class DroplessMoE(Layer):
-    """Sigmoid-routed experts with no capacity, plus shared experts.
+    """Routed experts with no capacity, plus shared experts.
 
-    ``s = sigmoid(x W_g)`` in float32; the ``top_k`` experts are chosen by
-    ``s + b`` (``score_bias``, a per-expert correction that steers load
-    and never enters the weights), weighted by ``s`` of the chosen,
-    normalised to sum 1 and scaled by ``routed_scale``.  Every expert is a
-    gated-SiLU MLP ``(silu(x W_gate) * (x W_up)) W_down``; the shared
-    expert (``shared_experts`` of them fused into one of that many widths)
-    sees every token once.  Dispatch sorts the (token, choice) pairs by
-    expert into ``ops.grouped_matmul.ragged_layout``'s tile-aligned rows
-    and ``ragged_gated_mlp`` runs only the tiles in use, so no token is
-    dropped whatever the routing and an expert nobody chose costs nothing.
-    Per-expert routed counts go to :mod:`paddle_tpu.moe.stats` (dropped is
-    0 by construction)."""
+    ``router="sigmoid"`` (the default): ``s = sigmoid(x W_g)`` in float32;
+    the ``top_k`` experts are chosen by ``s + b`` (``score_bias``, a
+    per-expert correction that steers load and never enters the weights),
+    weighted by ``s`` of the chosen, normalised to sum 1 and scaled by
+    ``routed_scale``.  ``router="softmax"``: ``p = softmax(x W_g)`` over ALL
+    the router's experts in float32, the ``top_k`` by ``p``, weighted by
+    ``p`` of the chosen (normalised over them with ``norm_topk``); no bias.
+    Every expert is a gated-SiLU MLP ``(silu(x W_gate) * (x W_up))
+    W_down``; the shared expert (``shared_experts`` of them fused into one
+    of that many widths) sees every token once, scaled by ``sigmoid(x
+    w_s)`` (``shared_gating``, ``[D, 1]``) where ``shared_gated``.
+
+    ``held = (first, count)``: this layer holds experts ``first .. first +
+    count - 1`` of the router's ``num_experts`` (one chip's share of a layer
+    divided over several): the three expert tensors are ``[count, ...]``,
+    the router keeps all its outputs and its ``top_k``, and the layer
+    returns the part of the sum that its own experts give, plus the shared
+    expert.  A (token, choice) pair whose expert is not held adds nothing,
+    gets no row of the layout and costs no tile; nothing here stands in for
+    the experts' other holders or for the exchange with them.
+
+    Dispatch sorts the (token, choice) pairs by expert into
+    ``ops.grouped_matmul.ragged_layout``'s tile-aligned rows and
+    ``ragged_gated_mlp`` runs only the tiles in use, so no token is dropped
+    whatever the routing and an expert nobody chose costs nothing.
+    Per-expert routed counts OF THE HELD experts go to
+    :mod:`paddle_tpu.moe.stats` (dropped is 0 by construction), with the
+    pairs the router made in all."""
 
     #: pairs per expert under which a call is decode-like: the small row
     #: tile keeps the padding of one-token groups low
@@ -216,11 +233,21 @@ class DroplessMoE(Layer):
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  shared_experts=1, routed_scale=1.0, norm_topk=True,
-                 dtype="float32", init_std=0.02):
+                 dtype="float32", init_std=0.02, router="sigmoid",
+                 held=None, shared_gated=False):
         super().__init__()
         D, F, E = int(hidden_size), int(expert_width), int(num_experts)
+        if router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router must be 'sigmoid' or 'softmax', got "
+                             f"{router!r}")
         self.num_experts, self.top_k = E, int(top_k)
         self.routed_scale, self.norm_topk = float(routed_scale), norm_topk
+        self.router_kind = router
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        if self.held is not None and not (
+                0 <= self.held[0] and 0 < self.held[1]
+                and self.held[0] + self.held[1] <= E):
+            raise ValueError(f"held={held!r} is no range of {E} experts")
         init = I.Normal(std=init_std)
 
         def p(shape, dt=dtype, spec=None):
@@ -230,23 +257,36 @@ class DroplessMoE(Layer):
             return w
 
         self.router = p((D, E))
-        self.score_bias = p((E,), "float32")
+        if router == "sigmoid":
+            self.score_bias = p((E,), "float32")
         ex = ("expert", None, None)
-        self.expert_gate = p((E, D, F), spec=ex)
-        self.expert_up = p((E, D, F), spec=ex)
-        self.expert_down = p((E, F, D), spec=ex)
+        Eh = E if self.held is None else self.held[1]
+        self.expert_gate = p((Eh, D, F), spec=ex)
+        self.expert_up = p((Eh, D, F), spec=ex)
+        self.expert_down = p((Eh, F, D), spec=ex)
         Fs = F * int(shared_experts)
         self.shared_gate = p((D, Fs)) if Fs else None
         self.shared_up = p((D, Fs)) if Fs else None
         self.shared_down = p((Fs, D)) if Fs else None
+        self.shared_gating = p((D, 1)) if Fs and shared_gated else None
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts if self.held is None else self.held[1]
 
     def route(self, xf):
         """``[N, D]`` -> (expert ids ``[N, k]`` int32, weights ``[N, k]``
-        float32)."""
+        float32), over all the router's experts."""
         f32 = jnp.float32
-        s = jax.nn.sigmoid(jnp.dot(
-            xf.astype(f32), self.router.value.astype(f32),
-            precision=jax.lax.Precision.HIGHEST))
+        logits = jnp.dot(xf.astype(f32), self.router.value.astype(f32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if self.router_kind == "softmax":
+            w, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     self.top_k)
+            if self.norm_topk:
+                w = w / w.sum(-1, keepdims=True)
+            return top_e.astype(jnp.int32), w * self.routed_scale
+        s = jax.nn.sigmoid(logits)
         _, top_e = jax.lax.top_k(s + self.score_bias.value.astype(f32),
                                  self.top_k)
         w = jnp.take_along_axis(s, top_e, axis=-1)
@@ -264,23 +304,42 @@ class DroplessMoE(Layer):
         with jax.named_scope("moe"):
             top_e, w = self.route(xf)
             A = N * k
+            # the pairs an expert sees are A / E whatever share of the E is
+            # held: A * held / E land here, on `held` experts
             tm = 16 if A < self._SMALL_ROWS * E else 128
-            lay = ragged_layout(top_e.reshape(-1), E, tm)
+            if self.held is None:
+                lay = ragged_layout(top_e.reshape(-1), E, tm)
+            else:
+                lay = ragged_layout(top_e.reshape(-1) - self.held[0],
+                                    self.held[1], tm, partial=True)
             rows = lay["tiles"] * tm
             # row r of the sorted layout reads token src[r]; N is a zero row
+            # (a pair with no row has its dest past the layout: dropped)
             src = jnp.full((rows,), N, jnp.int32).at[lay["dest"]].set(
-                jnp.arange(A, dtype=jnp.int32) // k)
+                jnp.arange(A, dtype=jnp.int32) // k,
+                **({} if self.held is None else {"mode": "drop"}))
             xs = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[src]
             ys = ragged_gated_mlp(xs, self.expert_gate.value,
                                   self.expert_up.value,
                                   self.expert_down.value, lay)
+            if self.held is None:
+                yk = ys[lay["dest"]]
+            else:
+                # a tile nobody uses is never written: read no row of it
+                yk = jnp.where(lay["present"][:, None],
+                               ys[jnp.minimum(lay["dest"], rows - 1)], 0)
             y = jnp.einsum("nkd,nk->nd",
-                           ys[lay["dest"]].reshape(N, k, D).astype(
-                               jnp.float32), w)
+                           yk.reshape(N, k, D).astype(jnp.float32), w)
             if self.shared_gate is not None:
-                y = y + gated_mlp(xf, self.shared_gate.value,
-                                  self.shared_up.value,
-                                  self.shared_down.value)
+                shared = gated_mlp(xf, self.shared_gate.value,
+                                   self.shared_up.value,
+                                   self.shared_down.value)
+                if self.shared_gating is not None:
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        xf, self.shared_gating.value,
+                        preferred_element_type=jnp.float32))
+                y = y + shared
             moe_stats.record(jnp.zeros((), jnp.float32), lay["counts"],
-                             jnp.zeros((E,), jnp.int32))
+                             jnp.zeros((self.experts_held,), jnp.int32),
+                             pairs=A)
         return y.astype(x.dtype).reshape(*lead, D)

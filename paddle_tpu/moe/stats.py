@@ -40,9 +40,13 @@ class MoEStats:
 
     def __init__(self):
         self.entries: List[tuple] = []
+        #: (token, choice) pairs the routers made, over the layers that said
+        #: so: a static number, read at trace time
+        self.pairs = 0
 
-    def add(self, aux, routed, dropped):
+    def add(self, aux, routed, dropped, pairs=None):
         self.entries.append((aux, routed, dropped))
+        self.pairs += int(pairs or 0)
 
     def total_aux(self):
         """Sum of the recorded load-balance losses (traced scalar), or
@@ -89,11 +93,13 @@ class collect:
         return False
 
 
-def record(aux, routed, dropped):
-    """Called by ``MoELayer.forward``; a no-op when nobody collects."""
+def record(aux, routed, dropped, pairs=None):
+    """Called by ``MoELayer.forward``; a no-op when nobody collects.
+    ``pairs``: the (token, choice) pairs the layer's router made (static),
+    of which ``routed`` counts those whose expert the layer holds."""
     st = _stack()
     if st:
-        st[-1].add(aux, routed, dropped)
+        st[-1].add(aux, routed, dropped, pairs)
 
 
 def active() -> bool:

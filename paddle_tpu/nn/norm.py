@@ -220,20 +220,24 @@ class LayerNorm(Layer):
 class RMSNorm(Layer):
     """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, computed
     in float32 and returned in ``x``'s dtype (Zhang & Sennrich 2019): no
-    mean subtraction, no bias."""
+    mean subtraction, no bias.  ``zero_centered``: the stored weight is the
+    gain's distance from one, ``* (1 + weight)``, initialised to 0."""
 
-    def __init__(self, size, epsilon=1e-6, dtype=None):
+    def __init__(self, size, epsilon=1e-6, dtype=None, zero_centered=False):
         super().__init__()
         self.epsilon = epsilon
+        self.zero_centered = bool(zero_centered)
         self.weight = self.create_parameter(
-            (int(size),), dtype=dtype, default_initializer=I.Constant(1.0))
+            (int(size),), dtype=dtype, default_initializer=I.Constant(
+                0.0 if zero_centered else 1.0))
 
     def forward(self, x):
         x = jnp.asarray(x)
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(
             jnp.mean(xf * xf, axis=-1, keepdims=True) + self.epsilon)
-        return (y * self.weight.value.astype(jnp.float32)).astype(x.dtype)
+        w = self.weight.value.astype(jnp.float32)
+        return (y * (1.0 + w if self.zero_centered else w)).astype(x.dtype)
 
 
 class GroupNorm(Layer):

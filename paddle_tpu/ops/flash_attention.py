@@ -192,11 +192,22 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
                 kv_len):
     B, H, S, D = q.shape
     K = k.shape[2]
+    # grouped K/V heads (forward only): query head h reads K/V head
+    # h // rep, so row b * H + h of the flat queries reads row
+    # (b * H + h) // rep of the flat keys
+    rep = H // k.shape[1]
     qs = q.reshape(B * H, S, D)
-    ks = k.reshape(B * H, K, D)
-    vs = v.reshape(B * H, K, D)
+    ks = k.reshape(B * H // rep, K, D)
+    vs = v.reshape(B * H // rep, K, D)
 
     _I0 = np.int32(0)  # index maps must stay i32 under global x64
+
+    def kv(b):
+        return b if rep == 1 else jax.lax.div(b, np.int32(rep))
+
+    # the grouped form is named for the trace's readers; the equal-heads
+    # form stays unnamed, as the programs that hold it were compiled
+    named = {"name": "flash_fwd_grouped"} if rep > 1 else {}
     triangle = _use_triangle(causal, q_offset, S, K, block_q, block_k)
 
     kern = functools.partial(_fwd_kernel, kv_seq=K, kv_len=kv_len,
@@ -218,7 +229,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
         nq = S // block_q
         qi_t, ki_t = (jnp.asarray(a) for a in _tri_lower_table(nq))
         qmp = lambda b, t, qt, kt: (b, qt[t], _I0)  # noqa: E731
-        kmp = lambda b, t, qt, kt: (b, kt[t], _I0)  # noqa: E731
+        kmp = lambda b, t, qt, kt: (kv(b), kt[t], _I0)  # noqa: E731
         # grid (BH, T): the flat tile dim is innermost/sequential so the
         # owner block's VMEM accumulators persist across its tiles
         out, lse = pl.pallas_call(
@@ -240,7 +251,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
+            interpret=interpret, **named,
         )(qi_t, ki_t, qs, ks, vs)
         return out.reshape(B, H, S, D), lse.reshape(B, H, S)
 
@@ -249,8 +260,8 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
         grid=(B * H, S // block_q, K // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, _I0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, _I0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (kv(b), j, _I0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, _I0)),
@@ -262,7 +273,7 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, **named,
     )(qs, ks, vs)
     return out.reshape(B, H, S, D), lse.reshape(B, H, S)
 
@@ -751,10 +762,11 @@ def flash_attention(q, k, v, causal: bool = False,
     """Memory-efficient attention.
 
     Args are [batch, num_heads, seq, head_dim] (q may have a different seq
-    than k/v).  ``q_position_offset`` is the global position of q's first
-    row — used by ring attention, where the local q chunk sits at an offset
-    into the global sequence for causal masking; any offset is exact (no
-    block alignment required).
+    than k/v, and ``rep`` times their heads: query head h then reads K/V
+    head ``h // rep``, forward only).  ``q_position_offset`` is the global
+    position of q's first row — used by ring attention, where the local q
+    chunk sits at an offset into the global sequence for causal masking; any
+    offset is exact (no block alignment required).
 
     Any shape takes the kernel path: ragged sequence lengths are padded up
     to block multiples and the kernels mask padded key positions, so there
@@ -787,6 +799,16 @@ def flash_attention(q, k, v, causal: bool = False,
     qp = q if Sp == S else jnp.pad(q, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
     kp = k if Kp == K else jnp.pad(k, ((0, 0), (0, 0), (0, Kp - K), (0, 0)))
     vp = v if Kp == K else jnp.pad(v, ((0, 0), (0, 0), (0, Kp - K), (0, 0)))
-    out = _flash(qp, kp, vp, causal, float(sm_scale), bq, bk,
-                 int(q_position_offset), int(K), tuned)
+    if k.shape[1] != q.shape[1]:
+        # grouped K/V heads: the forward kernel indexes them, the backward
+        # kernels do not, so this form is outside the custom VJP
+        if q.shape[1] % k.shape[1] or v.shape[1] != k.shape[1]:
+            raise ValueError(f"flash_attention: {k.shape[1]} K / "
+                             f"{v.shape[1]} V heads for {q.shape[1]} "
+                             f"query heads")
+        out, _ = _fwd_pallas(qp, kp, vp, causal, float(sm_scale), bq, bk,
+                             int(q_position_offset), int(K))
+    else:
+        out = _flash(qp, kp, vp, causal, float(sm_scale), bq, bk,
+                     int(q_position_offset), int(K), tuned)
     return out if Sp == S else out[:, :, :S]

@@ -30,6 +30,13 @@ Three routes to that function:
 
 Off the TPU (and on a mesh of several devices) both run their ``jnp``
 forms, which are also what the chip tool compares the kernels with.
+
+GROUPED KEY HEADS: ``q`` and ``k`` may have fewer heads than ``v`` (``H_v =
+rep * H_k``); value head ``h`` then reads key head ``h // rep``.  The state,
+the gates and the output are per value head.  The chunked form repeats the
+key heads where it builds its per-value-head operands (XLA fuses the
+repeat into them); the step kernel indexes: a block of ``block_h`` value
+heads fetches its ``block_h / rep`` key heads.
 """
 from __future__ import annotations
 
@@ -70,11 +77,22 @@ def _token(S, q, k, v, g, beta):
     return S, jnp.einsum("...k,...kv->...v", q, S, precision=_HI)
 
 
+def _per_value_head(q, k, heads, axis):
+    """``q`` and ``k`` of ``H_k`` heads along ``axis`` repeated to ``heads``
+    value heads (value head ``h`` reads key head ``h // rep``); themselves
+    where the counts are equal."""
+    rep = heads // q.shape[axis]
+    if rep == 1:
+        return q, k
+    return jnp.repeat(q, rep, axis=axis), jnp.repeat(k, rep, axis=axis)
+
+
 def gated_delta_recurrent(q, k, v, g, beta, state=None):
-    """``q``, ``k`` ``[B, T, H, dk]``, ``v`` ``[B, T, H, dv]``, ``g``,
+    """``q``, ``k`` ``[B, T, H_k, dk]``, ``v`` ``[B, T, H, dv]``, ``g``,
     ``beta`` ``[B, T, H]``; ``state`` ``[B, H, dk, dv]`` (zero when None).
     Returns float32 ``o`` ``[B, T, H, dv]`` and the state after token T."""
     q, k, v, g, beta = (jnp.asarray(t, _F32) for t in (q, k, v, g, beta))
+    q, k = _per_value_head(q, k, v.shape[2], axis=2)
     B, _, H, dk = q.shape
     if state is None:
         state = jnp.zeros((B, H, dk, v.shape[-1]), _F32)
@@ -130,14 +148,15 @@ def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
     in-chunk scores ``P_ij = (q_i . k_j) e^{b_i - b_j}`` ``[B, H, N, C, C]``
     and the chunk's whole decay ``e^{b_C}`` ``[B, H, N]``.  Float32; ``T``
     must be whole chunks."""
-    B, T, H, _ = q.shape
+    B, T = q.shape[:2]
     N = T // chunk
 
     def split(t):      # [B, T, H, ...] -> [B, H, N, C, ...]
         return jnp.moveaxis(jnp.asarray(t, _F32).reshape(
-            B, N, chunk, H, *t.shape[3:]), 3, 1)
+            B, N, chunk, *t.shape[2:]), 3, 1)
 
     q, k, v, g, beta = map(split, (q, k, v, g, beta))
+    q, k = _per_value_head(q, k, v.shape[1], axis=1)
     b = jnp.cumsum(g, axis=-1)                               # [B, H, N, C]
     i = np.arange(chunk)
     low = i[:, None] >= i[None, :]
@@ -242,10 +261,11 @@ def _walk_pallas(qg, kdT, W, U, P, dl, *, block_h):
 
 def gated_delta_chunk(q, k, v, g, beta, chunk=CHUNK):
     """A prompt from the zero state.  Shapes as
-    :func:`gated_delta_recurrent`; ``T`` is padded to whole chunks with
-    identity tokens.  Returns float32 ``o`` ``[B, T, H, dv]`` and the state
-    after the last token, ``[B, H, dk, dv]``."""
-    B, T, H, _ = q.shape
+    :func:`gated_delta_recurrent` (``q`` and ``k`` may have fewer heads
+    than ``v``); ``T`` is padded to whole chunks with identity tokens.
+    Returns float32 ``o`` ``[B, T, H, dv]`` and the state after the last
+    token, ``[B, H, dk, dv]``."""
+    B, T, H = v.shape[:3]
     pad = -T % chunk
     if pad:
         q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad))
@@ -261,6 +281,7 @@ def gated_delta_chunk(q, k, v, g, beta, chunk=CHUNK):
 # -- one token a slot ----------------------------------------------------------
 def _step_jnp(q, k, v, g, beta, state):
     B = q.shape[0]
+    q, k = _per_value_head(q, k, v.shape[1], axis=1)
     S, o = _token(state[:B], q, k, v, g, beta)
     return o, jax.lax.dynamic_update_slice(state, S, (0, 0, 0, 0))
 
@@ -268,25 +289,41 @@ def _step_jnp(q, k, v, g, beta, state):
 def _step_kernel(a_ref, beta_ref, q_ref, k_ref, v_ref, s_ref, o_ref, so_ref):
     """``block_h`` heads of one slot: keys and queries ride as columns
     ``[dk, 1]``, values as rows ``[1, dv]``, so the products with the state
-    are broadcasts and sums over sublanes."""
-    Sd = s_ref[0] * a_ref[0]                               # [h, dk, dv]
-    k = k_ref[0]
-    pred = jnp.sum(k * Sd, axis=1, keepdims=True)          # [h, 1, dv]
-    S = Sd + k * (beta_ref[0] * (v_ref[0] - pred))
-    so_ref[0] = S
-    o_ref[0] = jnp.sum(q_ref[0] * S, axis=1, keepdims=True)
+    are broadcasts and sums over sublanes.  With grouped key heads the
+    block holds ``block_h / rep`` of them, each serving the ``rep`` value
+    heads that follow one another."""
+    rep = s_ref.shape[1] // k_ref.shape[1]
+    if rep == 1:   # kept as it was written: the kernel the older cell compiled
+        Sd = s_ref[0] * a_ref[0]                           # [h, dk, dv]
+        k = k_ref[0]
+        pred = jnp.sum(k * Sd, axis=1, keepdims=True)      # [h, 1, dv]
+        S = Sd + k * (beta_ref[0] * (v_ref[0] - pred))
+        so_ref[0] = S
+        o_ref[0] = jnp.sum(q_ref[0] * S, axis=1, keepdims=True)
+        return
+    for j in range(k_ref.shape[1]):   # a [dk, 1] column over its rep heads
+        hs = slice(j * rep, (j + 1) * rep)
+        Sd = s_ref[0, hs] * a_ref[0, hs]                   # [rep, dk, dv]
+        k = k_ref[0, j]
+        pred = jnp.sum(k * Sd, axis=1, keepdims=True)
+        S = Sd + k * (beta_ref[0, hs] * (v_ref[0, hs] - pred))
+        so_ref[0, hs] = S
+        o_ref[0, hs] = jnp.sum(q_ref[0, j] * S, axis=1, keepdims=True)
 
 
 def _step_space(q, k, v, g, beta, state):
     H, dk, dv = state.shape[1:]
-    # the state block in and out, double-buffered
+    rep = H // q.shape[1]
+    # the state block in and out, double-buffered; whole groups of value
+    # heads, so that a block's key heads are a block too
     return [{"block_h": h} for h in range(1, H + 1)
-            if H % h == 0 and _at.vmem_fits(4 * 4 * h * dk * (-(-dv // 128)
-                                                              * 128))]
+            if H % h == 0 and h % rep == 0
+            and _at.vmem_fits(4 * 4 * h * dk * (-(-dv // 128) * 128))]
 
 
 def _step_heuristic(q, k, v, g, beta, state):
-    """The largest block of at most 10 heads: 0.7 MB of state a grid step."""
+    """The largest block of at most 10 heads: 0.7 MB of state a grid step
+    at 96 x 192, 0.5 MB (8 heads) at 128 x 128."""
     return max((c for c in _step_space(q, k, v, g, beta, state)
                 if c["block_h"] <= 10), key=lambda c: c["block_h"])
 
@@ -294,19 +331,23 @@ def _step_heuristic(q, k, v, g, beta, state):
 @_at.autotune("gated_delta_step", params=("block_h",), space=_step_space,
               heuristic=_step_heuristic)
 def _step_pallas(q, k, v, g, beta, state, *, block_h):
-    B, H, dk = q.shape
-    dv = v.shape[-1]
+    B, Hk, dk = q.shape
+    H, dv = v.shape[1:]
+    rep = H // Hk
     z = _at.I0
 
-    def blk(*tail):
-        return pl.BlockSpec((1, block_h) + tail, lambda b, h: (b, h, z, z))
+    def blk(*tail, heads=block_h):
+        return pl.BlockSpec((1, heads) + tail, lambda b, h: (b, h, z, z))
+
+    def key(*tail):   # block h of the key heads serves block h of the values
+        return blk(*tail, heads=block_h // rep)
 
     o, state = pl.pallas_call(
         _step_kernel,
         name="gated_delta_step",
         interpret=not _device.on_tpu(),
         grid=(B, H // block_h),
-        in_specs=[blk(1, 1), blk(1, 1), blk(dk, 1), blk(dk, 1), blk(1, dv),
+        in_specs=[blk(1, 1), blk(1, 1), key(dk, 1), key(dk, 1), blk(1, dv),
                   blk(dk, dv)],
         out_specs=[blk(1, dv), blk(dk, dv)],
         out_shape=[jax.ShapeDtypeStruct((B, H, 1, dv), _F32),
@@ -322,7 +363,7 @@ def _step_pallas(q, k, v, g, beta, state, *, block_h):
 
 
 def gated_delta_step(q, k, v, g, beta, state):
-    """One token of each slot: ``q``, ``k`` ``[B, H, dk]``, ``v`` ``[B, H,
+    """One token of each slot: ``q``, ``k`` ``[B, H_k, dk]``, ``v`` ``[B, H,
     dv]``, ``g``, ``beta`` ``[B, H]``, ``state`` ``[>= B, H, dk, dv]``
     float32 (row ``i`` is slot ``i``; further rows are left alone).
     Returns float32 ``o`` ``[B, H, dv]`` and the updated ``state``.  A slot

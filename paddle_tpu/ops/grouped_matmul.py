@@ -195,7 +195,8 @@ def ragged_tiles(num_rows: int, num_groups: int, tile_m: int) -> int:
     return min(num_rows // tile_m + num_groups, num_rows)
 
 
-def ragged_layout(group_ids, num_groups: int, tile_m: int):
+def ragged_layout(group_ids, num_groups: int, tile_m: int,
+                  partial: bool = False):
     """Sort ``[A]`` group ids (one per (token, choice) pair) into a
     tile-aligned row layout.  Returns a dict of int32 arrays:
 
@@ -205,11 +206,20 @@ def ragged_layout(group_ids, num_groups: int, tile_m: int):
     block index does not move there); ``tile_index`` [NT]: ``min(t,
     used - 1)``; ``used`` [1]: tiles in use.  The layout has ``NT *
     tile_m`` rows; a group's rows are contiguous from a tile boundary and
-    the rows that pad its last tile belong to nobody."""
+    the rows that pad its last tile belong to nobody.
+
+    ``partial``: ids outside ``[0, num_groups)`` name groups that are not
+    held here.  Such a pair gets NO row (its ``dest`` is ``NT * tile_m``,
+    one past the layout, and ``present`` [A] bool says which pairs have
+    one), is counted nowhere and costs no tile; no tile at all may be in
+    use.  The static row bound stays that of all ``A`` pairs."""
     i32 = jnp.int32
     ids = jnp.asarray(group_ids, i32)
     A, E, tm = ids.shape[0], int(num_groups), int(tile_m)
     NT = ragged_tiles(A, E, tm)
+    if partial:
+        present = (ids >= 0) & (ids < E)
+        ids = jnp.where(present, ids, E)       # the absent sort last
     counts = jnp.sum(ids[:, None] == jnp.arange(E, dtype=i32)[None, :],
                      axis=0, dtype=i32)
     order = jnp.argsort(ids, stable=True).astype(i32)
@@ -218,17 +228,27 @@ def ragged_layout(group_ids, num_groups: int, tile_m: int):
     tile_end = jnp.cumsum(tiles, dtype=i32)
     tile_start = tile_end - tiles
     group_start = jnp.cumsum(counts, dtype=i32) - counts
-    rank = jnp.arange(A, dtype=i32) - group_start[sorted_ids]
-    dest_sorted = tile_start[sorted_ids] * tm + rank
+    if partial:
+        held = jnp.minimum(sorted_ids, E - 1)
+        dest_sorted = jnp.where(
+            sorted_ids < E, tile_start[held] * tm
+            + jnp.arange(A, dtype=i32) - group_start[held], NT * tm)
+    else:
+        rank = jnp.arange(A, dtype=i32) - group_start[sorted_ids]
+        dest_sorted = tile_start[sorted_ids] * tm + rank
     dest = jnp.zeros((A,), i32).at[order].set(dest_sorted)
     used = tile_end[-1]
-    tile_index = jnp.minimum(jnp.arange(NT, dtype=i32), used - 1)
+    last = jnp.maximum(used - 1, 0) if partial else used - 1
+    tile_index = jnp.minimum(jnp.arange(NT, dtype=i32), last)
     tile_group = jnp.minimum(
         jnp.searchsorted(tile_end, tile_index, side="right").astype(i32),
         E - 1)
-    return {"dest": dest, "counts": counts, "tile_group": tile_group,
-            "tile_index": tile_index, "used": used.reshape(1),
-            "tiles": NT, "tile_m": tm}
+    lay = {"dest": dest, "counts": counts, "tile_group": tile_group,
+           "tile_index": tile_index, "used": used.reshape(1),
+           "tiles": NT, "tile_m": tm}
+    if partial:
+        lay["present"] = present
+    return lay
 
 
 def _gated_mlp_kernel(tg_ref, ti_ref, used_ref, x_ref, wg_ref, wu_ref,

@@ -406,21 +406,42 @@ def _paged_decode(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
 def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound):
     """The decode width, ``q`` ``[B, H, 1, hd]`` over float pages: the
     heads become the query rows of ONE head as wide as a pool row (see
-    the module docstring), so a block costs two products, not 2H."""
+    the module docstring), so a block costs two products, not 2H.  With
+    grouped K/V heads (a pool row of ``H_kv * hd`` lanes) row h holds
+    ``q_h`` in the lanes of ITS K/V head, ``h // (H / H_kv)``."""
     B, H, _, hd = q.shape
-    D = H * hd
+    D = k_pool.shape[2]
+    Hkv = D // hd
     Hp = -(-H // _at.SUBLANE) * _at.SUBLANE
-    # row h: q_h in lanes [h*hd, (h+1)*hd), zeros elsewhere
-    qbd = (q[:, :, 0, None, :]
-           * jnp.eye(H, dtype=q.dtype)[None, :, :, None]).reshape(B, H, D)
+    if Hkv == H:
+        own = jnp.eye(H, dtype=q.dtype)
+    else:  # [H, H_kv]: query head h reads K/V head h // rep
+        own = (jnp.arange(H)[:, None] // (H // Hkv)
+               == jnp.arange(Hkv)[None, :]).astype(q.dtype)
+    # row h: q_h in its K/V head's lanes, zeros elsewhere
+    qbd = (q[:, :, 0, None, :] * own[None, :, :, None]).reshape(B, H, D)
     qbd = jnp.pad(qbd, ((0, 0), (0, Hp - H), (0, 0)))[:, None]
     out = _sweep(qbd, k_pool, v_pool, tables, pos_map,
                  jnp.broadcast_to(positions, (B, Hp)), bound, None, None,
                  block_h=1, sm_scale=1.0 / math.sqrt(hd))  # [B, 1, Hp, D]
     # row h's own lanes are head h's context; the rest is other heads'
     # values under head h's weights, dropped
-    out = out[:, 0, :H].reshape(B, H, H, hd)
-    return jnp.einsum("bhhd->bhd", out)[:, :, None, :]
+    out = out[:, 0, :H].reshape(B, H, Hkv, hd)
+    if Hkv == H:
+        return jnp.einsum("bhhd->bhd", out)[:, :, None, :]
+    return jnp.einsum("bhgd,hg->bhd", out, own)[:, :, None, :]
+
+
+def _kv_heads(q, k_pool):
+    """K/V heads of a pool row for queries of ``q``'s head size."""
+    H, hd = q.shape[1], q.shape[3]
+    Hkv = k_pool.shape[2] // hd
+    if Hkv * hd != k_pool.shape[2] or H % Hkv:
+        raise InvalidArgumentError(
+            f"paged attention: a pool row of {k_pool.shape[2]} lanes holds "
+            f"no whole number of K/V heads of {hd} that {H} query heads "
+            f"divide into")
+    return Hkv
 
 
 def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
@@ -430,9 +451,11 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
 
     q: ``[B, H, T, hd]`` query block (T = 1 or the speculative ``1+k``
     verify width, or an admission bucket); k_pool/v_pool: ``[P+1, page,
-    H*hd]`` shared page pools in their stored order, a token's heads side
-    by side in one row (float, int8 or fp8-e4m3; the last page is the
-    write-drop page), ALREADY scattered with this step's K/V; tables:
+    H_kv*hd]`` shared page pools in their stored order, a token's K/V heads
+    side by side in one row (float, int8 or fp8-e4m3; the last page is the
+    write-drop page), ALREADY scattered with this step's K/V; ``H_kv`` is
+    read off the row's width and divides ``H`` (grouped heads: query head h
+    reads K/V head ``h // (H / H_kv)``; float pools only); tables:
     ``[B, G]`` i32 page-table rows with unmapped entries pre-clipped to
     a valid page (``jnp.maximum(table, 0)`` — their ``pos_map`` is -1);
     pos_map: ``[B, G*page]`` i32, the absolute position each cache entry
@@ -455,8 +478,21 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
             "paged_flash_decode: pass k_scale and v_scale together "
             "(or neither)")
     if q.shape[2] == 1 and k_scale is None and block_h is None:
+        _kv_heads(q, k_pool)
         return _decode_width(q, k_pool, v_pool, tables, pos_map, positions,
                              bound)
+    rep = q.shape[1] // _kv_heads(q, k_pool)
+    if rep > 1:
+        # the rep query heads of a K/V head are rep x T query rows of it
+        if k_scale is not None:
+            raise InvalidArgumentError(
+                "paged_flash_decode: quantized pools have one scale a "
+                "query head; grouped K/V heads are not among them")
+        B, H, T, hd = q.shape
+        out = paged_flash_decode(
+            q.reshape(B, H // rep, rep * T, hd), k_pool, v_pool, tables,
+            pos_map, jnp.tile(positions, (1, rep)), bound, block_h=block_h)
+        return out.reshape(B, H, T, hd)
     cfg = _paged_decode.resolve(q, k_pool, v_pool, tables, pos_map,
                                 positions, k_scale, v_scale, block_h=block_h)
     return _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound,
@@ -484,12 +520,15 @@ def paged_attention(q, k_pool, v_pool, gather_tab, mask, walk=None,
                                   k_scale, v_scale)
     B, H, _, hd = q.shape
     G, page = gather_tab.shape[1], k_pool.shape[1]
+    rep = H // _kv_heads(q, k_pool)
 
     def view(pool, *tail):
         # [P+1, page, *] pages → the slots' logical [B, H, C, *tail]
         t = jnp.take(pool, gather_tab, axis=0)  # [B,G,page,H*...]
-        t = t.reshape(B, G * page, H, *tail)
-        return jnp.moveaxis(t, 2, 1)
+        t = t.reshape(B, G * page, H // rep, *tail)
+        t = jnp.moveaxis(t, 2, 1)
+        # grouped K/V heads: query head h reads K/V head h // rep
+        return t if rep == 1 else jnp.repeat(t, rep, axis=1)
 
     kview, vview = view(k_pool, hd), view(v_pool, hd)
     if k_scale is not None:

@@ -372,6 +372,7 @@ class GenerationEngine:
         self._moe_experts = int(getattr(model, "moe_experts", 0) or 0)
         self._moe_pending = None
         self._moe_layers = 0  # expert layers a decode step runs (set at trace)
+        self._moe_pairs = 0   # (token, choice) pairs its routers make (ditto)
         self._moe_routed_cum = np.zeros(max(self._moe_experts, 1), np.int64)
         # batched multi-LoRA: capacity > 0 threads a per-slot adapter-id
         # column through every executable (warmup traces it with all -1,
@@ -714,6 +715,7 @@ class GenerationEngine:
         of those layers the expert got at least one token."""
         E = self._moe_experts
         self._moe_layers = len(ms.entries)  # static: read at trace time
+        self._moe_pairs = ms.pairs          # likewise
         return jnp.concatenate([ms.counts(E), ms.touched(E)[None]])
 
     def expert_counts(self) -> np.ndarray:
@@ -754,6 +756,8 @@ class GenerationEngine:
         m.incr("moe_dropped_tokens", dropped)
         m.incr("moe_experts_touched", int(c[2].sum()))
         m.incr("moe_layer_steps", self._moe_layers)
+        m.incr("moe_pairs_routed", self._moe_pairs)
+        m.incr("moe_pairs_local", routed)
         if self._warm:
             m.incr("moe_sampled_steps_after_warm")
             if dropped > 0:

@@ -129,7 +129,11 @@ HANDOFF_COUNTERS = ("handoffs_out", "handoffs_in")
 MOE_COUNTERS = ("moe_routed_tokens", "moe_dropped_tokens",
                 "moe_experts_touched", "moe_layer_steps",
                 "moe_sampled_steps_after_warm",
-                "moe_overflow_steps_after_warm")
+                "moe_overflow_steps_after_warm",
+                # (token, choice) pairs the decode steps' routers made, and
+                # those of them whose expert this engine's model holds (all
+                # of them unless the model holds a share of each layer)
+                "moe_pairs_routed", "moe_pairs_local")
 
 #: quantized-serving counters (``GenerationEngine(quantized=...)``):
 #: post-warmup decode steps served while the bound weight tree was NOT

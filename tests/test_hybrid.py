@@ -418,6 +418,9 @@ _LOCATION = re.compile(
 PROGRAMS_BEFORE = {
     "gpt": "29c4abe6536b4038a5d3f39371693a006dfab66d96d374ea230cd7345e01e3f6",
     "latent": "444987437563c7c729fe331e437a223b43795d807063c101384e9b26ac96abf1",
+    # the hybrid decoder at the parent of the commit that gave it grouped heads,
+    # rotary and experts (cc6cfe4)
+    "hybrid": "5b5e04967ab53c9ebeda0c418ff0a3099bfeb0a6b9bfd3d4855e321fb231c6d5",
 }
 
 
@@ -488,10 +491,14 @@ def _latent():
     return lfam.build_model(cfg, lfam.make_weights(cfg, 5))
 
 
-@pytest.mark.parametrize("which", ["gpt", "latent"])
+def _hybrid():
+    return build(tiny_cfg(cache_len=128))[0]
+
+
+@pytest.mark.parametrize("which", ["gpt", "latent", "hybrid"])
 def test_a_model_without_slot_state_builds_the_programs_it_built_before(
         which):
-    m = {"gpt": _gpt, "latent": _latent}[which]()
+    m = {"gpt": _gpt, "latent": _latent, "hybrid": _hybrid}[which]()
     m.eval()
     eng = engine(m, name=which)
     try:

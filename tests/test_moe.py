@@ -114,7 +114,8 @@ class TestRouting(unittest.TestCase):
 class TestDenseParity(unittest.TestCase):
     def test_forward_and_backward_bit_identical_to_dense_mlp(self):
         """Identically initialized experts + top-1 + capacity ≥ tokens:
-        the routed model IS the dense model, bit for bit, both ways."""
+        the routed model IS the dense model: the loss bit for bit, the
+        gradients to the last few places (see the assertion)."""
         E = 4
         pt.seed(0)
         net_d = GPTForCausalLM(gpt_tiny())
@@ -153,12 +154,29 @@ class TestDenseParity(unittest.TestCase):
         ld, gd = jax.jit(jax.value_and_grad(lossfn(net_d)))(pd)
         lm, gm = jax.jit(jax.value_and_grad(lossfn(net_m)))(pm)
         self.assertEqual(np.asarray(ld).tobytes(), np.asarray(lm).tobytes())
+        # The gradients are the same sums in another order.  The expert
+        # path's backward runs its matmuls per expert over capacity rows
+        # ([E, C, .] batches) where the dense path runs one [N, .] matmul:
+        # XLA blocks the two contractions differently, float addition does
+        # not associate, and everything upstream of the MLP's input
+        # gradient inherits the last-place difference.  This assertion
+        # compared bytes and stopped at the first leaf, `gpt.wte.weight`,
+        # on every run since the seed (ROADMAP D10); measured leaf by leaf,
+        # EVERY gradient differs, by 0.5 to 8 units in the last place of
+        # the leaf's largest entry (`gpt.blocks.0.ln1.weight` the 8,
+        # `gpt.wte.weight` 2.5).  An ulp of an entry's own value is no
+        # yardstick: the terms cancel.  Held to 16 ulps of the leaf's
+        # largest entry, twice the worst seen; a wrong dispatch, a dropped
+        # token or a combine weight other than 1 moves a leaf by 1e5 ulps
         for name in pd:
             if ".mlp." in name:
                 continue  # different parameterization; compared via sum
-            self.assertEqual(np.asarray(gd[name]).tobytes(),
-                             np.asarray(gm[name]).tobytes(),
-                             f"grad for {name} not bit-identical")
+            a, b = np.asarray(gd[name]), np.asarray(gm[name])
+            ulp = float(np.spacing(np.abs(a).max()))
+            self.assertLessEqual(
+                float(np.abs(a - b).max()), 16 * ulp,
+                f"grad for {name} more than 16 ulps of its largest entry "
+                f"from the dense model's")
         # gradients flow through dispatch into every expert weight, and
         # the expert copies' grads sum back to the dense MLP grad
         g = gm["gpt.blocks.0.mlp.expert_fc1"]

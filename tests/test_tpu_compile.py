@@ -515,3 +515,174 @@ def test_a_one_row_admission_of_the_long_prompt_models(chip, config, cls,
              if "tpu_custom_call" in line and " custom-call(" in line]
     for kernel, n in kernels.items():
         assert sum(kernel in c for c in calls) == n, (kernel, calls)
+
+
+# -- grouped heads, 128 x 128 states and a share of the experts: the kernels
+# -- at the qwen3_next_serve configuration's shapes -----------------------------
+def test_paged_decode_reads_2_kv_heads_of_256_for_16_query_heads(chip):
+    # a decode step of the qwen3_next cell: 64 slots x 128 pages of 16, pool
+    # rows of 2 x 256 = 512 lanes; the 16 query heads are the rows of one
+    # head as wide as a pool row, head h in the lanes of K/V head h // 8
+    from paddle_tpu.models.hybrid import _paged_flash
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    assert _paged_flash(256, 16)
+    B, H, Hkv, hd, page, G = 64, 16, 2, 256, 16, 128
+    pages = B * G + 1
+    _compiles_with_kernel(
+        chip, paged_flash_decode, ((B, H, 1, hd), bf16),
+        ((pages, page, Hkv * hd), bf16), ((pages, page, Hkv * hd), bf16),
+        ((B, G), i32), ((B, G * page), i32), ((B, 1), i32), ((B,), i32))
+
+
+def test_paged_decode_gives_grouped_heads_their_query_rows_at_a_width(chip):
+    # the general form (a verify width of 4): the 8 query heads of a K/V
+    # head become 8 x 4 query rows of it
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    B, H, Hkv, hd, page, G, T = 8, 16, 2, 256, 16, 128, 4
+    pages = B * G + 1
+    _compiles_with_kernel(
+        chip, paged_flash_decode, ((B, H, T, hd), bf16),
+        ((pages, page, Hkv * hd), bf16), ((pages, page, Hkv * hd), bf16),
+        ((B, G), i32), ((B, G * page), i32), ((B, T), i32), ((B,), i32))
+
+
+def test_flash_attention_16_query_heads_over_2_kv_heads_of_256(chip):
+    # the admission's prompt attention: 2 rows x the 1024 bucket; the K/V
+    # blocks of query head h are those of head h // 8 by the index map
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    _compiles_with_kernel(
+        chip, lambda q, k, v: flash_attention(q, k, v, causal=True),
+        ((2, 16, 1024, 256), bf16), ((2, 2, 1024, 256), bf16),
+        ((2, 2, 1024, 256), bf16))
+
+
+@pytest.mark.parametrize("T", [256, 1024])
+def test_gated_delta_chunk_16_key_heads_32_value_heads_of_128(chip, T):
+    # an admission of 2 rows x a bucket: q and k of 16 heads, v, the gates
+    # and the [128, 128] float32 state of 32
+    from paddle_tpu.ops import gated_delta as gd
+
+    B, Hk, Hv, d = 2, 16, 32, 128
+    _compiles_with_kernel(
+        chip, gd.gated_delta_chunk, ((B, T, Hk, d), f32), ((B, T, Hk, d), f32),
+        ((B, T, Hv, d), f32), ((B, T, Hv), f32), ((B, T, Hv), f32))
+
+
+def test_gated_delta_step_32_states_of_128_by_128_from_16_key_heads(chip):
+    # a decode step: 64 slots of the 65 stored rows (2.1 MB a slot),
+    # donated; a block of 8 value heads fetches its 4 key heads
+    from paddle_tpu.ops import gated_delta as gd
+
+    B, Hk, Hv, d = 64, 16, 32, 128
+    one = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
+        (B, Hk, d), (B, Hk, d), (B, Hv, d), (B, Hv), (B, Hv),
+        (B + 1, Hv, d, d))]
+    assert gd._step_heuristic(*args) == {"block_h": 8}
+    text = jax.jit(gd.gated_delta_step, donate_argnums=(5,)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text and "gated_delta_step" in text
+    assert "input_output_alias" in text
+    assert not [l for l in text.splitlines()
+                if " copy(" in l and "f32[65,32,128,128]" in l.split("=")[1]
+                .split("copy(")[0]]
+
+
+@pytest.mark.parametrize("rows,tile", [(64 * 10, 16), (2 * 1024 * 10, 128)],
+                         ids=["decode", "admit_2x1024"])
+def test_ragged_gated_mlp_128_held_of_512_experts_of_512(chip, rows, tile):
+    # one chip's share of a qwen3_next layer: the router's 512 ids, 128
+    # experts of 2048 x 512 held; a pair whose expert is absent has no row
+    from paddle_tpu.ops.grouped_matmul import (ragged_gated_mlp,
+                                               ragged_layout)
+
+    held, D, F = 128, 2048, 512
+
+    def fn(x, ids, wg, wu, wd):
+        lay = ragged_layout(ids, held, tile, partial=True)
+        n = lay["tiles"] * tile
+        src = jnp.full((n,), rows, i32).at[lay["dest"]].set(
+            jnp.arange(rows, dtype=i32), mode="drop")
+        xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[src]
+        ys = ragged_gated_mlp(xs, wg, wu, wd, lay)
+        return jnp.where(lay["present"][:, None],
+                         ys[jnp.minimum(lay["dest"], n - 1)], 0)
+
+    _compiles_with_kernel(chip, fn, ((rows, D), bf16), ((rows,), i32),
+                          ((held, D, F), bf16), ((held, D, F), bf16),
+                          ((held, F, D), bf16))
+
+
+@pytest.mark.parametrize("program,kernels", [
+    # one period of the model: three state kernels, the full layer's
+    # paged_decode, four expert kernels at the 16-row tile
+    ("step", {"gated_delta_step": 3, "paged_decode": 1,
+              "moe_gated_mlp_tm16": 4, "": 8}),
+    # a [2, 1024] admission: three chunk walks, the grouped flash kernel
+    # and four expert kernels at the 128-row tile
+    ("admit", {"gated_delta_chunk": 3, "moe_gated_mlp_tm128": 4,
+               "flash_fwd_grouped": 1, "": 8}),
+])
+def test_the_qwen3_next_engine_lowers_its_programs_for_the_chip(
+        chip, program, kernels):
+    # benchmarks/configs/qwen3_next_serve.json at published widths, one
+    # period of its layers, weights that are shapes only: 64 slots x 2048
+    # positions, the engine's own jitted programs with every TPU-only
+    # branch taken (slot state AND experts in one program)
+    import json
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.harness import loader
+    from paddle_tpu import nn
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    bench = os.path.join(repo, "benchmarks")
+    with open(os.path.join(bench, "configs", "qwen3_next_serve.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 4}
+    with open(os.path.join(bench, "traffic", "longgen_closed.json")) as f:
+        buckets = json.load(f)["prompt_buckets"]
+    fam = loader.load_module("families", cfg["family"], bench)
+    serve = cfg["serve"]
+    with nn.abstract_parameters():
+        model = fam.HybridForCausalLM(fam.model_config(cfg))
+    eng = GenerationEngine(
+        model, prompt_buckets=buckets, batch_size=serve["batch_size"],
+        cache_len=serve["cache_len"], kv_page_size=serve["kv_page_size"],
+        speculative_k=0, eos_token_id=None, name="compile-only-qnx")
+    try:
+        assert eng._admit_rows == {b: 2 for b in buckets}
+        one = SingleDeviceSharding(chip)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+        B, T, C = serve["batch_size"], buckets[-1], serve["cache_len"]
+        G = C // serve["kv_page_size"]
+        pool = on_chip(jax.eval_shape(eng._empty_pool))
+        assert pool["layers"][3]["k"].shape == (B * G + 1, 16, 512)
+        assert pool["layers"][0]["state"].shape == (B + 1, 32, 128, 128)
+        params, buffers = on_chip(eng._params), on_chip(eng._buffers)
+        if program == "step":
+            text = eng._step_jit.lower(params, buffers, ints(B, 2 + C + G),
+                                       pool).compile().as_text()
+        else:
+            text = eng._padmit.lower(
+                params, buffers, ints(2, T), ints(2, T), ints(2, C),
+                ints(2, G), ints(2), pool, None, ints(2)).compile().as_text()
+    finally:
+        eng.close()
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    for kernel, n in kernels.items():
+        assert sum(kernel in c for c in calls) == n, (kernel, calls)
