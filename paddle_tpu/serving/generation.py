@@ -30,9 +30,22 @@ unified decode/verify executable of static width ``1 +
 FLAGS_speculative_k``: an n-gram proposer (prompt-lookup) drafts up to k
 tokens per slot per step and the longest prefix matching the model's own
 argmax is accepted — token-identical to plain greedy, up to k+1 tokens per
-step when text repeats.  The loop runs serialized (each step harvested
-before the next dispatch) because drafting and page accounting depend on
-the previous step's tokens.
+step when text repeats.
+
+**One decode step in flight.**  The loop dispatches step n+1 BEFORE it
+reads step n's tokens and reads them while the device runs n+1: a slot's
+position, its pages and the end of its budget are known by count, and the
+one thing a step needs of the step before it, each row's last token,
+stays on the device (a row whose token is still unread carries ``-1`` for
+its id and the step program takes it from the previous step's output, its
+one operand beside the packed rows).  A slot whose budget ends with the
+step being dispatched is freed at that dispatch and its future resolves
+when the step is harvested (:class:`_Flight`, :meth:`_harvest`).  What
+needs the tokens on the host reads them first, and the iteration is then
+the serialized one: an engine that speculates drafts from them, so it
+reads every step before it packs the next; an admission, a preemption, a
+tenant over budget, a hand-off, ``close()``, new weights and a failed call
+all harvest the step in flight before they touch a slot.
 
 The admission program is ``[R(bucket), bucket]``, not ``[B, bucket]``: a
 few rows, as many as fit ``_ADMIT_TOKEN_SLOTS`` token slots and at most
@@ -108,6 +121,17 @@ from .metrics import (HANDOFF_COUNTERS, LOOP_COUNTERS, LORA_COUNTERS,
 from .paging import PagePool
 
 __all__ = ["GenerationEngine", "KVHandoff"]
+
+
+class _Flight(NamedTuple):
+    """A decode step that was dispatched and whose tokens are still on the
+    device: all :meth:`GenerationEngine._harvest` needs, captured at the
+    dispatch, so that a row is harvested into the request it was computed
+    for whatever its slot holds by then."""
+
+    out: object   # device handle of the step's tokens, [B, columns] int32
+    rows: tuple   # (slot, slot record, position, drafts) of each live row
+    moe: object   # device handle of its per-expert counts, or None
 
 _gen_counter = [0]
 
@@ -370,7 +394,7 @@ class GenerationEngine:
         # trace and a wrapper pops them off the jit output (_moe_tap) —
         # a 0-expert config builds the exact same executables as before
         self._moe_experts = int(getattr(model, "moe_experts", 0) or 0)
-        self._moe_pending = None
+        self._moe_last = None  # the newest step's counts handle
         self._moe_layers = 0  # expert layers a decode step runs (set at trace)
         self._moe_pairs = 0   # (token, choice) pairs its routers make (ditto)
         self._moe_routed_cum = np.zeros(max(self._moe_experts, 1), np.int64)
@@ -424,17 +448,21 @@ class GenerationEngine:
                                    table, lens, cache, aids, slots,
                                    buffers=buffers, training=False, call=body)
 
-        def pstep(params, buffers, packed, cache):
+        def pstep(params, buffers, packed, prev, cache):
             # the unified decode/verify step: T = 1 + speculative_k
             # columns (or the [B, 1] no-draft fast trace); rows with
             # position -1 (no draft / free slot) are inert.  All int32
             # per-step inputs ride ONE packed [B, 2T + C + G] transfer
-            # (ids | positions | pos_map | table) — the serialized loop
+            # (ids | positions | pos_map | table) — the loop
             # is dispatch-bound and one host transfer beats four.
+            # `prev` is the [B, 1] token column of the step dispatched
+            # before this one: a row whose first id is -1 (token ids are
+            # not negative) consumes the token that step computed for
+            # it, which the host has not read yet.
             # out[:, j] is the model's greedy next token after consuming
             # ids[:, :j+1] — column 0 is the plain decode token, columns
             # 1.. verify the drafts.
-            def body(packed, cache):
+            def body(packed, prev, cache):
                 traces["decode"] += 1
                 C = self._C
                 G = C // self._page
@@ -444,22 +472,26 @@ class GenerationEngine:
                 Tp = (packed.shape[1] - C - G - L) // 2
                 aids = packed[:, -1] if lora_on else None
                 tab = packed[:, 2 * Tp + C:packed.shape[1] - L]
+                ids = packed[:, :Tp]
+                ids = jnp.where(
+                    (ids < 0) & (jnp.arange(Tp, dtype=jnp.int32) == 0),
+                    prev, ids)
                 if self._moe_experts:
                     from ..moe import stats as moe_stats
 
                     with moe_stats.collect() as ms:
                         logits, cache = mdl.forward_paged(
-                            packed[:, :Tp], packed[:, Tp:2 * Tp],
+                            ids, packed[:, Tp:2 * Tp],
                             packed[:, 2 * Tp:2 * Tp + C], tab, cache,
                             adapter_ids=aids)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             cache, self._moe_sample(ms))
                 logits, cache = mdl.forward_paged(
-                    packed[:, :Tp], packed[:, Tp:2 * Tp],
+                    ids, packed[:, Tp:2 * Tp],
                     packed[:, 2 * Tp:2 * Tp + C], tab, cache,
                     adapter_ids=aids)
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-            return functional_call(mdl, params, packed, cache,
+            return functional_call(mdl, params, packed, prev, cache,
                                    buffers=buffers, training=False,
                                    call=body)
 
@@ -554,7 +586,7 @@ class GenerationEngine:
         # fresh-pool placement.  Admission is traced at each bucket's own
         # rows, [R(bucket), bucket], not [B, bucket].
         G = self._C // self._page
-        cache = self._init_pool()
+        prev, cache = self._init_pool()
         # sharded decode only: measured search over the collective
         # overlap schedule, BEFORE the production traces below (they
         # must be traced under the winning dials) and before
@@ -578,7 +610,7 @@ class GenerationEngine:
             self._params, self._buffers,
             self._pack_step(
                 np.zeros((B, T), np.int32),
-                np.full((B, T), -1, np.int32)), cache)
+                np.full((B, T), -1, np.int32)), prev, cache)
         if self._spec_k:
             # the no-draft fast path: a second [B, 1]-shaped trace of
             # the same step fn.  T=1 attention/logits are ~T x
@@ -589,7 +621,7 @@ class GenerationEngine:
                 self._params, self._buffers,
                 self._pack_step(
                     np.zeros((B, 1), np.int32),
-                    np.full((B, 1), -1, np.int32)), cache)
+                    np.full((B, 1), -1, np.int32)), prev, cache)
             # seed the loop's wide-vs-fast cost model with one timed
             # (warm, blocked) call per trace; the loop refines both
             # online from its own iteration times
@@ -601,7 +633,7 @@ class GenerationEngine:
                 for _ in range(2):
                     t0 = time.monotonic()
                     o, cache = self._step(self._params, self._buffers,
-                                          pk, cache)
+                                          pk, prev, cache)
                     np.asarray(o)
                     ms = (time.monotonic() - t0) * 1e3
                     best = ms if best is None else min(best, ms)
@@ -624,9 +656,9 @@ class GenerationEngine:
         from ..ops import autotune
         autotune.mark_warm()  # later tuner searches are hot-path (K701)
         _retry_mod.mark_warm()  # later retry storms / flaps are F801
-        # drop the last warmup step's pending expert counts so the
-        # dummy-data routing never lands in the post-warm S606 window
-        self._moe_pending = None
+        # the warm-up steps' expert counts go with their handles (only a
+        # harvested step's are folded): the dummy-data routing never lands
+        # in the post-warm S606 window
         self._warm = True  # starvation after this point is S603 material
         self._emit_quant()
         return self.compile_count
@@ -660,7 +692,8 @@ class GenerationEngine:
             try:
                 step = jax.jit(self._pstep)  # fresh trace under cfg dials
                 return _tengine.measure_ms(
-                    step, (self._params, self._buffers, pk, cache),
+                    step, (self._params, self._buffers, pk,
+                           self._no_prev(), cache),
                     repeats=2)
             finally:
                 plan_space.apply_decode_schedule(prev)
@@ -693,7 +726,7 @@ class GenerationEngine:
         snap = dict(self._traces)
         try:
             out = {"step": self._step_jit.lower(
-                self._params, self._buffers, i32(B, width),
+                self._params, self._buffers, i32(B, width), i32(B, 1),
                 pool).compile().as_text()}
             for sb in self._buckets:
                 R = self._admit_rows[sb]
@@ -728,27 +761,24 @@ class GenerationEngine:
         trailing ``[3, E]`` per-expert (routed, dropped, layers touched)
         counts array (:meth:`_moe_sample`):
         pop it off the output so every call site keeps its original
-        arity, and harvest the PREVIOUS call's counts — the one-step
-        deferral means the ``np.asarray`` sync always lands on an array
-        whose computation already finished."""
+        arity, and keep its handle for the caller that harvests the step
+        (the loop puts it into the step's :class:`_Flight` and reads it
+        with the step's tokens; :meth:`_moe_harvest` folds it)."""
 
         def tapped(*args, **kwargs):
             out = fn(*args, **kwargs)
-            self._moe_harvest()
-            self._moe_pending = out[-1]
+            self._moe_last = out[-1]
             return out[:-1]
 
         return tapped
 
-    def _moe_harvest(self):
-        """Fold the pending counts sample into the metrics: token totals,
-        post-warm sampled/overflow step counters (rule S606's ratio) and
-        the overflow-fraction / dead-expert gauges."""
-        pend = self._moe_pending
-        if pend is None:
+    def _moe_harvest(self, c: Optional[np.ndarray]):
+        """Fold one harvested step's ``[3, E]`` counts sample, on the host,
+        into the metrics: token totals, post-warm sampled/overflow step
+        counters (rule S606's ratio) and the overflow-fraction /
+        dead-expert gauges."""
+        if c is None:
             return
-        self._moe_pending = None
-        c = np.asarray(pend)
         routed, dropped = int(c[0].sum()), int(c[1].sum())
         self._moe_routed_cum += c[0].astype(np.int64)
         m = self.metrics
@@ -932,6 +962,86 @@ class GenerationEngine:
             r.future.set_result(res if res is not None
                                 else np.asarray(s["out"], np.int32))
 
+    def _harvest(self, flight: _Flight, host: np.ndarray, counts,
+                 slots: list, pos: np.ndarray, free_slot, cnt) -> bool:
+        """Harvest one decode step from its record, its tokens and its
+        expert counts (``None`` without experts) on the host: per row,
+        accept the longest draft prefix matching the model's own argmax,
+        append the tokens to the request the row was computed for, charge
+        its tenant, and resolve it where it ended (budget or EOS).  A
+        function of the record, not of ``slots``: by the time a step is
+        read the loop may have dispatched the next one, freed a row's slot
+        (its budget ended by count at the dispatch) and seated another
+        request there.  ``slots`` / ``pos`` are touched, and
+        ``free_slot(i)`` called, only for a row whose record still holds
+        its slot: an EOS, or any end with speculation on, where the count
+        is not known ahead.  A row whose request an EOS ended one step ago
+        was computed one token past that end: dropped and counted.
+        Returns whether a request ended (the loop then publishes)."""
+        pool, ten, eos, C = self._pool, self._tenancy, self._eos, self._C
+        self._moe_harvest(counts)
+        now = time.monotonic()
+        n_evicted = 0
+        evicted_traces: List = []
+        for i, s, p, prop in flight.rows:
+            if s.get("done"):
+                cnt["decode_tokens_stale"] += 1
+                continue
+            a = 0
+            while a < len(prop) and prop[a] == int(host[i, a]):
+                a += 1
+            # rejected drafts: their KV is stale — unmark it (overwritten
+            # when the real token arrives)
+            for j in range(a + 1, len(prop) + 1):
+                pool.pos_map[i, (p + j) % C] = -1
+            if prop:
+                cnt["spec_drafted"] += len(prop)
+                cnt["spec_accepted"] += a
+                # trailing acceptance estimate feeding the wide-step
+                # break-even decision
+                s["spec_ema"] = (
+                    0.5 * s.get("spec_ema", float(self._spec_k)) + 0.5 * a)
+                if a == 0:
+                    # exponential draft backoff (max 32 steps): proposer is
+                    # cold on this sequence; any acceptance resets it
+                    s["spec_fail"] = min(s.get("spec_fail", 0) + 1, 5)
+                    s["spec_cool"] = 1 << s["spec_fail"]
+                else:
+                    s["spec_fail"] = 0
+                # the dispatch counted the one token every row computes
+                pos[i] += a
+                s["sent"] += a
+            done = False
+            n_out = 0
+            for j in range(a + 1):
+                t = int(host[i, j])
+                s["out"].append(t)
+                s["hist"].append(t)
+                n_out += 1
+                if (len(s["out"]) >= s["budget"]
+                        or (eos is not None and t == eos)):
+                    done = True
+                    break
+            if ten is not None and n_out and s.get("tenant"):
+                ten.charge(s["tenant"], n_out)
+            if done:
+                s["done"] = True
+                if s["req"].trace is not None:
+                    evicted_traces.append(s["req"].trace)
+                if slots[i] is s:
+                    free_slot(i)
+                self._finish(s, now)
+                n_evicted += 1
+        if n_evicted:
+            tr = _tracing._active
+            if tr is not None and evicted_traces:
+                ev_ms = (time.monotonic() - now) * 1e3
+                for ctx in evicted_traces:
+                    tr.record("slot/evict", ctx, now, ev_ms, kind="evict",
+                              args={"engine": self.name})
+            cnt["evicted"] += n_evicted
+        return n_evicted > 0
+
     def _new_pool(self) -> PagePool:
         return PagePool(self._batch, self._kv_pages, self._page, self._C)
 
@@ -943,14 +1053,21 @@ class GenerationEngine:
         against (host-built arrays would silently recompile
         placement-specialised variants of admission and step on first
         use), and the fresh-pool placement variant of the step gets built
-        here, during warmup, not on first live use."""
+        here, during warmup, not on first live use.  Returns ``(prev,
+        pool)``: what the next step takes for the previous step's tokens
+        is likewise a step's output where the loop runs ahead (no
+        speculation), and the host's zeros where it never does."""
         B, T = self._batch, 1 + self._spec_k
-        _, cache = self._step(
+        out, cache = self._step(
             self._params, self._buffers,
             self._pack_step(np.zeros((B, T), np.int32),
                             np.full((B, T), -1, np.int32)),
-            self._empty_pool())
-        return cache
+            self._no_prev(), self._empty_pool())
+        return (self._no_prev() if self._spec_k else out), cache
+
+    def _no_prev(self) -> np.ndarray:
+        """The step's ``prev`` operand where no row reads it: ``[B, 1]``."""
+        return np.zeros((self._batch, 1), np.int32)
 
     def _empty_pool(self):
         """The model's cache, zeroed: the page pools and, for a model with
@@ -1097,15 +1214,32 @@ class GenerationEngine:
         covers their page demand (prefill lands straight in the pool —
         shared-prefix pages come mapped, not recomputed), then one
         unified decode/verify step for all live slots with n-gram drafts
-        in the extra columns, then immediate harvest — accept the
+        in the extra columns, then the harvest (:meth:`_harvest`) of the
+        oldest step whose tokens are unread — accept the
         longest draft prefix matching the model's own argmax, invalidate
         the rest via the position map.  CoW page copies collected from
         admission / first-divergent-write are dispatched before the step
         they protect.  Pool exhaustion mid-decode preempts the NEWEST
         slot (its request requeues and regenerates bit-identically);
-        eviction is a pure host table edit.  The loop is serialized (no
-        double buffering) because drafting and page accounting need the
-        previous step's tokens before the next dispatch.
+        eviction is a pure host table edit.
+
+        One step in flight.  Without speculation the step read after a
+        dispatch is the one BEFORE it: positions, pages and a budget's end
+        follow from counts, a row's last token stays on the device (id
+        ``-1``: taken from ``prev``, the previous step's output), and the
+        host reads step n while the device runs n+1.  ``flights`` holds the
+        steps dispatched and not yet harvested, oldest first: one between
+        iterations, two between a dispatch and the read that follows it.
+        The device runs its programs in dispatch order and each threads
+        the one donated pool, so pages freed at a dispatch can be mapped
+        by the admission dispatched next.  Whatever needs the tokens on
+        the host calls ``drain`` first and then runs as it always did:
+        speculation (drafts come from the tokens: every step is read
+        before the next is packed), an admission (once its calls are
+        dispatched, so the read hides behind them), a preemption, a tenant
+        over budget, a hand-off, ``close()``, new weights, nothing live.
+        A failed device call surfaces where its tokens are read, one
+        iteration late: the restart requeues the rows in flight too.
 
         Steps where no slot drafts run a ``[B, 1]`` fast trace of the
         same step fn instead of the wide ``[B, 1+k]`` verify trace, and
@@ -1119,7 +1253,8 @@ class GenerationEngine:
         ``serve/*`` phases of ``metrics.LOOP_PHASES`` (trace spans on this
         thread and ``loop_us_*`` counters), ``ph.counts`` gathers the
         iteration's work counts for the one ``metrics.add`` of its
-        ``flush``, each counted once its dispatch has returned.
+        ``flush``, each counted once its dispatch has returned (what comes
+        from a step's tokens, once it is harvested).
         """
         q = self._batcher
         B, C, page = self._batch, self._C, self._page
@@ -1134,6 +1269,10 @@ class GenerationEngine:
         ten = self._tenancy
         pool = self._pool
         cache = None                       # device handles: the page pool
+        prev = None                        # ... and the last step's tokens
+        flights: List[_Flight] = []        # steps dispatched, not yet read
+        ahead = k_max == 0                 # drafts need the tokens: serial
+        swapped = self._params             # the weights the last step took
         carry: List[tuple] = []            # (Request, n_restarts) to re-admit
         last_pub = 0.0
         # self-measured iteration costs (ms) of the [B, 1] fast trace vs
@@ -1161,10 +1300,7 @@ class GenerationEngine:
                 return None
             v = max(victims, key=lambda i: (slots[i]["t0"], i))
             vs = slots[v]
-            pool.release(v)
-            slots[v] = None
-            pos[v] = -1
-            aidsv[v] = -1
+            free_slot(v)
             # regeneration from the prompt is deterministic greedy —
             # the requeued request produces bit-identical tokens
             carry.insert(0, (vs["req"], vs["restarts"]))
@@ -1180,6 +1316,32 @@ class GenerationEngine:
                 ph.to("sched")
             return got
 
+        def free_slot(i):
+            pool.release(i)
+            slots[i] = None
+            pos[i] = -1
+            aidsv[i] = -1
+
+        def read_oldest() -> int:
+            # the blocking wait belongs to the open decode.device phase,
+            # the harvest is host work; a record leaves `flights` only once
+            # harvested, so a failed read requeues its rows
+            nonlocal force_pub
+            host, counts = jax.device_get((flights[0].out, flights[0].moe))
+            dt = ph.to("harvest")
+            force_pub |= self._harvest(flights[0], host, counts, slots, pos,
+                                       free_slot, cnt)
+            del flights[0]
+            return dt
+
+        def drain(back):
+            # read every step in flight, then return to phase `back`
+            if flights:
+                ph.to("decode.device", engine=self.name)
+                while flights:
+                    read_oldest()
+                ph.to(back)
+
         ph = LoopClock(self.metrics)
         cnt = ph.counts
         try:
@@ -1188,6 +1350,10 @@ class GenerationEngine:
                     ph.to("sched")
                     force_pub = False
                     closing = q.closing
+                    if closing or all(s is None for s in slots):
+                        # shutting down, or every slot ended by count at
+                        # the last dispatch: nothing to run ahead of
+                        drain("sched")
                     if closing and not q.drain_on_close:
                         err = UnavailableError(
                             f"{self.name}: dropped at shutdown "
@@ -1215,26 +1381,28 @@ class GenerationEngine:
                     # once the tenant is back in budget
                     if ten is not None and live:
                         over = ten.over_budget()
+                        if flights and any(slots[i].get("tenant") in over
+                                           for i in live):
+                            # charges trail the dispatch by one step
+                            drain("sched")
+                            over = ten.over_budget()
                         if over:
                             npre = 0
-                            for i in list(live):
+                            for i in live:
                                 s = slots[i]
                                 if s is None or s.get("tenant") not in over:
                                     continue
-                                pool.release(i)
                                 carry.insert(0, (s["req"], s["restarts"]))
                                 ten.note_preempted(s.get("tenant"))
-                                slots[i] = None
-                                pos[i] = -1
-                                aidsv[i] = -1
+                                free_slot(i)
                                 npre += 1
                             if npre:
                                 self.metrics.incr("preempted", npre)
                                 self.metrics.incr("tenant_preempted", npre)
-                                live = [i for i in range(B)
-                                        if slots[i] is not None]
-                                free = [i for i in range(B)
-                                        if slots[i] is None]
+                            live = [i for i in range(B)
+                                    if slots[i] is not None]
+                            free = [i for i in range(B)
+                                    if slots[i] is None]
 
                     # ---- admission: FCFS (or weighted-fair under a
                     # TenantScheduler), gated by the breaker AND the
@@ -1316,8 +1484,10 @@ class GenerationEngine:
                     n_adopted = 0
                     if take:
                         ph.to("admit.host", engine=self.name, rows=len(take))
+                        if any(r.meta[3] is not None for r, _ in take):
+                            drain("admit.host")  # a hand-off: serial
                         if cache is None:
-                            cache = self._init_pool()
+                            prev, cache = self._init_pool()
                         now = time.monotonic()
                         # hand-off adoptions first: no prefill compute at
                         # all — map fresh pages, scatter the exported KV
@@ -1346,7 +1516,7 @@ class GenerationEngine:
                                 cache = self._import(cache, kvp, dst)
                             t = int(hand.first_token)
                             slots[i] = {"req": r, "budget": budget,
-                                        "out": [t], "t0": now,
+                                        "out": [t], "sent": 1, "t0": now,
                                         "restarts": nre,
                                         "tenant": tenant,
                                         "hist": [int(x) for x in prompt]
@@ -1366,11 +1536,8 @@ class GenerationEngine:
                                     args={"engine": self.name, "slot": i})
                             if (hand.done or budget <= 1
                                     or (eos is not None and t == eos)):
-                                pool.release(i)
                                 self._finish(slots[i], time.monotonic())
-                                slots[i] = None
-                                pos[i] = -1
-                                aidsv[i] = -1
+                                free_slot(i)
                                 n_adevicted += 1
                         cnt["admitted"] += n_adopted
                         cnt["evicted"] += n_adevicted
@@ -1385,8 +1552,10 @@ class GenerationEngine:
                             cow_pairs += [(s_, d_, i) for s_, d_ in pairs]
                             pos[i] = len(prompt)
                             aidsv[i] = aid
+                            # "sent": the tokens computed or in flight;
+                            # ahead of len("out") while a step is unread
                             slots[i] = {"req": r, "budget": budget,
-                                        "out": [], "t0": now,
+                                        "out": [], "sent": 1, "t0": now,
                                         "restarts": nre,
                                         "tenant": tenant,
                                         "handoff": hand is True,
@@ -1449,6 +1618,9 @@ class GenerationEngine:
                                 jnp.asarray(lens), cache,
                                 self._aids_arg(ra), self._slots_arg(rs))
                             firsts.append(first)
+                        # the step in flight ran ahead of these calls: read
+                        # and harvest it while the device runs them
+                        drain("admit.device")
                         # serial harvest: a chunk's first n rows are the
                         # next n of `admitted`, the rest of its rows inert
                         host_first = np.concatenate([
@@ -1510,11 +1682,8 @@ class GenerationEngine:
                                          or (eos is not None
                                              and t == eos)))
                                 self.metrics.incr("handoffs_out")
-                                pool.release(i)
                                 self._finish(s, now)
-                                slots[i] = None
-                                pos[i] = -1
-                                aidsv[i] = -1
+                                free_slot(i)
                                 n_evicted += 1
                                 continue
                             s["out"].append(t)
@@ -1523,11 +1692,8 @@ class GenerationEngine:
                                 ten.charge(s["tenant"], 1)
                             if (len(s["out"]) >= s["budget"]
                                     or (eos is not None and t == eos)):
-                                pool.release(i)
                                 self._finish(s, now)
-                                slots[i] = None
-                                pos[i] = -1
-                                aidsv[i] = -1
+                                free_slot(i)
                                 n_evicted += 1
                         cnt.update(admitted=len(admitted), batches=1,
                                    evicted=n_evicted,
@@ -1589,10 +1755,14 @@ class GenerationEngine:
                             self.metrics.incr(
                                 "tenant_starved_steps_after_warm")
 
-                    # ---- unified decode/verify step (serialized) ----
+                    # ---- unified decode/verify step ----
                     dispatched = bool(take)
                     if live:
                         ph.to("decode.pack")
+                        if self._params is not swapped:
+                            # new weights: from a harvested state
+                            drain("decode.pack")
+                            swapped = self._params
                         # pass 1 — propose: drafts only while the ring has
                         # spare slots (once positions reach C, every slot
                         # holds a live window position, and a multi-token
@@ -1652,20 +1822,30 @@ class GenerationEngine:
                                                 (pr[0], pr[1], i))
                                     break
                                 except MemoryError:
-                                    v = preempt_newest()
-                                    if v is not None:
-                                        # drop the victim's pending
-                                        # copies: its freed dst pages may
-                                        # be re-allocated this very step
-                                        cow_pairs = [
-                                            t for t in cow_pairs
-                                            if t[2] != v]
+                                    if flights:
+                                        # an EOS in the step in flight
+                                        # may free pages, and a victim is
+                                        # chosen among harvested slots
+                                        drain("decode.pack")
+                                    else:
+                                        preempt_newest()
+                                    # drop the pending copies of a slot
+                                    # that ended: its freed dst pages may
+                                    # be re-allocated this very step
+                                    cow_pairs = [
+                                        t for t in cow_pairs
+                                        if slots[t[2]] is not None]
                             s = slots[i]
                             if s is None:
                                 continue  # preempted itself
                             for j in range(len(prop) + 1):
                                 pool.pos_map[i, (p + j) % C] = p + j
-                            ids[i, 0] = s["hist"][-1]
+                            # a token still in flight stays on the device:
+                            # -1 takes it from `prev` (read or not by now,
+                            # that column holds it)
+                            ids[i, 0] = (s["hist"][-1]
+                                         if s["sent"] == len(s["out"])
+                                         else -1)
                             pp[i, 0] = p
                             for j, d in enumerate(prop):
                                 ids[i, 1 + j] = d
@@ -1678,8 +1858,7 @@ class GenerationEngine:
                         # no slot drafting this step -> the [B, 1] fast
                         # trace (same fn, same math on column 0; rejected
                         # columns simply don't exist to compute)
-                        Td = (T if any(slots[i] is not None
-                                       and slots[i].get("_prop")
+                        Td = (T if any(slots[i].get("_prop")
                                        for i in live) else 1)
                         packed = self._pack_step(ids[:, :Td], pp[:, :Td],
                                                  pool.pos_map, pool.table,
@@ -1689,16 +1868,17 @@ class GenerationEngine:
                         ph.to("decode.device", engine=self.name,
                               live=len(live), columns=Td)
                         out, cache = self._step(self._params, self._buffers,
-                                                packed, cache)
+                                                packed, prev, cache)
+                        if ahead:
+                            prev = out
                         # while the device runs: the pages the paged_decode
                         # kernel's sweep is bounded to, by the rule and
                         # from the arrays the program was given
                         n_swept = int(sweep_bound(key_visible(
                             pool.pos_map[:, None, :], pp[:, :Td, None], C),
                             self._page).sum())
-                        host = np.asarray(out)  # serial harvest
-                        dt = ph.to("harvest") / 1e6
                         cnt.update(decode_steps=1, live_slot_steps=len(live),
+                                   decode_steps_ahead=len(flights),
                                    kv_pages_live_steps=n_pages,
                                    kv_pages_swept_steps=n_swept,
                                    kv_page_slots_steps=B * G)
@@ -1706,13 +1886,6 @@ class GenerationEngine:
                             # every slot's rows, live or not: in and out
                             cnt["state_bytes_steps"] += (
                                 2 * B * self._model.slot_state_bytes())
-                        if Td == 1:
-                            it_fast = (dt if it_fast is None
-                                       else 0.8 * it_fast + 0.2 * dt)
-                        else:
-                            it_wide = (dt if it_wide is None
-                                       else 0.8 * it_wide + 0.2 * dt)
-                        self.metrics.set_gauge("decode_step_ms", dt)
                         self._note_quant_step()
                         self.metrics.observe_occupancy(len(live) / B)
                         if self._lora_cap:
@@ -1721,72 +1894,37 @@ class GenerationEngine:
                             for i in live:
                                 if aidsv[i] >= 0:
                                     self._adapter_hits[aidsv[i]] += 1
-                        now = time.monotonic()
-                        n_evicted = 0
-                        evicted_traces: List = []
+                        flight = _Flight(out, tuple(
+                            (i, slots[i], int(pp[i, 0]),
+                             slots[i].pop("_prop")) for i in live),
+                            self._moe_last)
+                        flights.append(flight)
+                        for handle in (flight.out, flight.moe):
+                            if handle is not None:
+                                # on the host by the time it is read
+                                handle.copy_to_host_async()
+                        # by count: every row computes one token, and a
+                        # budget that ends with it ends the slot here (its
+                        # pages go to whatever is dispatched next; the
+                        # future resolves at this step's harvest).  With
+                        # drafts the count is the harvest's to make
                         for i in live:
                             s = slots[i]
-                            prop = s.pop("_prop", [])
-                            p = int(pos[i])
-                            a = 0
-                            while a < len(prop) and prop[a] == int(
-                                    host[i, a]):
-                                a += 1
-                            # rejected drafts: their KV is stale — unmark
-                            # it (overwritten when the real token arrives)
-                            for j in range(a + 1, len(prop) + 1):
-                                pool.pos_map[i, (p + j) % C] = -1
-                            if prop:
-                                cnt["spec_drafted"] += len(prop)
-                                cnt["spec_accepted"] += a
-                                # trailing acceptance estimate feeding
-                                # the wide-step break-even decision
-                                s["spec_ema"] = (
-                                    0.5 * s.get("spec_ema", float(k_max))
-                                    + 0.5 * a)
-                                if a == 0:
-                                    # exponential draft backoff (max 32
-                                    # steps): proposer is cold on this
-                                    # sequence; any acceptance resets it
-                                    s["spec_fail"] = min(
-                                        s.get("spec_fail", 0) + 1, 5)
-                                    s["spec_cool"] = 1 << s["spec_fail"]
-                                else:
-                                    s["spec_fail"] = 0
-                            pos[i] = p + a + 1
-                            done = False
-                            n_out = 0
-                            for j in range(a + 1):
-                                t = int(host[i, j])
-                                s["out"].append(t)
-                                s["hist"].append(t)
-                                n_out += 1
-                                if (len(s["out"]) >= s["budget"]
-                                        or (eos is not None and t == eos)):
-                                    done = True
-                                    break
-                            if ten is not None and n_out and \
-                                    s.get("tenant"):
-                                ten.charge(s["tenant"], n_out)
-                            if done:
-                                if s["req"].trace is not None:
-                                    evicted_traces.append(s["req"].trace)
-                                pool.release(i)
-                                self._finish(s, now)
-                                slots[i] = None
-                                pos[i] = -1
-                                aidsv[i] = -1
-                                n_evicted += 1
-                        if n_evicted:
-                            tr = _tracing._active
-                            if tr is not None and evicted_traces:
-                                ev_ms = (time.monotonic() - now) * 1e3
-                                for ctx in evicted_traces:
-                                    tr.record("slot/evict", ctx, now,
-                                              ev_ms, kind="evict",
-                                              args={"engine": self.name})
-                            cnt["evicted"] += n_evicted
-                            force_pub = True
+                            pos[i] += 1
+                            s["sent"] += 1
+                            if ahead and s["sent"] >= s["budget"]:
+                                free_slot(i)
+                        # read the oldest step unread: the one before this
+                        # where the loop runs ahead, else this one
+                        while len(flights) > int(ahead):
+                            dt = read_oldest() / 1e6
+                            if Td == 1:
+                                it_fast = (dt if it_fast is None
+                                           else 0.8 * it_fast + 0.2 * dt)
+                            else:
+                                it_wide = (dt if it_wide is None
+                                           else 0.8 * it_wide + 0.2 * dt)
+                            self.metrics.set_gauge("decode_step_ms", dt)
                         dispatched = True
 
                     if not dispatched and not blocked_wait:
@@ -1835,11 +1973,15 @@ class GenerationEngine:
                     if self.breaker is not None:
                         self.breaker.record_failure(0)
                     survivors: List[tuple] = []
-                    for i in range(B):
-                        s = slots[i]
-                        slots[i] = None
-                        if s is None:
-                            continue
+                    # ... and the rows of the steps in flight whose slots
+                    # were freed at the dispatch (a budget's end by count):
+                    # their tokens were never read
+                    lost = {id(s): s for s in slots if s is not None}
+                    lost.update((id(s), s) for f in flights
+                                for _, s, _, _ in f.rows if not s.get("done"))
+                    flights.clear()
+                    slots[:] = [None] * B
+                    for s in lost.values():
                         if is_transient(e) and s["restarts"] < max_restarts:
                             survivors.append((s["req"], s["restarts"] + 1))
                         else:
@@ -1848,7 +1990,7 @@ class GenerationEngine:
                                 s["req"].future.set_exception(e)
                     pos[:] = -1
                     aidsv[:] = -1
-                    cache = None
+                    cache = prev = None
                     pool = self._pool = self._new_pool()
                     carry = survivors + carry
                     if survivors:

@@ -20,7 +20,7 @@ starved_steps_after_warm`` plus per-step gauges (``set_gauge``) such as
 the starvation counters.
 
 The decode loop publishes the gauge ``decode_step_ms`` (the last
-step's measured device call) and the ``LOOP_COUNTERS`` family: its wall
+``decode.device`` interval) and the ``LOOP_COUNTERS`` family: its wall
 time by phase in integer microseconds (``loop_us_<phase>``; the phases
 tile every iteration and sum to ``loop_us_total``), the work it
 dispatched (admission rows/tokens against the row slots and the
@@ -28,7 +28,18 @@ dispatched (admission rows/tokens against the row slots and the
 bucket's rows by ``generation.admit_rows``;
 live slots and live page-table entries against ``B`` and ``B x G`` per
 decode step) and the summed queue wait and time to first token of the
-requests it admitted.  Beside each sum,
+requests it admitted.  The loop keeps one decode step in flight: it
+dispatches step n+1 before it reads step n's tokens.  Work is still
+counted once its dispatch has returned; what is derived from a step's
+TOKENS (``evicted``, ``completed``, ``tokens``, the tenants' charges, the
+experts' counts) is counted when the step is harvested and so trails the
+dispatch by one step.  ``decode_steps_ahead`` counts the decode steps
+dispatched while the step before them was still unread (over
+``decode_steps``: how often the loop ran ahead; 0 for an engine that
+speculates, which drafts from the tokens and so reads every step before
+the next), ``decode_tokens_stale`` the tokens a step computed for a row
+whose request the step before it had already ended (an ``eos_token_id``
+seen one step late; dropped, never returned).  Beside each sum,
 ``loop_max_us_<phase>`` is the phase's longest single interval since the
 engine started, so that a stall shows as one call of one phase and not
 as a mean that crept.  The same phases are ``serve/<phase>`` spans on
@@ -83,10 +94,12 @@ SLOT_COUNTERS = ("admitted", "evicted", "decode_steps", "restarts",
 #: inputs, CoW dispatch, first-token book-keeping), ``admit.device`` (the
 #: prefill calls, one per chunk of rows, until the last one's first tokens
 #: are on the host), ``decode.pack``
-#: (drafts, page growth, step inputs), ``decode.device`` (the step call
-#: until its tokens are on the host), ``harvest`` (accept/finish per
-#: slot), ``publish``, ``wait`` (sleeps and blocking polls with nothing
-#: live)
+#: (drafts, page growth, step inputs), ``decode.device`` (the dispatch of
+#: a step plus the blocking wait for the tokens of the step read next: the
+#: one before it where the loop runs one step ahead, its own where it
+#: cannot), ``harvest`` (accept/finish per row of the step just read: host
+#: work only), ``publish``, ``wait`` (sleeps and blocking polls with
+#: nothing live)
 LOOP_PHASES = ("sched", "admit.host", "admit.device", "decode.pack",
                "decode.device", "harvest", "publish", "wait")
 _PHASE_KEY = {p: "loop_us_" + p.replace(".", "_")
@@ -103,13 +116,15 @@ _MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
 #: filled apart from the bucket's padding; ``kv_page_slots_steps`` is
 #: ``B x G`` per decode step: the denominators of the useful shares;
 #: ``kv_pages_swept_steps`` is the part of ``B x G`` inside the slots' sweep bounds, what the
-#: ``paged_decode`` kernel walks), and the summed per-request
-#: times whose count is ``admit_rows``
+#: ``paged_decode`` kernel walks; ``decode_steps_ahead`` /
+#: ``decode_tokens_stale``: the module docstring), and the summed
+#: per-request times whose count is ``admit_rows``
 LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
                  "admit_steps", "admit_rows", "admit_row_slots",
                  "admit_tokens", "admit_token_slots", "live_slot_steps",
                  "kv_pages_live_steps", "kv_pages_swept_steps",
                  "kv_page_slots_steps",
+                 "decode_steps_ahead", "decode_tokens_stale",
                  "queue_wait_us", "ttft_us")
 
 #: page-accounting counters (see ``extra_counters``)

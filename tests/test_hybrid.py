@@ -412,21 +412,45 @@ _LOCATION = re.compile(
     r',?\s*(source_file="[^"]*"|(source_(end_)?(line|column)|stack_frame_id)'
     r'=\d+)|\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*')
 
-#: sha256 of ``compiled_programs()`` (source locations removed) of the two
-#: engines below at the parent of the commit that gave the engine slot state
-#: (dea3933, this suite's 8-device CPU configuration, produced by this code)
+#: sha256 of ``compiled_programs()`` (source locations removed) of the three
+#: engines below, this suite's 8-device CPU configuration, produced by this
+#: code at the commit that gave the step program the previous step's token
+#: column (PR 34: one operand, ``prev``, and a select on each row's first id;
+#: before it the digests were 29c4abe6..., 44498743... (dea3933's parent) and
+#: 5b5e0496... (cc6cfe4), and a diff of the step's text showed that operand
+#: and the renumbering behind it, nothing else)
 PROGRAMS_BEFORE = {
-    "gpt": "29c4abe6536b4038a5d3f39371693a006dfab66d96d374ea230cd7345e01e3f6",
-    "latent": "444987437563c7c729fe331e437a223b43795d807063c101384e9b26ac96abf1",
-    # the hybrid decoder at the parent of the commit that gave it grouped heads,
-    # rotary and experts (cc6cfe4)
-    "hybrid": "5b5e04967ab53c9ebeda0c418ff0a3099bfeb0a6b9bfd3d4855e321fb231c6d5",
+    "gpt": "9472a0fede8a48f4651836c002efdb5c8f05191c88dfb1ddd1ab38246b0494b7",
+    "latent": "2023c79525874e792d49224bfe94752e377ce1ad06802907a81cd977bd0dcd69",
+    "hybrid": "4967c2ee7a859a6a8dce6d9c758a088c0b4a050c00f4329eb199b245f3bcc4cc",
+}
+#: ... and of each admission program alone, at that commit's PARENT (2441a91,
+#: produced by the parent's code): the step's new operand left them as they
+#: were
+ADMIT_BEFORE = {
+    "gpt": {
+        "admit[16]":
+        "bd0fc62c1b0bf1f07ff5db05314e6d699c737b770442191a2d3b1a11b11f6d33",
+        "admit[32]":
+        "2cf8fc1d6785e848730dcf58b1428e48141397545a4348066b016a1f5c02dae8"},
+    "hybrid": {
+        "admit[16]":
+        "9f003015ed6fcf4b6f1bf77f858ccda2bd2fdb7e2b7eadbc9682ed31139abe36",
+        "admit[32]":
+        "a1f2712367897522fe70a20ebbd6172e1b22f9346c5307f9aafd44be5f80de8a"},
+    "latent": {
+        "admit[16]":
+        "9be4ccc8956ca37b42428892bc82897c9439cd87a405a213f043ad27bd6991f5",
+        "admit[32]":
+        "4e79eb71c15dda631fb294db21a0d89d96b5b4864f15c02ad40418396d0fafc0"},
 }
 
 
-def _digest(eng):
-    eng.warmup()
-    texts = eng.compiled_programs()
+def _sha(text):
+    return hashlib.sha256(_LOCATION.sub("", text).encode()).hexdigest()
+
+
+def _digest(texts):
     return hashlib.sha256("\n".join(
         _LOCATION.sub("", texts[k]) for k in sorted(texts)).encode()
     ).hexdigest()
@@ -459,7 +483,7 @@ def test_gpt2s_benchmark_engine_lowers_the_two_row_programs():
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
         want = {"step": eng._step_jit.lower(
-            eng._params, eng._buffers, i32(B, 2 + C + G), pool)}
+            eng._params, eng._buffers, i32(B, 2 + C + G), i32(B, 1), pool)}
         for sb in buckets:
             want[f"admit[{sb}]"] = eng._padmit.lower(
                 eng._params, eng._buffers, i32(2, sb), i32(2, sb), i32(2, C),
@@ -502,6 +526,10 @@ def test_a_model_without_slot_state_builds_the_programs_it_built_before(
     m.eval()
     eng = engine(m, name=which)
     try:
-        assert _digest(eng) == PROGRAMS_BEFORE[which]
+        eng.warmup()
+        texts = eng.compiled_programs()
     finally:
         eng.close()
+    assert {k: _sha(t) for k, t in texts.items()
+            if k != "step"} == ADMIT_BEFORE[which]
+    assert _digest(texts) == PROGRAMS_BEFORE[which]

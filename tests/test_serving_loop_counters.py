@@ -127,16 +127,27 @@ def test_served_tokens_are_the_uncached_greedy_reference(model, served):
 
 def test_a_phases_longest_interval_lies_between_its_mean_and_its_sum(served):
     s = served["snap"]
-    # one interval a decode step, and one an admitting iteration: all of
-    # its chunks' calls are dispatched inside the one admit.device phase
+    # one interval an admitting iteration: all of its chunks' calls are
+    # dispatched inside the one admit.device phase; a decode step opens
+    # one at its dispatch, and one more where it is read on its own (behind
+    # an admission's dispatch, or with nothing left to run ahead of it)
     intervals = {"admit_device": s["batches"],
-                 "decode_device": s["decode_steps"]}
+                 "decode_device": 2 * s["decode_steps"]}
     for k in PHASE_KEYS:
         longest = s[k.replace("loop_us_", "loop_max_us_")]
         assert 0 <= longest <= s[k], k
         n = intervals.get(k[len("loop_us_"):])
         if n:  # the longest interval is no shorter than the mean
             assert longest >= s[k] // n, k
+
+
+def test_steps_ahead_are_some_of_the_steps_and_no_token_is_stale(served):
+    s = served["snap"]
+    # requests of 3 to 14 tokens on two slots: pure decode iterations run
+    # ahead, the step behind an admission does not; no EOS, so no step
+    # computed a token past a request's end
+    assert 0 < s["decode_steps_ahead"] < s["decode_steps"]
+    assert s["decode_tokens_stale"] == 0
 
 
 def test_live_slot_steps_is_bounded_by_the_slots(served):
@@ -195,9 +206,9 @@ def test_swept_pages_are_the_bounds_the_step_program_was_given():
         eng.warmup()
         step = eng._step
 
-        def spy(params, buffers, packed, pool):
+        def spy(params, buffers, packed, prev, pool):
             seen.append(np.array(packed))
-            return step(params, buffers, packed, pool)
+            return step(params, buffers, packed, prev, pool)
 
         eng._step = spy
         before = eng.metrics.snapshot()

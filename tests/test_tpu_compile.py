@@ -332,7 +332,7 @@ def test_gpt2_paged_programs_never_copy_the_pool(chip, gpt2_small_engine,
     params, buffers = on_chip(eng._params), on_chip(eng._buffers)
     if program == "step":
         lowered = eng._step_jit.lower(params, buffers,
-                                      ints(B, 2 + C + G), pool)
+                                      ints(B, 2 + C + G), ints(B, 1), pool)
     else:
         T = int(program[len("admit"):])
         lowered = eng._padmit.lower(params, buffers, ints(R, T), ints(R, T),
@@ -675,7 +675,7 @@ def test_the_qwen3_next_engine_lowers_its_programs_for_the_chip(
         params, buffers = on_chip(eng._params), on_chip(eng._buffers)
         if program == "step":
             text = eng._step_jit.lower(params, buffers, ints(B, 2 + C + G),
-                                       pool).compile().as_text()
+                                       ints(B, 1), pool).compile().as_text()
         else:
             text = eng._padmit.lower(
                 params, buffers, ints(2, T), ints(2, T), ints(2, C),

@@ -95,7 +95,7 @@ def sweep(cell, seed, repeats, rehearse, emit):
 
     vocab, eng = _engine(cell, seed, rehearse)
     try:
-        cache = eng._init_pool()
+        _, cache = eng._init_pool()
         rng = np.random.default_rng(seed)
         for b in eng._buckets:
             ms = {}

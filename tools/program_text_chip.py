@@ -19,6 +19,7 @@ inside a kernel's serialized body), under ``<out dir>``:
 One run at a time (the TPU compiler's library is one process's), about six
 minutes each.  Equal digests say "the same program"; nothing here runs."""
 import hashlib
+import inspect
 import json
 import os
 import re
@@ -124,8 +125,12 @@ for config, traffic, layers in (("gpt2_small_serve", "docs_closed", 0),
         G = C // page
         pool = on_chip(jax.eval_shape(eng._empty_pool))
         params, buffers = on_chip(eng._params), on_chip(eng._buffers)
+        # since PR 34 the step takes the previous step's token column
+        prev = ([ints(B, 1)] if "prev" in inspect.signature(
+            eng._pstep).parameters else [])
         texts = {"step": eng._step_jit.lower(
-            params, buffers, ints(B, 2 + C + G), pool).compile().as_text()}
+            params, buffers, ints(B, 2 + C + G), *prev,
+            pool).compile().as_text()}
         for sb in (buckets[0], buckets[-1]):
             R = eng._admit_rows[sb]
             texts[f"admit[{sb}]"] = eng._padmit.lower(
