@@ -57,7 +57,13 @@ chunks (:func:`admit_chunks`: each padded to its widest row's bucket ``b``
 and holding at most ``R(b)`` rows, unused rows inert), dispatches one
 admission call per chunk back to back, each threading the pool to the
 next, and waits for the first tokens once, after the last — the prefill
-computes the rows admitted, not every slot.
+computes the rows admitted, not every slot.  A LAST chunk that is short of
+its program's rows waits where the row that fills it is known to come: with
+every free slot taken and a request still queued behind full slots, and the
+next live slot ending (by count) inside the break-even of the loop's own
+mean decode step against half its mean admission call (:func:`hold_pays`),
+the chunk's rows go back to the head of the queue before anything was done
+for them, and the freed slot's row joins them in one call.
 
 **Slot state beside the pages.**  A model may keep recurrent state per
 slot (a linear-attention layer's matrix state and conv window) that is no
@@ -93,7 +99,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -146,10 +152,10 @@ _gen_counter = [0]
 #:
 #: Two rows pay where the calls carry more rows than the second row costs:
 #: with ``t1`` a ``[1, b]`` call and ``t2`` a ``[2, b]`` call (taken with
-#: one row real; both real cost 0-11 % more), a row costs ``t2 / n`` at
-#: ``n`` rows a call against ``t1``.  Swept on a TPU v5 lite at the serving
-#: cells' own sizes (``tools/admit_rows_chip.py``, PERF.md, PR 32; host
-#: clock, ms):
+#: one row real; both real cost 0-11 % more for GPT-2 and nothing for the
+#: expert hybrid), a row costs ``t2 / n`` at ``n`` rows a call against
+#: ``t1``.  Swept on a TPU v5 lite at the serving cells' own sizes
+#: (``tools/admit_rows_chip.py``, PERF.md, PRs 32 and 38; host clock, ms):
 #:
 #:   GPT-2-small, 32 slots      b   64    128   256   512   640   768
 #:                              t1  3.98  4.44  5.06  7.15  7.66  9.20
@@ -159,18 +165,32 @@ _gen_counter = [0]
 #:                              t2  53.8   65.9   90.0  114.5
 #:   hybrid, 16 slots           t1  103.5  144.3  200.6  283.5
 #:                              t2  214.8  303.9  500.5  699.2
+#:   expert hybrid (qwen3_next) b   256    512    768    1024
+#:   128 of 512 experts, 64 s.  t1  18.2   23.5   29.5   39.9
+#:                              t2  23.5   41.2   54.5   71.8
+#:                both rows real    23.8   40.4   53.8   70.6
 #:
 #: About 2 ms of a call are its two ends on the host, whatever its rows.
-#: Up to 768 tokens that is a large part of the call: ``t2 / t1`` is
-#: 1.06-1.44, and a closed loop of short answers admits 1.56 rows a call
-#: (``docs_closed``), so two rows are a tenth cheaper a row; an open loop
-#: that admits one row a call pays 0.2-2.4 ms more a request for them, of
-#: a request of hundreds.  From 1536 tokens a row dwarfs the ends: ``t2 /
-#: t1`` is 1.53-1.72 (latent) and 2.07-2.49 (hybrid: two rows cost MORE
-#: than two calls of one), and a closed loop of long answers frees one
-#: slot at a time (1.03-1.06 rows a call), so the second row is paid for
-#: and empty.  The cap lies between: 2048 token slots a call keeps two
-#: rows up to a bucket of 1024 and gives one row past it.
+#: Up to 768 tokens of GPT-2 that is a large part of the call: ``t2 / t1``
+#: is 1.06-1.44, so two rows are cheaper a row from 1.5 rows a call; an
+#: open loop that admits one row a call pays 0.2-2.4 ms more a request for
+#: them, of a request of hundreds.  From 1536 tokens a row dwarfs the ends:
+#: ``t2 / t1`` is 1.53-1.72 (latent) and 2.07-2.49 (hybrid: two rows cost
+#: MORE than two calls of one), and a closed loop of long answers frees
+#: one slot at a time.  The cap lies between: 2048 token slots a call keeps
+#: two rows up to a bucket of 1024 and gives one row past it.
+#:
+#: Under the cap a lone row is alone only if the loop does not wait, and a
+#: FILLED call is the cheapest row there is (``t2 / 2``: 0.53-0.72 of
+#: ``t1`` for GPT-2, 0.65-0.91 for the expert hybrid, whose ``t2 / t1`` of
+#: 1.29-1.85 would otherwise make a lone row pay nearly two).  So a short
+#: last chunk waits for the next slot to end where that is known to be
+#: near (:func:`hold_pays`, the loop's admission block): the same cost a
+#: row as a ``[1, b]`` program beside the ``[2, b]`` one, which would add
+#: ``len(prompt_buckets)`` executables of 1.5-3 s each to a warm-up whose
+#: ``setup_s`` is held to 10 %.  Measured, PR 38: rows a call 1.03 -> 2.00
+#: (``longgen_closed``: a slot ends every 10 steps) and 1.56 -> 2.00
+#: (``docs_closed``), half a slot standing empty meanwhile.
 _ADMIT_ROWS = 2
 _ADMIT_TOKEN_SLOTS = 2048
 
@@ -199,6 +219,20 @@ def admit_chunks(buckets: Sequence[int], rows_of) -> List[List[int]]:
             chunks.append([j])
             widest = b
     return chunks
+
+
+def hold_pays(steps: int, live: int, clock: Mapping[str, int]) -> bool:
+    """Whether a short admission chunk should wait ``steps`` decode steps
+    for the slot that will fill it: one slot of ``live + 1`` left empty for
+    those steps costs less than the half call a filled chunk saves,
+    ``steps x decode_step / live < admit_call / 2``, both times the loop's
+    own means so far (``clock``: :attr:`LoopClock.sums`).  Never before
+    both have a reading."""
+    if not (clock["decode_steps"] and clock["admit_steps"]):
+        return False
+    step_us = clock["loop_us_decode_device"] / clock["decode_steps"]
+    call_us = clock["loop_us_admit_device"] / clock["admit_steps"]
+    return steps * step_us / live < call_us / 2
 
 
 class KVHandoff(NamedTuple):
@@ -927,6 +961,17 @@ class GenerationEngine:
             self.metrics.publish()
         return keep
 
+    def _short_tail(self, take: List[tuple]) -> int:
+        """How many rows the last admission call of ``take`` would carry
+        where that is fewer than its program's ``R(bucket)``, else 0.  An
+        iteration that adopts a hand-off runs serially and holds nothing."""
+        if any(isinstance(r.meta[3], KVHandoff) for r, _ in take):
+            return 0
+        widths = [self._buckets[r.bucket] for r, _ in take]
+        last = admit_chunks(widths, self._admit_rows.__getitem__)[-1]
+        full = self._admit_rows[max(widths[j] for j in last)]
+        return len(last) if len(last) < full else 0
+
     def _finish(self, s: dict, now: float):
         """Resolve one completed slot: future, latency/span/token metrics,
         breaker success."""
@@ -1274,6 +1319,7 @@ class GenerationEngine:
         ahead = k_max == 0                 # drafts need the tokens: serial
         swapped = self._params             # the weights the last step took
         carry: List[tuple] = []            # (Request, n_restarts) to re-admit
+        held: List[Request] = []           # ... the head of it held last time
         last_pub = 0.0
         # self-measured iteration costs (ms) of the [B, 1] fast trace vs
         # the wide [B, T] verify trace — seeded by warmup's timed calls
@@ -1481,6 +1527,30 @@ class GenerationEngine:
                                 break
                             take.append((r, nre))
                             budget_pages -= need
+                    # ---- a short last chunk waits for the row that is
+                    # known to come: where it is short for want of a SLOT
+                    # (every free slot taken, a request still waiting) and
+                    # the next live slot ends, by count, inside the
+                    # break-even (hold_pays), its rows go back to the head
+                    # of carry before anything was done for them, and the
+                    # freed slot's row fills the call.  Judged afresh every
+                    # iteration, so the rows go as soon as a slot joins
+                    # them, nobody waits any more or the bound has passed
+                    back: List[tuple] = []
+                    if (take and live and not closing
+                            and len(take) == len(free)
+                            and (carry or q.queue_depth > 0)):
+                        tail = self._short_tail(take)
+                        if tail and hold_pays(
+                                min(slots[i]["budget"] - slots[i]["sent"]
+                                    for i in live), len(live), ph.sums):
+                            back, take = take[-tail:], take[:-tail]
+                            carry = back + carry
+                    if back or held:
+                        cnt["admit_rows_held"] += sum(
+                            1 for r, _ in back
+                            if not any(r is h for h in held))
+                        held = [r for r, _ in back]
                     n_adopted = 0
                     if take:
                         ph.to("admit.host", engine=self.name, rows=len(take))
@@ -1715,7 +1785,7 @@ class GenerationEngine:
                         if ten is not None:
                             for r, _ in take:
                                 ten.note_admitted(self._tenant_of(r))
-                    elif (free and not closing
+                    elif (free and not closing and not back
                           and (carry or q.queue_depth > 0)):
                         if (ten is not None and carry
                                 and q.queue_depth == 0
@@ -1736,6 +1806,7 @@ class GenerationEngine:
                                 self.metrics.incr(
                                     "starved_steps_after_warm")
                     if (ten is not None and self._warm and carry
+                            and not back
                             and any(slots[i] is None for i in range(B))):
                         # per-tenant starvation signal for S607: an
                         # IN-budget tenant still waiting while a slot
@@ -1881,7 +1952,8 @@ class GenerationEngine:
                                    decode_steps_ahead=len(flights),
                                    kv_pages_live_steps=n_pages,
                                    kv_pages_swept_steps=n_swept,
-                                   kv_page_slots_steps=B * G)
+                                   kv_page_slots_steps=B * G,
+                                   admit_hold_slot_steps=len(back))
                         if self._slot_state:
                             # every slot's rows, live or not: in and out
                             cnt["state_bytes_steps"] += (

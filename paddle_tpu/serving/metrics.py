@@ -117,7 +117,12 @@ _MAX_KEY = {p: "loop_max_us_" + p.replace(".", "_") for p in LOOP_PHASES}
 #: ``B x G`` per decode step: the denominators of the useful shares;
 #: ``kv_pages_swept_steps`` is the part of ``B x G`` inside the slots' sweep bounds, what the
 #: ``paged_decode`` kernel walks; ``decode_steps_ahead`` /
-#: ``decode_tokens_stale``: the module docstring), and the summed
+#: ``decode_tokens_stale``: the module docstring; ``admit_rows_held``
+#: counts the rows of a short last chunk put back to wait for the slot that
+#: fills it, once a row however many iterations it waits, so over
+#: ``admit_rows`` it is the share of requests that waited for a partner;
+#: ``admit_hold_slot_steps`` the decode steps dispatched meanwhile, one per
+#: free slot so held: the slot-steps the hold cost), and the summed
 #: per-request times whose count is ``admit_rows``
 LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
                  "admit_steps", "admit_rows", "admit_row_slots",
@@ -125,7 +130,15 @@ LOOP_COUNTERS = (*_PHASE_KEY.values(), *_MAX_KEY.values(),
                  "kv_pages_live_steps", "kv_pages_swept_steps",
                  "kv_page_slots_steps",
                  "decode_steps_ahead", "decode_tokens_stale",
+                 "admit_rows_held", "admit_hold_slot_steps",
                  "queue_wait_us", "ttft_us")
+
+#: what the loop reads back of its own record, summed since it started
+#: (:attr:`LoopClock.sums`): its mean decode step and admission call are
+#: the two sides of the admission hold's break-even
+#: (``generation.hold_pays``)
+_KEPT = ("loop_us_decode_device", "decode_steps",
+         "loop_us_admit_device", "admit_steps")
 
 #: page-accounting counters (see ``extra_counters``)
 PAGED_COUNTERS = ("cow_copies", "spec_drafted", "spec_accepted",
@@ -373,11 +386,13 @@ class LoopClock:
     rose, so its delta over a window says how far an interval inside the
     window outlasted every one before it.  A request's future resolves
     inside an iteration, so the counters trail it by the rest of that
-    iteration."""
+    iteration.  ``sums`` keeps what the loop itself reads back (``_KEPT``),
+    summed over every flush."""
 
     def __init__(self, metrics: "ServingMetrics"):
         self._metrics = metrics
         self.counts: Dict[str, int] = collections.Counter()
+        self.sums: Dict[str, int] = dict.fromkeys(_KEPT, 0)
         self._ns = dict.fromkeys(_PHASE_KEY, 0)
         self._open_ns = 0  # the open phase's interval up to the last flush
         self._max_us = dict.fromkeys(LOOP_PHASES, 0)
@@ -415,5 +430,7 @@ class LoopClock:
         c = self.counts
         for p, ns in self._ns.items():
             c[_PHASE_KEY[p]], self._ns[p] = divmod(ns, 1000)
+        for k in _KEPT:
+            self.sums[k] += c[k]
         self._metrics.add(c)
         c.clear()

@@ -8,7 +8,15 @@ uncached greedy reference's whatever n is and wherever the buckets fall
 about the cap, siblings of one prefix may fall in different chunks, a
 quantized pool and per-row adapter ids follow their rows, and the compile
 set stays ``len(prompt_buckets) + 2``.
+
+A short LAST chunk waits (``hold_pays``): where it is short for want of a
+slot, with a request still queued behind full slots, and the next live slot
+ends by count inside the break-even of the loop's own mean step and call,
+its rows go back to the head of the queue and the freed slot's row fills the
+call.  The tests make the two means definite by pacing the engine's own
+device calls, never by setting anything of the rule's.
 """
+import contextlib
 import time
 
 import jax
@@ -82,7 +90,8 @@ def _burst(eng, requests):
         if snap["evicted"] - before["evicted"] >= len(requests):
             return outs, {k: snap[k] - before[k] for k in (
                 "batches", "admit_steps", "admit_rows", "admit_row_slots",
-                "admit_tokens", "admit_token_slots")}
+                "admit_tokens", "admit_token_slots", "admit_rows_held",
+                "admit_hold_slot_steps", "handoffs_in")}
         time.sleep(0.01)
     raise AssertionError("the loop never flushed its last iteration")
 
@@ -338,3 +347,283 @@ def test_adapter_ids_follow_the_chunks_rows():
     # the adapters are strong enough to move a token somewhere, so a row
     # served with another row's adapter would have shown
     assert any(o != b for o, b, a in zip(together, base, aids) if a >= 0)
+
+
+# -- a short last chunk waits for the row that is known to come ---------------
+@pytest.mark.parametrize("steps,live,clock,pays", [
+    # no reading of one side or the other: never
+    (1, 4, dict(d_us=0, d=0, a_us=0, a=0), False),
+    (1, 4, dict(d_us=900, d=3, a_us=0, a=0), False),
+    (1, 4, dict(d_us=0, d=0, a_us=9000, a=3), False),
+    # longgen_closed's readings (PERF.md): 14 ms steps, 63 live, 49.7 ms
+    # calls: the break-even is 111 steps
+    (10, 63, dict(d_us=14_000 * 50, d=50, a_us=49_700 * 9, a=9), True),
+    (111, 63, dict(d_us=14_000 * 50, d=50, a_us=49_700 * 9, a=9), True),
+    (112, 63, dict(d_us=14_000 * 50, d=50, a_us=49_700 * 9, a=9), False),
+    # docs_closed's: 2.21 ms steps, 31 live, 11.7 ms calls: 82 steps
+    (1, 31, dict(d_us=2_210 * 7, d=7, a_us=11_700 * 5, a=5), True),
+    (83, 31, dict(d_us=2_210 * 7, d=7, a_us=11_700 * 5, a=5), False),
+    # few slots, long answers: a step of one slot is no cheaper than the call
+    (1, 1, dict(d_us=5_000, d=1, a_us=8_000, a=1), False),
+    (37, 1, dict(d_us=20_000, d=1, a_us=200_000, a=1), False),
+], ids=["no_reading", "no_call_yet", "no_step_yet", "qnx_10", "qnx_111",
+        "qnx_112", "docs_1", "docs_83", "one_slot", "one_slot_long"])
+def test_the_break_even_is_the_loops_own_step_against_half_its_call(
+        steps, live, clock, pays):
+    sums = {"loop_us_decode_device": clock["d_us"], "decode_steps": clock["d"],
+            "loop_us_admit_device": clock["a_us"], "admit_steps": clock["a"]}
+    assert generation.hold_pays(steps, live, sums) is pays
+
+
+def test_the_loop_clock_keeps_what_the_rule_reads():
+    from paddle_tpu.serving.metrics import LoopClock, ServingMetrics
+
+    ph = LoopClock(ServingMetrics("kept", extra_counters=(
+        *generation.LOOP_COUNTERS, *generation.SLOT_COUNTERS)))
+    assert ph.sums == dict.fromkeys(
+        ("loop_us_decode_device", "decode_steps", "loop_us_admit_device",
+         "admit_steps"), 0)
+    for _ in range(2):
+        ph.to("admit.device")
+        time.sleep(0.004)
+        ph.to("decode.device")
+        ph.counts.update(admit_steps=2, decode_steps=1)
+        ph.flush()
+    ph.to(None)
+    assert (ph.sums["admit_steps"], ph.sums["decode_steps"]) == (4, 2)
+    assert ph.sums["loop_us_admit_device"] >= 4000
+    # a call of 2 ms against a step of microseconds: one step of one slot in
+    # five pays, a million do not
+    assert generation.hold_pays(1, 4, ph.sums)
+    assert not generation.hold_pays(10 ** 6, 4, ph.sums)
+
+
+@contextlib.contextmanager
+def _paced(eng, admit_s=0.0, step_s=0.0, before_step=lambda: None):
+    """Make the engine's own device calls last: what the loop's clock then
+    reads is definite whatever the machine is doing."""
+    padmit, step = eng._padmit, eng._step
+
+    def slow_admit(*a):
+        time.sleep(admit_s)
+        return padmit(*a)
+
+    def slow_step(*a):
+        before_step()
+        time.sleep(step_s)
+        return step(*a)
+
+    eng._padmit, eng._step = slow_admit, slow_step
+    try:
+        yield eng
+    finally:
+        eng._padmit, eng._step = padmit, step
+
+
+def _primed(eng):
+    """Both sides of the break-even have a reading: one request, served."""
+    p = _prompt_in(0, BUCKETS[0])
+    outs, delta = _burst(eng, [(p, 3, {})])
+    assert delta["admit_steps"] == 1 and delta["admit_rows_held"] == 0
+    return eng
+
+
+@pytest.fixture(scope="module")
+def holding(model):
+    """Five slots whose admission calls last 50 ms against decode steps of a
+    millisecond or two: a slot left empty for a few steps is cheap."""
+    with _engine(model, "hold") as eng:
+        assert eng.warmup() == COMPILE_SET
+        with _paced(eng, admit_s=0.05):
+            yield _primed(eng)
+
+
+#: seven requests on five slots: slot 0 ends after two decode steps, slot 1
+#: two steps later, the rest outlast the test's interest; two wait
+HELD_BUDGETS = [3, 5, 12, 12, 12, 4, 4]
+
+
+def _all_served_greedy(model, outs, reqs):
+    for out, (p, budget, _) in zip(outs, reqs):
+        assert out == _ref_greedy(model, p, budget)
+
+
+def test_a_short_chunk_waits_for_the_next_freed_slots_row(model, holding):
+    reqs = [(_prompt_in(k, BUCKETS[0]), n, {})
+            for k, n in enumerate(HELD_BUDGETS)]
+    outs, delta = _burst(holding, reqs)
+    # five at once with nothing live (3 calls, the last short and not held),
+    # then the sixth waits out slot 1's two steps and goes with the seventh
+    assert delta["batches"] == 2
+    assert (delta["admit_steps"], delta["admit_rows"],
+            delta["admit_row_slots"]) == (4, 7, 4 * R)
+    assert delta["admit_rows_held"] == 1  # once a row, not once an iteration
+    assert delta["admit_hold_slot_steps"] == HELD_BUDGETS[1] - HELD_BUDGETS[0]
+    assert delta["admit_token_slots"] == 4 * R * BUCKETS[0]
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_mixed_buckets_pair_in_the_wider_rows_program(model, holding):
+    # the held row is of the narrow bucket, its partner of the wide one
+    widths = [BUCKETS[0]] * 5 + [BUCKETS[0], BUCKETS[1]]
+    reqs = [(_prompt_in(k, b), n, {})
+            for k, (b, n) in enumerate(zip(widths, HELD_BUDGETS))]
+    outs, delta = _burst(holding, reqs)
+    assert (delta["admit_steps"], delta["admit_rows_held"]) == (4, 1)
+    assert delta["admit_token_slots"] == R * (3 * BUCKETS[0] + BUCKETS[1])
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_never_held_with_an_empty_queue(model, holding):
+    # the sixth request is the last: nobody is coming to fill its call
+    reqs = [(_prompt_in(k, BUCKETS[0]), n, {})
+            for k, n in enumerate(HELD_BUDGETS[:6])]
+    outs, delta = _burst(holding, reqs)
+    assert (delta["admit_steps"], delta["admit_rows"],
+            delta["admit_row_slots"]) == (4, 6, 4 * R)
+    assert (delta["admit_rows_held"], delta["admit_hold_slot_steps"]) == (0, 0)
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_never_held_with_no_live_slot(model, holding):
+    # answers of one token end at their admission: every slot is free in
+    # every iteration and nothing is decoding whose end could be waited for
+    reqs = [(_prompt_in(k, BUCKETS[0]), 1, {}) for k in range(11)]
+    outs, delta = _burst(holding, reqs)
+    assert (delta["batches"], delta["admit_steps"], delta["admit_rows"],
+            delta["admit_row_slots"]) == (3, 7, 11, 7 * R)
+    assert (delta["admit_rows_held"], delta["admit_hold_slot_steps"]) == (0, 0)
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_the_hold_tests_compiled_nothing(holding):
+    assert holding.metrics.snapshot()["admit_rows_held"] > 0
+    assert holding.compile_count == COMPILE_SET
+
+
+def test_never_held_where_the_bucket_has_one_row(model):
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(generation, "_ADMIT_TOKEN_SLOTS", BUCKETS[-1])
+        eng = _engine(model, "hold-one-row")
+    reqs = [(_prompt_in(k, BUCKETS[1]), n, {})
+            for k, n in enumerate(HELD_BUDGETS)]
+    with eng:
+        assert eng._admit_rows == {8: 2, 16: 1}
+        assert eng.warmup() == COMPILE_SET
+        with _paced(eng, admit_s=0.05):
+            _primed(eng)
+            outs, delta = _burst(eng, reqs)
+    # a call of the wide bucket is full with its one row
+    assert (delta["admit_steps"], delta["admit_rows"],
+            delta["admit_row_slots"]) == (7, 7, 7)
+    assert (delta["admit_rows_held"], delta["admit_hold_slot_steps"]) == (0, 0)
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_never_held_past_the_break_even(model):
+    # two slots and a long answer: the one live slot would decode alone for
+    # 21 steps of 20 ms to save half a call of a few
+    reqs = [(_prompt_in(k, BUCKETS[0]), n, {})
+            for k, n in enumerate([3, 24, 4, 4])]
+    with GenerationEngine(model, prompt_buckets=BUCKETS, batch_size=2,
+                          cache_len=CACHE, kv_page_size=PAGE,
+                          speculative_k=0, name="hold-two-slots") as eng:
+        assert eng._admit_rows == {b: 2 for b in BUCKETS}
+        assert eng.warmup() == COMPILE_SET
+        with _paced(eng, step_s=0.02):
+            _primed(eng)
+            outs, delta = _burst(eng, reqs)
+        assert eng.compile_count == COMPILE_SET
+    # the third goes alone as soon as slot 0 ends, though the fourth waits
+    assert (delta["admit_steps"], delta["admit_rows"],
+            delta["admit_row_slots"]) == (3, 4, 3 * R)
+    assert (delta["admit_rows_held"], delta["admit_hold_slot_steps"]) == (0, 0)
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_never_held_while_closing(model):
+    reqs = [(_prompt_in(k, BUCKETS[0]), n, {})
+            for k, n in enumerate(HELD_BUDGETS)]
+    eng = _engine(model, "hold-closing")
+    assert eng.warmup() == COMPILE_SET
+    with _paced(eng, admit_s=0.05):
+        _primed(eng)
+        before = eng.metrics.snapshot()
+        with eng._batcher._cv:
+            futs = [eng.submit(p, n) for p, n, _ in reqs]
+        # the first iteration's three calls last 150 ms: the engine is
+        # closing long before slot 0 ends
+        eng.close(drain=True, timeout=120)
+    outs = [f.result(1).tolist() for f in futs]
+    snap = eng.metrics.snapshot()
+    delta = {k: snap[k] - before[k] for k in (
+        "admit_steps", "admit_rows", "admit_rows_held",
+        "admit_hold_slot_steps")}
+    assert delta == {"admit_steps": 5, "admit_rows": 7, "admit_rows_held": 0,
+                     "admit_hold_slot_steps": 0}
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_a_hand_off_adoption_is_never_held(model):
+    reqs = [(_prompt_in(k, BUCKETS[0]), n, {"handoff": True})
+            for k, n in enumerate(HELD_BUDGETS)]
+    with _engine(model, "hold-pre", role="prefill") as pre, \
+            _engine(model, "hold-dec", role="decode") as dec:
+        assert pre.warmup() == dec.warmup() == COMPILE_SET + 1
+        hands = [pre.submit(p, n, **kw).result(120) for p, n, kw in reqs]
+        with _paced(dec, admit_s=0.05):
+            outs, delta = _burst(dec, [
+                (h.prompt, n, {"handoff": h})
+                for h, (_, n, _) in zip(hands, reqs)])
+    # adopted as slots free, one by one, with a request waiting behind them
+    assert delta["handoffs_in"] == len(reqs)
+    assert (delta["admit_steps"], delta["admit_rows_held"],
+            delta["admit_hold_slot_steps"]) == (0, 0, 0)
+    _all_served_greedy(model, outs, reqs)
+
+
+def test_a_held_request_still_expires_by_its_deadline(model):
+    from paddle_tpu.framework.errors import ExecutionTimeoutError
+
+    budgets = [3, 22, 22, 22, 22, 4, 4]
+    deadline_s = 2.0
+    reqs = [(_prompt_in(k, BUCKETS[0]), n,
+             {"deadline_ms": deadline_s * 1e3} if k == 5 else {})
+            for k, n in enumerate(budgets)]
+    with _engine(model, "hold-deadline") as eng:
+        assert eng.warmup() == COMPILE_SET
+        state = {}
+
+        def outlast_the_deadline():
+            # the loop's thread, about to dispatch a step: once the sixth
+            # request is held, stand still until its deadline has passed
+            if ("t0" in state and "stood" not in state
+                    and eng.metrics.snapshot()["admit_rows_held"]
+                    > state["held"]):
+                state["stood"] = True
+                time.sleep(max(0.0, state["t0"] + deadline_s
+                               - time.monotonic()) + 0.05)
+
+        with _paced(eng, admit_s=0.2, before_step=outlast_the_deadline):
+            _primed(eng)
+            before = eng.metrics.snapshot()
+            state["held"] = before["admit_rows_held"]
+            with eng._batcher._cv:
+                state["t0"] = time.monotonic()
+                futs = [eng.submit(p, n, **kw) for p, n, kw in reqs]
+            with pytest.raises(ExecutionTimeoutError):
+                futs[5].result(120)
+            outs = [f.result(120).tolist()
+                    for k, f in enumerate(futs) if k != 5]
+            for _ in range(500):
+                snap = eng.metrics.snapshot()
+                if snap["evicted"] - before["evicted"] >= len(reqs) - 1:
+                    break
+                time.sleep(0.01)
+    assert state.get("stood")
+    assert snap["expired"] - before["expired"] == 1
+    # it was held, and it expired where it waited; the seventh, with nobody
+    # behind it, went alone
+    assert snap["admit_rows_held"] - before["admit_rows_held"] == 1
+    assert snap["admit_rows"] - before["admit_rows"] == len(reqs) - 1
+    _all_served_greedy(model, outs, [r for k, r in enumerate(reqs) if k != 5])

@@ -32,7 +32,8 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 CELLS = ("olmo_hybrid.ragdocs_closed", "joyai_flash.ragdocs_closed",
-         "gpt2_small.docs_closed", "gpt2_small.chat_open")
+         "gpt2_small.docs_closed", "gpt2_small.chat_open",
+         "qwen3_next.longgen_closed")
 
 
 def _engine(cell, seed, rehearse):
