@@ -1,11 +1,14 @@
-"""A decoder whose layers are of two kinds, by a per-layer ``layer_types``
-list: ``linear_attention`` — the gated delta rule (``ops/gated_delta.py``)
-over a per-head float32 matrix state, behind a short causal depthwise
-convolution — and ``full_attention`` — causal softmax attention with
-QK-norm.  ONE stack; what differs between the published decoders of this
-shape are options of :class:`HybridConfig`, each with the default that
-leaves the first of them (reordered norms, a dense MLP, no rotary, equal
-head counts) the program it was:
+"""A decoder whose layers are of up to three kinds, by a per-layer
+``layer_types`` list (any non-empty subset of them): ``linear_attention`` —
+the gated delta rule (``ops/gated_delta.py``) over a per-head float32
+matrix state, behind a short causal depthwise convolution —,
+``full_attention`` — causal softmax attention with QK-norm — and
+``sliding_attention`` — the same projections, norms and heads, but a query
+at ``qp`` sees only the ``sliding_window`` keys ``qp - W + 1 .. qp``.  ONE
+stack; what differs between the published decoders of this shape are
+options of :class:`HybridConfig`, each with the default that leaves the
+first of them (reordered norms, a dense MLP, no rotary, equal head counts)
+the program it was:
 
 * the block: ``block_norm="post"``, ``h = x + norm(Mix(x))``, ``y = h +
   norm(FFN(h))`` (the reordered norm), or ``"pre"``, ``h = x +
@@ -23,6 +26,10 @@ head counts) the program it was:
   rotate-half pairs on the first ``partial_rotary_factor`` of each head's
   dims, ``attn_output_gate`` (the query projection is twice as wide, ``[q |
   gate]`` per head, and the context is scaled by ``sigmoid(gate)``);
+* ``sliding_window`` (None: no ``sliding_attention`` layer) and
+  ``rope_kinds``, WHICH of the two softmax kinds ``rope_theta`` rotates
+  (both by default; a decoder whose global layers carry no positions names
+  ``("sliding_attention",)``);
 * linear attention: ``linear_num_key_heads`` fewer than the value heads
   (value head h reads key head ``h // rep``), ``allow_neg_eigval``.
 
@@ -37,23 +44,38 @@ No bias anywhere, final norm, untied head.
     S_t = e^g S_{t-1} + beta k (v - (e^g S_{t-1})^T k)^T;  o = S_t^T q
     y = RMSNorm(o) * silu(x W_g);  y W_o
 
-Two kinds of cache, so the model owns the layout of both and declares
-``slot_state`` (the serving-model protocol, ``serving/generation.py``):
+Two kinds of cache, one that grows with the context and one that does not,
+so the model owns the layout of both and declares ``slot_state`` (the
+serving-model protocol, ``serving/generation.py``):
 
 * a full layer holds K and V page pools ``[P + 1, page, H_kv * hd]`` in
   ``GPTModel``'s stored order, read by the same ``paged_decode`` kernel;
 * a linear layer holds, PER SLOT and not per page, ``state`` ``[B + 1, H,
   d_k, d_v]`` float32 and ``conv`` ``[B + 1, K - 1, conv_width]`` (the last
   ``K - 1`` rows of ``[q~ | k~ | v~]``); row ``B`` is the write-drop row, as
-  page ``P`` is the write-drop page.
+  page ``P`` is the write-drop page;
+* a sliding layer holds, PER SLOT too, ``ring_k`` and ``ring_v`` ``[B + 1,
+  W, H_kv * hd]``: position ``p`` lives in ring row ``p % W``, so the cache
+  is ``W`` rows whatever the context.  What row ``r`` holds for a query at
+  ``qp`` is arithmetic, ``p_r = qp - ((qp - r) mod W)`` (:func:`ring_positions`),
+  real iff ``p_r >= 0``: a slot needs no reset and no ``pos_map``, because
+  what a previous tenant left has a negative ``p_r`` or was overwritten.
+  To the decode kernel a ring IS a pool of one ``W``-row page a slot
+  (``paged_decode`` under the name ``window_decode``).
+
+What ``slot_state`` brings it brings to a sliding layer too: the engine
+refuses speculation and hand-off for such a model and serves a
+``prefix_key`` cold (``prefix_unshared``).
 
 An ADMISSION (``forward_paged(..., slots=[R])``) takes whole prompts from
 position 0: it starts every row from the zero state, whatever its slot
 held, and writes the state and the conv window after the row's last real
-token into slot ``slots[r]`` (``-1``: the drop row).  A DECODE call
+token (a sliding layer: the K and V of its last ``min(len, W)`` real
+tokens) into slot ``slots[r]`` (``-1``: the drop row).  A DECODE call
 (``slots=None``, one token a row) continues slot ``i`` in row ``i``.  A
-padding token (position ``-1``) is the identity on both: it neither decays
-nor writes the state and does not enter the conv window.
+padding token (position ``-1``) is the identity on all of them: it neither
+decays nor writes the state, does not enter the conv window and writes no
+ring row.
 """
 from __future__ import annotations
 
@@ -70,14 +92,16 @@ from ..nn.layer_base import Layer
 from ..ops import autotune as _at
 from ..ops.gated_delta import gated_delta_chunk, gated_delta_step
 from ..ops.paged_attention import (key_visible, paged_attention,
-                                   paged_flash_eligible, sweep_bound)
+                                   paged_flash_decode, paged_flash_eligible,
+                                   sweep_bound)
 from .latent_moe import GatedMLP, _mm
 
 __all__ = ["HybridConfig", "HybridModel", "HybridForCausalLM",
-           "rope_rotate_half"]
+           "rope_rotate_half", "ring_positions"]
 
 _F32 = jnp.float32
-LAYER_TYPES = ("linear_attention", "full_attention")
+LAYER_TYPES = ("linear_attention", "full_attention", "sliding_attention")
+_SOFTMAX_KINDS = LAYER_TYPES[1:]
 FFN_TYPES = ("dense", "moe")
 
 
@@ -93,14 +117,15 @@ def _paged_flash(head_dim, page_size) -> bool:
 
 class HybridConfig:
     def __init__(self, vocab_size, hidden_size, num_heads, intermediate_size,
-                 layer_types, linear_num_heads, linear_key_head_dim,
-                 linear_value_head_dim, linear_conv_kernel=4,
+                 layer_types, linear_num_heads=None, linear_key_head_dim=None,
+                 linear_value_head_dim=None, linear_conv_kernel=4,
                  allow_neg_eigval=True, rms_norm_eps=1e-6, rope_theta=None,
                  max_position=4096, dtype="bfloat16", init_std=0.02,
                  num_kv_heads=None, head_dim=None, partial_rotary_factor=1.0,
                  qk_norm="projection", attn_output_gate=False,
                  linear_num_key_heads=None, block_norm="post",
-                 zero_centered_norms=False, ffn_types=None, moe=None):
+                 zero_centered_norms=False, ffn_types=None, moe=None,
+                 sliding_window=None, rope_kinds=_SOFTMAX_KINDS):
         bad = [t for t in layer_types if t not in LAYER_TYPES]
         if bad or not layer_types:
             raise InvalidArgumentError(
@@ -119,6 +144,18 @@ class HybridConfig:
             raise InvalidArgumentError(
                 f"qk_norm is 'projection' or 'head' and block_norm 'post' "
                 f"or 'pre', got {qk_norm!r}, {block_norm!r}")
+        linear = (linear_num_heads, linear_key_head_dim,
+                  linear_value_head_dim)
+        if (("linear_attention" in layer_types and None in linear)
+                or ("sliding_attention" in layer_types)
+                != (sliding_window is not None)
+                or any(k not in _SOFTMAX_KINDS for k in rope_kinds)):
+            raise InvalidArgumentError(
+                f"a 'linear_attention' layer needs the linear head sizes "
+                f"(got {linear!r}), `sliding_window` goes with a "
+                f"'sliding_attention' layer (got {sliding_window!r}), and "
+                f"rope_kinds names some of {_SOFTMAX_KINDS}, got "
+                f"{rope_kinds!r}")
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.num_heads = int(num_heads)
@@ -128,15 +165,18 @@ class HybridConfig:
         self.layer_types = tuple(layer_types)
         self.ffn_types = ffn_types
         self.moe = dict(moe or {})
-        self.linear_num_heads = int(linear_num_heads)      # value heads
+        self.linear_num_heads = int(linear_num_heads or 0)  # value heads
         self.linear_num_key_heads = int(linear_num_key_heads
-                                        or linear_num_heads)
-        self.linear_key_head_dim = int(linear_key_head_dim)
-        self.linear_value_head_dim = int(linear_value_head_dim)
+                                        or self.linear_num_heads)
+        self.linear_key_head_dim = int(linear_key_head_dim or 0)
+        self.linear_value_head_dim = int(linear_value_head_dim or 0)
         self.linear_conv_kernel = int(linear_conv_kernel)
         self.allow_neg_eigval = bool(allow_neg_eigval)
         self.rms_norm_eps = float(rms_norm_eps)
         self.rope_theta = None if rope_theta is None else float(rope_theta)
+        self.rope_kinds = tuple(rope_kinds)
+        self.sliding_window = (None if sliding_window is None
+                               else int(sliding_window))
         self.rotary_dim = int(self.head_dim * float(partial_rotary_factor))
         self.qk_norm, self.block_norm = qk_norm, block_norm
         self.attn_output_gate = bool(attn_output_gate)
@@ -145,7 +185,8 @@ class HybridConfig:
         self.dtype = dtype
         self.init_std = float(init_std)
         if (self.num_heads % self.num_kv_heads
-                or self.linear_num_heads % self.linear_num_key_heads
+                or (self.linear_num_heads
+                    and self.linear_num_heads % self.linear_num_key_heads)
                 or (self.rope_theta is not None and self.rotary_dim % 2)):
             raise InvalidArgumentError(
                 f"{self.num_heads} query heads over {self.num_kv_heads} K/V "
@@ -185,6 +226,16 @@ def rope_rotate_half(x, positions, theta, dims):
     a, b = xf[..., :half], xf[..., half:dims]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
                             xf[..., dims:]], axis=-1).astype(x.dtype)
+
+
+def ring_positions(qp, window):
+    """The absolute position ring row ``r`` holds for a query at ``qp``
+    (``[...]`` int32 -> ``[..., window]``): the last position ``<= qp``
+    that is ``r`` modulo ``window``, or ``-1`` where that is before
+    position 0 (a row this sequence has not written) or ``qp`` is padding."""
+    r = jnp.arange(window, dtype=jnp.int32)
+    p = qp[..., None] - jnp.mod(qp[..., None] - r, window)
+    return jnp.where((p >= 0) & (qp[..., None] >= 0), p, -1)
 
 
 def _norm(cfg, size, zero_centered=None):
@@ -329,9 +380,14 @@ class FullAttention(Layer):
     heads of ``head_dim``, QK-norm, and by the configuration's options
     rotary on the leading dims of each head and an output gate."""
 
+    kind = "full_attention"
+
     def __init__(self, cfg: HybridConfig):
         super().__init__()
         self.cfg = cfg
+        #: None: this kind of layer carries no positions
+        self.rope_theta = (cfg.rope_theta if self.kind in cfg.rope_kinds
+                           else None)
         D, hd = cfg.hidden_size, cfg.head_dim
         self.q_width, self.kv_width = cfg.num_heads * hd, cfg.num_kv_heads * hd
         # [W_q (per head [q | gate] with an output gate) | W_k | W_v]
@@ -357,12 +413,12 @@ class FullAttention(Layer):
             k, v = qkv[..., 2 * Q:2 * Q + KV], qkv[..., 2 * Q + KV:]
         else:
             q, k, v = qkv[..., :Q], qkv[..., Q:Q + KV], qkv[..., Q + KV:]
-        if cfg.qk_norm == "head" or cfg.rope_theta is not None:
+        if cfg.qk_norm == "head" or self.rope_theta is not None:
             q, k = q.reshape(B, T, -1, hd), k.reshape(B, T, -1, hd)
         q, k = self.q_norm(q), self.k_norm(k)
-        if cfg.rope_theta is not None:
+        if self.rope_theta is not None:
             q, k = (rope_rotate_half(t, positions[:, :, None],
-                                     cfg.rope_theta, cfg.rotary_dim)
+                                     self.rope_theta, cfg.rotary_dim)
                     for t in (q, k))
         return q.reshape(B, T, Q), k.reshape(B, T, KV), v, gate
 
@@ -377,22 +433,29 @@ class FullAttention(Layer):
             y = y.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))
         return _mm(y.astype(self.cfg.dtype), self.out.value)
 
+    @staticmethod
+    def _attend(q, k, v, seen):
+        """Plain softmax attention of head-major ``q`` ``[B, H, T, hd]`` over
+        ``k``, ``v`` ``[B, H_kv, T, hd]`` under the ``[T, T]`` mask ``seen``
+        (the path without a kernel)."""
+        rep = q.shape[1] // k.shape[1]
+        if rep > 1:
+            k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=_F32) / math.sqrt(q.shape[3])
+        s = jnp.where(seen, s, jnp.finfo(_F32).min)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                          preferred_element_type=_F32)
+
     def forward(self, x, positions):
         """A prompt from position 0 (causal by row), no cache."""
         with jax.named_scope("attn"):
             q, k, v, gate = self._qkv(x, positions)
-            q, k, v = map(self._heads, (q, k, v))
-            rep = q.shape[1] // k.shape[1]
-            if rep > 1:
-                k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
-            T, hd = q.shape[2], q.shape[3]
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                           preferred_element_type=_F32) / math.sqrt(hd)
-            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s,
-                          jnp.finfo(_F32).min)
-            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-            return self._merge(jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                                          preferred_element_type=_F32), gate)
+            T = x.shape[1]
+            return self._merge(self._attend(
+                *map(self._heads, (q, k, v)),
+                jnp.tril(jnp.ones((T, T), bool))), gate)
 
     def forward_paged(self, x, kv, write_page, write_off, gather_tab, mask,
                       walk, prompt, positions):
@@ -418,13 +481,89 @@ class FullAttention(Layer):
             return self._merge(ctx, gate), pools
 
 
+class SlidingAttention(FullAttention):
+    """The ``sliding_attention`` mixer: :class:`FullAttention`'s
+    projections, QK-norm and heads under the window rule
+    (``key_visible(kp, qp, W)``), its K and V in per-slot rings (module
+    docstring)."""
+
+    kind = "sliding_attention"
+
+    def _prompt(self, x, positions):
+        """Prompts from position 0: the context ``[B, H, T, hd]``, the K
+        and V rows ``[B, T, H_kv * hd]`` as a ring holds them, the gate."""
+        q, k, v, gate = self._qkv(x, positions)
+        W, (qh, kh, vh) = self.cfg.sliding_window, map(self._heads, (q, k, v))
+        if _kernels(self.cfg.head_dim):
+            from ..ops.flash_attention import flash_attention
+
+            return flash_attention(qh, kh, vh, causal=True,
+                                   window=W), k, v, gate
+        t = jnp.arange(qh.shape[2], dtype=jnp.int32)
+        return self._attend(qh, kh, vh, key_visible(
+            t[None, :], t[:, None], W)), k, v, gate
+
+    def forward(self, x, positions):
+        """A prompt from position 0, no cache."""
+        with jax.named_scope("win"):
+            ctx, _, _, gate = self._prompt(x, positions)
+            return self._merge(ctx, gate)
+
+    def admit(self, x, positions, kv, rows):
+        """Prompts from position 0 into the rings of the slots' rows
+        ``rows`` ``[R]``: ring row ``r`` takes the row's last real token
+        at a position that is ``r`` modulo ``W`` (a ring row no token of
+        the prompt maps to takes token 0's: masked at every later read,
+        its position being negative then)."""
+        with jax.named_scope("win"):
+            ctx, k, v, gate = self._prompt(x, positions)
+            last = jnp.sum(positions >= 0, axis=1) - 1        # [R]
+            idx = jnp.maximum(ring_positions(jnp.maximum(last, 0),
+                                             self.cfg.sliding_window), 0)
+            return self._merge(ctx, gate), {
+                n: kv[n].at[rows].set(jnp.take_along_axis(
+                    t, idx[..., None], axis=1).astype(kv[n].dtype))
+                for n, t in (("ring_k", k), ("ring_v", v))}
+
+    def decode(self, x, positions, kv):
+        """One token of slot ``i`` in row ``i``: its K and V go into ring
+        row ``qp % W`` (a padding row's into the drop row), and the query
+        attends to the slot's ``W`` rows, each at the position
+        :func:`ring_positions` gives it."""
+        with jax.named_scope("win"):
+            cfg, B = self.cfg, x.shape[0]
+            W, hd = cfg.sliding_window, cfg.head_dim
+            q, k, v, gate = self._qkv(x, positions)
+            qp = positions[:, 0]
+            slot = jnp.where(qp >= 0, jnp.arange(B, dtype=jnp.int32), B)
+            rings = {n: kv[n].at[slot, jnp.mod(qp, W)].set(
+                t[:, 0].astype(kv[n].dtype))
+                for n, t in (("ring_k", k), ("ring_v", v))}
+            held = ring_positions(qp, W)                      # [B, W]
+            tab = jnp.arange(B, dtype=jnp.int32)[:, None]     # slot i: page i
+            q = self._heads(q)
+            if _paged_flash(hd, W):
+                ctx = paged_flash_decode(
+                    q, rings["ring_k"], rings["ring_v"], tab, held,
+                    positions, (qp >= 0).astype(jnp.int32),
+                    name="window_decode")
+            else:
+                ctx = paged_attention(
+                    q, rings["ring_k"], rings["ring_v"], tab,
+                    key_visible(held[:, None, :], positions[:, :, None], W))
+            return self._merge(ctx, gate), rings
+
+
+_MIXERS = {"linear_attention": GatedDeltaNet, "full_attention": FullAttention,
+           "sliding_attention": SlidingAttention}
+
+
 class HybridBlock(Layer):
     def __init__(self, cfg: HybridConfig, kind: str, ffn: str = "dense"):
         super().__init__()
         self.kind = kind
         self.pre_norm = cfg.block_norm == "pre"
-        self.mixer = (GatedDeltaNet(cfg) if kind == "linear_attention"
-                      else FullAttention(cfg))
+        self.mixer = _MIXERS[kind](cfg)
         self.norm1 = _norm(cfg, cfg.hidden_size)
         if ffn == "moe":
             self.mlp = DroplessMoE(cfg.hidden_size, dtype=cfg.dtype,
@@ -476,7 +615,8 @@ class HybridModel(Layer):
                          slots=None):
         """Per full layer K and V pools ``[P + 1, page, H_kv * hd]``; per
         linear layer ``state`` ``[slots + 1, H, dk, dv]`` float32 and
-        ``conv`` ``[slots + 1, K - 1, conv_width]``."""
+        ``conv`` ``[slots + 1, K - 1, conv_width]``; per sliding layer
+        ``ring_k`` and ``ring_v`` ``[slots + 1, W, H_kv * hd]``."""
         cfg = self.cfg
         if slots is None:
             raise InvalidArgumentError(
@@ -490,6 +630,10 @@ class HybridModel(Layer):
             if kind == "full_attention":
                 return {"k": jnp.zeros(pool, dtype or cfg.dtype),
                         "v": jnp.zeros(pool, dtype or cfg.dtype)}
+            if kind == "sliding_attention":
+                ring = (rows, cfg.sliding_window, pool[2])
+                return {"ring_k": jnp.zeros(ring, dtype or cfg.dtype),
+                        "ring_v": jnp.zeros(ring, dtype or cfg.dtype)}
             return {"state": jnp.zeros(
                         (rows, cfg.linear_num_heads, cfg.linear_key_head_dim,
                          cfg.linear_value_head_dim), _F32),
@@ -526,27 +670,29 @@ class HybridModel(Layer):
             raise InvalidArgumentError(
                 "slot state decodes one token a row: a wider step would "
                 "have to roll the state back for a rejected draft")
-        full = next(kv for kv in cache["layers"] if "k" in kv)["k"]
-        P, page, G = full.shape[0] - 1, full.shape[1], table.shape[1]
-        C = G * page
+        full = next((kv["k"] for kv in cache["layers"] if "k" in kv), None)
         x = jnp.take(jnp.asarray(self.embed.value),
                      jnp.asarray(input_ids, jnp.int32), axis=0)
-        ring = jnp.where(positions >= 0, positions % C, -1)
-        g = jnp.clip(ring // page, 0, G - 1)
-        phys = jnp.take_along_axis(table, g, axis=1)
-        # padding tokens and unmapped pages write into the drop page P
-        phys = jnp.where((ring >= 0) & (phys >= 0), phys, P)
-        mask = key_visible(pos_map[:, None, :], positions[:, :, None], C)
-        walk = None
-        if not prompt and _paged_flash(cfg.head_dim, page):
-            walk = (pos_map, positions, sweep_bound(mask, page))
-        paged = (phys.reshape(-1), jnp.clip(ring % page, 0, page - 1)
-                 .reshape(-1), jnp.maximum(table, 0), mask, walk, prompt,
-                 positions)
+        if full is not None:  # what the layers with pages share
+            P, page, G = full.shape[0] - 1, full.shape[1], table.shape[1]
+            C = G * page
+            ring = jnp.where(positions >= 0, positions % C, -1)
+            g = jnp.clip(ring // page, 0, G - 1)
+            phys = jnp.take_along_axis(table, g, axis=1)
+            # padding tokens and unmapped pages write into the drop page P
+            phys = jnp.where((ring >= 0) & (phys >= 0), phys, P)
+            mask = key_visible(pos_map[:, None, :], positions[:, :, None], C)
+            walk = None
+            if not prompt and _paged_flash(cfg.head_dim, page):
+                walk = (pos_map, positions, sweep_bound(mask, page))
+            paged = (phys.reshape(-1), jnp.clip(ring % page, 0, page - 1)
+                     .reshape(-1), jnp.maximum(table, 0), mask, walk, prompt,
+                     positions)
         if prompt:
             slots = jnp.asarray(slots, jnp.int32)
-            drop = next(kv for kv in cache["layers"]
-                        if "state" in kv)["state"].shape[0] - 1
+            # the drop row of the per-slot tensors, whichever kind has them
+            drop = next((t.shape[0] - 1 for kv in cache["layers"]
+                         if "k" not in kv for t in kv.values()), 0)
             rows = jnp.where(slots >= 0, slots, drop)
         layers = []
         for blk, kv in zip(self.blocks, cache["layers"]):
@@ -582,13 +728,16 @@ class HybridForCausalLM(Layer):
 
     def slot_state_bytes(self) -> int:
         """Bytes of slot state one slot holds over all the linear layers
-        (what a decode step reads and writes for it)."""
-        cfg = self.cfg
-        n = sum(kind == "linear_attention" for kind in cfg.layer_types)
-        return n * (4 * cfg.linear_num_heads * cfg.linear_key_head_dim
-                    * cfg.linear_value_head_dim
-                    + jnp.dtype(cfg.dtype).itemsize
-                    * (cfg.linear_conv_kernel - 1) * cfg.conv_width)
+        (what a decode step reads and writes for it) and all the sliding
+        layers' rings."""
+        cfg, item = self.cfg, jnp.dtype(self.cfg.dtype).itemsize
+        kinds = cfg.layer_types
+        return (kinds.count("linear_attention") * (
+            4 * cfg.linear_num_heads * cfg.linear_key_head_dim
+            * cfg.linear_value_head_dim
+            + item * (cfg.linear_conv_kernel - 1) * cfg.conv_width)
+            + kinds.count("sliding_attention") * 2 * item
+            * (cfg.sliding_window or 0) * cfg.num_kv_heads * cfg.head_dim)
 
     def init_paged_cache(self, num_pages, page_size, dtype=None, slots=None):
         return self.model.init_paged_cache(num_pages, page_size, dtype,

@@ -278,6 +278,109 @@ def _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
     return out.reshape(B, H, S, D), lse.reshape(B, H, S)
 
 
+# -- sliding window: the band of tiles a window of W keys touches --------------
+# Causal self-attention from position 0 in which query ``qp`` sees the keys
+# ``qp - (W - 1) .. qp`` (``ops.paged_attention.key_visible`` with
+# ``window=W``).  Query block ``qi`` of ``b`` rows can only see key blocks
+# ``(qi * b - (W - 1)) // b .. qi``: the flat grid enumerates that band and
+# nothing else (the triangle grid's pattern: a tile that is not in the table
+# costs neither its DMA nor a grid step), and inside a tile the mask is the
+# rule itself.  Forward only, square blocks, its own ``name=`` so that a
+# trace tells it from the global layers' call.
+@functools.lru_cache(maxsize=64)
+def _band_table(nq, block, window):
+    """Three 1-D [T] int32 arrays (qi, ki, first) enumerating, row-major,
+    the tiles of the band; ``first`` marks a query block's first tile."""
+    rows = [(qi, ki, int(ki == lo))
+            for qi in range(nq)
+            for lo in (max(0, (qi * block - (window - 1)) // block),)
+            for ki in range(lo, qi + 1)]
+    a = np.asarray(rows, np.int32)
+    return a[:, 0].copy(), a[:, 1].copy(), a[:, 2].copy()
+
+
+def _fwd_window_kernel(qi_ref, ki_ref, first_ref, q_ref, k_ref, v_ref, o_ref,
+                       m_scr, l_scr, acc_scr, *, kv_len: int, window: int,
+                       sm_scale: float):
+    i32 = jnp.int32
+    t = pl.program_id(1).astype(i32)
+    qi, ki = qi_ref[t], ki_ref[t]
+    block = q_ref.shape[1]
+
+    @pl.when(first_ref[t] == 1)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # products of the stored type are exact in float32: no upcast of q and k
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    q_pos = qi * i32(block) + jax.lax.broadcasted_iota(i32, s.shape, 0)
+    k_pos = ki * i32(block) + jax.lax.broadcasted_iota(i32, s.shape, 1)
+    seen = ((k_pos <= q_pos) & (k_pos > q_pos - i32(window))
+            & (k_pos < i32(kv_len)))
+    s = jnp.where(seen, s, _NEG_INF)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    # a row that has seen nothing yet: exp(-inf - -inf) would be nan
+    m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+    p = jnp.exp(s - m_safe)
+    alpha = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
+    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+        p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == qi)
+    def _fin():
+        l = l_scr[:, :1]
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def _fwd_window_pallas(q, k, v, sm_scale, block, window, kv_len):
+    """``q`` ``[B, H, S, D]``, ``k`` / ``v`` ``[B, H_kv, S, D]``, ``S`` a
+    multiple of ``block``; keys at or past ``kv_len`` are padding."""
+    B, H, S, D = q.shape
+    rep = H // k.shape[1]
+    qs = q.reshape(B * H, S, D)
+    ks = k.reshape(B * H // rep, S, D)
+    vs = v.reshape(B * H // rep, S, D)
+    _I0 = np.int32(0)
+
+    def kv(b):
+        return b if rep == 1 else jax.lax.div(b, np.int32(rep))
+
+    qi_t, ki_t, first_t = (jnp.asarray(a) for a in _band_table(
+        S // block, block, window))
+    qmp = lambda b, t, qt, kt, ft: (b, qt[t], _I0)  # noqa: E731
+    kmp = lambda b, t, qt, kt, ft: (kv(b), kt[t], _I0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_fwd_window_kernel, kv_len=kv_len, window=window,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B * H, qi_t.shape[0]),
+            in_specs=[pl.BlockSpec((1, block, D), qmp),
+                      pl.BlockSpec((1, block, D), kmp),
+                      pl.BlockSpec((1, block, D), kmp)],
+            out_specs=pl.BlockSpec((1, block, D), qmp),
+            scratch_shapes=[
+                pltpu.VMEM((block, 128), jnp.float32),  # running max
+                pltpu.VMEM((block, 128), jnp.float32),  # running sum
+                pltpu.VMEM((block, D), jnp.float32),    # output accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=not _device.on_tpu(), name="flash_fwd_window",
+    )(qi_t, ki_t, first_t, qs, ks, vs)
+    return out.reshape(B, H, S, D)
+
+
 # ---------------------------------------------------------------------------
 # backward (flash-2 recurrence)
 # ---------------------------------------------------------------------------
@@ -632,6 +735,34 @@ def _dkv_tuned(q, k, v, do, lse, delta, *, causal, sm_scale, q_offset,
                     block_k, q_offset, kv_len)
 
 
+def _window_space(q, k, v, *, window, **_):
+    """Square blocks for the band grid: a block narrower than the window
+    visits more tiles, a wider one masks more of each."""
+    S, D = q.shape[2], q.shape[3]
+    itemsize = np.dtype(q.dtype).itemsize
+    return [{"block": b} for b in _at.tile_candidates(
+        S, base=(128, 256, 512))
+        if _at.vmem_fits(4 * b * D * itemsize
+                         + (2 * b * 128 + 3 * b * D + 2 * b * b) * 4)]
+
+
+def _window_heuristic(q, k, v, *, window, **_):
+    # the window's own width in whole lane tiles, at most 512
+    return {"block": min(512, _round_up(window, 128))}
+
+
+@_at.autotune("flash_fwd_window", params=("block",), space=_window_space,
+              heuristic=_window_heuristic, key_kwargs=("window",))
+def _window_tuned(q, k, v, *, window, sm_scale, block):
+    S = q.shape[2]
+    b = _pick_block(block, S)
+    Sp = _round_up(S, b)
+    if Sp != S:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+                   for t in (q, k, v))
+    return _fwd_window_pallas(q, k, v, sm_scale, b, window, S)[:, :, :S]
+
+
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
@@ -758,7 +889,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    q_position_offset: int = 0):
+                    q_position_offset: int = 0,
+                    window: Optional[int] = None):
     """Memory-efficient attention.
 
     Args are [batch, num_heads, seq, head_dim] (q may have a different seq
@@ -772,6 +904,11 @@ def flash_attention(q, k, v, causal: bool = False,
     to block multiples and the kernels mask padded key positions, so there
     is no O(S²) fallback.
 
+    ``window`` (causal self-attention from position 0, forward only): query
+    ``qp`` sees the ``window`` keys ``qp - window + 1 .. qp``, its own
+    included; the grid visits only the key blocks that band touches, under
+    one square block (``block_q``, else the autotuner's).
+
     Block sizes default to the autotuner (``ops.autotune``): a measured
     search on TPU — the forward and both backward kernels pick their tile
     sizes independently, memoized persistently per shape bucket — and the
@@ -782,6 +919,16 @@ def flash_attention(q, k, v, causal: bool = False,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     S, K = q.shape[2], k.shape[2]
+    if window is not None:
+        if (not causal or q_position_offset or S != K or window < 1
+                or q.shape[1] % k.shape[1] or v.shape != k.shape):
+            raise ValueError(
+                f"flash_attention: a window is for causal self-attention "
+                f"from position 0 (causal={causal}, offset "
+                f"{q_position_offset}, q {q.shape}, k {k.shape}, v "
+                f"{v.shape}, window {window})")
+        return _window_tuned(q, k, v, window=int(window),
+                             sm_scale=float(sm_scale), block=block_q)
     tuned = block_q is None and block_k is None
     if tuned:
         cfg = _fwd_tuned.config(q, k, v, causal=causal,

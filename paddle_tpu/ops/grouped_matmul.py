@@ -266,13 +266,141 @@ def _gated_mlp_kernel(tg_ref, ti_ref, used_ref, x_ref, wg_ref, wu_ref,
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
+#: the most VMEM an expert call asks for: a v5e core has 128 MiB, the
+#: compiler and the pipeline's own buffers want some of it
+_VMEM_CAP = 100 << 20
+
+
+def _gated_mlp_vmem(tm, D, F, item):
+    """x and out tiles, the three weight blocks of ``F`` columns (all
+    double-buffered) and the f32 intermediates of one tile."""
+    return 2 * (2 * tm * D + 3 * D * F) * item + 4 * tm * (3 * F + D)
+
+
+def _vmem_limit(need):
+    return int(min(max(need * 5 // 4, 16 << 20), _VMEM_CAP))
+
+
+def _gated_mlp_kernel_wide(tg_ref, ti_ref, used_ref, x_ref, wg_ref, wu_ref,
+                           wd_ref, o_ref, acc_ref):
+    """One (row tile, block of the expert's width) step: the block's part
+    of the down projection joins the tile's float32 accumulator, which is
+    written once, at the last block."""
+    del tg_ref, ti_ref  # consumed by the index maps
+    f = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        @pl.when(f == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        acc_ref[...] += jnp.dot(h, wd_ref[0],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(f == pl.num_programs(1) - 1)
+        def _fin():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _wide_blocks(tm, D, F, item):
+    """Blocks of an expert's width, widest first, that divide it in whole
+    lane tiles and whose call fits :data:`_VMEM_CAP` (none does: the
+    narrowest)."""
+    blocks = [bf for bf in range(F - _at.LANE, 0, -_at.LANE) if F % bf == 0]
+    fit = [bf for bf in blocks if bf > _at.LANE and (
+        _gated_mlp_vmem(tm, D, bf, item) + 4 * tm * D) * 5 // 4 <= _VMEM_CAP]
+    return fit or blocks[-1:]
+
+
+def _wide_space(xs, w_gate, w_up, w_down, *, tile_m):
+    (_, D, F), item = w_gate.shape, np.dtype(xs.dtype).itemsize
+    return [{"block_f": bf} for bf in _wide_blocks(tile_m, D, F, item)]
+
+
+def _wide_heuristic(xs, w_gate, w_up, w_down, *, tile_m):
+    # the widest block that fits: the fewest grid steps a tile
+    return _wide_space(xs, w_gate, w_up, w_down, tile_m=tile_m)[0]
+
+
+@_at.autotune("moe_gated_mlp_wide", params=("block_f",), space=_wide_space,
+              heuristic=_wide_heuristic, key_kwargs=("tile_m",))
+def _gated_mlp_wide_measured(xs, w_gate, w_up, w_down, *, tile_m, block_f):
+    """The measurable unit of the ``block_f`` search: every tile in use,
+    tile ``t`` the rows of expert ``t mod E`` (a decode step's pattern: an
+    expert's matrices are fetched for one tile)."""
+    NT, E = xs.shape[0] // tile_m, w_gate.shape[0]
+    tiles = jnp.arange(NT, dtype=jnp.int32)
+    lay = {"tiles": NT, "tile_m": tile_m, "tile_group": tiles % E,
+           "tile_index": tiles, "used": jnp.full((1,), NT, jnp.int32)}
+    return _gated_mlp_wide(xs, w_gate, w_up, w_down, lay, block_f)
+
+
+def _gated_mlp_wide(xs, w_gate, w_up, w_down, lay, bf):
+    """An expert too wide for VMEM whole: grid ``(NT, F / bf)``, the
+    ``[tm, D]`` output accumulated in float32 scratch over the blocks of
+    the width.  A tile in use fetches its expert's matrices block by block,
+    so an expert's second row tile reads them again; a tile past the last
+    one in use fetches nothing."""
+    NT, tm = lay["tiles"], lay["tile_m"]
+    E, D, F = w_gate.shape
+    item = np.dtype(xs.dtype).itemsize
+    need = _gated_mlp_vmem(tm, D, bf, item) + 4 * tm * D
+    last = np.int32(F // bf - 1)
+
+    def blk(t, f, u):
+        # a tile not in use keeps the block the last tile in use ended on:
+        # its index does not move, so nothing is fetched for it
+        return jnp.where(t < u[0], f, last)
+
+    return pl.pallas_call(
+        _gated_mlp_kernel_wide,
+        name=f"moe_gated_mlp_tm{tm}",
+        interpret=not _device.on_tpu(),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(NT, F // bf),
+            in_specs=[
+                pl.BlockSpec((tm, D),
+                             lambda t, f, tg, ti, u: (ti[t], _at.I0)),
+                pl.BlockSpec((1, D, bf), lambda t, f, tg, ti, u: (
+                    tg[t], _at.I0, blk(t, f, u))),
+                pl.BlockSpec((1, D, bf), lambda t, f, tg, ti, u: (
+                    tg[t], _at.I0, blk(t, f, u))),
+                pl.BlockSpec((1, bf, D), lambda t, f, tg, ti, u: (
+                    tg[t], blk(t, f, u), _at.I0)),
+            ],
+            out_specs=pl.BlockSpec((tm, D),
+                                   lambda t, f, tg, ti, u: (ti[t], _at.I0)),
+            scratch_shapes=[pltpu.VMEM((tm, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((NT * tm, D), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(need)),
+    )(lay["tile_group"], lay["tile_index"], lay["used"], xs, w_gate, w_up,
+      w_down)
+
+
 def _gated_mlp_pallas(xs, w_gate, w_up, w_down, lay):
     NT, tm = lay["tiles"], lay["tile_m"]
     E, D, F = w_gate.shape
     item = np.dtype(xs.dtype).itemsize
-    # x and out tiles, the three weight blocks (all double-buffered) and
-    # the f32 intermediates of one tile
-    need = 2 * (2 * tm * D + 3 * D * F) * item + 4 * tm * (3 * F + D)
+    need = _gated_mlp_vmem(tm, D, F, item)
+    if need * 5 // 4 > _VMEM_CAP:
+        # one whole expert, double-buffered, does not fit: tile its width
+        # the search's stand-ins: a few tiles over two experts (the real
+        # operands' twins would not fit beside a model that fills the chip)
+        cfg = _gated_mlp_wide_measured.config(
+            jax.ShapeDtypeStruct((min(NT, 32) * tm, D), xs.dtype),
+            *(jax.ShapeDtypeStruct((min(E, 2), *w.shape[1:]), w.dtype)
+              for w in (w_gate, w_up, w_down)), tile_m=tm)
+        return _gated_mlp_wide(xs, w_gate, w_up, w_down, lay,
+                               cfg["block_f"])
     return pl.pallas_call(
         _gated_mlp_kernel,
         name=f"moe_gated_mlp_tm{tm}",
@@ -295,8 +423,7 @@ def _gated_mlp_pallas(xs, w_gate, w_up, w_down, lay):
         out_shape=jax.ShapeDtypeStruct((NT * tm, D), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(min(max(need * 5 // 4, 16 << 20),
-                                     100 << 20))),
+            vmem_limit_bytes=_vmem_limit(need)),
     )(lay["tile_group"], lay["tile_index"], lay["used"], xs, w_gate, w_up,
       w_down)
 

@@ -303,9 +303,10 @@ def _heuristic(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
     return fits[0] if fits else {"block_h": _head_blocks(H, hd)[-1]}
 
 
-@functools.partial(jax.jit, static_argnames=("block_h", "sm_scale"))
+@functools.partial(jax.jit, static_argnames=("block_h", "sm_scale", "name"))
 def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
-           v_scale, *, block_h: int, sm_scale: float):
+           v_scale, *, block_h: int, sm_scale: float,
+           name: str = "paged_decode"):
     B, H, T, hd = q.shape
     P1, page, D = k_pool.shape
     G = tables.shape[1]
@@ -387,7 +388,7 @@ def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=not _device.on_tpu(),
-        name="paged_decode",
+        name=name,
     )(tab, nb, *operands)
     return out[:, :, :T, :]
 
@@ -403,7 +404,8 @@ def _paged_decode(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
                   sm_scale=1.0 / math.sqrt(q.shape[3]))
 
 
-def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound):
+def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound,
+                  name="paged_decode"):
     """The decode width, ``q`` ``[B, H, 1, hd]`` over float pages: the
     heads become the query rows of ONE head as wide as a pool row (see
     the module docstring), so a block costs two products, not 2H.  With
@@ -423,7 +425,8 @@ def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound):
     qbd = jnp.pad(qbd, ((0, 0), (0, Hp - H), (0, 0)))[:, None]
     out = _sweep(qbd, k_pool, v_pool, tables, pos_map,
                  jnp.broadcast_to(positions, (B, Hp)), bound, None, None,
-                 block_h=1, sm_scale=1.0 / math.sqrt(hd))  # [B, 1, Hp, D]
+                 block_h=1, sm_scale=1.0 / math.sqrt(hd),
+                 name=name)                                # [B, 1, Hp, D]
     # row h's own lanes are head h's context; the rest is other heads'
     # values under head h's weights, dropped
     out = out[:, 0, :H].reshape(B, H, Hkv, hd)
@@ -446,7 +449,8 @@ def _kv_heads(q, k_pool):
 
 def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
                        bound=None, k_scale=None, v_scale=None, *,
-                       block_h: Optional[int] = None):
+                       block_h: Optional[int] = None,
+                       name: str = "paged_decode"):
     """Flash decode over a paged KV pool, page walk in-kernel.
 
     q: ``[B, H, T, hd]`` query block (T = 1 or the speculative ``1+k``
@@ -471,7 +475,9 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
     step) defaults to the autotuner; pass it explicitly to bypass tuning.
     At the decode width over float pages there is nothing to tune: the
     whole row is swept as one block-diagonal head (module docstring)
-    unless ``block_h`` asks for the per-head form.
+    unless ``block_h`` asks for the per-head form.  ``name`` names the
+    kernel's call in the program and the trace (a model whose per-slot key
+    rings are one-page pools tells those calls from its page pools').
     """
     if (k_scale is None) != (v_scale is None):
         raise InvalidArgumentError(
@@ -480,7 +486,7 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
     if q.shape[2] == 1 and k_scale is None and block_h is None:
         _kv_heads(q, k_pool)
         return _decode_width(q, k_pool, v_pool, tables, pos_map, positions,
-                             bound)
+                             bound, name)
     rep = q.shape[1] // _kv_heads(q, k_pool)
     if rep > 1:
         # the rep query heads of a K/V head are rep x T query rows of it
@@ -491,13 +497,14 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
         B, H, T, hd = q.shape
         out = paged_flash_decode(
             q.reshape(B, H // rep, rep * T, hd), k_pool, v_pool, tables,
-            pos_map, jnp.tile(positions, (1, rep)), bound, block_h=block_h)
+            pos_map, jnp.tile(positions, (1, rep)), bound, block_h=block_h,
+            name=name)
         return out.reshape(B, H, T, hd)
     cfg = _paged_decode.resolve(q, k_pool, v_pool, tables, pos_map,
                                 positions, k_scale, v_scale, block_h=block_h)
     return _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound,
                   k_scale, v_scale, sm_scale=1.0 / math.sqrt(q.shape[3]),
-                  **cfg)
+                  name=name, **cfg)
 
 
 def paged_attention(q, k_pool, v_pool, gather_tab, mask, walk=None,

@@ -65,9 +65,12 @@ mean decode step against half its mean admission call (:func:`hold_pays`),
 the chunk's rows go back to the head of the queue before anything was done
 for them, and the freed slot's row joins them in one call.
 
-**Slot state beside the pages.**  A model may keep recurrent state per
-slot (a linear-attention layer's matrix state and conv window) that is no
-function of pages.  It says so by ``slot_state = True`` and the protocol
+**Slot state beside the pages.**  A model may keep state per slot that is no
+function of pages: a linear-attention layer's matrix state and conv
+window, or a WINDOW layer's K and V, which a ring of ``window`` rows a slot
+holds whatever the context (position ``p`` in row ``p % window``; what a
+row holds is arithmetic on the query's position, so an admission overwrites
+what it needs and nothing is reset).  It says so by ``slot_state = True`` and the protocol
 grows by one keyword, ``slots``, on two verbs: ``init_paged_cache(...,
 slots=B)`` returns a cache whose per-slot leaves have ``B + 1`` rows (row
 ``B`` is the write-drop row, as page ``P`` is the write-drop page), and
@@ -82,7 +85,9 @@ state is refused at construction or at ``submit``, by name: speculation (a
 rejected draft cannot be rolled back), ``role != 'any'`` and ``handoff=``
 (the payload carries pages), ``quantized=``; a ``prefix_key`` is served
 cold and counted (``prefix_unshared``: mapped pages would come without the
-state at the prefix's boundary).  A model without the attribute is handed
+state at the prefix's boundary).  All of these apply to a window layer's
+rings as they stand (a ring of exactly ``window`` rows would lose the key a
+rejected draft overwrote).  A model without the attribute is handed
 neither keyword and builds the programs it always built.
 
 The compile set is closed and traced in :meth:`warmup`:

@@ -686,3 +686,165 @@ def test_the_qwen3_next_engine_lowers_its_programs_for_the_chip(
              if "tpu_custom_call" in line and " custom-call(" in line]
     for kernel, n in kernels.items():
         assert sum(kernel in c for c in calls) == n, (kernel, calls)
+
+
+# -- window rings, a window in the flash forward and an expert wider than
+# -- VMEM: the kernels at the k_exaone_serve configuration's shapes ---------------
+@pytest.mark.parametrize("T", [1536, 4096])
+@pytest.mark.parametrize("block", [128, 256, 512])
+def test_windowed_flash_forward_64_heads_over_8_kv_heads_of_128(chip, T,
+                                                                block):
+    # a one-row admission's window layer: every candidate of the search
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=128,
+                               block_q=block)
+
+    one = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, bf16, sharding=one)
+            for s in ((1, 64, T, 128), (1, 8, T, 128), (1, 8, T, 128))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "flash_fwd_window" in text
+
+
+def test_window_decode_reads_32_rings_of_128_rows_of_1024_lanes(chip):
+    # a decode step's window layer: the rings are a pool of one 128-row
+    # page a slot (row 32 the drop row), 64 query heads on 8 K/V heads
+    from paddle_tpu.models.hybrid import _paged_flash
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    assert _paged_flash(128, 128)
+    B, H, Hkv, hd, W = 32, 64, 8, 128, 128
+
+    def fn(q, rk, rv, tab, held, pos, bound):
+        return paged_flash_decode(q, rk, rv, tab, held, pos, bound,
+                                  name="window_decode")
+
+    one = SingleDeviceSharding(chip)
+    shapes = (((B, H, 1, hd), bf16), ((B + 1, W, Hkv * hd), bf16),
+              ((B + 1, W, Hkv * hd), bf16), ((B, 1), i32), ((B, W), i32),
+              ((B, 1), i32), ((B,), i32))
+    text = jax.jit(fn).lower(*[jax.ShapeDtypeStruct(s, d, sharding=one)
+                               for s, d in shapes]).compile().as_text()
+    assert "tpu_custom_call" in text and "window_decode" in text
+
+
+def test_paged_decode_reads_8_kv_heads_of_128_for_64_query_heads(chip):
+    # a decode step's global layer: 32 slots x 288 pages of 16, pool rows
+    # of 8 x 128 = 1024 lanes, rep 8
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    B, H, Hkv, hd, page, G = 32, 64, 8, 128, 16, 288
+    pages = B * G + 1
+    _compiles_with_kernel(
+        chip, paged_flash_decode, ((B, H, 1, hd), bf16),
+        ((pages, page, Hkv * hd), bf16), ((pages, page, Hkv * hd), bf16),
+        ((B, G), i32), ((B, G * page), i32), ((B, 1), i32), ((B,), i32))
+
+
+@pytest.mark.parametrize("rows,tile,blocks", [
+    (32 * 8, 16, (1024, 512, 256)), (4096 * 8, 128, (512, 256))],
+    ids=["decode", "admit_1x4096"])
+def test_ragged_gated_mlp_16_held_of_128_experts_of_6144_by_2048(
+        chip, rows, tile, blocks):
+    # one chip's share of a k_exaone layer: an expert's three matrices,
+    # double-buffered, are 151 MB, so the kernel tiles the expert's width;
+    # every candidate of the search, and the call the layer makes
+    import importlib
+
+    gm = importlib.import_module("paddle_tpu.ops.grouped_matmul")
+    held, D, F = 16, 6144, 2048
+    assert tuple(gm._wide_blocks(tile, D, F, 2)) == blocks
+
+    def layer(block_f):
+        def fn(x, ids, wg, wu, wd):
+            lay = gm.ragged_layout(ids, held, tile, partial=True)
+            n = lay["tiles"] * tile
+            src = jnp.full((n,), rows, i32).at[lay["dest"]].set(
+                jnp.arange(rows, dtype=i32), mode="drop")
+            xs = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[src]
+            ys = (gm.ragged_gated_mlp(xs, wg, wu, wd, lay)
+                  if block_f is None
+                  else gm._gated_mlp_wide(xs, wg, wu, wd, lay, block_f))
+            return jnp.where(lay["present"][:, None],
+                             ys[jnp.minimum(lay["dest"], n - 1)], 0)
+        return fn
+
+    shapes = (((rows, D), bf16), ((rows,), i32), ((held, D, F), bf16),
+              ((held, D, F), bf16), ((held, F, D), bf16))
+    for block_f in (None, *blocks):
+        _compiles_with_kernel(chip, layer(block_f), *shapes)
+
+
+@pytest.mark.parametrize("program,kernels", [
+    # one period of the model, layer 0 dense: three window layers' decode
+    # over the rings, the global layer's paged_decode, three expert kernels
+    ("step", {"window_decode": 3, "paged_decode": 1,
+              "moe_gated_mlp_tm16": 3, "": 7}),
+    # a [1, 4096] admission: three windowed flash calls, the global layer's
+    # grouped flash call, three expert kernels at the 128-row tile
+    ("admit", {"flash_fwd_window": 3, "flash_fwd_grouped": 1,
+               "moe_gated_mlp_tm128": 3, "": 7}),
+])
+def test_the_k_exaone_engine_lowers_its_programs_for_the_chip(
+        chip, program, kernels):
+    # benchmarks/configs/k_exaone_serve.json at published widths, one
+    # period of its layers, weights that are shapes only: 32 slots x 4608
+    # positions, the engine's own jitted programs with every TPU-only
+    # branch taken (rings, pages AND experts in one program)
+    import json
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.harness import loader
+    from paddle_tpu import nn
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    bench = os.path.join(repo, "benchmarks")
+    with open(os.path.join(bench, "configs", "k_exaone_serve.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 4}
+    with open(os.path.join(bench, "traffic", "ragdocs_closed.json")) as f:
+        buckets = json.load(f)["prompt_buckets"]
+    fam = loader.load_module("families", cfg["family"], bench)
+    serve = cfg["serve"]
+    with nn.abstract_parameters():
+        model = fam.HybridForCausalLM(fam.model_config(cfg))
+    eng = GenerationEngine(
+        model, prompt_buckets=buckets, batch_size=serve["batch_size"],
+        cache_len=serve["cache_len"], kv_page_size=serve["kv_page_size"],
+        speculative_k=0, eos_token_id=None, name="compile-only-kex")
+    try:
+        assert eng._admit_rows == {b: 1 for b in buckets}
+        one = SingleDeviceSharding(chip)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+        B, T, C = serve["batch_size"], buckets[-1], serve["cache_len"]
+        G = C // serve["kv_page_size"]
+        pool = on_chip(jax.eval_shape(eng._empty_pool))
+        assert pool["layers"][3]["k"].shape == (B * G + 1, 16, 1024)
+        # the window layers' cache is no function of the context
+        assert pool["layers"][0]["ring_k"].shape == (B + 1, 128, 1024)
+        params, buffers = on_chip(eng._params), on_chip(eng._buffers)
+        if program == "step":
+            text = eng._step_jit.lower(params, buffers, ints(B, 2 + C + G),
+                                       ints(B, 1), pool).compile().as_text()
+        else:
+            text = eng._padmit.lower(
+                params, buffers, ints(1, T), ints(1, T), ints(1, C),
+                ints(1, G), ints(1), pool, None, ints(1)).compile().as_text()
+    finally:
+        eng.close()
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    for kernel, n in kernels.items():
+        assert sum(kernel in c for c in calls) == n, (kernel, calls)

@@ -116,7 +116,8 @@ for config, traffic, layers in (("gpt2_small_serve", "docs_closed", 0),
                                 ("gpt2_small_serve", "chat_open", 0),
                                 ("joyai_flash_serve", "ragdocs_closed", 2),
                                 ("olmo_hybrid_serve", "ragdocs_closed", 4),
-                                ("qwen3_next_serve", "longgen_closed", 4)):
+                                ("qwen3_next_serve", "longgen_closed", 4),
+                                ("k_exaone_serve", "ragdocs_closed", 4)):
     if not os.path.exists(os.path.join(bench, "configs", config + ".json")):
         continue  # a tree from before the configuration
     eng, buckets = engine_of(config, traffic, layers)
