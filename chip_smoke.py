@@ -429,8 +429,15 @@ def serve_phase(mon, cfg=None, buckets=(64, 256, 512), batch_size=4,
     served, info = _serve(model, prompts, list(new_tokens), mon,
                           prompt_buckets=list(buckets),
                           batch_size=batch_size, name="chip-smoke")
-    counters = kernel_counters("paged_decode")
-    check_dispatched(counters)
+    # paged_decode has nothing to search (its tile is fixed by the shape,
+    # its heads a step by rule), so the tuner's bus does not see it: the
+    # model's own gate says whether the kernel went into the programs
+    from paddle_tpu.framework.flags import flag
+    from paddle_tpu.models.gpt import _paged_flash
+    counters = {"paged_decode": {"gate_open": _paged_flash(
+        cfg.hidden_size // cfg.num_heads, int(flag("kv_page_size")))}}
+    check(counters["paged_decode"]["gate_open"],
+          "the paged_decode kernel's gate is shut on the chip")
     hist, starts, counts = histories(prompts, served)
     ref = _teacher_forced_logits(model, hist, starts, counts, "highest")
     dflt = _teacher_forced_logits(model, hist, starts, counts, "default")
@@ -441,13 +448,8 @@ def serve_phase(mon, cfg=None, buckets=(64, 256, 512), batch_size=4,
     q_served, q_info = _serve(model, q_prompts, list(int8_new_tokens), mon,
                               prompt_buckets=[int8_bucket], batch_size=2,
                               quantized="int8", name="chip-smoke-int8")
-    q_counters = kernel_counters("paged_decode", "quantized_matmul")
+    q_counters = kernel_counters("quantized_matmul")
     check_dispatched(q_counters)
-    check(q_counters["paged_decode"]["searches"]
-          + q_counters["paged_decode"]["disk_hits"]
-          > counters["paged_decode"]["searches"]
-          + counters["paged_decode"]["disk_hits"],
-          "the int8 pool resolved no paged_decode config of its own")
     for toks in q_served:
         check(all(0 <= t < cfg.vocab_size for t in toks),
               "int8 engine served a token outside the vocabulary")

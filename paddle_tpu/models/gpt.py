@@ -604,7 +604,9 @@ class GPTModel(Layer):
         # the kernel's side of the same rule, once for all the layers: what
         # it rebuilds the mask from, and how many key blocks of each slot
         # hold a key that some row of this call can see (0: a free slot, a
-        # padding row; the whole window: a slot that has wrapped)
+        # padding row; the whole window: a slot that has wrapped); at an
+        # admission width, of each TILE of query rows, so that the kernel
+        # walks the causal triangle and not its square
         walk = None
         if _paged_flash(self.cfg.hidden_size // self.cfg.num_heads, page):
             walk = (pos_map, positions, sweep_bound(mask, page))
@@ -642,7 +644,10 @@ class GPTForCausalLM(Layer):
     # with recurrent state per slot beside its pages also declares
     # ``slot_state = True``, takes ``slots=`` in ``init_paged_cache`` and
     # in an admission's ``forward_paged`` and answers ``slot_state_bytes()``
-    # (models/hybrid.py); this one has pages only ---------------------------
+    # (models/hybrid.py); this one has pages only.  ``admit_page_walk``: an
+    # admission's attention is the page walk of ops/paged_attention.py too,
+    # so the loop counts the blocks it walks -------------------------------
+    admit_page_walk = True
     max_position = property(lambda self: self.gpt.cfg.max_position)
     moe_experts = property(
         lambda self: int(getattr(self.gpt.cfg, "moe_experts", 0) or 0))

@@ -28,17 +28,36 @@ is that kernel for TPU, in the shape of the repo's other Pallas kernels:
   scalar-prefetched i32 page table and copied into a two-slot VMEM buffer
   by ``make_async_copy`` (the ``bh*hd`` lanes of this step's heads of each
   page's rows), block ``i+1`` in flight while block ``i`` is multiplied;
-* the loop walks only the blocks of the slot's first ``bound[b]`` pages,
-  a second scalar-prefetched operand: the slot's SWEEP BOUND
-  (:func:`sweep_bound`), up to the last page that holds a key some query
-  row can see, in whole blocks; 0 for a free slot or a padding row.  ``GPTModel.forward_paged`` computes it once a program
+* a call of more than :data:`QUERY_TILE` (320) query rows, an admission
+  bucket, is cut evenly into the fewest QUERY TILES of at most that many
+  rows (:func:`query_tile`: 768 -> 3 x 256, 640 -> 2 x 320), and the tile
+  is a grid axis: grid ``(B, nq, H/bh)``, the same kernel body.  The q, out and
+  position blocks and the online-softmax scratch are a TILE's, so VMEM does
+  not grow with the bucket (a step that held a 768-row bucket whole had
+  room for two of GPT-2's twelve heads and 896 rows did not compile; a tile
+  takes all twelve, the page table walked once and each page row fetched in
+  one piece for all of them).  A call of at most one tile (the decode step,
+  the verify width, a short bucket) keeps the two-axis grid and is the
+  program it was;
+* the loop walks only the blocks of the first ``bound`` pages, a second
+  scalar-prefetched operand: the SWEEP BOUND (:func:`sweep_bound`), up to
+  the last page that holds a key some query row of the step can see, in
+  whole blocks; 0 for a free slot or a padding row.  It is a slot's,
+  ``[B]``, for a one-tile call and a tile's, ``[B, nq]``, past it: each
+  tile is swept to ITS OWN last visible key block, so an admission walks
+  the causal triangle and not its square, and a tile of padding rows
+  nothing.  Skipping is exact: a block in which no row of the tile sees a
+  key would leave the running max, sum and accumulator bit for bit as
+  they were.  ``GPTModel.forward_paged`` computes the bound once a program
   from the validity mask it builds anyway and every layer shares it; it
-  is an upper bound only (a ring-wrapped slot gets the whole window):
-  every key inside a walked block is still tested by the model's rule;
+  is data, not shape (a row admitted behind a shared prefix reads the
+  prefix's blocks from its first tile), and an upper bound only (a
+  ring-wrapped slot gets the whole window): every key inside a walked
+  block is still tested by the model's rule;
 * that rule (:func:`key_visible`: a real token, causally at or before the
   query, within the last ``C`` positions) is rebuilt in the kernel from
   the slot's ``pos_map`` row and the rows' ``positions`` — three integer
-  comparisons on a ``[Tp, 128]`` tile, shared by the block's heads —
+  comparisons on a ``[rows, 128]`` tile, shared by the block's heads —
   instead of moving a ``[B, T, C]`` float mask through HBM (at an
   admission width the mask tile was four times the K/V bytes).  Causality,
   ragged page counts, the write-drop page and the speculative ``1+k``
@@ -72,15 +91,17 @@ partial sum); the reference path stays the bit-identical CPU/fallback —
 ``fused_epilogues_eligible`` does for the other epilogues (TPU backend,
 one-device mesh, aligned dims).
 
-Tile parameters resolve through ``ops.autotune`` (kernel name
-``"paged_decode"``): ``block_h`` — heads per grid step, i.e. how many
-lanes of a page row one step fetches — trades grid steps against VMEM
-residency, which the query width sets: a verify width takes all heads; a
-768-token admission block takes two (the decode width over float pages
-has nothing to tune, see above).  The search times the sweep of the whole
-window (synthetic arguments carry no bound).  Candidates are the divisors
-of H whose ``bh*hd`` lanes are whole lane tiles (or all of ``H*hd``) and
-that fit the VMEM budget; per-candidate equivalence is tested in
+There is nothing to tune, so two checkouts of one tree build one program:
+the key block is fixed by the page size, the query tile by the shape
+(:data:`QUERY_TILE`), and ``block_h`` — heads per grid step, i.e. how many
+lanes of a page row one step fetches — is by rule the most that fit the
+VMEM budget (:func:`_heads_a_step`) among the divisors of H whose
+``bh*hd`` lanes are whole lane tiles (or all of ``H*hd``).  Until PR 42
+``block_h`` was a measured search of ``ops.autotune``, whose near-ties fell
+either way in a cold checkout; the rule is the winner of
+``tools/paged_decode_chip.py --admit`` on the chip.  ``block_h=`` stays as
+an explicit argument; per-block equivalence and the tiled grid's bit
+identity with the one-tile grid are tested in
 tests/test_paged_attention.py.
 """
 from __future__ import annotations
@@ -101,7 +122,7 @@ from ..framework.flags import flag
 from . import autotune as _at
 
 __all__ = ["paged_flash_decode", "paged_flash_eligible", "paged_attention",
-           "key_visible", "block_pages", "sweep_bound"]
+           "key_visible", "block_pages", "sweep_bound", "query_tile"]
 
 # mask fill; exp(_NEG - m) underflows to exactly 0.0 in f32.  Typed f32:
 # under the package's global x64 a bare Python float reaches ``jnp.where``
@@ -127,26 +148,77 @@ def block_pages(page: int) -> int:
     return -(-_at.LANE // page)
 
 
-def sweep_bound(visible, page: int):
-    """Logical pages the sweep has to walk for each slot: up to the last
-    page that holds a key visible to ANY query row, rounded up to whole
-    key blocks (:func:`block_pages` pages; the window's end cuts the last
-    one), 0 where nothing is visible.  ``visible``: ``[B, T, C]`` bool
-    (numpy or jax), the mask of :func:`key_visible`; returns ``[B]``
-    int32.  A ring-wrapped slot's live pages are not a prefix of its
-    table: its bound is simply the whole window."""
-    B, _, C = visible.shape
+#: Query rows a grid step holds at most: a call of more rows (an admission
+#: bucket) is cut evenly into the fewest tiles of at most this many
+#: (:func:`query_tile`), each swept to its own bound.  Fixed by the shape,
+#: nothing to search: the winner of ``tools/paged_decode_chip.py --admit``
+#: on a v5e (a tile's every (head, key block) chain of products costs
+#: 0.4 us whatever its rows and 0.19 us per 128 rows: 128-row tiles lose
+#: the triangle's gain to their chains, 256 to 320 keep it).
+QUERY_TILE = 320
+
+
+def query_tile(T: int, most: int = QUERY_TILE) -> int:
+    """Rows of a query tile of a call of ``T`` rows: the call whole up to
+    ``most`` rows (one tile: the grid a decode step has), else ``T`` cut
+    evenly into the fewest tiles of at most ``most``, in whole sublane
+    tiles (768 -> 3 x 256, 640 -> 2 x 320, 1024 -> 4 x 256)."""
+    Tp = -(-T // _at.SUBLANE) * _at.SUBLANE
+    nq = -(-Tp // most)
+    return -(-Tp // (nq * _at.SUBLANE)) * _at.SUBLANE
+
+
+#: Heads of a grid step whose chains of products (scores, weights, context)
+#: the kernel issues abreast, phase by phase: a chain is bound by the
+#: latency of its dependent products, not by their work
+#: (``tools/paged_decode_chip.py --admit``: 0.159 ms one head at a time,
+#: 0.117 four abreast, a ``[2, 768]`` layer call in tiles of 256 on a v5e).
+#: The decode width sweeps ONE head as wide as a pool row: nothing abreast
+_ABREAST = 4
+
+#: VMEM the tiled grid's kernel may use (the compiler's default scope is
+#: ``autotune.VMEM_BYTES``, which the one-tile programs keep)
+_TILED_VMEM = 32 * 1024 * 1024
+
+
+def sweep_bound(visible, page: int, tile: Optional[int] = None):
+    """Logical pages the sweep has to walk: up to the last page that holds
+    a key visible to ANY query row, rounded up to whole key blocks
+    (:func:`block_pages` pages; the window's end cuts the last one), 0
+    where nothing is visible.  ``visible``: ``[B, T, C]`` bool (numpy or
+    jax), the mask of :func:`key_visible`.  A call of at most ``tile`` rows
+    (:func:`query_tile` of the call's, unless given) is one tile: ``[B]``
+    int32, a slot's bound.  A wider one gets a bound a QUERY TILE, ``[B,
+    ceil(T / tile)]``: the causal triangle's rows, not its square; 0 for a
+    tile of padding rows.  A ring-wrapped slot's live pages are not a
+    prefix of its table: its bounds are simply the whole window."""
+    B, T, C = visible.shape
+    tile = tile or query_tile(T)
     G = C // page
-    live = visible.any(axis=1).reshape(B, G, page).any(axis=2)  # [B, G]
-    pages = (live * np.arange(1, G + 1, dtype=np.int32)).max(axis=1)
+    nth = np.arange(1, G + 1, dtype=np.int32)  # a page's number, from 1
+    if T <= tile:  # the reduction a step program has had since PR 30
+        live = visible.any(axis=1).reshape(B, G, page).any(axis=2)  # [B, G]
+        pages = (live * nth).max(axis=1)
+    else:  # the same, a tile of rows at a time
+        nq = -(-T // tile)
+        if nq * tile != T:
+            xp = np if isinstance(visible, np.ndarray) else jnp
+            visible = xp.pad(visible, ((0, 0), (0, nq * tile - T), (0, 0)))
+        live = visible.reshape(B, nq, tile, C).any(axis=2).reshape(
+            B, nq, G, page).any(axis=3)  # [B, nq, G]
+        pages = (live * nth).max(axis=2)
     ppb = block_pages(page)
     return ((pages + (ppb - 1)) // ppb * ppb).clip(0, G).astype(np.int32)
 
 
 def _kernel(tab_ref, bound_ref, q_ref, qp_ref, kp_ref, k_hbm, v_hbm, *refs,
-            block_h: int, window: int, sm_scale: float, quantized: bool):
-    """One (slot, head-block) step: the online-softmax sweep over the
-    slot's ``bound`` key blocks, each fetched by this step's own DMA."""
+            block_h: int, window: int, sm_scale: float, quantized: bool,
+            tiled: bool, abreast: int = 1):
+    """One (slot, head-block) step, or with ``tiled`` one (slot, query
+    tile, head-block) step: the online-softmax sweep of the step's query
+    rows over the key blocks of its own bound, each fetched by this step's
+    own DMA, ``abreast`` heads' products at a time.  One body for both
+    grids: a tile is a slot's rows, fewer."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
          m_s, l_s, acc_s) = refs
@@ -155,8 +227,12 @@ def _kernel(tab_ref, bound_ref, q_ref, qp_ref, kp_ref, k_hbm, v_hbm, *refs,
     # i32 constants are typed: under the package's global x64 a Python int
     # next to a traced i32 becomes an i64, which Mosaic does not lower
     i32 = np.int32
-    b, hb = pl.program_id(0), pl.program_id(1)
-    n = bound_ref[b]  # key blocks to walk
+    if tiled:  # grid (B, tiles, head blocks), bound [B, tiles]
+        b, hb = pl.program_id(0), pl.program_id(2)
+        n = bound_ref[b, pl.program_id(1)]
+    else:
+        b, hb = pl.program_id(0), pl.program_id(1)
+        n = bound_ref[b]  # key blocks to walk
     hd = q_ref.shape[-1]
     _, ppb, page, width = k_buf.shape  # two slots of ppb pages' lanes
     bk = ppb * page
@@ -196,7 +272,10 @@ def _kernel(tab_ref, bound_ref, q_ref, qp_ref, kp_ref, k_hbm, v_hbm, *refs,
             ks_all, vs_all = ks_buf[slot], vs_buf[slot]  # [ppb, page, H..]
             head_of = jax.lax.broadcasted_iota(jnp.int32, ks_all.shape, 2)
             h0 = hb * i32(block_h)  # first head of this block (i32)
-        for h in range(block_h):  # static unroll: 2-D MXU dots per head
+
+        def scores(h):
+            """Head ``h`` of this step's block: its scores against the key
+            block, and the block's values."""
             lanes = slice(h * hd, (h + 1) * hd)  # this head's lanes of a row
             q = q_ref[0, h].astype(jnp.float32)              # [Tp, hd]
             k = k_buf[slot, :, :, lanes].astype(jnp.float32)  # [ppb,page,hd]
@@ -214,19 +293,32 @@ def _kernel(tab_ref, bound_ref, q_ref, qp_ref, kp_ref, k_hbm, v_hbm, *refs,
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale  # [Tp, bk]
-            s = jnp.where(valid, s, _NEG)
+            return jnp.where(valid, s, _NEG), v
 
+        def weights(h, s):
+            """The running max and sum of head ``h`` take the block in."""
             m_prev = m_s[h]                       # [Tp, LANE], lanes equal
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)       # [Tp, LANE]
             # masked / padded keys -> 0 (a row with nothing yet: exp(0))
             p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), _ZERO)
             l_s[h] = l_s[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_s[h] = (acc_s[h] * alpha[:, :1]
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-            m_s[h] = m_new
+            return m_new, alpha, p
+
+        # static unroll, 2-D MXU dots per head, ``abreast`` heads at a time:
+        # a head's scores, weights and context are one chain of dependent
+        # products, bound by their latency; the chains of a group are
+        # issued phase by phase, for the compiler to overlap
+        for g0 in range(0, block_h, abreast):
+            heads = range(g0, min(g0 + abreast, block_h))
+            sv = [scores(h) for h in heads]
+            mp = [weights(h, s) for h, (s, _) in zip(heads, sv)]
+            for h, (_, v), (m_new, alpha, p) in zip(heads, sv, mp):
+                acc_s[h] = (acc_s[h] * alpha[:, :1]
+                            + jax.lax.dot_general(
+                                p, v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32))
+                m_s[h] = m_new
 
     def step(i, carry):
         """Iteration ``i`` of ``bound + 1``: start block ``i``'s copies,
@@ -262,51 +354,51 @@ def _head_blocks(H: int, hd: int):
             if H % bh == 0 and (bh == H or (bh * hd) % _at.LANE == 0)]
 
 
-def _space(q, k_pool, v_pool, tables, pos_map, positions, k_scale, v_scale):
-    """Candidate head-block sizes (:func:`_head_blocks`) whose resident
-    blocks fit the VMEM budget: the pipelined q/out/position blocks twice
-    (double-buffered), the two-slot K/V (and scale) buffers and the three
-    scratch accumulators once, minor dims padded to whole tiles as VMEM
-    holds them."""
-    B, H, T, hd = q.shape
+def _heads_a_step(q, k_pool, tables, quantized: bool,
+                  tile: Optional[int] = None) -> int:
+    """Heads a grid step takes, by rule: the most that fit
+    (:func:`_head_blocks`, largest first): the fewest grid steps, each page
+    row fetched in the fewest pieces (one, where the whole row fits), the
+    validity tile built once for all of them.  What a step keeps resident is
+    a TILE's, whatever the width of the call: the pipelined q / out /
+    position blocks twice (double-buffered), the two-slot K/V (and scale)
+    buffers and the three scratch accumulators once, minor dims padded to
+    whole tiles as VMEM holds them."""
+    _, H, T, hd = q.shape
     page = k_pool.shape[1]
     ppb = block_pages(page)
     bk, nblk = ppb * page, -(-tables.shape[1] // ppb)
-    Tp = -(-T // _at.SUBLANE) * _at.SUBLANE
+    tile = tile or query_tile(T)
+    tq = min(-(-T // _at.SUBLANE) * _at.SUBLANE, tile)
     kv_item = np.dtype(k_pool.dtype).itemsize
     q_item = np.dtype(q.dtype).itemsize
 
     def lanes(n):
         return -(-n // _at.LANE) * _at.LANE
 
-    out = []
-    for bh in _head_blocks(H, hd):
-        piped = (2 * bh * Tp * lanes(hd) * q_item      # q + out
-                 + Tp * _at.LANE * 4                   # positions column
+    # the tiled grid asks the compiler for its own scope of VMEM
+    budget = int(_at.VMEM_BUDGET_FRAC
+                 * (_TILED_VMEM if T > tile else _at.VMEM_BYTES))
+    blocks = _head_blocks(H, hd)
+    for bh in blocks:
+        piped = (2 * bh * tq * lanes(hd) * q_item      # q + out
+                 + tq * _at.LANE * 4                   # positions column
                  + nblk * _at.SUBLANE * lanes(bk) * 4)  # pos_map row
         bufs = 2 * 2 * bk * lanes(bh * hd) * kv_item   # k + v, two slots
-        if k_scale is not None:
+        if quantized:
             bufs += 2 * 2 * bk * lanes(H) * 4  # scale planes, all heads
-        scratch = bh * Tp * (2 * _at.LANE + lanes(hd)) * 4  # m/l/acc
-        if _at.vmem_fits(2 * piped + bufs + scratch):
-            out.append({"block_h": bh})
-    return out
+        scratch = bh * tq * (2 * _at.LANE + lanes(hd)) * 4  # m/l/acc
+        if 2 * piped + bufs + scratch <= budget:
+            return bh
+    return blocks[-1]
 
 
-def _heuristic(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
-               v_scale):
-    # the most heads that fit: the fewest grid steps, and each page row
-    # fetched in the fewest pieces (one, at the verify width)
-    fits = _space(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
-                  v_scale)
-    H, hd = q.shape[1], q.shape[3]
-    return fits[0] if fits else {"block_h": _head_blocks(H, hd)[-1]}
-
-
-@functools.partial(jax.jit, static_argnames=("block_h", "sm_scale", "name"))
+@functools.partial(jax.jit, static_argnames=("block_h", "sm_scale", "name",
+                                             "tile", "abreast"))
 def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
            v_scale, *, block_h: int, sm_scale: float,
-           name: str = "paged_decode"):
+           name: str = "paged_decode", tile: Optional[int] = None,
+           abreast: int = _ABREAST):
     B, H, T, hd = q.shape
     P1, page, D = k_pool.shape
     G = tables.shape[1]
@@ -325,9 +417,15 @@ def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
     nblk = -(-G // ppb)  # key blocks in a slot's window
     bk = ppb * page
 
-    # pad the verify width to the sublane tile; padded rows sit at
-    # position -1, see nothing, finalize to zeros and are sliced away
+    # pad the verify width to the sublane tile, a wider call to whole query
+    # tiles; padded rows sit at position -1, see nothing, finalize to zeros
+    # and are sliced away
     Tp = -(-T // _at.SUBLANE) * _at.SUBLANE
+    tile = tile or query_tile(T)
+    nq = -(-Tp // tile)  # query tiles: the one-tile grid stays what it was
+    tiled = nq > 1
+    tq = tile if tiled else Tp
+    Tp = nq * tq
     qp = q if Tp == T else jnp.pad(q, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
     qpos = jnp.pad(positions.astype(jnp.int32), ((0, 0), (0, Tp - T)),
                    constant_values=-1)[:, :, None]           # [B, Tp, 1]
@@ -338,15 +436,36 @@ def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
     tab = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, nblk * ppb - G)))
     nb = (jnp.full((B,), nblk, jnp.int32) if bound is None  # pages -> blocks
           else jnp.minimum(-(-bound.astype(jnp.int32) // ppb), nblk))
+    if nb.ndim == 2 and not (tiled and nb.shape == (B, nq)):
+        raise InvalidArgumentError(
+            f"paged_flash_decode: bound {nb.shape} for {nq} query tile(s) "
+            f"of {tq} rows")
+    if tiled:
+        # a slot's bound holds for each of its tiles
+        nb = jnp.broadcast_to(nb if nb.ndim == 2 else nb[:, None], (B, nq))
+        grid = (B, nq, H // bh)
 
-    def qmap(b, h, tab, nb):
-        return (b, h, _at.I0, _at.I0)
+        def qmap(b, t, h, *_):
+            return (b, h, t, _at.I0)
+
+        def pmap(b, t, *_):
+            return (b, t, _at.I0)
+    else:
+        grid = (B, H // bh)
+
+        def qmap(b, h, *_):
+            return (b, h, _at.I0, _at.I0)
+
+        def pmap(b, *_):
+            return (b, _at.I0, _at.I0)
+
+    def kmap(b, *_):  # a slot's position map, whole, at every step of it
+        return (b, _at.I0, _at.I0, _at.I0)
 
     in_specs = [
-        pl.BlockSpec((1, bh, Tp, hd), qmap),
-        pl.BlockSpec((1, Tp, 1), lambda b, h, tab, nb: (b, _at.I0, _at.I0)),
-        pl.BlockSpec((1, nblk, 1, bk),
-                     lambda b, h, tab, nb: (b, _at.I0, _at.I0, _at.I0)),
+        pl.BlockSpec((1, bh, tq, hd), qmap),
+        pl.BlockSpec((1, tq, 1), pmap),
+        pl.BlockSpec((1, nblk, 1, bk), kmap),
         # the pools as they are stored, left in HBM: the kernel copies
         # the pages it walks, the bh*hd lanes of this head block of each
         pl.BlockSpec(memory_space=pl.ANY),
@@ -368,40 +487,31 @@ def _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound, k_scale,
         scratch += [pltpu.VMEM((2, ppb, page, Hl), jnp.float32)] * 2
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),                # one a buffer slot
-        pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running max
-        pltpu.VMEM((bh, Tp, _at.LANE), jnp.float32),  # running sum
-        pltpu.VMEM((bh, Tp, hd), jnp.float32),        # out accum
+        pltpu.VMEM((bh, tq, _at.LANE), jnp.float32),  # running max
+        pltpu.VMEM((bh, tq, _at.LANE), jnp.float32),  # running sum
+        pltpu.VMEM((bh, tq, hd), jnp.float32),        # out accum
     ]
 
     kern = functools.partial(_kernel, block_h=bh, window=C,
-                             sm_scale=sm_scale, quantized=quantized)
+                             sm_scale=sm_scale, quantized=quantized,
+                             tiled=tiled, abreast=abreast)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # the page table and the sweep bounds
-            grid=(B, H // bh),  # the key blocks are the kernel's own loop
+            grid=grid,  # the key blocks are the kernel's own loop
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bh, Tp, hd), qmap),
+            out_specs=pl.BlockSpec((1, bh, tq, hd), qmap),
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel",) * len(grid),
+            **({"vmem_limit_bytes": _TILED_VMEM} if tiled else {})),
         interpret=not _device.on_tpu(),
         name=name,
     )(tab, nb, *operands)
     return out[:, :, :T, :]
-
-
-@_at.autotune("paged_decode", params=("block_h",), space=_space,
-              heuristic=_heuristic)
-def _paged_decode(q, k_pool, v_pool, tables, pos_map, positions, k_scale,
-                  v_scale, *, block_h: int):
-    """The measurable unit of the ``block_h`` search: the sweep of every
-    slot's whole window (no bound)."""
-    return _sweep(q, k_pool, v_pool, tables, pos_map, positions, None,
-                  k_scale, v_scale, block_h=block_h,
-                  sm_scale=1.0 / math.sqrt(q.shape[3]))
 
 
 def _decode_width(q, k_pool, v_pool, tables, pos_map, positions, bound,
@@ -447,6 +557,21 @@ def _kv_heads(q, k_pool):
     return Hkv
 
 
+def _fold_bound(bound, T: int, rep: int):
+    """The bounds of the ``rep x T`` query rows of the grouped-head fold
+    (``jnp.tile(positions, (1, rep))``) from those of a call's ``T`` rows:
+    a row keeps the bound its own positions gave it, a tile of the fold
+    takes the largest of its rows'.  A slot's bound ``[B]`` holds for any
+    row of it."""
+    if bound is None or bound.ndim == 1:
+        return bound
+    rows = jnp.tile(jnp.repeat(bound, query_tile(T), axis=1)[:, :T], (1, rep))
+    tq = query_tile(rep * T)
+    nq = -(-rep * T // tq)
+    return jnp.pad(rows, ((0, 0), (0, nq * tq - rep * T))).reshape(
+        -1, nq, tq).max(axis=2)
+
+
 def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
                        bound=None, k_scale=None, v_scale=None, *,
                        block_h: Optional[int] = None,
@@ -465,19 +590,24 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
     pos_map: ``[B, G*page]`` i32, the absolute position each cache entry
     holds (-1: none); positions: ``[B, T]`` i32, the query rows' absolute
     positions (-1: padding) — validity is :func:`key_visible` of the two,
-    the gather path's mask; bound: ``[B]`` i32 logical pages to walk per
-    slot (:func:`sweep_bound` of that mask; None walks the whole window);
-    k_scale/v_scale: ``[P+1, page, H]`` f32 dequant multipliers for
-    quantized pools (both or neither).
+    the gather path's mask; bound: i32 logical pages to walk
+    (:func:`sweep_bound` of that mask: ``[B]``, a slot's, or for a call of
+    more than :data:`QUERY_TILE` rows ``[B, nq]``, a query tile's; a
+    slot's bound is taken for each of its tiles; None walks the whole
+    window); k_scale/v_scale: ``[P+1, page, H]`` f32 dequant multipliers
+    for quantized pools (both or neither).
 
-    Returns the attention context ``[B, H, T, hd]`` in q's dtype.
-    ``block_h`` (heads, so ``block_h*hd`` lanes of a page row, per grid
-    step) defaults to the autotuner; pass it explicitly to bypass tuning.
-    At the decode width over float pages there is nothing to tune: the
-    whole row is swept as one block-diagonal head (module docstring)
-    unless ``block_h`` asks for the per-head form.  ``name`` names the
-    kernel's call in the program and the trace (a model whose per-slot key
-    rings are one-page pools tells those calls from its page pools').
+    Returns the attention context ``[B, H, T, hd]`` in q's dtype.  The
+    grid is ``(B, H / block_h)`` up to one query tile of rows and ``(B,
+    nq, H / block_h)`` past it; there is nothing to tune: the tile is
+    fixed by the shape and ``block_h`` (heads, so ``block_h*hd`` lanes of
+    a page row, per grid step) is the most that fit
+    (:func:`_heads_a_step`); pass it explicitly to choose another.  At
+    the decode width over float pages the whole row is swept as one
+    block-diagonal head (module docstring) unless ``block_h`` asks for
+    the per-head form.  ``name`` names the kernel's call in the program
+    and the trace (a model whose per-slot key rings are one-page pools
+    tells those calls from its page pools').
     """
     if (k_scale is None) != (v_scale is None):
         raise InvalidArgumentError(
@@ -497,14 +627,14 @@ def paged_flash_decode(q, k_pool, v_pool, tables, pos_map, positions,
         B, H, T, hd = q.shape
         out = paged_flash_decode(
             q.reshape(B, H // rep, rep * T, hd), k_pool, v_pool, tables,
-            pos_map, jnp.tile(positions, (1, rep)), bound, block_h=block_h,
-            name=name)
+            pos_map, jnp.tile(positions, (1, rep)),
+            _fold_bound(bound, T, rep), block_h=block_h, name=name)
         return out.reshape(B, H, T, hd)
-    cfg = _paged_decode.resolve(q, k_pool, v_pool, tables, pos_map,
-                                positions, k_scale, v_scale, block_h=block_h)
+    if block_h is None:
+        block_h = _heads_a_step(q, k_pool, tables, k_scale is not None)
     return _sweep(q, k_pool, v_pool, tables, pos_map, positions, bound,
-                  k_scale, v_scale, sm_scale=1.0 / math.sqrt(q.shape[3]),
-                  name=name, **cfg)
+                  k_scale, v_scale, block_h=block_h,
+                  sm_scale=1.0 / math.sqrt(q.shape[3]), name=name)
 
 
 def paged_attention(q, k_pool, v_pool, gather_tab, mask, walk=None,

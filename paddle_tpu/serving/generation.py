@@ -104,7 +104,8 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Mapping, NamedTuple, Optional, Sequence
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -119,13 +120,14 @@ from ..framework.errors import (
 )
 from ..framework.flags import flag
 from ..nn.layer_base import functional_call
-from ..ops.paged_attention import key_visible, sweep_bound
+from ..ops.paged_attention import block_pages, key_visible, sweep_bound
 from ..observability import tracing as _tracing
 from ..resilience import CircuitBreaker
 from ..resilience import retry as _retry_mod
 from ..resilience.faults import fault_point
 from .batcher import MicroBatcher, Request
-from .metrics import (HANDOFF_COUNTERS, LOOP_COUNTERS, LORA_COUNTERS,
+from .metrics import (ADMIT_WALK_COUNTERS, HANDOFF_COUNTERS, LOOP_COUNTERS,
+                      LORA_COUNTERS,
                       MOE_COUNTERS, PAGED_COUNTERS, QUANT_COUNTERS,
                       SLOT_COUNTERS, STATE_COUNTERS, TENANCY_COUNTERS,
                       LoopClock, ServingMetrics)
@@ -224,6 +226,20 @@ def admit_chunks(buckets: Sequence[int], rows_of) -> List[List[int]]:
             chunks.append([j])
             widest = b
     return chunks
+
+
+def attn_blocks(pos_map, positions, page: int) -> Tuple[int, int]:
+    """(walked, square) of one admission call, in (query tile, key block)
+    pairs: what the ``paged_decode`` kernel walks, each tile of each row to
+    its own bound, and what it walked when every tile went to its slot's
+    bound; the kernel's own bound function on the arrays the program was
+    given (``pos_map`` ``[R, C]``, ``positions`` ``[R, bucket]``)."""
+    R, C = pos_map.shape
+    pages = sweep_bound(key_visible(pos_map[:, None, :],
+                                    positions[:, :, None], C), page)
+    blocks = -(-pages.reshape(R, -1) // block_pages(page))  # [R, tiles]
+    return (int(blocks.sum()),
+            int(blocks.max(axis=1).sum()) * blocks.shape[1])
 
 
 def hold_pays(steps: int, live: int, clock: Mapping[str, int]) -> bool:
@@ -458,6 +474,10 @@ class GenerationEngine:
             extra = extra + TENANCY_COUNTERS
         if self._slot_state:
             extra = extra + STATE_COUNTERS
+        # the model's admissions attend by the page walk: count its blocks
+        self._admit_walk = bool(getattr(model, "admit_page_walk", False))
+        if self._admit_walk:
+            extra = extra + ADMIT_WALK_COUNTERS
         self.metrics = ServingMetrics(name, extra_counters=extra)
 
         mdl, traces = model, self._traces
@@ -1696,6 +1716,13 @@ class GenerationEngine:
                         # the step in flight ran ahead of these calls: read
                         # and harvest it while the device runs them
                         drain("admit.device")
+                        if self._admit_walk:
+                            # still while the device runs them: the blocks
+                            # the kernel walks in these calls, by the rule
+                            for _, pp, pm, *_ in chunks:
+                                walked, square = attn_blocks(pm, pp, page)
+                                cnt["admit_attn_blocks_walked"] += walked
+                                cnt["admit_attn_blocks_square"] += square
                         # serial harvest: a chunk's first n rows are the
                         # next n of `admitted`, the rest of its rows inert
                         host_first = np.concatenate([

@@ -199,6 +199,15 @@ STATE_COUNTERS = ("state_slots_reset", "gdn_prefill_tokens",
                   "gdn_prefill_token_slots", "state_bytes_steps",
                   "prefix_unshared")
 
+#: where the model declares ``admit_page_walk`` (an admission's attention is
+#: the page walk of ``ops/paged_attention.py``): per admission call, the
+#: (query tile, key block) pairs the kernel walks, each tile to its own
+#: bound (``admit_attn_blocks_walked``), and the tiles of the call times
+#: each slot's bound, the square it walked before a tile had a bound of
+#: its own (``admit_attn_blocks_square``); both by
+#: ``ops.paged_attention.sweep_bound`` of the arrays the program was given.
+ADMIT_WALK_COUNTERS = ("admit_attn_blocks_walked", "admit_attn_blocks_square")
+
 
 def _quantile(sorted_vals, q: float) -> float:
     """Nearest-rank quantile with the CEIL rank convention: the q-th

@@ -15,11 +15,13 @@ import jax.numpy as jnp
 
 from paddle_tpu.framework.errors import InvalidArgumentError
 from paddle_tpu.framework.flags import set_flags
-from paddle_tpu.ops.paged_attention import (_head_blocks, _paged_decode,
+from paddle_tpu.ops import autotune
+from paddle_tpu.ops.paged_attention import (QUERY_TILE, _head_blocks,
+                                            _heads_a_step, _sweep,
                                             block_pages, key_visible,
                                             paged_flash_decode,
                                             paged_flash_eligible,
-                                            sweep_bound)
+                                            query_tile, sweep_bound)
 
 
 def _ref_attend(q, k_pool, v_pool, tables, mask, k_scale=None, v_scale=None):
@@ -111,16 +113,19 @@ def _args(q, kp, vp, tab, pm, pos):
 
 
 class TestEquivalence:
-    def test_float_all_candidates(self):
+    def test_float_all_head_blocks(self):
         rng = np.random.RandomState(0)
         q, kp, vp, tab, pm, pos = _geometry(rng)
-        cands = _paged_decode.candidates(q, kp, vp, tab, pm, pos, None, None)
         # H=4 heads of 64: a block of 4 (the whole row) or 2 (one lane
-        # tile); one head alone is half a tile and is not offered
-        assert sorted(c["block_h"] for c in cands) == [2, 4]
+        # tile); one head alone is half a tile and is not offered.  The
+        # rule takes the whole row: nothing is searched
+        assert _head_blocks(4, 64) == [4, 2]
+        assert _heads_a_step(q, kp, tab, False) == 4
+        assert "paged_decode" not in autotune.registered_kernels()
         want = _ref_attend(q, kp, vp, tab, _mask(pm, pos))
-        for cfg in cands:
-            out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos), **cfg)
+        for bh in (None, 4, 2):
+            out = paged_flash_decode(*_args(q, kp, vp, tab, pm, pos),
+                                     block_h=bh)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=2e-4, atol=2e-5)
 
@@ -154,7 +159,7 @@ class TestEquivalence:
         assert np.abs(out2[:2, 3] - out[:2, 3]).max() > 1.0
 
     @pytest.mark.parametrize("qdtype", ["int8", "fp8"])
-    def test_quantized_all_candidates(self, qdtype):
+    def test_quantized_all_head_blocks(self, qdtype):
         rng = np.random.RandomState(1)
         q, kp, vp, tab, pm, pos = _geometry(rng)
         kq, ks = _quantize(kp, qdtype)
@@ -162,10 +167,10 @@ class TestEquivalence:
         # the oracle attends over the SAME dequantized values, so the
         # comparison isolates the kernel, not the quantizer
         want = _ref_attend(q, kq, vq, tab, _mask(pm, pos), ks, vs)
-        cands = _paged_decode.candidates(q, kq, vq, tab, pm, pos, ks, vs)
-        for cfg in cands:
+        assert _heads_a_step(q, kq, tab, True) == 4
+        for bh in (None, 4, 2):
             out = paged_flash_decode(*_args(q, kq, vq, tab, pm, pos),
-                                     k_scale=ks, v_scale=vs, **cfg)
+                                     k_scale=ks, v_scale=vs, block_h=bh)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=2e-4, atol=2e-4)
 
@@ -357,6 +362,215 @@ def test_the_bound_is_what_the_kernel_walks():
     assert np.abs(out[0] - full[0]).max() > 1e-2
 
 
+class _Shape:
+    def __init__(self, shape, dtype):
+        self.shape, self.dtype = shape, np.dtype(dtype)
+
+
+#: the fixed rule at the shapes the search used to race (and the width the
+#: one-tile form could not hold): rows of the call, tile (None: the
+#: rule's), heads, head size, pool dtype -> rows a tile, heads a grid step.
+#: A tile's VMEM, so every GPT-2 bucket takes all 12 heads where the
+#: bucket whole took 2 or 4
+_RULE = {
+    "gpt2_admit_512": ((512, None, 12, 64, "f4"), (256, 12)),
+    "gpt2_admit_640": ((640, None, 12, 64, "f4"), (320, 12)),
+    "gpt2_admit_768": ((768, None, 12, 64, "f4"), (256, 12)),
+    "gpt2_admit_1024": ((1024, None, 12, 64, "f4"), (256, 12)),
+    "gpt2_admit_256_is_one_tile": ((256, None, 12, 64, "f4"), (256, 6)),
+    "gpt2_admit_768_tiles_of_128": ((768, 128, 12, 64, "f4"), (128, 12)),
+    "gpt2_admit_768_one_tile": ((768, 768, 12, 64, "f4"), (768, 2)),
+    "gpt2_admit_640_one_tile": ((640, 640, 12, 64, "f4"), (640, 4)),
+    "gpt2_admit_512_one_tile": ((512, 512, 12, 64, "f4"), (512, 4)),
+    "gpt2_verify_width": ((5, None, 12, 64, "f4"), (8, 12)),
+    "gpt2_int8_pool": ((768, None, 12, 64, "i1"), (256, 12)),
+    "gpt2_large": ((768, None, 20, 64, "f4"), (256, 20)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE))
+def test_tile_and_heads_a_step_are_rules_of_the_shape_not_a_search(case):
+    (T, tile, H, hd, kv), (rows, heads) = _RULE[case]
+    assert QUERY_TILE == 320
+    assert (tile or query_tile(T)) == rows
+    got = _heads_a_step(_Shape((2, H, T, hd), "f4"),
+                        _Shape((9, 16, H * hd), kv),
+                        _Shape((2, 64), "i4"), kv == "i1", tile)
+    assert got == heads and got in _head_blocks(H, hd)
+
+
+def _brute_bound(mask, page, tile):
+    """A tile's bound read off the mask one (slot, tile) at a time."""
+    B, T, C = mask.shape
+    G, ppb = C // page, block_pages(page)
+    nq = -(-T // tile)
+    out = np.zeros((B, nq), np.int32)
+    for b in range(B):
+        for t in range(nq):
+            keys = np.nonzero(mask[b, t * tile:(t + 1) * tile].any(0))[0]
+            if len(keys):
+                pages = keys.max() // page + 1
+                out[b, t] = min(-(-pages // ppb) * ppb, G)
+    return out
+
+
+def _admission(rng, case):
+    """An admission call ``[B, T]`` over 32 pages of 16 (four key blocks)
+    a slot, two heads of 64: ``(q, k, v, tables, pos_map, positions)`` and
+    the per-tile bounds expected, in pages."""
+    B, H, hd, page, G = 2, 2, 64, 16, 32
+    kw = dict(B=B, H=H, hd=hd, page=page, G=G)
+    if case == "two_ragged_rows":
+        # [2, 768] in three tiles of 256 over six key blocks: 400 tokens
+        # end in the second tile, 704 in the third
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=768, lengths=[768, 768],
+                                            **{**kw, "G": 48})
+        for b, n in enumerate((400, 704)):
+            pm[b, n:] = pos[b, n:] = -1
+        return (q, kp, vp, tab, pm, pos), [[16, 32, 0], [16, 32, 48]]
+    if case == "behind_a_shared_prefix":
+        # 300 rows at positions 160 .. 459 of a slot whose first 160
+        # entries another request wrote, in two tiles of 192: the first
+        # tile already reads three key blocks; row 1 starts cold, 200 tokens
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=384, lengths=[460, 200],
+                                            **kw)
+        pos[:] = -1
+        pos[0, :300] = np.arange(160, 460)
+        pos[1, :200] = np.arange(200)
+        return (q, kp, vp, tab, pm, pos), [[24, 32], [16, 16]]
+    if case == "padding_row":
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=512, lengths=[512, 0],
+                                            **kw)
+        return (q, kp, vp, tab, pm, pos), [[16, 32], [0, 0]]
+    if case == "ring_wrapped_slot":
+        # the slot's newest 384 positions lie past its window's end: live
+        # pages are no prefix of its table, every tile gets the window
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=384, lengths=[512, 300],
+                                            **kw)
+        _wrapped(pm, pos, 0, 512 + 200)
+        pos[1] = -1
+        pos[1, :256] = np.arange(44, 300)
+        return (q, kp, vp, tab, pm, pos), [[32, 32], [16, 24]]
+    if case == "rows_not_whole_tiles":
+        # 396 rows: two tiles of 200, four rows of padding
+        q, kp, vp, tab, pm, pos = _geometry(rng, T=396, lengths=[396, 131],
+                                            **kw)
+        pos[1] = -1
+        pos[1, :131] = np.arange(131)
+        return (q, kp, vp, tab, pm, pos), [[16, 32], [16, 0]]
+    raise KeyError(case)
+
+
+_ADMISSIONS = ("two_ragged_rows", "behind_a_shared_prefix", "padding_row",
+               "ring_wrapped_slot", "rows_not_whole_tiles")
+
+
+@pytest.mark.parametrize("quant", [None, "int8", "fp8"],
+                         ids=["float32", "int8", "fp8"])
+@pytest.mark.parametrize("case", _ADMISSIONS)
+def test_tiled_sweep_equals_the_one_tile_sweep_bit_for_bit(case, quant):
+    """An admission width in query tiles, each swept to its own bound,
+    against the same call as ONE tile swept to the slot's bound (the form
+    the kernel had): skipping a block no row of a tile can see leaves the
+    running max, sum and accumulator as they were, so every bit agrees."""
+    rng = np.random.RandomState(_ADMISSIONS.index(case))
+    (q, kp, vp, tab, pm, pos), want_bound = _admission(rng, case)
+    B, H, T, hd = q.shape
+    page = kp.shape[1]
+    mask = _mask(pm, pos)
+    bound = sweep_bound(mask, page)
+    assert bound.dtype == np.int32 and bound.tolist() == want_bound
+    np.testing.assert_array_equal(bound, _brute_bound(mask, page,
+                                                      query_tile(T)))
+    # tiles of any other size: their own bounds, the same bits (below)
+    fine = sweep_bound(mask, page, tile=128)
+    np.testing.assert_array_equal(fine, _brute_bound(mask, page, 128))
+    np.testing.assert_array_equal(  # numpy and jax: one rule
+        np.asarray(sweep_bound(jnp.asarray(mask), page)), bound)
+    # a slot's bound is the largest of its tiles'
+    slot = sweep_bound(mask, page, tile=T)
+    np.testing.assert_array_equal(slot, bound.max(axis=1))
+    scales = (None, None)
+    if quant:
+        (kp, ks), (vp, vs) = _quantize(kp, quant, H), _quantize(vp, quant, H)
+        scales = (ks, vs)
+    args = _args(q, kp, vp, tab, pm, pos)
+    tiled = np.asarray(paged_flash_decode(*args, jnp.asarray(bound),
+                                          *scales))
+    one = np.asarray(_sweep(*args, jnp.asarray(slot), *scales, block_h=H,
+                            sm_scale=hd ** -0.5, tile=T + 7))
+    np.testing.assert_array_equal(tiled, one)
+    # and it is the attention the gather path computes
+    ref = _ref_attend(q, kp, vp, tab, mask, *scales)
+    seen = mask.any(-1)
+    np.testing.assert_array_equal(
+        tiled[~seen[:, None].repeat(H, 1)], 0.0)
+    vb, vt = np.nonzero(seen)
+    np.testing.assert_allclose(tiled[vb, :, vt], ref[vb, :, vt], rtol=2e-4,
+                               atol=2e-4 if quant else 2e-5)
+    # a slot's bound given for a tiled call holds for each of its tiles
+    np.testing.assert_array_equal(
+        np.asarray(paged_flash_decode(*args, jnp.asarray(slot), *scales)),
+        tiled)
+    if not quant:  # and tiles of 128 rows walk less to the same bits
+        np.testing.assert_array_equal(np.asarray(_sweep(
+            *args, jnp.asarray(fine), *scales, block_h=H,
+            sm_scale=hd ** -0.5, tile=128)), tiled)
+
+
+def test_grouped_head_fold_gives_a_row_the_bound_of_its_own_positions():
+    # 8 query heads over 2 K/V heads: the 4 query heads of a K/V head are
+    # 4 x T query rows of it, positions tiled; T = 400 is two tiles of 200
+    # and the fold's 1600 rows five of 320, so a tile of the fold straddles
+    # tiles and copies of the rows and takes the largest of their bounds.
+    # Bit for bit the one-tile sweep of the same folded rows
+    rng = np.random.RandomState(21)
+    B, H, Hkv, hd, page, G, T = 2, 8, 2, 64, 16, 32, 400
+    rep = H // Hkv
+    q, kp, vp, tab, pm, pos = _geometry(rng, B=B, H=Hkv, hd=hd, page=page,
+                                        G=G, T=T, lengths=[400, 70])
+    pos[1] = -1
+    pos[1, :70] = np.arange(70)
+    q = rng.randn(B, H, T, hd).astype(np.float32)
+    mask = _mask(pm, pos)
+    bound = sweep_bound(mask, page)
+    assert bound.tolist() == [[16, 32], [8, 0]]
+    args = _args(q, kp, vp, tab, pm, pos)
+    got = np.asarray(paged_flash_decode(*args, jnp.asarray(bound)))
+    folded = jnp.asarray(q).reshape(B, Hkv, rep * T, hd)
+    fpos = jnp.tile(jnp.asarray(pos), (1, rep))
+    # the fold's bounds hold those its own mask gives (a tile of the fold
+    # takes the bound of every tile of the call it touches)
+    from paddle_tpu.ops.paged_attention import _fold_bound
+    fb = np.asarray(_fold_bound(jnp.asarray(bound), T, rep))
+    own = sweep_bound(_mask(pm, np.asarray(fpos)), page)
+    assert fb.shape == own.shape == (B, 5) and (fb >= own).all()
+    assert fb.tolist() == [[32, 32, 32, 32, 32], [8, 8, 8, 8, 8]]
+    one = np.asarray(_sweep(
+        folded, *args[1:5], fpos, jnp.asarray(sweep_bound(mask, page, T)),
+        None, None, block_h=Hkv, sm_scale=hd ** -0.5,
+        tile=rep * T)).reshape(B, H, T, hd)
+    np.testing.assert_array_equal(got, one)
+    kf = np.repeat(kp.reshape(-1, page, Hkv, hd), rep, 2).reshape(
+        -1, page, H * hd)
+    vf = np.repeat(vp.reshape(-1, page, Hkv, hd), rep, 2).reshape(
+        -1, page, H * hd)
+    ref = _ref_attend(q, kf, vf, tab, mask)
+    vb, vt = np.nonzero(mask.any(-1))
+    np.testing.assert_allclose(got[vb, :, vt], ref[vb, :, vt], rtol=2e-4,
+                               atol=2e-5)
+
+
+def test_a_bound_of_another_tiling_is_refused():
+    rng = np.random.RandomState(22)
+    args = _args(*_geometry(rng, B=2, H=2, G=32, T=384, lengths=[384, 9]))
+    with pytest.raises(InvalidArgumentError):
+        paged_flash_decode(*args, jnp.zeros((2, 3), jnp.int32))
+    one_tile = _args(*_geometry(rng, B=2, H=2, G=32, T=8, lengths=[80, 9]))
+    with pytest.raises(InvalidArgumentError):
+        paged_flash_decode(*one_tile, jnp.zeros((2, 1), jnp.int32))
+
+
 @pytest.mark.parametrize("pool_dtype", [None, "int8"], ids=["float", "int8"])
 def test_forward_paged_gives_the_kernel_its_bound(monkeypatch, pool_dtype):
     """``GPTModel.forward_paged`` with the kernel's gate open (interpret
@@ -382,7 +596,7 @@ def test_forward_paged_gives_the_kernel_its_bound(monkeypatch, pool_dtype):
         for g in range(-(-(n + 5) // page)):
             table[b, g] = free.pop()
     pos_map = np.full((B, C), -1, np.int32)
-    Tb = 160
+    Tb = 352  # two query tiles of 176 rows
     ids = rng.randint(0, cfg.vocab_size, (B, Tb)).astype(np.int32)
     pos = np.full((B, Tb), -1, np.int32)
     for b, n in enumerate(lengths):
@@ -418,7 +632,11 @@ def test_forward_paged_gives_the_kernel_its_bound(monkeypatch, pool_dtype):
                                ((pos_map, pos), (step_map, step_pos))):
         mask = key_visible(pm[:, None, :], pp[:, :, None], C)
         np.testing.assert_array_equal(got_b, sweep_bound(mask, page))
-    assert given[0].tolist() == [16, 0, 8] and given[-1].tolist() == [16, 0, 8]
+    # the admission's 352 rows are two query tiles, each with its own
+    # bound: the second holds padding rows alone and walks nothing; the
+    # step's five rows are one tile
+    assert given[0].tolist() == [[16, 0], [0, 0], [8, 0]]
+    assert given[-1].tolist() == [16, 0, 8]
     for a, b in zip(given[:cfg.num_layers], given[1:cfg.num_layers]):
         np.testing.assert_array_equal(a, b)  # one bound, every layer
     live0, live1 = pos >= 0, step_pos >= 0

@@ -240,6 +240,81 @@ def test_swept_pages_are_the_bounds_the_step_program_was_given():
     assert d["kv_pages_swept_steps"] < d["kv_page_slots_steps"]
 
 
+def test_admission_blocks_walked_against_a_hand_count():
+    # admit_attn_blocks_walked / _square: per admission call, the (query
+    # tile, key block) pairs the paged_decode kernel walks, each tile to
+    # its own bound, beside the tiles of the call x each slot's bound (what
+    # it walked while a grid step held the bucket whole).  One call of two
+    # rows in the 384 bucket (two tiles of 192 rows), pages of 16, key
+    # blocks of 128:
+    #   a cold row of 300 tokens: its tiles end at keys 191 and 299:
+    #     2 + 3 blocks walked, 2 tiles x 3 blocks square;
+    #   a row of 100 tokens behind a shared prefix of 160 (positions 160 ..
+    #     259, all in the first tile, which reads the prefix's blocks too):
+    #     3 + 0 walked, 2 x 3 square
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.ops.paged_attention import block_pages, query_tile
+    from paddle_tpu.serving.generation import attn_blocks
+
+    page, cache, bucket = 16, 512, 384
+    assert (query_tile(bucket), block_pages(page) * page) == (192, 128)
+    pt.seed(7)
+    m = GPTForCausalLM(GPTConfig(vocab_size=97, hidden_size=32, num_layers=1,
+                                 num_heads=4, max_position=cache,
+                                 dropout=0.0))
+    m.eval()
+    sys_p = (np.arange(160) * 7 + 5) % 97
+    kw = {"prefix_key": "sys", "prefix_len": len(sys_p)}
+    keys = ("admit_attn_blocks_walked", "admit_attn_blocks_square",
+            "admit_steps", "evicted")
+    given = []
+    with GenerationEngine(m, prompt_buckets=[bucket], batch_size=3,
+                          cache_len=cache, kv_page_size=page,
+                          speculative_k=0, name="walkctr") as eng:
+        assert eng._admit_rows == {bucket: 2}
+        eng.warmup()
+        warm = eng.metrics.snapshot()
+        # warm-up's rows are inert: nothing to walk
+        assert warm["admit_attn_blocks_walked"] == 0
+        assert warm["admit_attn_blocks_square"] == 0
+        first = np.concatenate([sys_p, np.arange(5) % 97])
+        eng.submit(first, 2, **kw).result(120)
+        before = _settled(eng, warm["evicted"] + 1)
+        # 165 tokens, all in the first tile: 2 + 0 of 2 x 2
+        assert before["admit_attn_blocks_walked"] == 2
+        assert before["admit_attn_blocks_square"] == 4
+        admit = eng._padmit
+
+        def spy(params, buffers, ids, pp, pm, *rest):
+            given.append((np.asarray(pm), np.asarray(pp)))
+            return admit(params, buffers, ids, pp, pm, *rest)
+
+        eng._padmit = spy
+        hits = eng.stats()["prefix_hits"]
+        with eng._batcher._cv:  # one admitting iteration takes both
+            futs = [eng.submit((np.arange(300) * 3 + 1) % 97, 2),
+                    eng.submit(np.concatenate(
+                        [sys_p, (np.arange(100) * 11) % 97]), 2, **kw)]
+        for f in futs:
+            f.result(120)
+        after = _settled(eng, before["evicted"] + 2)
+        assert eng.stats()["prefix_hits"] == hits + 1
+    d = {k: after[k] - before[k] for k in keys}
+    assert d["admit_steps"] == 1 and len(given) == 1
+    pm, pp = given[0]
+    assert pp[0, :300].tolist() == list(range(300)) and pp[0, 300] == -1
+    assert pp[1, :100].tolist() == list(range(160, 260)) and pp[1, 100] == -1
+    assert attn_blocks(pm, pp, page) == (8, 12)
+    assert (d["admit_attn_blocks_walked"],
+            d["admit_attn_blocks_square"]) == (8, 12)
+
+
+def test_walked_blocks_never_pass_the_square(served):
+    # a bucket of one tile: the two are the same count, and not zero
+    s = served["snap"]
+    assert 0 < s["admit_attn_blocks_walked"] == s["admit_attn_blocks_square"]
+
+
 def test_request_times_are_ordered_and_inside_what_the_caller_saw(served):
     s = served["snap"]
     # a request waits, is prefilled, and only then can complete: summed

@@ -183,22 +183,56 @@ def test_paged_flash_decode_gpt2_small(chip, pool, page, T):
     _compiles_with_kernel(chip, paged_flash_decode, *shapes)
 
 
-@pytest.mark.parametrize("T", [64, 768])
-def test_paged_flash_admission_prefill_gpt2_small(chip, T):
+@pytest.mark.parametrize("R,T", [(2, 64), (2, 512), (2, 640), (2, 768),
+                                 (2, 1024), (1, 1024)],
+                         ids=lambda v: str(v))
+def test_paged_flash_admission_prefill_gpt2_small(chip, R, T):
     # the paged admission program's attention: the same kernel over the
-    # engine's two rows x the narrowest and the widest prompt bucket the
-    # chip benchmark serves (chat_open 64, docs_closed 768), float pages
-    # of 16
-    from paddle_tpu.ops.paged_attention import paged_flash_decode
+    # engine's two rows x the narrowest prompt bucket the chip benchmark
+    # serves (chat_open 64: one query tile, the grid a decode step has) and
+    # docs_closed's three, in query tiles of 256 or 320 rows each with its
+    # own sweep bound, all 12 heads a grid step; and at 1024 rows, a width the
+    # kernel could not hold while a step held the bucket whole (VMEM).
+    # Float pages of 16
+    import re
+
+    from paddle_tpu.ops.paged_attention import (paged_flash_decode,
+                                                query_tile)
     from paddle_tpu.serving.generation import admit_rows
 
-    R, H, hd, page = admit_rows(T, 32), 12, 64, 16
-    assert R == 2
+    H, hd, page = 12, 64, 16
+    assert admit_rows(T, 32) == 2
     G, pages = 1024 // page, 2048 + 1
-    _compiles_with_kernel(
-        chip, paged_flash_decode, ((R, H, T, hd), f32),
-        ((pages, page, H * hd), f32), ((pages, page, H * hd), f32),
-        ((R, G), i32), ((R, G * page), i32), ((R, T), i32), ((R,), i32))
+    nq = -(-T // query_tile(T))  # 1, 2, 2, 3, 4, 4 tiles
+    shapes = [((R, H, T, hd), f32),
+              ((pages, page, H * hd), f32), ((pages, page, H * hd), f32),
+              ((R, G), i32), ((R, G * page), i32), ((R, T), i32),
+              ((R,) if nq == 1 else (R, nq), i32)]
+    _compiles_with_kernel(chip, paged_flash_decode, *shapes)
+    grid = re.findall(r"grid=\([0-9, ]*\)", str(jax.make_jaxpr(
+        paged_flash_decode)(*[jax.ShapeDtypeStruct(*s) for s in shapes])))
+    assert grid == [f"grid=({R}, 1)" if nq == 1 else f"grid=({R}, {nq}, 1)"]
+
+
+def test_paged_decode_has_nothing_to_search_and_the_step_keeps_its_grid():
+    # the tile is fixed by the shape and the heads a step by rule: the
+    # kernel is not among the autotuner's, so two checkouts of one tree
+    # build one program.  A one-tile call (the decode step's [32, 1], the
+    # verify width) keeps the grid (slots, head blocks) it had
+    import re
+
+    from paddle_tpu.ops import autotune
+    from paddle_tpu.ops.paged_attention import paged_flash_decode
+
+    assert "paged_decode" not in autotune.registered_kernels()
+    B, H, hd, page, G, pages = 32, 12, 64, 16, 64, 2049
+    for T in (1, 5):
+        shapes = [((B, H, T, hd), f32), ((pages, page, H * hd), f32),
+                  ((pages, page, H * hd), f32), ((B, G), i32),
+                  ((B, G * page), i32), ((B, T), i32), ((B,), i32)]
+        text = str(jax.make_jaxpr(paged_flash_decode)(
+            *[jax.ShapeDtypeStruct(*s) for s in shapes]))
+        assert re.findall(r"grid=\([0-9, ]*\)", text) == ["grid=(32, 1)"]
 
 
 # -- the stored order of GPT-2's page pool ------------------------------------
@@ -303,7 +337,8 @@ ENTRY %main (a: f32[9,4,8]) -> f32[9,4,8] {
     assert {"parameter", "scatter", "fusion", "bitcast", "copy"} == kinds
 
 
-@pytest.mark.parametrize("program", ["step", "admit512", "admit768"])
+@pytest.mark.parametrize("program", ["step", "admit512", "admit640",
+                                     "admit768"])
 def test_gpt2_paged_programs_never_copy_the_pool(chip, gpt2_small_engine,
                                                  program):
     # 24 K/V arrays of [2049, 16, 768] float32 (100 MB each) go in, are

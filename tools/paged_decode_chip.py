@@ -17,6 +17,19 @@ CPU suite green), so before a number is quoted:
    program: the decode width at ``docs_closed``'s and ``chat_open``'s
    occupancy, the admission chunk at its widest and narrowest bucket.
 
+``--admit`` instead times the kernel at the admission width alone (a
+builder's tool, no cell runs it): one layer's ``[2, bucket]`` call for
+every ``docs_closed`` bucket (and ``[2, 1024]``) over two rows of real
+ragged lengths, in each form the grid can take: the bucket as ONE tile
+swept to the slot's bound with 2 or 4 heads a step (the form the kernel
+had until PR 42, ``block_h`` raced by the autotuner), query tiles of 128
+to 384 rows each swept to its own bound with all 12 heads a step and one
+to twelve heads' chains of products abreast, and ``rule``: what
+``paged_flash_decode`` itself resolves to.  A form the compiler refuses
+(VMEM) reads ``null``.  The rule in ``ops/paged_attention.py``
+(``QUERY_TILE``, ``query_tile``, ``_ABREAST``, ``_heads_a_step``) is the
+winner of this table.
+
 ``--trace <dir>`` instead reads a profiler trace a benchmark run left
 (``.cache/bench_trace/<cell>``) and prints, for each paged program in
 it, its mean time on the device and the mean time of one ``paged_decode``
@@ -134,8 +147,95 @@ def agree():
     return ok, out
 
 
-def kernel_ms():
+def _ms_a_layer(attend, q, k, v, *args):
+    """Best mean time of one call of ``attend``, 12 dependent calls in one
+    program (each needs the one before it), 5 x 10 runs."""
     import jax
+
+    @jax.jit
+    def twelve(q, k, v, *a):
+        for _ in range(12):
+            q = q + 1e-3 * attend(q, k, v, *a)
+        return q
+
+    twelve(q, k, v, *args).block_until_ready()
+    best = float("inf")
+    for _ in range(5):
+        t = time.perf_counter()
+        for _ in range(10):
+            r = twelve(q, k, v, *args)
+        r.block_until_ready()
+        best = min(best, (time.perf_counter() - t) / 120)
+    return best * 1e3
+
+
+def _pools(rng):
+    import jax.numpy as jnp
+
+    return [jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
+                        jnp.float32) for _ in range(2)]
+
+
+#: the forms of the admission grid: name -> (query tile or None for the
+#: bucket whole, heads a step, heads' chains abreast)
+ADMIT_FORMS = {"one_tile_h2": (None, 2, 1), "one_tile_h4": (None, 4, 1),
+               "one_tile_h4_g4": (None, 4, 4),
+               "tile128_h12": (128, 12, 1), "tile128_h12_g4": (128, 12, 4),
+               "tile256_h12": (256, 12, 1), "tile256_h12_g2": (256, 12, 2),
+               "tile256_h12_g4": (256, 12, 4), "tile256_h12_g6": (256, 12, 6),
+               "tile256_h12_g12": (256, 12, 12),
+               "tile320_h12_g4": (320, 12, 4), "tile320_h12_g6": (320, 12, 6),
+               "tile384_h12_g4": (384, 12, 4), "rule": (0, 0, 0)}
+#: two rows a call, as docs_closed fills them: uniform 384-768, the shorter
+#: row padded to the wider one's bucket
+ADMIT_ROWS = {512: (500, 400), 640: (600, 530), 768: (704, 512),
+              1024: (1000, 800)}
+
+
+def admit_ms():
+    import functools
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.paged_attention import (_sweep, block_pages,
+                                                key_visible,
+                                                paged_flash_decode,
+                                                query_tile, sweep_bound)
+
+    rng = np.random.RandomState(42)
+    k, v = _pools(rng)
+    out = {}
+    for T, lengths in ADMIT_ROWS.items():
+        table, pos_map, pos = _layout(rng, lengths, T)
+        pos[:] = -1  # rows left-aligned, as an admission packs them
+        for b, n in enumerate(lengths):
+            pos[b, :n] = np.arange(n)
+        mask = key_visible(pos_map[:, None, :], pos[:, :, None], C)
+        q = jnp.asarray(rng.standard_normal((2, H, T, HD)), jnp.float32)
+        row = out[f"admit[2,{T}]"] = {"lengths": list(lengths)}
+        for name, (tile, bh, abreast) in ADMIT_FORMS.items():
+            if name == "rule":  # what the model's call resolves to
+                tile, attend = query_tile(T), paged_flash_decode
+            else:
+                tile = tile or T
+                attend = functools.partial(
+                    _sweep, k_scale=None, v_scale=None, block_h=bh,
+                    sm_scale=HD ** -0.5, tile=tile, abreast=abreast)
+            bound = sweep_bound(mask, PAGE, tile)
+            args = (jnp.maximum(jnp.asarray(table), 0), jnp.asarray(pos_map),
+                    jnp.asarray(pos), jnp.asarray(bound))
+            try:
+                ms = _ms_a_layer(attend, q, k, v, *args)
+            except Exception as e:  # the compiler's refusal (VMEM)
+                ms, row[name + "_error"] = None, repr(e)[:300]
+            row[name] = ms
+            # [128 rows x 128 keys] products a head the form multiplies
+            row[name + "_products"] = int(
+                (-(-bound // block_pages(PAGE))).sum()) * -(-tile // 128)
+    return out
+
+
+def kernel_ms():
     import jax.numpy as jnp
 
     from paddle_tpu.ops.paged_attention import (key_visible,
@@ -143,10 +243,7 @@ def kernel_ms():
                                                 sweep_bound)
 
     rng = np.random.RandomState(31)
-    k = jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
-                    jnp.float32)
-    v = jnp.asarray(rng.standard_normal((PAGES + 1, PAGE, H * HD)),
-                    jnp.float32)
+    k, v = _pools(rng)
     cases = {
         "decode_docs_closed[32,1]": (1, [int(n) for n in
                                          rng.randint(400, 800, 32)]),
@@ -165,22 +262,9 @@ def kernel_ms():
         q = jnp.asarray(rng.standard_normal((B, H, T, HD)), jnp.float32)
         args = (jnp.maximum(jnp.asarray(table), 0), jnp.asarray(pos_map),
                 jnp.asarray(pos), jnp.asarray(bound))
-
-        @jax.jit
-        def twelve(q, k, v, *a):
-            for _ in range(12):  # each call needs the one before it
-                q = q + 1e-3 * paged_flash_decode(q, k, v, *a)
-            return q
-
-        twelve(q, k, v, *args).block_until_ready()
-        best = float("inf")
-        for _ in range(5):
-            t = time.perf_counter()
-            for _ in range(10):
-                r = twelve(q, k, v, *args)
-            r.block_until_ready()
-            best = min(best, (time.perf_counter() - t) / 120)
-        out[name] = {"ms_a_layer": best * 1e3, "pages": int(bound.sum()),
+        out[name] = {"ms_a_layer": _ms_a_layer(paged_flash_decode, q, k, v,
+                                               *args),
+                     "pages": int(bound.sum()),
                      "kv_mb": int(bound.sum()) * PAGE * 2 * H * HD * 4 / 1e6}
     return out
 
@@ -217,6 +301,8 @@ def read_trace(trace_dir):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", help="read this trace directory instead")
+    ap.add_argument("--admit", action="store_true",
+                    help="time the admission width's grid forms instead")
     a = ap.parse_args()
     if a.trace:
         print(json.dumps({"trace": a.trace, "programs": read_trace(a.trace)}))
@@ -227,6 +313,10 @@ def main():
     if d.platform != "tpu":
         print(json.dumps({"ok": False, "why": f"no TPU here: {d.platform}"}))
         return 1
+    if a.admit:
+        print(json.dumps({"ok": True, "device": d.device_kind,
+                          "admit_ms_a_layer": admit_ms()}))
+        return 0
     ok, rows = agree()
     line = {"ok": ok, "device": d.device_kind, "agree": rows}
     if ok:  # no timing of a kernel that disagrees
