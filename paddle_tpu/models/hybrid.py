@@ -253,7 +253,33 @@ def _weight(layer, *shape, dtype=None, init=None):
 
 
 class GatedDeltaNet(Layer):
-    """The ``linear_attention`` mixer."""
+    """The ``linear_attention`` mixer.
+
+    THE CONTRACT OF A SUBCLASS with another gate and another rule
+    (``models/kimi_linear.py:KimiDeltaAttention``).  It builds its own
+    parameters (``Layer.__init__``, not this class's) and keeps the conv,
+    its window, the head split with the l2 norms, and what :meth:`admit`
+    and :meth:`decode` do to a slot.  Those shared parts read
+
+    * of ``cfg``: ``linear_num_heads`` (value heads),
+      ``linear_num_key_heads``, ``linear_key_head_dim``,
+      ``linear_conv_kernel``, ``conv_width`` (and ``dtype``, ``init_std``
+      through :func:`_weight`);
+    * of the layer: ``qkv`` ``[D, conv_width]`` and ``conv`` ``[K,
+      conv_width]``;
+    * :meth:`_gates` ``(x, valid) -> (g, beta)`` in the shapes the rule
+      takes, the identity (``g = 0``, ``beta = 0``) for a padding token, and
+      :meth:`_output` ``(x, o) -> [..., D]``;
+    * the class attributes ``scope`` (the ``jax.named_scope`` a traced
+      metric finds the mixer by), ``_chunk(q, k, v, g, beta) -> (o, S)`` (a
+      prompt from the zero state) and ``_step(q, k, v, g, beta, state) ->
+      (o, state)`` (one token a slot, the states in place).
+
+    ``tests/test_kimi_linear_model.py`` holds a subclass to all of it."""
+
+    scope = "gdn"
+    _chunk, _step = staticmethod(gated_delta_chunk), staticmethod(
+        gated_delta_step)
 
     def __init__(self, cfg: HybridConfig):
         super().__init__()
@@ -330,18 +356,18 @@ class GatedDeltaNet(Layer):
         input and which tokens are real."""
         valid = positions >= 0
         y, padded = self._conv_prompt(_mm(x, self.qkv.value))
-        o, S = gated_delta_chunk(*self._qkv_heads(y), *self._gates(x, valid))
+        o, S = self._chunk(*self._qkv_heads(y), *self._gates(x, valid))
         return self._output(x, o), S, padded, valid
 
     def forward(self, x, positions):
         """A prompt from position 0, no cache."""
-        with jax.named_scope("gdn"):
+        with jax.named_scope(self.scope):
             return self._prompt(x, positions)[0]
 
     def admit(self, x, positions, kv, rows):
         """Prompts from position 0 into the slots' rows ``rows`` ``[R]``
         (already resolved: the drop row for an inert row)."""
-        with jax.named_scope("gdn"):
+        with jax.named_scope(self.scope):
             K = self.cfg.linear_conv_kernel
             out, S, padded, valid = self._prompt(x, positions)
             # the K - 1 rows before the row's end: tokens L - K + 1 .. L - 1
@@ -357,7 +383,7 @@ class GatedDeltaNet(Layer):
 
     def decode(self, x, positions, kv):
         """One token of slot ``i`` in row ``i``: ``x`` ``[B, 1, D]``."""
-        with jax.named_scope("gdn"):
+        with jax.named_scope(self.scope):
             B = x.shape[0]
             valid = positions[:, 0] >= 0
             old = kv["conv"][:B]                         # [B, K - 1, W]
@@ -366,8 +392,8 @@ class GatedDeltaNet(Layer):
             w = jnp.asarray(self.conv.value).astype(_F32)
             y = jnp.sum(w[None] * window.astype(_F32), axis=1)
             g, beta = self._gates(x[:, 0], valid)
-            o, state = gated_delta_step(*self._qkv_heads(y), g, beta,
-                                        kv["state"])
+            o, state = self._step(*self._qkv_heads(y), g, beta,
+                                  kv["state"])
             conv = jax.lax.dynamic_update_slice(
                 kv["conv"], jnp.where(valid[:, None, None], window[:, 1:],
                                       old), (0, 0, 0))
@@ -712,12 +738,24 @@ class HybridForCausalLM(Layer):
     """The decoder with its untied head; answers the serving-model protocol
     and declares ``slot_state``: its cache holds per-slot rows beside the
     pages, so the engine hands ``init_paged_cache`` its batch size and
-    every admission the rows' slot numbers."""
+    every admission the rows' slot numbers.
 
-    def __init__(self, cfg: HybridConfig):
+    A subclass names another ``decoder`` (``models/kimi_linear.py``): a
+    ``Layer`` built from ``cfg`` alone that answers ``forward(ids)``,
+    ``init_paged_cache(num_pages, page_size, dtype, slots)``,
+    ``copy_pages(cache, src, dst)`` and ``forward_paged(ids, positions,
+    pos_map, table, cache, slots)``, each returning hidden states before
+    the head.  Its ``cfg`` supplies ``hidden_size``, ``vocab_size``,
+    ``max_position``, ``experts_held``, ``dtype`` and ``init_std``; the
+    subclass overrides :meth:`slot_state_bytes`, which counts this
+    decoder's layer kinds."""
+
+    decoder = HybridModel
+
+    def __init__(self, cfg):
         super().__init__()
         self.cfg = cfg
-        self.model = HybridModel(cfg)
+        self.model = self.decoder(cfg)
         self.head = _weight(self, cfg.hidden_size, cfg.vocab_size)
 
     max_position = property(lambda self: self.cfg.max_position)

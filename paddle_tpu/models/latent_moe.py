@@ -6,11 +6,13 @@ The block is ``h = x + Attn(norm(x)); y = h + FFN(norm(h))``; the first
 ``first_dense_layers`` blocks carry a dense gated MLP, the rest a
 :class:`paddle_tpu.moe.DroplessMoE`.  Attention is multi-head LATENT
 attention (DeepSeek-V2, arXiv:2405.04434 section 2.1): queries go through a
-rank-``q_lora_rank`` bottleneck; keys and values are expanded from ONE
-``kv_lora_rank``-wide latent per token, and one ``qk_rope_head_dim``-wide
-rotary key is shared by every head.  So the cache holds, per token and
-layer, ``kv_lora_rank + qk_rope_head_dim`` values (the normed latent and
-the rotated key) instead of ``heads x (qk + v)``.
+rank-``q_lora_rank`` bottleneck (``None``: one full-rank projection); keys
+and values are expanded from ONE ``kv_lora_rank``-wide latent per token, and
+one ``qk_rope_head_dim``-wide rotary key is shared by every head
+(``rope_theta=None``: nothing is rotated, the shared key and the queries'
+``qk_rope_head_dim`` dims are used as projected).  So the cache holds, per
+token and layer, ``kv_lora_rank + qk_rope_head_dim`` values (the normed
+latent and the shared key) instead of ``heads x (qk + v)``.
 
 Two formulations of the same attention:
 
@@ -66,7 +68,7 @@ class LatentMoEConfig:
         self.hidden_size = int(hidden_size)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
-        self.q_lora_rank = int(q_lora_rank)
+        self.q_lora_rank = None if q_lora_rank is None else int(q_lora_rank)
         self.kv_lora_rank = int(kv_lora_rank)
         self.qk_nope_head_dim = int(qk_nope_head_dim)
         self.qk_rope_head_dim = int(qk_rope_head_dim)
@@ -80,7 +82,7 @@ class LatentMoEConfig:
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.norm_topk_prob = bool(norm_topk_prob)
         self.rms_norm_eps = float(rms_norm_eps)
-        self.rope_theta = float(rope_theta)
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
         self.max_position = int(max_position)
         self.dtype = dtype
         self.init_std = float(init_std)
@@ -147,6 +149,14 @@ class GatedMLP(Layer):
 
 
 class LatentAttention(Layer):
+    """Reads of ``cfg`` (a :class:`LatentMoEConfig`, or another model's
+    config with these names, as ``models/kimi_linear.py``'s):
+    ``hidden_size``, ``num_heads``, ``q_lora_rank`` (``None``: no
+    bottleneck), ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``, ``rope_theta`` (``None``: no
+    rotation), ``rms_norm_eps``, ``dtype``, ``init_std`` and the two
+    derived widths ``latent_width`` and ``page_width``."""
+
     def __init__(self, cfg: LatentMoEConfig):
         super().__init__()
         self.cfg = cfg
@@ -157,10 +167,13 @@ class LatentAttention(Layer):
             return self.create_parameter(shape, dtype=dt,
                                          default_initializer=init)
 
-        self.q_a = p(D, cfg.q_lora_rank)
-        self.q_norm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps, dt)
-        self.q_b = p(cfg.q_lora_rank,
-                     H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+        q_width = H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+        if cfg.q_lora_rank is None:
+            self.q = p(D, q_width)
+        else:
+            self.q_a = p(D, cfg.q_lora_rank)
+            self.q_norm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps, dt)
+            self.q_b = p(cfg.q_lora_rank, q_width)
         self.kv_a = p(D, cfg.latent_width)
         self.kv_norm = nn.RMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps, dt)
         self.kv_b = p(cfg.kv_lora_rank,
@@ -170,14 +183,21 @@ class LatentAttention(Layer):
                                      + cfg.qk_rope_head_dim)
 
     # -- the two halves every formulation shares ---------------------------
+    def _rotate(self, t, positions):
+        theta = self.cfg.rope_theta
+        return t if theta is None else rope_interleaved(t, positions, theta)
+
     def _queries(self, x, positions):
         cfg = self.cfg
         B, T, _ = x.shape
-        q = _mm(self.q_norm(_mm(x, self.q_a.value)), self.q_b.value)
+        if cfg.q_lora_rank is None:
+            q = _mm(x, self.q.value)
+        else:
+            q = _mm(self.q_norm(_mm(x, self.q_a.value)), self.q_b.value)
         q = q.reshape(B, T, cfg.num_heads, -1)
         q_nope = q[..., :cfg.qk_nope_head_dim]
-        q_rope = rope_interleaved(q[..., cfg.qk_nope_head_dim:],
-                                  positions[:, :, None], cfg.rope_theta)
+        q_rope = self._rotate(q[..., cfg.qk_nope_head_dim:],
+                              positions[:, :, None])
         return q_nope, q_rope
 
     def _latent(self, x, positions):
@@ -186,8 +206,7 @@ class LatentAttention(Layer):
         kva = _mm(x, self.kv_a.value)
         return jnp.concatenate(
             [self.kv_norm(kva[..., :r]),
-             rope_interleaved(kva[..., r:], positions, self.cfg.rope_theta)],
-            axis=-1)
+             self._rotate(kva[..., r:], positions)], axis=-1)
 
     def _kv_b_heads(self):
         cfg = self.cfg

@@ -21,7 +21,8 @@ that beat the compiler, plus the autotuner that picks their tile sizes:
 * ``gated_delta_chunk`` / ``gated_delta_step`` — the gated delta rule
   (linear attention over a decaying matrix state): a prompt in chunks with
   the state resident in VMEM, and one token a slot updating the stored
-  states in place (gated_delta.py);
+  states in place (gated_delta.py); ``kda_chunk`` / ``kda_step`` — the
+  same rule with one decay a key channel (kda.py);
 * ``quantized_matmul`` / ``fp8_matmul`` — int8×int8→int32 (and
   fp8-e4m3) matmul with the dequant + bias epilogue fused, the serving
   quantization hot path (quantized_matmul.py);
@@ -31,8 +32,11 @@ that beat the compiler, plus the autotuner that picks their tile sizes:
   never materializes the [rows, vocab] probability matrix
   (fused_softmax_xent.py);
 * ``autotune`` — measured block-size search with a persistent on-disk
-  cache; every kernel above resolves its tile parameters through it
-  (autotune.py).
+  cache; the kernels above resolve their tile parameters through it
+  (autotune.py), but for ``paged_decode`` (PR 42) and the four delta-rule
+  kernels (PR 44), whose tiles are rules of the shape from tables timed on
+  the chip: a race between near-ties draws differently in each cold
+  checkout.
 """
 from . import autotune  # noqa: F401
 from .flash_attention import (  # noqa: F401
@@ -51,6 +55,7 @@ from .gated_delta import (  # noqa: F401
     gated_delta_step,
 )
 from .grouped_matmul import grouped_matmul  # noqa: F401
+from .kda import kda_chunk, kda_step  # noqa: F401
 from .paged_attention import (  # noqa: F401
     paged_flash_decode,
     paged_flash_eligible,

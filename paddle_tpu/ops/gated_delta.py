@@ -29,7 +29,9 @@ Three routes to that function:
   writes it once, in place (``input_output_aliases``).
 
 Off the TPU (and on a mesh of several devices) both run their ``jnp``
-forms, which are also what the chip tool compares the kernels with.
+forms, which are also what the chip tool compares the kernels with.  The
+heads a grid step takes (``block_h``) are a RULE of the shape in both
+kernels (:func:`walk_heads`, :func:`step_heads`), nothing is searched.
 
 GROUPED KEY HEADS: ``q`` and ``k`` may have fewer heads than ``v`` (``H_v =
 rep * H_k``); value head ``h`` then reads key head ``h // rep``.  The state,
@@ -221,16 +223,26 @@ def _walk_kernel(dl_ref, qg_ref, kdT_ref, w_ref, u_ref, p_ref, o_ref, s_ref,
                        + dot(kdT_ref[0, j, 0], vn))
 
 
-def _walk_space(qg, kdT, W, U, P, dl):
-    H = qg.shape[1]
-    return [{"block_h": h} for h in (1, 2, 3, 5) if H % h == 0]
+def walk_heads(H: int) -> int:
+    """Heads a grid step of ``gated_delta_chunk`` walks.  A rule of the
+    shape, from the table ``tools/gated_delta_chip.py --tiles`` timed on
+    the chip (``PERF.md`` section 6, PR 44): the MOST of 1, 2, 3, 5 that
+    divide the heads (5 of 30 heads of 96 x 192: 0.69-1.53 ms a one-row
+    call at 1536-4096 tokens against 0.78-1.82 at one; 2 of 32 over 16 key
+    heads of 128 x 128: 0.41-0.79 ms a two-row call at 256-1024 against
+    0.44-0.91), monotone in both tables.  It was a measured search of
+    ``ops.autotune`` until PR 44; a race between candidates this close
+    draws differently from one cold checkout to the next, and moved a cell
+    by more than its bound with no edit to this file.  ``block_h=`` on the
+    two kernels stays for that tool and for the tests that run every
+    block."""
+    return max(h for h in (1, 2, 3, 5) if H % h == 0)
 
 
-@_at.autotune("gated_delta_chunk", params=("block_h",), space=_walk_space,
-              heuristic=lambda *a: {"block_h": 1})
-def _walk_pallas(qg, kdT, W, U, P, dl, *, block_h):
+def _walk_pallas(qg, kdT, W, U, P, dl, *, block_h=None):
     B, H, N, C, dk = qg.shape
     dv = U.shape[-1]
+    block_h = block_h or walk_heads(H)
     z = _at.I0
 
     def blk(*tail):
@@ -321,19 +333,24 @@ def _step_space(q, k, v, g, beta, state):
             and _at.vmem_fits(4 * 4 * h * dk * (-(-dv // 128) * 128))]
 
 
-def _step_heuristic(q, k, v, g, beta, state):
-    """The largest block of at most 10 heads: 0.7 MB of state a grid step
-    at 96 x 192, 0.5 MB (8 heads) at 128 x 128."""
-    return max((c for c in _step_space(q, k, v, g, beta, state)
-                if c["block_h"] <= 10), key=lambda c: c["block_h"])
+def step_heads(q, k, v, g, beta, state) -> int:
+    """Heads a grid step of ``gated_delta_step`` takes.  A rule of the
+    shape, from the table ``tools/gated_delta_chip.py --tiles`` timed on
+    the chip (``PERF.md`` section 6, PR 44): the largest block of
+    :func:`_step_space` of at most 10 heads (10 of 30 at 96 x 192 and 16
+    slots: 0.350 ms, the table's best 0.349 at 5; 8 of 32 at 128 x 128 and
+    64 slots: 0.754 ms, the best 0.744 at 16: ties inside 2 %, so the
+    blocks the kernel always started from).  A measured search of
+    ``ops.autotune`` until PR 44 (:func:`walk_heads` says why no more)."""
+    return max(c["block_h"] for c in _step_space(q, k, v, g, beta, state)
+               if c["block_h"] <= 10)
 
 
-@_at.autotune("gated_delta_step", params=("block_h",), space=_step_space,
-              heuristic=_step_heuristic)
-def _step_pallas(q, k, v, g, beta, state, *, block_h):
+def _step_pallas(q, k, v, g, beta, state, *, block_h=None):
     B, Hk, dk = q.shape
     H, dv = v.shape[1:]
     rep = H // Hk
+    block_h = block_h or step_heads(q, k, v, g, beta, state)
     z = _at.I0
 
     def blk(*tail, heads=block_h):

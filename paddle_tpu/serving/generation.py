@@ -88,7 +88,9 @@ cold and counted (``prefix_unshared``: mapped pages would come without the
 state at the prefix's boundary).  All of these apply to a window layer's
 rings as they stand (a ring of exactly ``window`` rows would lose the key a
 rejected draft overwrote).  A model without the attribute is handed
-neither keyword and builds the programs it always built.
+neither keyword and builds the programs it always built.  The pages beside
+the slot state are the model's own too: K/V pools (``models/hybrid.py``) or
+latent pools (``models/kimi_linear.py``), through the same page verbs.
 
 The compile set is closed and traced in :meth:`warmup`:
 ``len(prompt_buckets) + 3`` with speculation (per-bucket ``[R(bucket),
@@ -711,6 +713,12 @@ class GenerationEngine:
             jax.device_get(self._export(cache, idx0))
         elif self._role == "decode":
             cache = self._import(cache, self._handoff_zero(), idx0)
+        # this pool dies with the frame, but only once the device has run
+        # what is queued on it: where every program came from the compile
+        # cache the calls above return at once, and the loop's own fresh
+        # pool (_init_pool at its first admission) would be allocated
+        # BESIDE this one, one more copy of the pool at the memory's peak
+        jax.block_until_ready(cache)
         self.metrics.set_counter("compiles", self.compile_count)
         from ..ops import autotune
         autotune.mark_warm()  # later tuner searches are hot-path (K701)

@@ -616,7 +616,12 @@ def test_gated_delta_step_32_states_of_128_by_128_from_16_key_heads(chip):
     args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
         (B, Hk, d), (B, Hk, d), (B, Hv, d), (B, Hv), (B, Hv),
         (B + 1, Hv, d, d))]
-    assert gd._step_heuristic(*args) == {"block_h": 8}
+    from paddle_tpu.ops import autotune
+
+    # a rule of the shape since PR 44, no measured search
+    assert gd.step_heads(*args) == 8
+    assert not {"gated_delta_step", "gated_delta_chunk", "kda_step",
+                "kda_chunk"} & set(autotune.registered_kernels())
     text = jax.jit(gd.gated_delta_step, donate_argnums=(5,)).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text and "gated_delta_step" in text
@@ -812,6 +817,45 @@ def test_ragged_gated_mlp_16_held_of_128_experts_of_6144_by_2048(
         _compiles_with_kernel(chip, layer(block_f), *shapes)
 
 
+# -- Kimi Delta Attention at the kimi_linear cell's shapes ---------------------
+@pytest.mark.parametrize("T", [1536, 4096])
+def test_kda_chunk_32_heads_of_128_by_128_with_a_vector_decay(chip, T):
+    # a one-row admission of the kimi_linear cell: 32 heads, a [128, 128]
+    # float32 state, one decay a key channel; the whole op, the sub-block
+    # WY operands in XLA and the walk over chunks (the state transposed in
+    # VMEM) in the kernel
+    from paddle_tpu.ops import kda
+
+    B, H, d = 1, 32, 128
+    one = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
+        (B, T, H, d), (B, T, H, d), (B, T, H, d), (B, T, H, d), (B, T, H))]
+    exe = jax.jit(kda.kda_chunk).lower(*args).compile()
+    text = exe.as_text()
+    assert "tpu_custom_call" in text and "kda_chunk" in text
+    # the pairwise decays of the diagonal sub-blocks ([.., 16, 16, 128] a
+    # sub-block: 1 GB at 4096 tokens) are summed where they are made
+    assert exe.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_kda_step_updates_129_rows_of_state_in_place(chip):
+    # a decode step of the cell: 128 slots of the 129 stored rows, donated
+    from paddle_tpu.ops import kda
+
+    B, H, d = 128, 32, 128
+    assert kda.step_heads(H, d) * 3 <= d
+    one = SingleDeviceSharding(chip)
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
+        (B, H, d), (B, H, d), (B, H, d), (B, H, d), (B, H),
+        (B + 1, H, d, d))]
+    text = jax.jit(kda.kda_step, donate_argnums=(5,)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in text and "kda_step" in text
+    assert "input_output_alias" in text
+    assert not [l for l in text.splitlines()
+                if " copy(" in l and "f32[129,32,128,128]" in l.split("=")[1]]
+
+
 @pytest.mark.parametrize("program,kernels", [
     # one period of the model, layer 0 dense: three window layers' decode
     # over the rings, the global layer's paged_decode, three expert kernels
@@ -883,3 +927,82 @@ def test_the_k_exaone_engine_lowers_its_programs_for_the_chip(
              if "tpu_custom_call" in line and " custom-call(" in line]
     for kernel, n in kernels.items():
         assert sum(kernel in c for c in calls) == n, (kernel, calls)
+
+
+@pytest.mark.parametrize("program,kernels", [
+    # one period of the model, layer 1 dense: three KDA layers' state
+    # kernel, three expert kernels (the latent layer's absorbed decode is
+    # XLA's)
+    ("step", {"kda_step": 3, "moe_gated_mlp_tm16": 3, "": 6}),
+    # a [1, 4096] admission: three chunked scans, the latent layer's prompt
+    # attention, three expert kernels at the 128-row tile
+    ("admit", {"kda_chunk": 3, "latent_prefill_attention": 1,
+               "moe_gated_mlp_tm128": 3, "": 7}),
+])
+def test_the_kimi_linear_engine_lowers_its_programs_for_the_chip(
+        chip, program, kernels):
+    # benchmarks/configs/kimi_linear_serve.json at published widths, one
+    # period of its layers, weights that are shapes only: 128 slots x 4864
+    # positions, the engine's own jitted programs with every TPU-only
+    # branch taken (slot state, latent pages AND experts in one program)
+    import json
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.harness import loader
+    from paddle_tpu import nn
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    bench = os.path.join(repo, "benchmarks")
+    with open(os.path.join(bench, "configs", "kimi_linear_serve.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 4}
+    with open(os.path.join(bench, "traffic", "longdoc_gen_closed.json")) as f:
+        buckets = json.load(f)["prompt_buckets"]
+    fam = loader.load_module("families", cfg["family"], bench)
+    serve = cfg["serve"]
+    with nn.abstract_parameters():
+        model = fam.KimiLinearForCausalLM(fam.model_config(cfg))
+    eng = GenerationEngine(
+        model, prompt_buckets=buckets, batch_size=serve["batch_size"],
+        cache_len=serve["cache_len"], kv_page_size=serve["kv_page_size"],
+        speculative_k=0, eos_token_id=None, name="compile-only-kml")
+    try:
+        assert eng._admit_rows == {b: 1 for b in buckets}
+        one = SingleDeviceSharding(chip)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+        B, T, C = serve["batch_size"], buckets[-1], serve["cache_len"]
+        G = C // serve["kv_page_size"]
+        pool = on_chip(jax.eval_shape(eng._empty_pool))
+        # latent pages beside slot state, under one manager
+        assert pool["layers"][3]["latent"].shape == (B * G + 1, 16, 640)
+        assert pool["layers"][0]["state"].shape == (B + 1, 32, 128, 128)
+        assert pool["layers"][0]["conv"].shape == (B + 1, 3, 12288)
+        params, buffers = on_chip(eng._params), on_chip(eng._buffers)
+        if program == "step":
+            exe = eng._step_jit.lower(params, buffers, ints(B, 2 + C + G),
+                                      ints(B, 1), pool).compile()
+        else:
+            exe = eng._padmit.lower(
+                params, buffers, ints(1, T), ints(1, T), ints(1, C),
+                ints(1, G), ints(1), pool, None, ints(1)).compile()
+    finally:
+        eng.close()
+    text = exe.as_text()
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    for kernel, n in kernels.items():
+        assert sum(kernel in c for c in calls) == n, (kernel, calls)
+    # beside 10.8 GB of weights and caches a program's temporaries must fit
+    # what is left of 16 GB: the step's slot views of 128 slots' latent
+    # pages, the admission's float32 WY operands
+    assert exe.memory_analysis().temp_size_in_bytes < 3.0e9

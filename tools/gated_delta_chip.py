@@ -1,6 +1,8 @@
 """The hybrid decoder's TPU-only branches on the chip, at a cell's own
-shapes: ``--config olmo_hybrid_serve`` (the default) or
-``qwen3_next_serve``.
+shapes: ``--config olmo_hybrid_serve`` (the default), ``qwen3_next_serve``
+or ``kimi_linear_serve`` (``ops/kda.py``'s two kernels, one decay a key
+channel: phases 1, 3 and ``--tiles``; the model against the plain reference
+is the cell's own ``correct``).
 
 ``gated_delta_chunk`` / ``gated_delta_step`` (``ops/gated_delta.py``), the
 admission's ``flash_attention``, the decode's ``paged_decode`` and (where the
@@ -25,7 +27,19 @@ runs the kernels' code, not Mosaic's), so before a number is quoted:
    with free slots, every kernel gate open, against the same calls with
    every gate shut (the ``jnp`` forms and the gather path): the widest
    logit gap and the mean.
-3. ``ms``: each kernel alone, one layer's call, mean of 10 dependent calls.
+3. ``ms``: each kernel alone, one layer's call, mean of 10 dependent calls;
+   for ``kimi_linear_serve`` also ``operand_stages``: the making of the WY
+   operands taken apart at one row x the widest bucket (the numbers
+   ``PERF.md`` section 5 quotes for an admission's KDA layer).
+
+``--tiles`` instead times EVERY candidate of the two delta-rule kernels'
+``block_h`` (heads a grid step) at the cell's shapes: the walk at each prompt
+bucket of the cell's traffic x the rows its admission call has, the step at
+the cell's slots with the states donated and threaded (as the engine's step
+does), each the best of three means of 10 calls.  The table behind
+``ops/gated_delta.py:walk_heads`` / ``step_heads`` and ``ops/kda.py``'s
+(``PERF.md`` section 6, PR 44); ``chiprun_out/gated_delta_tiles.<config>.json``
+keeps it.
 
 One JSON object, last line; exit 1 on a disagreement.
 """
@@ -50,7 +64,19 @@ SHAPES = {
     "qwen3_next_serve": dict(HK=16, H=32, DK=128, DV=128, B=64, C=2048,
                              T=1024, ragged=700, admit=1024,
                              family="qwen3_next"),
+    "kimi_linear_serve": dict(HK=32, H=32, DK=128, DV=128, B=128, C=4864,
+                              T=4096, ragged=2500, admit=1536,
+                              family="kimi_linear", kda=True),
 }
+#: (traffic file, rows an admission call has) of each configuration's cell
+TRAFFIC = {"olmo_hybrid_serve": ("ragdocs_closed", 1),
+           "qwen3_next_serve": ("longgen_closed", 2),
+           "kimi_linear_serve": ("longdoc_gen_closed", 1)}
+#: heads a grid step of the walk kernel may take, the candidates ``--tiles``
+#: times: ``gated_delta_chunk``'s (the kernel's rule, ``walk_heads``, picks
+#: among the same four) and ``kda_chunk``'s
+WALK_HEADS = {False: (1, 2, 3, 5), True: (1, 2, 4, 8, 16, 32)}
+KDA = False
 HK, H, DK, DV = 30, 30, 96, 192
 B, C, PAGE = 16, 4608, 16
 #: float32 kernels against float32 oracles in another summation order
@@ -68,9 +94,23 @@ def _inputs(rng, B_, T):
     q = (unit(rng.normal(size=(B_, T, HK, DK))) * DK ** -0.5).astype(f)
     k = unit(rng.normal(size=(B_, T, HK, DK))).astype(f)
     v = rng.normal(size=(B_, T, H, DV)).astype(f)
-    g = -rng.uniform(0.001, 1.6, size=(B_, T, H)).astype(f)
-    beta = rng.uniform(0.0, 2.0, size=(B_, T, H)).astype(f)
+    # one decay a key channel, some strong enough to pass float32's range
+    # inside a chunk (ops/kda.py), or one a head
+    g = (-np.exp(rng.uniform(-6.0, np.log(8.0), size=(B_, T, H, DK)))
+         if KDA else -rng.uniform(0.001, 1.6, size=(B_, T, H))).astype(f)
+    beta = rng.uniform(0.0, 1.0 if KDA else 2.0, size=(B_, T, H)).astype(f)
     return q, k, v, g, beta
+
+
+def _rule():
+    """The module of the cell's rule and its three routes."""
+    if KDA:
+        from paddle_tpu.ops import kda
+
+        return kda, kda.kda_chunk, kda.kda_recurrent
+    from paddle_tpu.ops import gated_delta as gd
+
+    return gd, gd.gated_delta_chunk, gd.gated_delta_recurrent
 
 
 def _timed(fn, *args, n=10):
@@ -89,8 +129,7 @@ def kernels(shape, cfg):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import gated_delta as gd
-
+    gd, chunk, recurrent = _rule()
     rng = np.random.default_rng(0)
     T, ragged = shape["T"], shape["ragged"]
     q, k, v, g, beta = _inputs(rng, 2, T)
@@ -102,9 +141,9 @@ def kernels(shape, cfg):
     o_j, s_j = jax.jit(gd._walk_jnp)(*ops)
     out = {"walk_vs_jnp_o": float(jnp.abs(o_k - o_j).max()),
            "walk_vs_jnp_state": float(jnp.abs(s_k - s_j).max())}
-    o, S = jax.jit(gd.gated_delta_chunk)(q, k, v, g, beta)
+    o, S = jax.jit(chunk)(q, k, v, g, beta)
     cut = tuple(t[1:2, :ragged] for t in (q, k, v, g, beta))
-    o_r, s_r = jax.jit(gd.gated_delta_recurrent)(*cut)
+    o_r, s_r = jax.jit(recurrent)(*cut)
     out.update(chunk_vs_recurrence_o=float(jnp.abs(
         o[1, :ragged] - o_r[0]).max()),
         chunk_vs_recurrence_state=float(jnp.abs(S[1] - s_r[0]).max()))
@@ -121,15 +160,18 @@ def kernels(shape, cfg):
     ms = {}
     if cfg.get("num_key_value_heads", 0) != cfg["num_attention_heads"]:
         out.update(_grouped_paged_decode(rng, cfg, ms))
-    if cfg.get("num_experts"):
+    if cfg.get("num_experts") and not KDA:
         out.update(_held_experts(rng, cfg, ms))
     out["ok"] = bool(all(v < KERNEL_TOL for v in out.values()
                          if isinstance(v, float))
                      and all(v["ok"] for v in out.values()
                              if isinstance(v, dict))
                      and out["free_slot_kept"] and out["drop_row_kept"])
-    ms = {**ms,"chunk_operands_xla": _timed(jax.jit(gd.chunk_operands), q, k, v,
-                                       g, beta),
+    if KDA:
+        ms["operand_stages"] = operand_stages(q, k, v, g, beta)
+    ms = {**ms,
+          "chunk_operands_xla": _timed(jax.jit(gd.chunk_operands), q, k, v, g,
+                                       beta),
           "gated_delta_chunk_walk": _timed(jax.jit(gd._walk_pallas), *ops),
           "gated_delta_chunk_walk_jnp": _timed(jax.jit(gd._walk_jnp), *ops,
                                                n=2),
@@ -138,6 +180,97 @@ def kernels(shape, cfg):
           "gated_delta_step_jnp": _timed(jax.jit(gd._step_jnp), q1, k1, v1,
                                          g1, b1, state)}
     return out, ms
+
+
+def operand_stages(q, k, v, g, beta):
+    """``ops/kda.py:chunk_operands`` taken apart, each stage a program of
+    its own over ONE row x the widest bucket (a layer's share of an
+    admission call): the running sum of ``g``, the decayed products ``A``
+    and ``P``, the inverse, and the whole making beside the whole chunked
+    op.  The parts do not sum to the whole (XLA fuses across them there):
+    they say where to look."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import gated_delta as gd
+    from paddle_tpu.ops import kda
+
+    q, k, v, g, beta = (t[:1] for t in (q, k, v, g, beta))
+    Bq, T = q.shape[:2]
+    Cn = kda.CHUNK
+
+    def split(t):      # [B, T, H, ...] -> [B, H, N, C, ...]
+        return jnp.moveaxis(t.reshape(Bq, T // Cn, Cn, *t.shape[2:]), 3, 1)
+
+    qs, ks, gs, bs = map(split, (q, k, g, beta))
+    tri = jnp.tril(jnp.ones((Cn, Cn), jnp.float32))
+    run = jax.jit(lambda g: jnp.matmul(tri, g, precision=gd._HI))
+    b = run(gs)
+    kb = ks * bs[..., None]
+    prod = jax.jit(lambda kb, q, k, b: kda._decayed_products((kb, q), k, b))
+    A, _ = prod(kb, qs, ks, b)
+    low = np.tril(np.ones((Cn, Cn), bool), -1)
+    A = jnp.where(low, A, 0.0)
+    return {"rows_x_tokens": f"1x{T}",
+            "running_sum": _timed(run, gs),
+            "running_sum_as_cumsum": _timed(
+                jax.jit(lambda g: jnp.cumsum(g, axis=-2)), gs),
+            "decayed_products": _timed(prod, kb, qs, ks, b),
+            "inverse": _timed(jax.jit(gd._unit_lower_inverse), A),
+            "chunk_operands": _timed(jax.jit(kda.chunk_operands), q, k, v,
+                                     g, beta),
+            "kda_chunk": _timed(jax.jit(kda.kda_chunk), q, k, v, g, beta)}
+
+
+def tiles(shape, cfg, config):
+    """Every candidate ``block_h`` of the walk and of the step at the
+    cell's shapes: ``{kernel: {shape: {block_h: ms}}}``."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import loader
+
+    gd, _, _ = _rule()
+    traffic, rows = TRAFFIC[config]
+    rng = np.random.default_rng(2)
+
+    def best(fn, *args):
+        return min(_timed(fn, *args) for _ in range(3))
+
+    out = {"walk": {}, "step": {}}
+    for T in loader.load_json("traffic", traffic + ".json")["prompt_buckets"]:
+        ops = jax.jit(gd.chunk_operands)(*map(jnp.asarray,
+                                              _inputs(rng, rows, T)))
+        space = [h for h in WALK_HEADS[KDA] if H % h == 0]
+        out["walk"][f"{rows}x{T}"] = {
+            h: best(jax.jit(functools.partial(gd._walk_pallas, block_h=h)),
+                    *ops) for h in space}
+        print(json.dumps({"walk": out["walk"]}), flush=True)
+    one = [jnp.asarray(t[:, 0]) for t in _inputs(rng, B, 1)]
+    state = jnp.asarray(rng.normal(size=(B + 1, H, DK, DV)), jnp.float32)
+    # a KDA block's rows are [block_h, dk] tiles: whole sublane tiles
+    space = ([h for h in (8, 16, 32) if H % h == 0 and 3 * h <= DK]
+             if KDA else [c["block_h"] for c in gd._step_space(*one, state)])
+    for h in space:
+        # donated and threaded: the states are updated where they lie, as
+        # in the engine's step (undonated, a copy of them rides every call)
+        fn = jax.jit(functools.partial(gd._step_pallas, block_h=h),
+                     donate_argnums=(5,))
+
+        def run(n, state):
+            for _ in range(n):
+                _, state = fn(*one, state)
+            return jax.block_until_ready(state)
+
+        state, times = run(2, state), []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            state = run(10, state)
+            times.append((time.perf_counter() - t0) / 10 * 1e3)
+        out["step"][h] = min(times)
+    out["step"] = {f"{B}": out["step"]}
+    print(json.dumps({"step": out["step"]}), flush=True)
+    return out
 
 
 def _grouped_paged_decode(rng, cfg, ms):
@@ -310,18 +443,35 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="olmo_hybrid_serve",
                     choices=sorted(SHAPES))
-    config = ap.parse_args().config
+    ap.add_argument("--tiles", action="store_true")
+    args = ap.parse_args()
+    config = args.config
     shape = SHAPES[config]
-    global HK, H, DK, DV, B, C
+    global HK, H, DK, DV, B, C, KDA
     HK, H, DK, DV, B, C = (shape[k] for k in ("HK", "H", "DK", "DV", "B",
                                               "C"))
+    KDA = bool(shape.get("kda"))
     cfg = loader.load_json("configs", config + ".json")
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(json.dumps({"ok": False, "why": f"no chip: {dev.platform}"}))
         return 1
+    if args.tiles:
+        table = {"config": config, "device": dev.device_kind,
+                 **tiles(shape, cfg, config)}
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        os.makedirs(os.path.join(root, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(root, "chiprun_out",
+                               f"gated_delta_tiles.{config}.json"), "w") as f:
+            json.dump(table, f, indent=1)
+        print(json.dumps({"ok": True, **table}))
+        return 0
     k, ms = kernels(shape, cfg)
     print(json.dumps({"kernels": k, "ms": ms}), flush=True)
+    if KDA:   # the model against the reference is the cell's own `correct`
+        print(json.dumps({"ok": k["ok"], "kernels": k, "ms": ms,
+                          "device": dev.device_kind}))
+        return 0 if k["ok"] else 1
     mdl = model(shape, cfg)
     ok = k["ok"] and mdl["ok"]
     print(json.dumps({"ok": ok, "kernels": k, "model": mdl, "ms": ms,

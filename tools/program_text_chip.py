@@ -99,8 +99,9 @@ def engine_of(config, traffic, layers):
             from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
             model = GPTForCausalLM(GPTConfig(dropout=0.0, num_layers=2))
         else:
-            cls = {"joyai_flash": "LatentMoEForCausalLM"}.get(
-                cfg["family"], "HybridForCausalLM")
+            cls = {"joyai_flash": "LatentMoEForCausalLM",
+                   "kimi_linear": "KimiLinearForCausalLM"}.get(
+                       cfg["family"], "HybridForCausalLM")
             model = getattr(fam, cls)(fam.model_config(cfg))
     kw = {}
     if "cache_len" in serve:
@@ -117,7 +118,9 @@ for config, traffic, layers in (("gpt2_small_serve", "docs_closed", 0),
                                 ("joyai_flash_serve", "ragdocs_closed", 2),
                                 ("olmo_hybrid_serve", "ragdocs_closed", 4),
                                 ("qwen3_next_serve", "longgen_closed", 4),
-                                ("k_exaone_serve", "ragdocs_closed", 4)):
+                                ("k_exaone_serve", "ragdocs_closed", 4),
+                                ("kimi_linear_serve", "longdoc_gen_closed",
+                                 4)):
     if not os.path.exists(os.path.join(bench, "configs", config + ".json")):
         continue  # a tree from before the configuration
     eng, buckets = engine_of(config, traffic, layers)
