@@ -25,7 +25,9 @@ x W_kva``, ``c = RMSNorm(c)``, ``[k_n | v] = c W_kvb``, scores ``(q_n . k_n
 + q_p . k_p) (d_n + d_p)^-1/2``; no rotation anywhere, so positions reach
 these layers only through the KDA layers' state.  A page row holds ``[c |
 k_p]`` in ``LatentMoEConfig.page_width``'s lanes, and both formulations
-(expanded prefill, absorbed decode) are that class's.
+(expanded prefill; absorbed decode, on a TPU the ``latent_decode`` page
+walk of ``ops/latent_attention.py`` handed the un-rotated ``q_p`` lanes,
+elsewhere over a gathered view) are that class's.
 
 The serving-model protocol (``serving/generation.py``) with ``slot_state``:
 ``init_paged_cache(..., slots=B)`` returns ``{"layers": [...]}`` with

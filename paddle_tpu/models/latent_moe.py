@@ -23,8 +23,14 @@ Two formulations of the same attention:
   grow with the prompt bucket;
 * absorbed (decode, ``T == 1``): the K expansion is folded into the query
   (``q' = q_nope W^K``) and the V expansion is applied after the
-  probabilities (``(P c) W^V``), so a step reads the latent pages once
-  and never materialises per-head K/V.
+  probabilities (``(P c) W^V``), so a step never materialises per-head
+  K/V.  On a TPU it gathers no view either: the ``latent_decode`` kernel
+  of ``ops/latent_attention.py`` walks each slot's page-table row up to
+  its sweep bound and attends to the latent rows where they lie in the
+  pool, each page fetched once (scores from a row's every lane, values
+  its first ``kv_lora_rank``); elsewhere (the CPU, a mesh of several
+  devices) the same products run over a gathered ``[slots, C, width]``
+  view of every slot's whole window, the reference the kernel is held to.
 
 The model owns its page layout: :meth:`LatentMoEModel.init_paged_cache`
 returns ``{"layers": [{"latent": [P + 1, page, page_width]}]}`` and the page
@@ -45,6 +51,8 @@ from ..moe import DroplessMoE
 from ..moe.layer import gated_mlp
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
+from ..ops.latent_attention import latent_decode, latent_decode_eligible
+from ..ops.paged_attention import key_visible, sweep_bound
 
 __all__ = ["LatentMoEConfig", "LatentMoEModel", "LatentMoEForCausalLM",
            "rope_interleaved"]
@@ -122,11 +130,16 @@ def rope_interleaved(x, positions, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _walks_pages(pool, T) -> bool:
+    """Does a paged call of ``T`` tokens a row attend through the
+    ``latent_decode`` kernel?  One indirection so tests can steer it."""
+    return latent_decode_eligible(pool, T)
+
+
 def _visible(kpos, qpos, ring):
     """``[B, Tq, Tk]``: key position is written, not after the query, and
     inside the ring window (the paged mask of ``GPTModel.forward_paged``)."""
-    kp, qp = kpos[:, None, :], qpos[:, :, None]
-    return (kp >= 0) & (kp <= qp) & (kp > qp - ring)
+    return key_visible(kpos[:, None, :], qpos[:, :, None], ring)
 
 
 class GatedMLP(Layer):
@@ -266,27 +279,54 @@ class LatentAttention(Layer):
         return ctx.reshape(B, T, H * dv)
 
     # -- absorbed: one query row against the latents themselves ---------------
+    def _fold_keys(self, q_nope, dtype):
+        """``q' = q_nope W^K``: the K expansion folded into the one query
+        token of each row, ``[B, H, kv_lora_rank]``."""
+        return jnp.einsum("bhd,chd->bhc", q_nope[:, 0], self._kv_b_heads()[0],
+                          preferred_element_type=_F32).astype(dtype)
+
+    def _expand_values(self, o):
+        """``(P c) W^V``: the V expansion after the probabilities,
+        ``[B, H, kv_lora_rank]`` -> ``[B, 1, H * v]``."""
+        o = jnp.einsum("bhc,chv->bhv", o, self._kv_b_heads()[1],
+                       preferred_element_type=_F32).astype(o.dtype)
+        return o.reshape(o.shape[0], 1, -1)
+
     def absorbed(self, q_nope, q_rope, latent, qpos, kpos, ring):
         """Same function as :meth:`expanded` for ``T == 1``: ``W^K`` is
         folded into the query and ``W^V`` applied after the probabilities,
-        so the latents are read as they lie in the pages."""
+        so the latents are read as they lie in the pages.  Over a GATHERED
+        view ``latent`` ``[B, S, width]``: the CPU path of a decode step,
+        and the reference :meth:`absorbed_paged` is held to."""
         r = self.cfg.kv_lora_rank
-        B, _, H, _ = q_nope.shape
-        wk, wv = self._kv_b_heads()
         c, k_rope = latent[..., :r], latent[..., r:]
-        q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], wk,
-                           preferred_element_type=_F32).astype(c.dtype)
-        s = (jnp.einsum("bhc,bsc->bhs", q_abs, c,
+        s = (jnp.einsum("bhc,bsc->bhs", self._fold_keys(q_nope, c.dtype), c,
                         preferred_element_type=_F32)
              + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], k_rope,
                           preferred_element_type=_F32)) * self.scale
         s = jnp.where(_visible(kpos, qpos, ring), s, jnp.finfo(_F32).min)
         p = jax.nn.softmax(s, axis=-1).astype(c.dtype)
-        o = jnp.einsum("bhs,bsc->bhc", p, c,
-                       preferred_element_type=_F32).astype(c.dtype)
-        o = jnp.einsum("bhc,chv->bhv", o, wv,
-                       preferred_element_type=_F32).astype(c.dtype)
-        return o.reshape(B, 1, -1)
+        return self._expand_values(
+            jnp.einsum("bhs,bsc->bhc", p, c,
+                       preferred_element_type=_F32).astype(c.dtype))
+
+    def absorbed_paged(self, q_nope, q_rope, pool, tables, qpos, kpos):
+        """:meth:`absorbed` with no view: the ``latent_decode`` kernel of
+        ``ops/latent_attention.py`` walks each slot's page-table row
+        (``tables`` ``[B, G]``, clipped to valid pages) up to its sweep
+        bound and attends to the rows of ``pool`` where they lie, each page
+        fetched once.  The query rows are laid out as a page row is,
+        ``[q_nope W^K | q_rope | 0]``."""
+        cfg, page = self.cfg, pool.shape[1]
+        q = jnp.concatenate([self._fold_keys(q_nope, pool.dtype),
+                             q_rope[:, 0].astype(pool.dtype)], axis=-1)
+        q = jnp.pad(q, ((0, 0), (0, 0),
+                        (0, cfg.page_width - cfg.latent_width)))
+        seen = _visible(kpos, qpos, tables.shape[1] * page)
+        o = latent_decode(q, pool, tables, kpos, qpos,
+                          sweep_bound(seen, page), scale=self.scale,
+                          value_width=cfg.kv_lora_rank)
+        return self._expand_values(o.astype(q_nope.dtype))
 
     def forward(self, x, positions):
         """Causal attention over the sequence itself, no cache."""
@@ -307,13 +347,18 @@ class LatentAttention(Layer):
             lat = jnp.pad(lat, ((0, 0),
                                 (0, cfg.page_width - cfg.latent_width)))
             pool = pool.at[write_page, write_off].set(lat.astype(pool.dtype))
-            C = gather_tab.shape[1] * pool.shape[1]
             # the table is clipped to valid pages by the caller
-            view = pool.at[gather_tab].get(mode="promise_in_bounds")
-            view = view.reshape(B, C, -1)[..., :cfg.latent_width]
-            view = view.astype(x.dtype)
-            attend = self.absorbed if T == 1 else self.expanded
-            ctx = attend(q_nope, q_rope, view, positions, pos_map, C)
+            if _walks_pages(pool, T):
+                # TPU, a decode step: no view, the kernel walks the pages
+                ctx = self.absorbed_paged(q_nope, q_rope, pool, gather_tab,
+                                          positions, pos_map)
+            else:
+                C = gather_tab.shape[1] * pool.shape[1]
+                view = pool.at[gather_tab].get(mode="promise_in_bounds")
+                view = view.reshape(B, C, -1)[..., :cfg.latent_width]
+                view = view.astype(x.dtype)
+                attend = self.absorbed if T == 1 else self.expanded
+                ctx = attend(q_nope, q_rope, view, positions, pos_map, C)
             return _mm(ctx, self.out.value), {"latent": pool}
 
 

@@ -36,6 +36,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from benchmarks.harness import loader  # noqa: E402
+from conftest import LATENT_WALK_CASES  # noqa: E402
 
 from paddle_tpu.models import kimi_linear as kl  # noqa: E402
 from paddle_tpu.models.latent_moe import LatentAttention  # noqa: E402
@@ -307,6 +308,28 @@ def test_through_the_engine_state_latent_pages_and_experts_in_one_loop(tiny):
     assert all(len(o) == 12 for o in outs)
     gaps = ref.served_token_gaps(w, cfg, prompts, outs)
     assert max(g["gap"].max() for g in gaps) < F32_TOL
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", F32_TOL),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", LATENT_WALK_CASES)
+def test_nope_latent_decode_kernel_against_absorbed_over_a_gathered_view(
+        case, dtype, tol, latent_walk_check):
+    """The NoPE latent layer (no bottleneck, nothing rotated, 4 heads
+    padded to a sublane tile of rows): ``latent_decode`` walking the pool
+    against ``absorbed`` over the gathered view, as a share of the layer's
+    largest output (float32 8e-7 at the widest; bfloat16 one ulp, 0.5 %:
+    the output is rounded to 8 bits of mantissa on both sides)."""
+    m, _ = build(tiny_cfg(dtype))
+    latent_walk_check(m.model.blocks[3].mixer, case, tol)
+
+
+def test_through_the_engine_the_page_walk_serves_the_gather_paths_tokens(
+        tiny, latent_walk_serves_the_same):
+    rng = np.random.default_rng(0)
+    latent_walk_serves_the_same(
+        tiny[0], [rng.integers(1, 512, size=n).astype(np.int32)
+                  for n in (5, 16, 20, 31, 9, 12, 2)], 8)
 
 
 @pytest.mark.parametrize("kw,why", [

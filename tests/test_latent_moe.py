@@ -206,6 +206,78 @@ def test_latent_prefill_kernel_against_plain_softmax(case, monkeypatch):
         assert need.sum() < need.size / 2   # and skips most of the rest
 
 
+# -- the decode step's page walk ---------------------------------------------
+from conftest import LATENT_WALK_CASES  # noqa: E402
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", F32_TOL),
+                                       ("bfloat16", BF16_TOL)])
+@pytest.mark.parametrize("case", LATENT_WALK_CASES)
+def test_latent_decode_kernel_against_absorbed_over_a_gathered_view(
+        case, dtype, tol, latent_walk_check):
+    """The rotary layer at the cells' 32 heads: ``latent_decode`` walking
+    the pool against ``absorbed`` over the gathered view, as a share of the
+    layer's largest output: 8e-7 was the widest float32 gap (a key left out
+    of 640 moves it by 1e-3), one bfloat16 ulp (0.5 %) the widest there."""
+    m, _ = build(tiny_cfg(dtype, layers=1, num_attention_heads=32))
+    latent_walk_check(m.model.blocks[0].attn, case, tol)
+
+
+@pytest.mark.parametrize("keys", [16, 48, 128, 512])
+def test_latent_decode_is_the_same_sum_in_blocks_of_any_size(keys):
+    """Keys a block is a tile, not a result: 11 pages of 16 in blocks of 1,
+    3, 8 pages and in one, bounds that end inside a block, a free slot."""
+    from paddle_tpu.ops import latent_attention as la
+    from paddle_tpu.ops.paged_attention import key_visible, sweep_bound
+
+    rng = np.random.default_rng(2)
+    B, H, W, r, page, G = 4, 5, 128, 40, 16, 11
+    C = G * page
+    pool = jnp.asarray(rng.normal(size=(B * G + 1, page, W)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, H, W)), jnp.float32)
+    table = rng.permutation(B * G).reshape(B, G).astype(np.int32)
+    pos = np.array([[C - 1], [37], [-1], [90]], np.int32)
+    pos_map = np.where(np.arange(C)[None] <= pos, np.arange(C)[None], -1)
+    seen = key_visible(pos_map[:, None, :], pos[:, :, None], C)
+    bound = sweep_bound(seen, page)
+    assert bound.tolist() == [11, 8, 0, 8]
+    assert la.decode_block_pages(page, G, keys) == min(keys, 256) // page
+    got = np.asarray(la.latent_decode(
+        q, pool, jnp.asarray(table), jnp.asarray(pos_map), jnp.asarray(pos),
+        jnp.asarray(bound), scale=0.3, value_width=r, block_keys=keys))
+    view = np.asarray(pool)[table].reshape(B, C, W)
+    s = np.where(seen, np.einsum("bhw,bcw->bhc", np.asarray(q), view) * 0.3,
+                 -np.inf)
+    with np.errstate(invalid="ignore"):
+        p = np.nan_to_num(np.exp(s - s.max(-1, keepdims=True)))
+    want = np.einsum("bhc,bcv->bhv", p, view[..., :r]) / np.maximum(
+        p.sum(-1, keepdims=True), 1e-30)
+    assert got.shape == (B, H, r)
+    assert np.abs(got - want).max() < F32_TOL
+    assert (got[2] == 0).all()  # the free slot: zeros, nothing fetched
+
+
+def test_latent_decode_eligible_is_the_tpu_decode_width_on_one_device(
+        monkeypatch, use_mesh):
+    from paddle_tpu.framework import device as pdevice
+    from paddle_tpu.ops import latent_attention as la
+
+    pool = jax.ShapeDtypeStruct((9, 16, 640), jnp.bfloat16)
+    assert not la.latent_decode_eligible(pool, 1)     # the CPU: gather path
+    monkeypatch.setattr(pdevice, "on_tpu", lambda: True)
+    assert not la.latent_decode_eligible(pool, 1)     # the suite's 8 devices
+    use_mesh(jax.devices()[:1])
+    assert la.latent_decode_eligible(pool, 1)
+    assert not la.latent_decode_eligible(pool, 2)     # an admission, a verify
+    for shape, dt in (((9, 16, 576), jnp.bfloat16),
+                      ((9, 8, 640), jnp.bfloat16),
+                      ((9, 12, 640), jnp.float32)):
+        assert not la.latent_decode_eligible(
+            jax.ShapeDtypeStruct(shape, dt), 1), shape
+    assert la.latent_decode_eligible(
+        jax.ShapeDtypeStruct((9, 8, 640), jnp.float32), 1)
+
+
 # -- routing ---------------------------------------------------------------
 def moe_layer(seed=0, E=16, k=4, D=64, F=32):
     import paddle_tpu as paddle
@@ -373,6 +445,15 @@ def test_the_engine_serves_the_model_with_a_closed_compile_set():
     gaps = ref.served_token_gaps(w, cfg, prompts, outs)
     assert all(len(o) == 6 for o in outs)
     assert max(g["gap"].max() for g in gaps) < F32_TOL
+
+
+def test_the_engine_serves_the_gather_paths_tokens_through_the_page_walk(
+        latent_walk_serves_the_same):
+    m, _ = build(tiny_cfg(cache_len=128))
+    rng = np.random.default_rng(0)
+    latent_walk_serves_the_same(
+        m, [rng.integers(1, 512, size=n).astype(np.int32)
+            for n in (5, 16, 20, 31, 9, 12)], 6)
 
 
 #: what ``GenerationEngine`` served for this seeded GPT at commit ed5b0fe,

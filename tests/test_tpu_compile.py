@@ -931,9 +931,9 @@ def test_the_k_exaone_engine_lowers_its_programs_for_the_chip(
 
 @pytest.mark.parametrize("program,kernels", [
     # one period of the model, layer 1 dense: three KDA layers' state
-    # kernel, three expert kernels (the latent layer's absorbed decode is
-    # XLA's)
-    ("step", {"kda_step": 3, "moe_gated_mlp_tm16": 3, "": 6}),
+    # kernel, three expert kernels, the latent layer's page walk
+    ("step", {"kda_step": 3, "moe_gated_mlp_tm16": 3, "latent_decode": 1,
+              "": 7}),
     # a [1, 4096] admission: three chunked scans, the latent layer's prompt
     # attention, three expert kernels at the 128-row tile
     ("admit", {"kda_chunk": 3, "latent_prefill_attention": 1,
@@ -1003,6 +1003,108 @@ def test_the_kimi_linear_engine_lowers_its_programs_for_the_chip(
     for kernel, n in kernels.items():
         assert sum(kernel in c for c in calls) == n, (kernel, calls)
     # beside 10.8 GB of weights and caches a program's temporaries must fit
-    # what is left of 16 GB: the step's slot views of 128 slots' latent
-    # pages, the admission's float32 WY operands
+    # what is left of 16 GB (the admission's float32 WY operands; the step
+    # gathers no slot views since its latent layer walks the pool)
     assert exe.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+# -- the latent decoders' decode step walks the pool where it lies ------------
+@pytest.mark.parametrize("B,G", [(32, 288), (128, 304)],
+                         ids=["joyai_flash", "kimi_linear"])
+def test_latent_decode_walks_the_latent_cells_pages_of_640_lanes(chip, B, G):
+    # a decode step of the two latent cells: 32 slots x 288 pages and 128
+    # slots x 304 pages of 16 rows of 640 bfloat16 lanes (512 latent + 64
+    # shared key + pad), 32 heads as the query rows of a slot's grid step;
+    # the page table rides flat in SMEM (38 912 entries at 128 slots)
+    from paddle_tpu.ops.latent_attention import (DECODE_KEYS, latent_decode,
+                                                 latent_decode_eligible)
+
+    pool = ((B * G + 1, 16, 640), bf16)
+    assert latent_decode_eligible(jax.ShapeDtypeStruct(*pool), 1)
+    assert not latent_decode_eligible(jax.ShapeDtypeStruct(*pool), 1536)
+    assert DECODE_KEYS == 512  # by rule, nothing to search
+
+    def fn(q, pool, tab, pm, pos, bound):
+        return latent_decode(q, pool, tab, pm, pos, bound, scale=192 ** -0.5,
+                             value_width=512)
+
+    _compiles_with_kernel(chip, fn, ((B, 32, 640), bf16), pool, ((B, G), i32),
+                          ((B, G * 16), i32), ((B, 1), i32), ((B,), i32))
+
+
+@pytest.mark.parametrize("config,traffic,cls,layers,walks", [
+    # layer 0 and one expert layer: a latent layer each
+    ("joyai_flash_serve", "ragdocs_closed", "LatentMoEForCausalLM", 2, 2),
+    # one period: three KDA layers and the NoPE latent layer
+    ("kimi_linear_serve", "longdoc_gen_closed", "KimiLinearForCausalLM", 4, 1),
+], ids=["joyai_flash", "kimi_linear"])
+def test_the_latent_step_programs_walk_the_pool_and_gather_no_slot_view(
+        chip, config, traffic, cls, layers, walks):
+    # the sibling of test_gpt2_paged_programs_never_copy_the_pool for the
+    # latent cells' decode step (published widths, a few layers, weights
+    # that are shapes only): every latent layer attends through
+    # `latent_decode`, and no instruction of the optimized program produces
+    # a [slots, C, 640] view of the slots' windows (the gather the step
+    # made a layer until PR 45: 94 M elements at 32 slots, 398 M at 128),
+    # its 576 used lanes, or another array of the pool's size
+    import json
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmarks.harness import loader
+    from paddle_tpu import nn
+    from paddle_tpu.serving.generation import GenerationEngine
+
+    bench = os.path.join(repo, "benchmarks")
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": layers}
+    with open(os.path.join(bench, "traffic", traffic + ".json")) as f:
+        buckets = json.load(f)["prompt_buckets"]
+    fam = loader.load_module("families", cfg["family"], bench)
+    serve = cfg["serve"]
+    with nn.abstract_parameters():
+        model = getattr(fam, cls)(fam.model_config(cfg))
+    eng = GenerationEngine(
+        model, prompt_buckets=buckets, batch_size=serve["batch_size"],
+        cache_len=serve["cache_len"], kv_page_size=serve["kv_page_size"],
+        speculative_k=0, eos_token_id=None, name="compile-only-walk")
+    try:
+        one = SingleDeviceSharding(chip)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one), tree)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32, sharding=one)
+
+        B, C, page = serve["batch_size"], serve["cache_len"], 16
+        G = C // page
+        pool = on_chip(jax.eval_shape(eng._empty_pool))
+        pools = [kv["latent"] for kv in pool["layers"] if "latent" in kv]
+        assert len(pools) == walks
+        assert all(p.shape == (B * G + 1, page, 640) for p in pools)
+        exe = eng._step_jit.lower(
+            on_chip(eng._params), on_chip(eng._buffers),
+            ints(B, 2 + C + G), ints(B, 1), pool).compile()
+    finally:
+        eng.close()
+    text = exe.as_text()
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sum("latent_decode" in c for c in calls) == walks, calls
+    for what, elems in (("pool", (B * G + 1) * page * 640),
+                        ("slot views", B * C * 640),
+                        ("slot views' latent lanes", B * C * 576)):
+        stray, kinds = _stray_pool_results(text, elems)
+        assert not stray, f"{what}-sized results: {stray}"
+        if what == "pool":  # scattered into in place, donated
+            assert "parameter" in kinds and kinds & {
+                "scatter", "dynamic-update-slice", "fusion"}
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= walks * (B * G + 1) * page * 640 * 2
+    # one slot view alone was 121 MB (32 slots) or 797 MB (128 slots)
+    assert mem.temp_size_in_bytes < 0.8 * B * C * 640 * 2

@@ -30,10 +30,26 @@ to twelve heads' chains of products abreast, and ``rule``: what
 (``QUERY_TILE``, ``query_tile``, ``_ABREAST``, ``_heads_a_step``) is the
 winner of this table.
 
+``--latent`` instead times ``ops/latent_attention.py:latent_decode``, the
+latent decoders' page walk, alone at both latent cells' decode shapes (32
+slots x 288 pages and 128 slots x 304 pages of 16 rows of 640 bfloat16
+lanes, 32 heads as the query rows), windows 57 % live as the cells' are:
+first the kernel against plain softmax attention over a gathered view (a
+wrapped slot, a free slot, a last page partly filled among the rows), then
+ms a layer call for every candidate keys-a-block, the GB/s and the share
+of ``swept bytes / 819 GB/s`` that is (swept = the pages inside
+``sweep_bound``, what the engine's ``kv_pages_swept_steps`` counts: 16 x
+640 x 2 B each), and beside it the gather path (``pool.at[tab].get`` and
+the absorbed products, what ``LatentAttention.absorbed`` does to the view)
+and ``paged_decode`` handed the pool as both its K and its V pool (every
+page read twice: the floor).  ``ops/latent_attention.py:DECODE_KEYS`` is
+the winner of this table.
+
 ``--trace <dir>`` instead reads a profiler trace a benchmark run left
 (``.cache/bench_trace/<cell>``) and prints, for each paged program in
 it, its mean time on the device and the mean time of one ``paged_decode``
-call inside it.  One JSON object, last line; exit 1 on a disagreement.
+(or ``latent_decode``) call inside it.  One JSON object, last line; exit 1
+on a disagreement.
 """
 import argparse
 import json
@@ -147,23 +163,23 @@ def agree():
     return ok, out
 
 
-def _ms_a_layer(attend, q, k, v, *args):
-    """Best mean time of one call of ``attend``, 12 dependent calls in one
-    program (each needs the one before it), 5 x 10 runs."""
+def _ms_a_layer(attend, q, *args):
+    """Best mean time of one call of ``attend(q, *args)``, 12 dependent
+    calls in one program (each needs the one before it), 5 x 10 runs."""
     import jax
 
     @jax.jit
-    def twelve(q, k, v, *a):
+    def twelve(q, *a):
         for _ in range(12):
-            q = q + 1e-3 * attend(q, k, v, *a)
+            q = q + (1e-3 * attend(q, *a)).astype(q.dtype)
         return q
 
-    twelve(q, k, v, *args).block_until_ready()
+    twelve(q, *args).block_until_ready()
     best = float("inf")
     for _ in range(5):
         t = time.perf_counter()
         for _ in range(10):
-            r = twelve(q, k, v, *args)
+            r = twelve(q, *args)
         r.block_until_ready()
         best = min(best, (time.perf_counter() - t) / 120)
     return best * 1e3
@@ -269,6 +285,123 @@ def kernel_ms():
     return out
 
 
+#: the latent cells' decode shapes: slots, pages a slot, contexts' range
+LATENT_SHAPES = {"joyai_flash[32x288]": (32, 288, (1100, 4200)),
+                 "kimi_linear[128x304]": (128, 304, (1100, 4500))}
+LATENT_KEYS = (128, 256, 512, 1024)
+L_PAGE, L_WIDTH, L_RANK, L_HEADS = 16, 640, 512, 32
+
+
+def _latent_layout(rng, B, G, span):
+    """A pool's worth of pages dealt to ``B`` slots of contexts drawn from
+    ``span``; slot 0 has wrapped, slot 1 is free, slot 2 ends inside a
+    page."""
+    C = G * L_PAGE
+    lengths = rng.integers(*span, B)
+    lengths[1], lengths[2] = 0, lengths[2] // L_PAGE * L_PAGE + 5
+    free = list(rng.permutation(B * G))
+    table = np.full((B, G), -1, np.int32)
+    pos_map = np.full((B, C), -1, np.int32)
+    pos = np.full((B, 1), -1, np.int32)
+    for b, n in enumerate(lengths):
+        for g in range(-(-n // L_PAGE)):
+            table[b, g] = free.pop()
+        pos_map[b, :n] = np.arange(n)
+        if n:
+            pos[b, 0] = n - 1
+    at = C + 777  # slot 0: the ring has wrapped, every page live
+    table[0] = [free.pop() if t < 0 else t for t in table[0]]
+    c = np.arange(C)
+    lap = at - at % C + c
+    pos_map[0] = np.where(c <= at % C, lap, lap - C)
+    pos[0, 0] = at
+    return table, pos_map, pos
+
+
+def latent_ms(hbm_bytes_s):
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.latent_attention import (DECODE_KEYS,
+                                                 decode_block_pages,
+                                                 latent_decode)
+    from paddle_tpu.ops.paged_attention import (_sweep, key_visible,
+                                                sweep_bound)
+
+    scale = np.float32(192 ** -0.5)
+    out, ok = {}, True
+    for name, (B, G, span) in LATENT_SHAPES.items():
+        rng = np.random.default_rng(45)
+        C = G * L_PAGE
+        table, pos_map, pos = _latent_layout(rng, B, G, span)
+        mask = key_visible(pos_map[:, None, :], pos[:, :, None], C)
+        bound = sweep_bound(mask, L_PAGE)
+        pool = jax.random.normal(jax.random.PRNGKey(45),
+                                 (B * G + 1, L_PAGE, L_WIDTH), jnp.bfloat16)
+        q = jnp.asarray(rng.normal(size=(B, L_HEADS, L_WIDTH)) * 0.3,
+                        jnp.bfloat16)
+        args = (jnp.maximum(jnp.asarray(table), 0), jnp.asarray(pos_map),
+                jnp.asarray(pos), jnp.asarray(bound))
+        swept = int(bound.sum()) * L_PAGE * L_WIDTH * 2
+
+        def gather(q, pool, tab, pm, qp, _bound):
+            # LatentAttention.absorbed over the view forward_paged gathers
+            view = pool.at[tab].get(mode="promise_in_bounds").reshape(
+                B, C, L_WIDTH)
+            s = jnp.einsum("bhw,bsw->bhs", q, view,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(key_visible(pm[:, None, :], qp[:, :, None], C), s,
+                          jnp.finfo(jnp.float32).min)
+            p = jax.nn.softmax(s, axis=-1).astype(view.dtype)
+            return jnp.einsum("bhs,bsc->bhc", p, view[..., :L_RANK],
+                              preferred_element_type=jnp.float32).astype(
+                                  view.dtype)
+
+        def twice(q, pool, tab, pm, qp, bound):
+            # the K/V walk handed the pool as both pools: each page twice
+            o = _sweep(q[:, None], pool, pool, tab, pm,
+                       jnp.broadcast_to(qp, (B, L_HEADS)), bound, None, None,
+                       block_h=1, sm_scale=float(scale))
+            return o[:, 0, :, :L_RANK]
+
+        def walk(keys):
+            return functools.partial(latent_decode, scale=float(scale),
+                                     value_width=L_RANK, block_keys=keys)
+
+        def rates(attend):
+            def fed_back(q, pool, *a):  # [B, H, rank] back into a query
+                o = attend(q, pool, *a)
+                return jnp.pad(o, ((0, 0), (0, 0), (0, L_WIDTH - L_RANK)))
+            t = _ms_a_layer(fed_back, q, pool, *args)
+            return {"ms_a_layer": t, "gb_s": swept / t / 1e6,
+                    "roofline_share": swept / hbm_bytes_s / (t / 1e3)}
+
+        # a free slot's row is the kernel's zeros and the view's mean: unread
+        live = pos[:, 0] >= 0
+        want = np.asarray(jax.jit(gather)(q, pool, *args), np.float32)[live]
+        row = out[name] = {
+            "live_share": float((pos_map >= 0).mean()),
+            "pages_swept": int(bound.sum()), "swept_mb": swept / 1e6,
+            "roofline_ms": swept / hbm_bytes_s * 1e3, "rule": DECODE_KEYS}
+        for keys in LATENT_KEYS:
+            got = np.asarray(jax.jit(walk(keys))(q, pool, *args), np.float32)
+            gap = float(np.abs(got[live] - want).max())
+            ppb = decode_block_pages(L_PAGE, G, keys)
+            row[f"keys{keys}"] = {
+                "max_gap": gap, "ref_max": float(np.abs(want).max()),
+                "free_slot_zero": bool((got[1] == 0).all()),
+                "pages_fetched": int((-(-bound // ppb)).sum()) * ppb,
+                **rates(walk(keys))}
+            # bfloat16 probabilities and contexts on both sides
+            ok = bool(ok and gap < 2e-2 * np.abs(want).max()
+                      and row[f"keys{keys}"]["free_slot_zero"])
+        row["gather_path"] = rates(gather)
+        row["paged_decode_pool_twice"] = rates(twice)
+    return ok, out
+
+
 def read_trace(trace_dir):
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks"))
@@ -277,9 +410,11 @@ def read_trace(trace_dir):
     dev = tr.load(tr.find_xplane(trace_dir))["devices"]
     dev = dev[min(dev)]
     # an op is named by its HLO text, operands included: the kernel is the
-    # instruction CALLED paged_decode.N, not every op that reads its result
+    # instruction CALLED paged_decode.N (latent_decode.N: the latent
+    # decoders' walk), not every op that reads its result
     kern = sorted((s, d) for n, s, d in dev["ops"]
-                  if tr.short_op_name(n).startswith("paged_decode"))
+                  if tr.short_op_name(n).startswith(("paged_decode",
+                                                     "latent_decode")))
     out = {}
     for name, s, d in dev["modules"]:
         mine = [kd for ks, kd in kern if s <= ks < s + d]
@@ -303,6 +438,8 @@ def main():
     ap.add_argument("--trace", help="read this trace directory instead")
     ap.add_argument("--admit", action="store_true",
                     help="time the admission width's grid forms instead")
+    ap.add_argument("--latent", action="store_true",
+                    help="time the latent decoders' page walk instead")
     a = ap.parse_args()
     if a.trace:
         print(json.dumps({"trace": a.trace, "programs": read_trace(a.trace)}))
@@ -317,6 +454,16 @@ def main():
         print(json.dumps({"ok": True, "device": d.device_kind,
                           "admit_ms_a_layer": admit_ms()}))
         return 0
+    if a.latent:
+        sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks"))
+        from harness import peaks
+
+        ok, rows = latent_ms(
+            peaks.peaks_for(d.device_kind)["hbm_bytes_per_s"])
+        print(json.dumps({"ok": ok, "device": d.device_kind,
+                          "latent_decode": rows}))
+        return 0 if ok else 1
     ok, rows = agree()
     line = {"ok": ok, "device": d.device_kind, "agree": rows}
     if ok:  # no timing of a kernel that disagrees
