@@ -112,27 +112,32 @@ def peak_bytes():
     return int(stats.get("peak_bytes_in_use", 0))
 
 
-def kernel_counters(*names):
-    """The autotune bus's view of each kernel: how its tile config was
-    resolved (a measured search, or the tuning cache) and what failed."""
-    from paddle_tpu.ops import autotune
+def forward_text(exe, main, loss, feeds):
+    """A ``fluid.Program``'s forward, feeds to loss, as JAX lowers it for
+    this backend (StableHLO text; traced under the gates the run's own
+    trace saw, nothing compiled or run)."""
+    import jax
 
-    out = {}
-    for n in names:
-        c = autotune.get_counters(n)
-        out[n] = {k: c[k] for k in ("searches", "configs_timed", "disk_hits",
-                                    "hits", "heuristic", "search_failures")}
-    return out
+    def forward(params, buffers, feeds):
+        env, _ = exe._execute(main, params, buffers, feeds, True,
+                              rng=jax.random.PRNGKey(SEED))
+        return env[loss.name]
+
+    return jax.jit(forward).lower(dict(main.scope), dict(main.buffers),
+                                  feeds).as_text()
 
 
-def check_dispatched(counters):
-    """Every named kernel resolved its config on the chip (search or cache)
-    and never through the off-TPU heuristic — i.e. its gate was open and
-    the compiled kernel, not a stand-in, went into the program."""
-    for name, c in counters.items():
-        resolved = c["searches"] + c["disk_hits"] + c["hits"]
-        check(resolved > 0 and c["heuristic"] == 0,
-              f"kernel {name} was not dispatched on the chip: {c}")
+def check_dispatched(text, at_least, *names):
+    """The program's own text (lowered or compiled) holds its kernels: at
+    least ``at_least`` Mosaic custom calls, and each of ``names`` (a
+    ``pallas_call``'s ``name=``) among them, as ``tests/test_tpu_compile.py``
+    reads a program.  A gate that was shut, or a stand-in that ran in a
+    kernel's place, leaves no call."""
+    calls = text.count("tpu_custom_call")
+    check(calls >= at_least and all(n in text for n in names),
+          f"the program holds {calls} Mosaic kernel calls, wanted "
+          f"{at_least} or more with {names} among them")
+    return {"tpu_custom_calls": calls}
 
 
 # -- device -------------------------------------------------------------------
@@ -217,8 +222,9 @@ def train_phase(mon, batch=256, seq=128, max_pred=20, n_steps=4, cfg=None):
           f"non-finite BERT loss: {w1.tolist()} {w2.tolist()}")
     check(w2[-1] < w1[0],
           f"BERT loss did not fall on a fixed batch: {w1[0]} -> {w2[-1]}")
-    counters = kernel_counters("layernorm_residual", "softmax_xent")
-    check_dispatched(counters)
+    # layernorm_residual twice a layer and softmax_xent over the MLM head
+    counters = check_dispatched(forward_text(exe, main, loss, feeds),
+                                2 * cfg.num_layers + 1)
     del exe, main, loss
     gc.collect()
 
@@ -286,8 +292,8 @@ def resnet_phase(mon, batch=128, image=224, n_steps=3):
     w2, steady_s = _window(exe, main, loss, feeds, n_steps)
     check(np.isfinite(w1).all() and np.isfinite(w2).all(),
           f"non-finite ResNet-50 loss: {w1.tolist()} {w2.tolist()}")
-    counters = kernel_counters("conv1x1_bn_stats", "conv1x1_bn_apply")
-    check_dispatched(counters)
+    # conv1x1_bn_stats and bn_apply_relu in a bottleneck's tail
+    counters = check_dispatched(forward_text(exe, main, loss, feeds), 2)
     del exe, main, loss, net
     gc.collect()
     return {
@@ -369,7 +375,8 @@ def _margin_account(ref_logits, noisy_logits, served, need_clear):
 
 def _serve(model, prompts, new_tokens, mon, **engine_kw):
     """Warm an engine, answer every request concurrently, count compiles
-    after warm-up; returns (served tokens, timings/counters)."""
+    after warm-up; returns (served tokens, timings/counters, the decode
+    step's compiled text)."""
     from paddle_tpu.resilience import retry
     from paddle_tpu.serving import GenerationEngine
 
@@ -384,6 +391,7 @@ def _serve(model, prompts, new_tokens, mon, **engine_kw):
         wall_s = time.perf_counter() - t1
         post = mon.since(snap)
         compile_count = eng.compile_count
+        step_text = eng.compiled_programs()["step"]
     for toks, n in zip(served, new_tokens):
         check(len(toks) == n, f"request answered {len(toks)} of {n} tokens")
     check(post["xla_compiles"] == 0 and compile_count == compiled,
@@ -394,7 +402,8 @@ def _serve(model, prompts, new_tokens, mon, **engine_kw):
     return served, {"warmup_executables": compiled,
                     "smoke_cold_warmup_s": round(warm_s, 2),
                     "smoke_steady_serve_s": round(wall_s, 3),
-                    "post_warmup_xla_compiles": post["xla_compiles"]}
+                    "post_warmup_xla_compiles": post["xla_compiles"]
+                    }, step_text
 
 
 def serve_phase(mon, cfg=None, buckets=(64, 256, 512), batch_size=4,
@@ -426,18 +435,11 @@ def serve_phase(mon, cfg=None, buckets=(64, 256, 512), batch_size=4,
 
     # float engine: default kv_page_size / speculative_k
     prompts = make(prompt_lens)
-    served, info = _serve(model, prompts, list(new_tokens), mon,
-                          prompt_buckets=list(buckets),
-                          batch_size=batch_size, name="chip-smoke")
-    # paged_decode has nothing to search (its tile is fixed by the shape,
-    # its heads a step by rule), so the tuner's bus does not see it: the
-    # model's own gate says whether the kernel went into the programs
-    from paddle_tpu.framework.flags import flag
-    from paddle_tpu.models.gpt import _paged_flash
-    counters = {"paged_decode": {"gate_open": _paged_flash(
-        cfg.hidden_size // cfg.num_heads, int(flag("kv_page_size")))}}
-    check(counters["paged_decode"]["gate_open"],
-          "the paged_decode kernel's gate is shut on the chip")
+    served, info, step = _serve(model, prompts, list(new_tokens), mon,
+                                prompt_buckets=list(buckets),
+                                batch_size=batch_size, name="chip-smoke")
+    # a layer's page walk
+    counters = check_dispatched(step, cfg.num_layers, "paged_decode")
     hist, starts, counts = histories(prompts, served)
     ref = _teacher_forced_logits(model, hist, starts, counts, "highest")
     dflt = _teacher_forced_logits(model, hist, starts, counts, "default")
@@ -445,11 +447,13 @@ def serve_phase(mon, cfg=None, buckets=(64, 256, 512), batch_size=4,
 
     # int8 engine: quantized weights + int8 KV pages, two requests
     q_prompts = make(int8_prompt_lens)
-    q_served, q_info = _serve(model, q_prompts, list(int8_new_tokens), mon,
-                              prompt_buckets=[int8_bucket], batch_size=2,
-                              quantized="int8", name="chip-smoke-int8")
-    q_counters = kernel_counters("quantized_matmul")
-    check_dispatched(q_counters)
+    q_served, q_info, q_step = _serve(
+        model, q_prompts, list(int8_new_tokens), mon,
+        prompt_buckets=[int8_bucket], batch_size=2, quantized="int8",
+        name="chip-smoke-int8")
+    # the int8 page walk, and quantized_matmul (it has no name of its own)
+    # for a layer's linears beside it
+    q_counters = check_dispatched(q_step, 2 * cfg.num_layers, "paged_decode")
     for toks in q_served:
         check(all(0 <= t < cfg.vocab_size for t in toks),
               "int8 engine served a token outside the vocabulary")
@@ -600,14 +604,12 @@ def main(argv=None):
 
         from paddle_tpu import sysconfig
         from paddle_tpu.framework.flags import set_flags
-        from paddle_tpu.ops import autotune
 
         cache_dir = sysconfig.enable_persistent_compilation_cache()
         # a device error must surface at its first occurrence, not after a
         # backoff loop: one attempt, and the phases assert zero retries
         set_flags({"transient_max_retries": 1})
-        emit({"phase": "caches", "xla_cache_dir": cache_dir,
-              "kernel_tuning_cache": autotune.cache_path()})
+        emit({"phase": "caches", "xla_cache_dir": cache_dir})
         mon = CompileMonitor()
         if args.chips == 4:
             import jax

@@ -87,9 +87,9 @@ RULES = {
              "multi-tenant isolation failure (an in-budget tenant "
              "sustainedly starved past the weighted-fair share, or "
              "installed LoRA adapters never matched by any request)"),
-    # -- kernel autotuner (K7xx) ---------------------------------------------
+    # -- measured search (K7xx) ----------------------------------------------
     "K701": (Severity.WARNING,
-             "kernel autotuning inside a serving hot path (tuning cache "
+             "measured search inside a serving hot path (tuning cache "
              "miss after warmup)"),
     # -- resilience monitor (F8xx) -------------------------------------------
     "F801": (Severity.WARNING,

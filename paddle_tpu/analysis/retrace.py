@@ -59,7 +59,7 @@ class RetraceMonitor:
         # ("router", "<router>[<i>]") per-replica snapshots: latest state /
         # outstanding / counters per replica (rule S602 context)
         self._router_sites: Dict[str, dict] = {}
-        # ("autotune", kernel) tuner snapshots: latest per kernel (rule K701)
+        # ("autotune", name) search snapshots: latest per client (rule K701)
         self._autotune_sites: Dict[str, dict] = {}
         # ("resilience", retry:<name>|circuit:<name>|fault:<site>) counter
         # snapshots: latest per policy / per circuit key (rule F801)
@@ -126,7 +126,7 @@ class RetraceMonitor:
                 self._router_sites[key[1]] = dict(info)
             return
         if key[0] == "autotune":
-            # tuner snapshot: latest counters per kernel — deduping would
+            # search snapshot: latest counters per client — deduping would
             # drop the counter ticks K701 exists to observe
             with self._lock:
                 self._autotune_sites[key[1]] = dict(info)
@@ -230,13 +230,13 @@ class RetraceMonitor:
                 return dict(self._router_sites.get(replica, {}))
             return {k: dict(v) for k, v in self._router_sites.items()}
 
-    def autotune_stats(self, kernel: str = None):
-        """Latest autotuner snapshot(s) observed (resolution event, chosen
-        config, counter totals): the dict for one kernel (``kernel`` like
-        ``"flash_fwd"``), or all of them."""
+    def autotune_stats(self, name: str = None):
+        """Latest measured-search snapshot(s) observed (resolution event,
+        chosen config, counter totals): the dict for one client of
+        ``tuning.engine`` (its ``name``), or all of them."""
         with self._lock:
-            if kernel is not None:
-                return dict(self._autotune_sites.get(kernel, {}))
+            if name is not None:
+                return dict(self._autotune_sites.get(name, {}))
             return {k: dict(v) for k, v in self._autotune_sites.items()}
 
     def resilience_stats(self, name: str = None):
@@ -571,15 +571,13 @@ class RetraceMonitor:
             late = int(counters.get("searches_after_warm", 0))
             if late <= 0:
                 continue
-            # the measured-search engine tunes more than kernels: every
-            # config space (kernel tiles, sharding plans, serving dials)
-            # publishes on the same bus, and a post-warmup search is a
-            # hot-path stall whichever space it came from
-            space = stats.get("space", "kernel")
-            what = {"kernel": "kernel", "plan": "sharding plan",
+            # every config space of the measured-search engine (sharding
+            # plans, serving dials) publishes on the same bus, and a
+            # post-warmup search is a hot-path stall whichever it came from
+            space = stats.get("space", "")
+            what = {"plan": "sharding plan",
                     "serving": "serving config"}.get(space, space)
-            detail = {"kernel": "timed block-size",
-                      "plan": "timed train-step",
+            detail = {"plan": "timed train-step",
                       "serving": "timed trace-replay"}.get(space, "measured")
             out.add("K701",
                     f"{what} {name!r} ran {late} {detail} "

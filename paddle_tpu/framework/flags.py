@@ -106,14 +106,10 @@ define_flag("persistent_compilation_cache", False,
             "JAX_COMPILATION_CACHE_DIR when that is set, else the fixed "
             "<checkout>/.cache/xla; see "
             "sysconfig.enable_persistent_compilation_cache().")
-define_flag("kernel_autotune", "on",
-            "Pallas kernel tile-size tuning mode (ops/autotune.py): 'on' "
-            "runs a measured search on TPU and heuristic defaults "
-            "elsewhere; 'off' always takes the heuristic defaults; 'force' "
-            "measures even off-TPU (interpret mode — CI smoke only, the "
-            "timings are meaningless).")
 define_flag("kernel_tuning_cache", "",
-            "Persistent kernel-tuning cache (JSON). Empty picks the "
+            "Persistent cache of the measured searches' winners (JSON; "
+            "sharding plans and serving configs: a kernel's tile is a "
+            "rule of its shape and is searched nowhere). Empty picks the "
             "default <checkout>/.cache/kernel_tuning.json; '0'/'off' "
             "disables persistence (winners live for the process only); "
             "any other value is the cache file path. Pre-warm it by "
@@ -124,8 +120,7 @@ define_flag("measured_search", "on",
             "(tuning/plan_space.py, tuning/serving_space.py): 'on' lets "
             "tune_plan/tune_serving compile+time candidates on the real "
             "backend when a caller asks; 'off' returns the hand-set "
-            "defaults untimed. Kernel tile tuning keeps its own "
-            "FLAGS_kernel_autotune; all spaces share "
+            "defaults untimed. Both spaces share "
             "FLAGS_kernel_tuning_cache for persisted winners.")
 define_flag("fused_epilogues", True,
             "Let the BERT/GPT hot paths call the fused Pallas epilogues "

@@ -376,7 +376,7 @@ _FAMILY_LABEL = {
     "executor_cache": "executor",
     "serving": "engine",
     "resilience": "site",
-    "autotune": "kernel",
+    "autotune": "name",
     "steptrace": "name",
     "router": "replica",
     "slo": "engine",
@@ -400,7 +400,7 @@ def install_bridge(registry: Optional[MetricRegistry] = None) -> Callable:
     """Subscribe a trace_events observer that republishes every numeric
     field of the snapshot families as gauges
     ``paddle_tpu_<family>_<field>{<label>="<site name>"}``.  Nested dicts
-    (the autotuner's ``counters``) flatten one level.  Idempotent; returns
+    (the measured searches' ``counters``) flatten one level.  Idempotent; returns
     the observer so tests can unregister it directly."""
     global _bridge_fn
     from ..framework import trace_events

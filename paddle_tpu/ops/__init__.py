@@ -2,7 +2,9 @@
 
 The reference's 650-kernel operator library (paddle/fluid/operators/) maps
 almost entirely to XLA-fused lax ops; this package holds the hand kernels
-that beat the compiler, plus the autotuner that picks their tile sizes:
+that beat the compiler.  A kernel's tile is a rule of its arguments'
+shapes, written beside the kernel from a table timed on the chip; nothing
+is measured at run time (``autotune.py`` says what that name still holds):
 
 * ``flash_attention`` (+ ``flash_attention_fwd_lse`` /
   ``flash_attention_bwd_chunk``) — O(S)-memory attention, forward and
@@ -31,12 +33,9 @@ that beat the compiler, plus the autotuner that picks their tile sizes:
 * ``softmax_cross_entropy`` — online-logsumexp label cross-entropy that
   never materializes the [rows, vocab] probability matrix
   (fused_softmax_xent.py);
-* ``autotune`` — measured block-size search with a persistent on-disk
-  cache; the kernels above resolve their tile parameters through it
-  (autotune.py), but for ``paged_decode`` (PR 42) and the four delta-rule
-  kernels (PR 44), whose tiles are rules of the shape from tables timed on
-  the chip: a race between near-ties draws differently in each cold
-  checkout.
+* ``autotune`` — Mosaic's tile and VMEM constants, the block clamp, and
+  the gates the model hot paths ask before they call a kernel
+  (autotune.py; the measured tile search it was left in PR 48).
 """
 from . import autotune  # noqa: F401
 from .flash_attention import (  # noqa: F401

@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..framework import device as _device
-from . import autotune as _at
+from .autotune import blocks_or as _blocks_or
 
 __all__ = ["flash_attention", "flash_attention_fwd_lse",
            "flash_attention_bwd_chunk"]
@@ -484,10 +484,10 @@ def _bwd_dkv_kernel(*refs, block_q: int, causal: bool, sm_scale: float,
 
 def _bwd_prepad(q, k, v, do, lse, delta, block_q, block_k):
     """Clamp this backward kernel's blocks to ITS OWN padded problem and
-    pad every operand up to them — dq and dk/dv may run different tile
-    sizes than the forward (the autotuner picks each independently), so
-    each backward pallas_call re-establishes the block-multiple invariant
-    itself.  New padded q rows carry do = 0, so their (garbage-lse)
+    pad every operand up to them — the backward kernels' blocks are their
+    own rule's or the caller's, not the forward's, so each backward
+    pallas_call re-establishes the block-multiple invariant itself.  New
+    padded q rows carry do = 0, so their (garbage-lse)
     contributions to dq/dk/dv are exactly zero; padded kv columns are
     masked by kv_len as everywhere else."""
     S, K = q.shape[2], k.shape[2]
@@ -663,104 +663,51 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# autotuning (ops/autotune.py): the three kernels tune independently
+# tiles: rules of the shape
 # ---------------------------------------------------------------------------
-def _seq_candidates(n):
-    """Block candidates for a length-n sequence dim, clamped to the PADDED
-    length — short serving buckets never pay full-width padded tiles."""
-    return _at.tile_candidates(n, base=(128, 256, 512, 1024))
+def flash_blocks(S: int, K: int, row_bytes: int):
+    """``(block_q, block_k)`` of the FORWARD kernel for ``S`` queries over
+    ``K`` keys of ``row_bytes`` a head (``head_dim * itemsize``): 1024 up to
+    512 bytes, else 512, held to the sequence by :func:`_pick_block` (512
+    at 1536, one block of 768 at 768).  A rule of the shape, from the table
+    ``tools/tile_table_chip.py`` timed on the chip at the cells' admission
+    shapes (``PERF.md`` section 6, PR 48; causal, bfloat16): the LARGEST
+    square block wins at every bucket and by more the longer the prompt
+    (30 heads of 128: 0.52 ms against 0.78 at 512-blocks at 2048 tokens,
+    1.58 against 2.61 at 4096; 64 over 8 heads: 3.33 against 5.56 at 4096;
+    16 over 2 heads of 256, two rows: 0.27 against 0.32 at 1024), 128-blocks
+    lose 7 x.  It was a measured search of ``ops.autotune`` until PR 48,
+    keyed by the power-of-two bucket of the shape: 1536 and 2048 shared a
+    key, searched at whichever came first, where "1024" and "512" are one
+    program at 1536 and the toss between them set the 2048 bucket's tile.
+    Past 512 bytes a head (float32 heads of 256, bfloat16 heads of 384)
+    the chip's compiler refuses a 1024-block for its VMEM, and nothing
+    there was timed: 512, which is what ran.  ``block_q=`` / ``block_k=``
+    stay for that tool and for the tests that run every block."""
+    b = 1024 if row_bytes <= 512 else 512
+    return _pick_block(b, S), _pick_block(b, K)
 
 
-def _flash_space(q, k, v, *rest, causal=False, q_offset=0, **_):
-    """Candidate (block_q, block_k) pairs.  The plain-causal case keeps
-    square blocks only so every candidate stays on the triangle grid; the
-    rectangular cases keep the aspect ratio within [1/2, 2] (strongly
-    skewed tiles starve one of the matmul dims).  The VMEM estimate
-    covers the resident q/k/v/do blocks, the f32 accumulators and the
-    (block, 128) running-stat scratch."""
-    S, K, D = q.shape[2], k.shape[2], q.shape[3]
-    itemsize = np.dtype(q.dtype).itemsize
-    square_only = causal and q_offset == 0 and S == K
-    out = []
-    for bq in _seq_candidates(S):
-        for bk in _seq_candidates(K):
-            if square_only:
-                if bq != bk:
-                    continue
-            elif not 0.5 <= bq / bk <= 2.0:
-                continue
-            resident = ((2 * bq + 2 * bk) * D * itemsize
-                        + (2 * bq * 128 + (bq + 2 * bk) * D + 2 * bq) * 4)
-            if _at.vmem_fits(resident):
-                out.append({"block_q": bq, "block_k": bk})
-    return out
+def flash_bwd_blocks(S: int, K: int):
+    """``(block_q, block_k)`` of both backward kernels: 512, held to the
+    sequence (each kernel clamps to its own padded problem).  No benchmark
+    cell runs them, so no chip table is owed: 512 is what they ran wherever
+    the measured search of ``ops.autotune`` (until PR 48) did not, and what
+    measured fastest on a v5e at 32k tokens when they were written (128s
+    were 4 x slower)."""
+    return _pick_block(512, S), _pick_block(512, K)
 
 
-def _flash_heuristic(*args, **_):
-    # the pre-autotuner defaults (512-blocks measured fastest on v5e at
-    # 32k); _pick_block clamps them to short sequences exactly as before
-    return {"block_q": 512, "block_k": 512}
-
-
-_TUNE_KW = ("causal", "q_offset")  # non-array kwargs that shape the kernel
-
-
-@_at.autotune("flash_fwd", params=("block_q", "block_k"),
-              space=_flash_space, heuristic=_flash_heuristic,
-              key_kwargs=_TUNE_KW)
-def _fwd_tuned(q, k, v, *, causal, sm_scale, q_offset, kv_len,
-               block_q, block_k):
-    S, K = q.shape[2], k.shape[2]
-    bq, bk, padq, padk = _blocks_and_pad(S, K, block_q, block_k)
-    out, lse = _fwd_pallas(padq(q), padk(k), padk(v), causal, sm_scale,
-                           bq, bk, q_offset, kv_len)
-    return out[:, :, :S], lse[:, :, :S]
-
-
-@_at.autotune("flash_bwd_dq", params=("block_q", "block_k"),
-              space=_flash_space, heuristic=_flash_heuristic,
-              key_kwargs=_TUNE_KW)
-def _dq_tuned(q, k, v, do, lse, delta, *, causal, sm_scale, q_offset,
-              kv_len, block_q, block_k):
-    return _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, block_q,
-                   block_k, q_offset, kv_len)
-
-
-@_at.autotune("flash_bwd_dkv", params=("block_q", "block_k"),
-              space=_flash_space, heuristic=_flash_heuristic,
-              key_kwargs=_TUNE_KW)
-def _dkv_tuned(q, k, v, do, lse, delta, *, causal, sm_scale, q_offset,
-               kv_len, block_q, block_k):
-    return _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q,
-                    block_k, q_offset, kv_len)
-
-
-def _window_space(q, k, v, *, window, **_):
-    """Square blocks for the band grid: a block narrower than the window
-    visits more tiles, a wider one masks more of each."""
-    S, D = q.shape[2], q.shape[3]
-    itemsize = np.dtype(q.dtype).itemsize
-    return [{"block": b} for b in _at.tile_candidates(
-        S, base=(128, 256, 512))
-        if _at.vmem_fits(4 * b * D * itemsize
-                         + (2 * b * 128 + 3 * b * D + 2 * b * b) * 4)]
-
-
-def _window_heuristic(q, k, v, *, window, **_):
-    # the window's own width in whole lane tiles, at most 512
-    return {"block": min(512, _round_up(window, 128))}
-
-
-@_at.autotune("flash_fwd_window", params=("block",), space=_window_space,
-              heuristic=_window_heuristic, key_kwargs=("window",))
-def _window_tuned(q, k, v, *, window, sm_scale, block):
-    S = q.shape[2]
-    b = _pick_block(block, S)
-    Sp = _round_up(S, b)
-    if Sp != S:
-        q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
-                   for t in (q, k, v))
-    return _fwd_window_pallas(q, k, v, sm_scale, b, window, S)[:, :, :S]
+def window_block(S: int) -> int:
+    """The square block of the banded forward over ``S`` positions: 512,
+    held to the sequence.  A rule of the shape from the same table (PR 48;
+    64 query heads over 8 K/V heads of 128, window 128, one row): 512 wins
+    at every bucket (0.84 / 1.15 / 1.78 / 2.41 ms at 1536 / 2048 / 3072 /
+    4096 against 1.07 / 1.43 / 2.19 / 2.93 at 256 and 1.20 / 1.59 / 2.39 /
+    3.18 at 128, the window's own width): a wide block masks more of each
+    tile but walks a quarter of the grid steps, and a step is not free.
+    Until PR 48 a measured search of ``ops.autotune``, which drew 512 too."""
+    return _pick_block(512, S)
 
 
 # ---------------------------------------------------------------------------
@@ -768,33 +715,31 @@ def _window_tuned(q, k, v, *, window, sm_scale, block):
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, sm_scale, block_q, block_k, q_offset, kv_len,
-           tuned):
+           bwd_blocks):
     out, _ = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k,
                          q_offset, kv_len)
     return out
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset,
-               kv_len, tuned):
+               kv_len, bwd_blocks):
     out, lse = _fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k,
                            q_offset, kv_len)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, kv_len, tuned,
-               res, do):
+def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, kv_len,
+               bwd_blocks, res, do):
     q, k, v, out, lse = res
     # delta = rowsum(dO ⊙ O): one fused elementwise+reduce in XLA,
     # loop-invariant across both backward kernels
     delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    # `tuned` (the forward took autotuner blocks): let each backward
-    # kernel resolve its own tile sizes; explicit blocks pin both.
-    bq, bk = (None, None) if tuned else (block_q, block_k)
-    kw = dict(causal=causal, sm_scale=sm_scale, q_offset=q_offset,
-              kv_len=kv_len, block_q=bq, block_k=bk)
-    dq = _dq_tuned(q, k, v, do, lse, delta, **kw)
-    dk, dv = _dkv_tuned(q, k, v, do, lse, delta, **kw)
-    return dq, dk, dv
+    # `bwd_blocks`: the caller's explicit blocks, else the backward
+    # kernels' own rule (not the forward's)
+    args = (q, k, v, do, lse, delta, causal, sm_scale, *bwd_blocks,
+            q_offset, kv_len)
+    dk, dv = _bwd_dkv(*args)
+    return _bwd_dq(*args), dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -847,12 +792,18 @@ def flash_attention_fwd_lse(q, k, v, causal: bool = False,
     """Forward-only kernel run returning ``(out, lse)`` — the building
     block ring attention's custom_vjp forward uses to merge per-chunk
     partials (sequence_parallel.py).  Not differentiable on its own.
-    Blocks default to the autotuner; pass them explicitly to pin."""
+    Blocks default to the rule (:func:`flash_blocks`); an explicit one
+    wins."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    return _fwd_tuned(q, k, v, causal=causal, sm_scale=float(sm_scale),
-                      q_offset=int(q_position_offset), kv_len=int(k.shape[2]),
-                      block_q=block_q, block_k=block_k)
+    S, K = q.shape[2], k.shape[2]
+    block_q, block_k = _blocks_or(
+        flash_blocks(S, K, q.shape[-1] * q.dtype.itemsize), block_q, block_k)
+    bq, bk, padq, padk = _blocks_and_pad(S, K, block_q, block_k)
+    out, lse = _fwd_pallas(padq(q), padk(k), padk(v), causal,
+                           float(sm_scale), bq, bk, int(q_position_offset),
+                           int(K))
+    return out[:, :, :S], lse[:, :, :S]
 
 
 def flash_attention_bwd_chunk(q, k, v, out, lse, do, causal: bool = False,
@@ -867,8 +818,8 @@ def flash_attention_bwd_chunk(q, k, v, out, lse, do, causal: bool = False,
     p = exp(s − lse_global) the backward is linear over kv chunks.  Ring
     attention's custom_vjp backward sums these around the ring; it passes
     the loop-invariant ``delta = rowsum(dO·O)`` so it is computed once,
-    not once per ring step.  Blocks default to the autotuner (dq and
-    dk/dv resolve independently); pass them explicitly to pin both."""
+    not once per ring step.  Blocks default to the rule
+    (:func:`flash_bwd_blocks`); an explicit one wins, for both kernels."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     S, K = q.shape[2], k.shape[2]
@@ -877,11 +828,11 @@ def flash_attention_bwd_chunk(q, k, v, out, lse, do, causal: bool = False,
     if delta is None:
         delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
     delta = delta[:, :, :S]
-    kw = dict(causal=causal, sm_scale=float(sm_scale),
-              q_offset=int(q_position_offset), kv_len=int(K),
-              block_q=block_q, block_k=block_k)
-    dq = _dq_tuned(q, k, v, do, lse, delta, **kw)
-    dk, dv = _dkv_tuned(q, k, v, do, lse, delta, **kw)
+    args = (q, k, v, do, lse, delta, causal, float(sm_scale),
+            *_blocks_or(flash_bwd_blocks(S, K), block_q, block_k),
+            int(q_position_offset), int(K))
+    dq = _bwd_dq(*args)
+    dk, dv = _bwd_dkv(*args)
     return dq[:, :, :S], dk[:, :, :K], dv[:, :, :K]
 
 
@@ -907,14 +858,13 @@ def flash_attention(q, k, v, causal: bool = False,
     ``window`` (causal self-attention from position 0, forward only): query
     ``qp`` sees the ``window`` keys ``qp - window + 1 .. qp``, its own
     included; the grid visits only the key blocks that band touches, under
-    one square block (``block_q``, else the autotuner's).
+    one square block (``block_q``, else :func:`window_block`'s).
 
-    Block sizes default to the autotuner (``ops.autotune``): a measured
-    search on TPU — the forward and both backward kernels pick their tile
-    sizes independently, memoized persistently per shape bucket — and the
-    512-block heuristic elsewhere (512s measured fastest on v5e at 32k:
-    ~34 TFLOP/s effective causal fwd; 128-blocks were 4× slower).  Pass
-    ``block_q``/``block_k`` explicitly to pin all three kernels.
+    Block sizes default to the rules of the shape (:func:`flash_blocks`
+    for the forward, from a table timed on the chip;
+    :func:`flash_bwd_blocks` for the two backward kernels);
+    ``block_q``/``block_k`` given explicitly win over them, for all three
+    kernels.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -927,18 +877,16 @@ def flash_attention(q, k, v, causal: bool = False,
                 f"from position 0 (causal={causal}, offset "
                 f"{q_position_offset}, q {q.shape}, k {k.shape}, v "
                 f"{v.shape}, window {window})")
-        return _window_tuned(q, k, v, window=int(window),
-                             sm_scale=float(sm_scale), block=block_q)
-    tuned = block_q is None and block_k is None
-    if tuned:
-        cfg = _fwd_tuned.config(q, k, v, causal=causal,
-                                sm_scale=float(sm_scale),
-                                q_offset=int(q_position_offset),
-                                kv_len=int(K))
-        block_q, block_k = cfg["block_q"], cfg["block_k"]
-    else:
-        block_q = 512 if block_q is None else block_q
-        block_k = 512 if block_k is None else block_k
+        b = window_block(S) if block_q is None else _pick_block(block_q, S)
+        Sp = _round_up(S, b)
+        if Sp != S:
+            q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+                       for t in (q, k, v))
+        return _fwd_window_pallas(q, k, v, float(sm_scale), b, int(window),
+                                  S)[:, :, :S]
+    bwd_blocks = _blocks_or(flash_bwd_blocks(S, K), block_q, block_k)
+    block_q, block_k = _blocks_or(
+        flash_blocks(S, K, q.shape[-1] * q.dtype.itemsize), block_q, block_k)
     bq = _pick_block(block_q, S)
     bk = _pick_block(block_k, K)
     Sp = _round_up(S, bq)
@@ -957,5 +905,5 @@ def flash_attention(q, k, v, causal: bool = False,
                              int(q_position_offset), int(K))
     else:
         out = _flash(qp, kp, vp, causal, float(sm_scale), bq, bk,
-                     int(q_position_offset), int(K), tuned)
+                     int(q_position_offset), int(K), bwd_blocks)
     return out if Sp == S else out[:, :, :S]

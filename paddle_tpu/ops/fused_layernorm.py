@@ -23,7 +23,7 @@ Numerics match ``nn.functional.layer_norm`` exactly: the sum is rounded
 to the activation dtype first (that rounded value is what the unfused
 path normalizes), statistics accumulate in float32.
 
-Tile sizes come from ``ops.autotune`` (kernel name "layernorm_residual");
+The row block is a rule of the shape (:func:`ln_block`);
 the feature dim stays whole per block, so eligibility on real TPUs wants
 ``D % 128 == 0`` (``autotune.fused_epilogues_eligible``).
 """
@@ -34,7 +34,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -66,8 +65,7 @@ def _kernel(x_ref, r_ref, g_ref, b_ref, s_ref, y_ref, mean_ref, rstd_ref,
 def _ln_res_pallas(x, r, g, b, epsilon, block_m):
     """2-D [M, D] impl; returns (s, y, mean, rstd) with stats [M, 1] f32."""
     M, D = x.shape
-    bm = min(block_m, max(M, 8))
-    bm = -(-bm // 8) * 8
+    bm = _at.clamp_tile(block_m, M)
     Mp = -(-M // bm) * bm
     if Mp != M:
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
@@ -105,22 +103,20 @@ def _ln_res_pallas(x, r, g, b, epsilon, block_m):
     return s[:M], y[:M], mean[:M], rstd[:M]
 
 
-def _space(x, r, g, b, **_):
-    M, D = x.shape
-    itemsize = np.dtype(x.dtype).itemsize
-    out = []
-    for bm in _at.tile_candidates(M, base=(128, 256, 512, 1024, 2048)):
-        # resident: x/r in + s/y out blocks, f32 compute copy, affine rows
-        resident = 4 * bm * D * itemsize + bm * D * 4 + 2 * D * 4
-        if _at.vmem_fits(resident):
-            out.append({"block_m": bm})
-    return out
-
-
-@_at.autotune("layernorm_residual", params=("block_m",), space=_space,
-              heuristic=lambda *a, **k: {"block_m": 512})
-def _ln_res_measured(x, r, g, b, *, epsilon, block_m):
-    return _ln_res_pallas(x, r, g, b, epsilon, block_m)
+def ln_block(M: int) -> int:
+    """Rows a grid step of ``layernorm_residual`` takes of ``M``: 512, held
+    to the padded rows.  A rule of the shape, held by the table
+    ``tools/tile_table_chip.py`` timed on the chip (``PERF.md`` section 6,
+    PR 48; BERT's ``[256 x 128, 768]`` bfloat16): 128, 256, 512 and 1024
+    rows tie inside their spread (0.54-0.58 ms a call with the statistics a
+    training step keeps, 0.41-0.42 the forward alone), and the chip's
+    compiler REFUSES 1024 rows in a forward that drops the statistics (16.26
+    MB of VMEM against 16.00).  Until PR 48 a measured search of
+    ``ops.autotune``, which timed the four-output call, drew 1024 and so
+    left a forward-only program of this shape unable to compile.
+    ``block_m=`` stays for that tool and for the tests that run every
+    block."""
+    return _at.clamp_tile(512, M)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -162,7 +158,8 @@ def layernorm_residual(x, residual, weight, bias, *, epsilon: float = 1e-5,
     x/residual: ``[..., D]`` (same shape/dtype), weight/bias: ``[D]``.
     Returns ``(s, y)`` — the residual stream and the normalized output;
     pre-LN blocks use both, post-LN blocks use ``y``.  Differentiable in
-    x, residual, weight and bias.  ``block_m`` defaults to the autotuner.
+    x, residual, weight and bias.  ``block_m`` defaults to the rule
+    (:func:`ln_block`); an explicit one wins.
     """
     x = jnp.asarray(x)
     residual = jnp.asarray(residual)
@@ -180,8 +177,6 @@ def layernorm_residual(x, residual, weight, bias, *, epsilon: float = 1e-5,
     x2 = x.reshape(-1, D)
     r2 = residual.reshape(-1, D)
     if block_m is None:
-        cfg = _ln_res_measured.config(x2, r2, weight, bias,
-                                      epsilon=float(epsilon))
-        block_m = cfg["block_m"]
+        block_m = ln_block(x2.shape[0])
     s, y = _ln_res(x2, r2, weight, bias, float(epsilon), int(block_m))
     return s.reshape(*lead, D), y.reshape(*lead, D)

@@ -17,7 +17,7 @@ XLA elementwise pass from the saved per-row ``lse``; the probability
 matrix still never exists on its own.  Integer labels get a symbolic-zero
 (float0) cotangent.
 
-Tile sizes come from ``ops.autotune`` (kernel name "softmax_xent").  The
+Tile sizes are a rule of the shape (:func:`xent_blocks`).  The
 vocab axis is padded to the block multiple and masked in-kernel, so any V
 works (no 128-alignment requirement on the caller).
 """
@@ -83,10 +83,8 @@ def _kernel(x_ref, lab_ref, loss_ref, lse_ref, m_scr, l_scr, acc_scr,
 def _sxent_pallas(logits, labels, block_m, block_v):
     """2-D [M, V] impl; labels [M] i32.  Returns (loss [M], lse [M]) f32."""
     M, V = logits.shape
-    bm = min(block_m, max(M, 8))
-    bm = -(-bm // 8) * 8
-    bv = min(block_v, max(V, 128))
-    bv = -(-bv // 128) * 128
+    bm = _at.clamp_tile(block_m, M)
+    bv = _at.clamp_tile(block_v, V, _at.LANE)
     Mp = -(-M // bm) * bm
     Vp = -(-V // bv) * bv
     xp = logits
@@ -124,25 +122,18 @@ def _sxent_pallas(logits, labels, block_m, block_v):
     return loss[:M, 0], lse[:M, 0]
 
 
-def _space(logits, labels, **_):
-    M, V = logits.shape
-    itemsize = np.dtype(logits.dtype).itemsize
-    out = []
-    for bm in _at.tile_candidates(M, base=(64, 128, 256, 512)):
-        for bv in _at.tile_candidates(V, multiple=_at.LANE,
-                                      base=(512, 1024, 2048, 4096, 8192)):
-            # resident: the logits block (input dtype + f32 working copy)
-            # plus the three (bm, 128) stat scratches
-            resident = bm * bv * (itemsize + 4) + 3 * bm * 128 * 4
-            if _at.vmem_fits(resident):
-                out.append({"block_m": bm, "block_v": bv})
-    return out
-
-
-@_at.autotune("softmax_xent", params=("block_m", "block_v"), space=_space,
-              heuristic=lambda *a, **k: {"block_m": 256, "block_v": 2048})
-def _sxent_measured(logits, labels, *, block_m, block_v):
-    return _sxent_pallas(logits, labels, block_m, block_v)
+def xent_blocks(M: int, V: int):
+    """``(block_m, block_v)`` of ``softmax_cross_entropy`` over ``M`` rows
+    of ``V`` logits: 512 x 2048, held to the padded problem.  A rule of the
+    shape, from the table ``tools/tile_table_chip.py`` timed on the chip
+    (``PERF.md`` section 6, PR 48; BERT's 5120 masked rows x 30 522,
+    bfloat16, all seventeen blocks that fit): 512 x 2048 wins at 2.359 ms
+    (spread 0.006), 256 x 2048 (the default before there was a search)
+    2.417, 128 x 8192 2.447, 512 x 1024 2.462, 512-wide blocks 2.69-4.13.
+    Until PR 48 a measured search of ``ops.autotune``, which drew it too.
+    ``block_m=`` / ``block_v=`` stay for that tool and for the tests that
+    run every block."""
+    return _at.clamp_tile(512, M), _at.clamp_tile(2048, V, _at.LANE)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -179,8 +170,8 @@ def softmax_cross_entropy(logits, labels, *, block_m: Optional[int] = None,
 
     logits: ``[..., V]``, labels: ``[...]`` integer class ids in
     ``[0, V)``.  Returns float32 losses of the label shape.  Blocks
-    default to the autotuner.  Differentiable in logits; labels get a
-    symbolic-zero cotangent.
+    default to the rule (:func:`xent_blocks`); an explicit one wins.
+    Differentiable in logits; labels get a symbolic-zero cotangent.
     """
     logits = jnp.asarray(logits)
     labels = jnp.asarray(labels)
@@ -192,9 +183,6 @@ def softmax_cross_entropy(logits, labels, *, block_m: Optional[int] = None,
     lead = labels.shape
     x2 = logits.reshape(-1, V)
     lab2 = labels.reshape(-1).astype(jnp.int32)
-    if block_m is None or block_v is None:
-        cfg = _sxent_measured.config(x2, lab2)
-        block_m = cfg["block_m"] if block_m is None else block_m
-        block_v = cfg["block_v"] if block_v is None else block_v
-    loss = _sxent(x2, lab2, int(block_m), int(block_v))
+    loss = _sxent(x2, lab2, *_at.blocks_or(
+        xent_blocks(x2.shape[0], V), block_m, block_v))
     return loss.reshape(lead)

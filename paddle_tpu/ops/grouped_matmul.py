@@ -22,9 +22,9 @@ batch over E and fuse fine; no second custom kernel needed):
 
 ``group_sizes`` gets a symbolic-zero (float0) cotangent.
 
-Tile sizes come from ``ops.autotune`` (kernel name "grouped_matmul");
-the contraction dim D stays whole per block, so eligibility on real
-TPUs wants ``D % 128 == 0`` (same shape class as the other epilogues).
+Tile sizes are a rule of the shape (:func:`gmm_blocks`); the contraction
+dim D stays whole per block, so eligibility on real TPUs wants
+``D % 128 == 0`` (same shape class as the other epilogues).
 
 The DROPLESS layout (``ragged_layout`` / ``ragged_gated_mlp``) has no
 capacity: the (token, choice) pairs are sorted by expert and each
@@ -69,10 +69,8 @@ def _gmm_pallas(x, w, group_sizes, block_m, block_n):
     """[E, C, D] @ [E, D, F] with per-expert valid-row counts [E] i32."""
     E, C, D = x.shape
     F = w.shape[2]
-    bm = min(block_m, max(C, 8))
-    bm = -(-bm // 8) * 8
-    bn = min(block_n, max(F, 128))
-    bn = -(-bn // 128) * 128
+    bm = _at.clamp_tile(block_m, C)
+    bn = _at.clamp_tile(block_n, F, _at.LANE)
     Cp = -(-C // bm) * bm
     Fp = -(-F // bn) * bn
     if Cp != C:
@@ -102,25 +100,14 @@ def _gmm_pallas(x, w, group_sizes, block_m, block_n):
     return out[:, :C, :F]
 
 
-def _space(x, w, group_sizes, **_):
-    E, C, D = x.shape
-    F = w.shape[2]
-    itemsize = np.dtype(x.dtype).itemsize
-    out = []
-    for bm in _at.tile_candidates(C, base=(64, 128, 256, 512)):
-        for bn in _at.tile_candidates(F, multiple=_at.LANE,
-                                      base=(128, 256, 512)):
-            # resident: x row block, w col block, f32 acc + out block
-            resident = (bm * D + D * bn) * itemsize + bm * bn * (4 + itemsize)
-            if _at.vmem_fits(resident):
-                out.append({"block_m": bm, "block_n": bn})
-    return out
-
-
-@_at.autotune("grouped_matmul", params=("block_m", "block_n"), space=_space,
-              heuristic=lambda *a, **k: {"block_m": 128, "block_n": 128})
-def _gmm_measured(x, w, group_sizes, *, block_m, block_n):
-    return _gmm_pallas(x, w, group_sizes, block_m, block_n)
+def gmm_blocks(C: int, F: int):
+    """``(block_m, block_n)`` of ``grouped_matmul`` over ``C`` capacity
+    rows and ``F`` columns: 128 x 128, held to the padded problem.  A rule
+    of the shape and no measured search (it was one of ``ops.autotune``
+    until PR 48, and 128 x 128 is what ran wherever that search did not:
+    no benchmark cell runs this kernel, so no chip table is owed).
+    ``block_m=`` / ``block_n=`` stay for the tests that run every block."""
+    return _at.clamp_tile(128, C), _at.clamp_tile(128, F, _at.LANE)
 
 
 def _rowmask(group_sizes, C):
@@ -160,7 +147,8 @@ def grouped_matmul(x, w, group_sizes, *, block_m: Optional[int] = None,
     counts.  Returns ``[E, C, F]`` equal to ``einsum("ecd,edf->ecf", x *
     rowmask, w)`` — rows at or beyond ``group_sizes[e]`` are exactly
     zero.  Differentiable in x and w; ``group_sizes`` gets a
-    symbolic-zero cotangent.  Blocks default to the autotuner.
+    symbolic-zero cotangent.  Blocks default to the rule
+    (:func:`gmm_blocks`); an explicit one wins.
     """
     x = jnp.asarray(x)
     w = jnp.asarray(w)
@@ -181,11 +169,8 @@ def grouped_matmul(x, w, group_sizes, *, block_m: Optional[int] = None,
             f"grouped_matmul: group_sizes dtype {group_sizes.dtype} is "
             f"not integer")
     group_sizes = group_sizes.astype(jnp.int32)
-    if block_m is None or block_n is None:
-        cfg = _gmm_measured.config(x, w, group_sizes)
-        block_m = cfg["block_m"] if block_m is None else block_m
-        block_n = cfg["block_n"] if block_n is None else block_n
-    return _gmm(x, w, group_sizes, int(block_m), int(block_n))
+    return _gmm(x, w, group_sizes, *_at.blocks_or(
+        gmm_blocks(C, w.shape[2]), block_m, block_n))
 
 
 # -- dropless ragged groups: tile-aligned rows sorted by expert --------------
@@ -317,27 +302,18 @@ def _wide_blocks(tm, D, F, item):
     return fit or blocks[-1:]
 
 
-def _wide_space(xs, w_gate, w_up, w_down, *, tile_m):
-    (_, D, F), item = w_gate.shape, np.dtype(xs.dtype).itemsize
-    return [{"block_f": bf} for bf in _wide_blocks(tile_m, D, F, item)]
-
-
-def _wide_heuristic(xs, w_gate, w_up, w_down, *, tile_m):
-    # the widest block that fits: the fewest grid steps a tile
-    return _wide_space(xs, w_gate, w_up, w_down, tile_m=tile_m)[0]
-
-
-@_at.autotune("moe_gated_mlp_wide", params=("block_f",), space=_wide_space,
-              heuristic=_wide_heuristic, key_kwargs=("tile_m",))
-def _gated_mlp_wide_measured(xs, w_gate, w_up, w_down, *, tile_m, block_f):
-    """The measurable unit of the ``block_f`` search: every tile in use,
-    tile ``t`` the rows of expert ``t mod E`` (a decode step's pattern: an
-    expert's matrices are fetched for one tile)."""
-    NT, E = xs.shape[0] // tile_m, w_gate.shape[0]
-    tiles = jnp.arange(NT, dtype=jnp.int32)
-    lay = {"tiles": NT, "tile_m": tile_m, "tile_group": tiles % E,
-           "tile_index": tiles, "used": jnp.full((1,), NT, jnp.int32)}
-    return _gated_mlp_wide(xs, w_gate, w_up, w_down, lay, block_f)
+def wide_block(tile_m: int, D: int, F: int, itemsize: int) -> int:
+    """The block of an expert's width a grid step of the width-tiled
+    kernel takes: the WIDEST that fits (:func:`_wide_blocks`), the fewest
+    grid steps a tile.  A rule of the shape, held by the table
+    ``tools/tile_table_chip.py`` timed on the chip (``PERF.md`` section 6,
+    PR 48; 16 held experts of 6144 x 2048, bfloat16): at the decode step's
+    16-row tiles 1024 and 512 tie (1.670 against 1.662 ms a layer call) and
+    256 loses 4 %; at the admission's 128-row tiles 512 beats 256 by 6 %
+    (5.25 against 5.58).  Until PR 48 a measured search of ``ops.autotune``
+    over a few tiles of two stand-in experts (the real operands' twins did
+    not fit beside a model that fills the chip); it drew these."""
+    return _wide_blocks(tile_m, D, F, itemsize)[0]
 
 
 def _gated_mlp_wide(xs, w_gate, w_up, w_down, lay, bf):
@@ -393,14 +369,8 @@ def _gated_mlp_pallas(xs, w_gate, w_up, w_down, lay):
     need = _gated_mlp_vmem(tm, D, F, item)
     if need * 5 // 4 > _VMEM_CAP:
         # one whole expert, double-buffered, does not fit: tile its width
-        # the search's stand-ins: a few tiles over two experts (the real
-        # operands' twins would not fit beside a model that fills the chip)
-        cfg = _gated_mlp_wide_measured.config(
-            jax.ShapeDtypeStruct((min(NT, 32) * tm, D), xs.dtype),
-            *(jax.ShapeDtypeStruct((min(E, 2), *w.shape[1:]), w.dtype)
-              for w in (w_gate, w_up, w_down)), tile_m=tm)
         return _gated_mlp_wide(xs, w_gate, w_up, w_down, lay,
-                               cfg["block_f"])
+                               wide_block(tm, D, F, item))
     return pl.pallas_call(
         _gated_mlp_kernel,
         name=f"moe_gated_mlp_tm{tm}",

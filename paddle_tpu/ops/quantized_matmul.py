@@ -14,9 +14,8 @@ operands, and ONE fused epilogue applies the combined
     fp8:   acc = x_q  @ w_q   (e4m3 operands, f32 accumulate)
     out    = acc * (weight_scale * act_scale) + bias
 
-Tile sizes come from ``ops.autotune`` (kernel name "quantized_matmul");
-the cache key carries each operand's dtype, so one registration covers
-the int8 and fp8 legs with independent tunings.  int8/fp8 arrays tile
+Tile sizes are a rule of the shape (:func:`qmm_blocks`), one for the
+int8 and the fp8 leg.  int8/fp8 arrays tile
 as (32, 128) on Mosaic — row blocks are multiples of 32, column blocks
 of 128, and the whole contraction dim rides in VMEM zero-padded to a
 lane multiple (exact: padded products are zero).
@@ -34,7 +33,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -71,10 +69,8 @@ def _qmm_pallas(xq, wq, scale, bias, block_m, block_n):
     already folds the activation scale in)."""
     M, K = xq.shape
     N = wq.shape[1]
-    bm = min(block_m, max(M, _SUBLANE_8BIT))
-    bm = -(-bm // _SUBLANE_8BIT) * _SUBLANE_8BIT
-    bn = min(block_n, max(N, 128))
-    bn = -(-bn // 128) * 128
+    bm = _at.clamp_tile(block_m, M, _SUBLANE_8BIT)
+    bn = _at.clamp_tile(block_n, N, _at.LANE)
     Mp = -(-M // bm) * bm
     Np = -(-N // bn) * bn
     Kp = -(-K // 128) * 128
@@ -106,30 +102,15 @@ def _qmm_pallas(xq, wq, scale, bias, block_m, block_n):
     return out[:M, :N]
 
 
-def _space(xq, wq, scale, bias, **_):
-    M, K = xq.shape
-    N = wq.shape[1]
-    Kp = -(-K // 128) * 128
-    item = np.dtype(xq.dtype).itemsize  # 1 for int8 and e4m3
-    out = []
-    for bm in _at.tile_candidates(M, multiple=_SUBLANE_8BIT,
-                                  base=(64, 128, 256, 512)):
-        for bn in _at.tile_candidates(N, multiple=_at.LANE,
-                                      base=(128, 256, 512)):
-            # resident: x row block + w col block (whole K), scale/bias
-            # rows, f32 accumulator/out block
-            resident = ((bm * Kp + Kp * bn) * item + 2 * bn * 4
-                        + bm * bn * 4)
-            if _at.vmem_fits(resident):
-                out.append({"block_m": bm, "block_n": bn})
-    return out
-
-
-@_at.autotune("quantized_matmul", params=("block_m", "block_n"),
-              space=_space,
-              heuristic=lambda *a, **k: {"block_m": 128, "block_n": 128})
-def _qmm_measured(xq, wq, scale, bias, *, block_m, block_n):
-    return _qmm_pallas(xq, wq, scale, bias, block_m, block_n)
+def qmm_blocks(M: int, N: int):
+    """``(block_m, block_n)`` of the quantized matmul over ``M`` rows and
+    ``N`` output features: 128 x 128, held to the padded problem (rows in
+    the 32-row tiles of 8-bit operands).  A rule of the shape and no
+    measured search (it was one of ``ops.autotune`` until PR 48, and 128 x
+    128 is what ran wherever that search did not: no benchmark cell runs
+    this kernel, so no chip table is owed)."""
+    return (_at.clamp_tile(128, M, _SUBLANE_8BIT),
+            _at.clamp_tile(128, N, _at.LANE))
 
 
 def quantize_activations(x, mode: str):
@@ -189,7 +170,7 @@ def quantized_linear(x, w_q, weight_scale, bias=None):
     b = (jnp.zeros((N,), jnp.float32) if bias is None
          else jnp.asarray(bias, jnp.float32).reshape(-1))
     if _use_pallas(N):
-        out2 = _qmm_measured(x2, w_q, combined, b)
+        out2 = _qmm_pallas(x2, w_q, combined, b, *qmm_blocks(x2.shape[0], N))
     else:
         if mode == "int8":
             acc = jax.lax.dot_general(

@@ -56,7 +56,7 @@ def register_summary_section(render_fn, on_reset=None) -> None:
     ``on_reset`` (optional) runs inside ``reset_profiler()`` so the
     subsystem can snapshot its counters — sections report activity since
     the last reset, matching the host-event table's lifecycle.  Used by
-    ``ops.autotune`` for the kernel-tuning cache statistics."""
+    ``tuning.engine`` for the measured searches' cache statistics."""
     with _lock:
         _sections.append((render_fn, on_reset))
 

@@ -152,8 +152,8 @@ class InferenceEngine:
         executable count."""
         for i in range(len(self._buckets)):
             self._executable(i)
-        from ..ops import autotune
-        autotune.mark_warm()  # later tuner searches are hot-path (K701)
+        from ..tuning import engine as _tuning
+        _tuning.mark_warm()  # later measured searches are hot-path (K701)
         _retry_mod.mark_warm()  # later retry storms / flaps are F801
         return self.compile_count
 

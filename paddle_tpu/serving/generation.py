@@ -720,8 +720,8 @@ class GenerationEngine:
         # BESIDE this one, one more copy of the pool at the memory's peak
         jax.block_until_ready(cache)
         self.metrics.set_counter("compiles", self.compile_count)
-        from ..ops import autotune
-        autotune.mark_warm()  # later tuner searches are hot-path (K701)
+        from ..tuning import engine as _tuning
+        _tuning.mark_warm()  # later measured searches are hot-path (K701)
         _retry_mod.mark_warm()  # later retry storms / flaps are F801
         # the warm-up steps' expert counts go with their handles (only a
         # harvested step's are folded): the dummy-data routing never lands

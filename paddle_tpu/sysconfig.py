@@ -92,9 +92,10 @@ def maybe_enable_persistent_compilation_cache() -> None:
 
 
 def kernel_tuning_cache_path() -> str | None:
-    """Where the Pallas kernel autotuner persists measured block sizes
-    (``FLAGS_kernel_tuning_cache``; the XLA executable cache above is a
-    separate store).  ``None`` when disk persistence is disabled."""
-    from .ops.autotune import cache_path
+    """Where the measured searches (sharding plans, serving configs)
+    persist their winners (``FLAGS_kernel_tuning_cache``; the XLA
+    executable cache above is a separate store).  ``None`` when disk
+    persistence is disabled."""
+    from .tuning.engine import cache_path
 
     return cache_path()

@@ -1,10 +1,9 @@
-"""Measured-search tuning: one engine, three config spaces.
+"""Measured-search tuning: one engine, two config spaces.
 
 ``tuning.engine`` is the generic search core (enumerate → pre-filter →
 compile+time on the real backend → persistent JSON cache → counters /
 trace events).  Its clients:
 
-* ``ops.autotune`` — Pallas kernel tile parameters (space ``"kernel"``);
 * ``tuning.plan_space`` — per-parameter-group mesh-axis assignment and
   collective schedule dials, pre-filtered by ``analysis.check_plan``,
   timed as real train steps (space ``"plan"``);
@@ -15,8 +14,8 @@ trace events).  Its clients:
 ``tuning.trace`` records and replays the deterministic request traces
 the serving space measures against.
 
-Only the engine is imported eagerly — ``ops.autotune`` is a client of
-it, so the config-space modules (which import analysis/distributed/
+Only the engine is imported eagerly — ``ops.autotune`` re-exports two of
+its names, so the config-space modules (which import analysis/distributed/
 serving machinery on top of ops) load lazily via ``__getattr__``.
 """
 from . import engine  # noqa: F401

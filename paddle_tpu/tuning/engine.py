@@ -1,31 +1,32 @@
-"""Generic measured-search engine — the core the kernel autotuner, the
-sharding-plan tuner, and the serving-config tuner all share.
+"""Generic measured-search engine — the core the sharding-plan tuner and
+the serving-config tuner share.
 
-PR 4's lesson was that measured search on the real backend beats
-heuristics for Pallas tile sizes; this module is that search loop with
-the kernel-specific parts factored out, so ANY config space can use it:
+The search loop (it began as PR 4's search over Pallas tile sizes; the
+kernels left it in PR 48, each for a rule of its shape, because a race
+between near-ties draws differently in every cold checkout) takes ANY
+config space:
 
 * **candidate enumeration** is the client's (a list, or a lazy callable
   so cache hits never pay enumeration);
 * **validity pre-filter** rejects candidates before any compile (the
-  kernel client filters on VMEM fit inside its space; the plan client
-  filters through ``analysis.check_plan.is_valid_plan``);
+  plan client filters through ``analysis.check_plan.is_valid_plan``);
 * **compile + time on the real backend** via :func:`measure_ms` — an
   untimed warm call first (absorbs compilation), then best-of-N wall
   times, so dispatch jitter can't crown a flaky winner;
 * **persistent JSON cache** keyed ``space | client key | device kind``
   where the client key carries the shape bucket and (for distributed
   spaces) the mesh — entries carry ``version``/``space``/``name``
-  fields (schema v2); stale pre-versioned entries are ignored, never a
-  crash, and :func:`clear_cache` can scope a wipe to one space;
+  fields (schema v2); stale entries (pre-versioned ones, and a
+  ``kernel`` entry from before PR 48) are ignored, never a crash, and
+  :func:`clear_cache` can scope a wipe to one space;
 * **counters and trace events**: every resolution publishes an
   ``("autotune", name)`` event with the space attached, so
   ``analysis.RetraceMonitor`` raises K701 for ANY measured search after
-  :func:`mark_warm` — kernel, plan, or serving — and the profiler grows
-  one "Measured search" summary section covering all three.
+  :func:`mark_warm` — plan or serving — and the profiler grows one
+  "Measured search" summary section covering both.
 
-Clients: ``ops.autotune`` (space ``"kernel"``), ``tuning.plan_space``
-(``"plan"``), ``tuning.serving_space`` (``"serving"``).
+Clients: ``tuning.plan_space`` (``"plan"``), ``tuning.serving_space``
+(``"serving"``).
 """
 from __future__ import annotations
 
@@ -56,7 +57,7 @@ SCHEMA_VERSION = 2
 
 #: the registered config spaces (informational; the engine accepts any
 #: space string, these are the ones shipped in-tree)
-SPACES = ("kernel", "plan", "serving")
+SPACES = ("plan", "serving")
 
 _lock = threading.RLock()
 _mem_cache: Dict[str, dict] = {}          # spaced key -> config
@@ -134,10 +135,12 @@ def cache_path() -> Optional[str]:
 def _valid_entry(v) -> bool:
     """Schema filter: v2+ entries only.  PR-4-era kernel entries carry no
     ``version`` field — they key differently anyway (no space prefix), so
-    they are dropped rather than trusted across the schema change."""
+    they are dropped rather than trusted across the schema change; so is
+    a ``kernel`` entry of the tile search that PR 48 removed."""
     return (isinstance(v, dict) and "config" in v
             and isinstance(v.get("version"), int)
-            and v["version"] >= SCHEMA_VERSION)
+            and v["version"] >= SCHEMA_VERSION
+            and v.get("space") != "kernel")
 
 
 def _disk_entries() -> Dict[str, dict]:
@@ -180,8 +183,6 @@ def _disk_store(spaced_key: str, space: str, name: str, config: dict,
     entry = {"space": space, "name": name, "config": dict(config),
              "best_ms": round(float(best_ms), 4),
              "version": SCHEMA_VERSION}
-    if space == "kernel":
-        entry["kernel"] = name  # PR-4 field name, kept for tooling compat
     entries[spaced_key] = entry
     try:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -203,9 +204,9 @@ def _entry_space(key: str, entry: dict) -> str:
 def clear_cache(memory: bool = True, disk: bool = False,
                 space: Optional[str] = None) -> None:
     """Drop tuned winners.  ``disk=True`` also clears the JSON file;
-    ``space`` scopes the wipe to one config space (``"kernel"`` /
-    ``"plan"`` / ``"serving"``) so re-tuning sharding plans doesn't cost
-    the kernel winners, and vice versa."""
+    ``space`` scopes the wipe to one config space (``"plan"`` /
+    ``"serving"``) so re-tuning sharding plans doesn't cost the serving
+    winners, and vice versa."""
     with _lock:
         if memory:
             if space is None:
@@ -270,8 +271,8 @@ def reset_counters() -> None:
 
 def mark_warm() -> None:
     """Declare tuning warmup over (serving engines call this after
-    ``warmup()``): any measured search past this point — kernel tiles, a
-    sharding plan, serving dials — is tuning work on a hot path, a cache
+    ``warmup()``): any measured search past this point — a sharding
+    plan, serving dials — is tuning work on a hot path, a cache
     miss the pre-warmed JSON cache should have absorbed, and is flagged
     by analysis rule K701."""
     global _warm
@@ -496,7 +497,7 @@ def _summary_section() -> str:
             d = {k: _counters[name][k] - base.get(k, 0)
                  for k in _COUNTER_KEYS}
             if any(d.values()):
-                rows.append((_spaces.get(name, "kernel"), name, d))
+                rows.append((_spaces.get(name, ""), name, d))
     if not rows:
         return ""
     path = cache_path() or "<in-memory only>"

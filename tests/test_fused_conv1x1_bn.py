@@ -11,8 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops.fused_conv1x1_bn import (_bn_apply, bn_apply_relu,
-                                             conv1x1_bn_relu,
+from paddle_tpu.ops.fused_conv1x1_bn import (bn_apply_relu, conv1x1_bn_relu,
                                              conv1x1_bn_stats)
 
 
@@ -110,10 +109,9 @@ class TestBnApplyRelu:
         res = jnp.asarray(rng.randn(M, N).astype(np.float32))
         want = np.maximum(np.asarray(y) * np.asarray(scale)
                           + np.asarray(shift) + np.asarray(res), 0.0)
-        cands = _bn_apply.candidates(y, scale, shift, res)
-        assert len(cands) >= 2
-        for cfg in cands:
-            out = bn_apply_relu(y, scale, shift, res, **cfg)
+        for bm, bn in ((64, 128), (200, 128), (200, 256), (512, 256)):
+            out = bn_apply_relu(y, scale, shift, res, block_m=bm,
+                                block_n=bn)
             np.testing.assert_allclose(np.asarray(out), want,
                                        rtol=1e-5, atol=1e-5)
         # no-residual leg

@@ -167,7 +167,7 @@ class TestBridge:
                                 {"queue_depth": 3})
             trace_events.notify(("resilience", "retry:r"),
                                 {"retries": 1})
-            trace_events.notify(("autotune", "flash_fwd"),
+            trace_events.notify(("autotune", "t-plan"),
                                 {"counters": {"searches": 4}})
             trace_events.notify(("steptrace", "train"),
                                 {"steps": 7})

@@ -28,7 +28,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.framework import device as pdevice
-from paddle_tpu.framework.flags import get_flags, set_flags
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 fp8 = jnp.float8_e4m3fn
@@ -53,8 +52,6 @@ def _compile_for_the_chip(chip, monkeypatch, use_mesh):
     assert jax.config.jax_enable_x64  # production setting, kept on
     monkeypatch.setattr(pdevice, "on_tpu", lambda: True)
     use_mesh([chip])
-    prev_mode = get_flags("kernel_autotune")["kernel_autotune"]
-    set_flags({"kernel_autotune": "off"})
     # conftest forces "highest" for the numpy-oracle tests; production runs
     # the default, and Mosaic refuses an fp32-precision dot on bf16/int8
     # operands
@@ -67,7 +64,6 @@ def _compile_for_the_chip(chip, monkeypatch, use_mesh):
     jax.config.update("jax_default_matmul_precision", prev_prec)
     jax.config.update("jax_enable_compilation_cache", prev_cache)
     cc.reset_cache()
-    set_flags({"kernel_autotune": prev_mode})
 
 
 def _compiles_with_kernel(chip, fn, *shapes):
@@ -98,6 +94,74 @@ def test_flash_attention(chip, shape, bwd):
     if bwd:
         fn = _grad_of(fn, (0, 1, 2))
     _compiles_with_kernel(chip, fn, *[(shape, bf16)] * 3)
+
+
+# -- the five kernels in the benchmark cells' programs whose tile a measured
+# -- search raced until PR 48: each lowers at its cell shape under the RULE's
+# -- tile
+def _cell_kernel(kernel):
+    """``(fn, shapes, the rule's tiles)`` of one cell-run kernel at the
+    widest shape its cell hands it."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    if kernel == "flash_fwd":              # olmo_hybrid: a one-row admission
+        shape = ((1, 30, 4096, 128), bf16)
+        return (lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                [shape] * 3, fa.flash_blocks(4096, 4096, 128 * 2))
+    if kernel == "flash_fwd_window":       # k_exaone: 64 heads over 8
+        kv = ((1, 8, 4096, 128), bf16)
+        return (lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                                   window=128),
+                [((1, 64, 4096, 128), bf16), kv, kv],
+                [fa.window_block(4096)])
+    if kernel == "moe_gated_mlp_wide":     # k_exaone: 16 held experts, a step
+        gm = importlib.import_module("paddle_tpu.ops.grouped_matmul")
+        E, D, F, tm, NT = 16, 6144, 2048, 16, 32
+
+        def fn(xs, tg, wg, wu, wd):
+            lay = {"tiles": NT, "tile_m": tm, "tile_group": tg,
+                   "tile_index": jnp.arange(NT, dtype=i32),
+                   "used": jnp.full((1,), NT, i32)}
+            return gm.ragged_gated_mlp(xs, wg, wu, wd, lay, kernel=True)
+
+        return (fn, [((NT * tm, D), bf16), ((NT,), i32), ((E, D, F), bf16),
+                     ((E, D, F), bf16), ((E, F, D), bf16)],
+                [gm.wide_block(tm, D, F, 2)])
+    if kernel == "layernorm_residual":     # bert_base: 256 x 128 rows
+        from paddle_tpu.ops.fused_layernorm import layernorm_residual, ln_block
+        return (layernorm_residual,
+                [((32768, 768), bf16)] * 2 + [((768,), bf16)] * 2,
+                [ln_block(32768)])
+    from paddle_tpu.ops.fused_softmax_xent import (softmax_cross_entropy,
+                                                   xent_blocks)
+    return (softmax_cross_entropy,         # bert_base: 256 x 20 masked rows
+            [((5120, 30522), bf16), ((5120,), i32)], xent_blocks(5120, 30522))
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_fwd", "flash_fwd_window", "moe_gated_mlp_wide",
+    "layernorm_residual", "softmax_xent"])
+def test_cell_kernel_lowers_at_its_cell_shape_under_the_rules_tile(chip,
+                                                                   kernel):
+    import re
+
+    fn, shapes, tiles = _cell_kernel(kernel)
+    _compiles_with_kernel(chip, fn, *shapes)
+    # and the blocks of the call that was lowered are the rule's
+    blocks = set(map(int, re.findall(r"block_size=(\d+)", str(jax.make_jaxpr(
+        fn)(*[jax.ShapeDtypeStruct(*s) for s in shapes])))))
+    assert set(tiles) <= blocks, (kernel, tiles, sorted(blocks))
+
+
+def test_flash_forward_of_float32_heads_of_256_keeps_512_blocks(chip):
+    # past 512 bytes a head the rule keeps 512-blocks: the 1024 it takes
+    # below that run out of VMEM here (RESOURCE_EXHAUSTED at compile)
+    from paddle_tpu.ops.flash_attention import flash_attention
+
+    _compiles_with_kernel(
+        chip, lambda q, k, v: flash_attention(q, k, v, causal=True),
+        *[((2, 4, 2048, 256), f32)] * 3)
 
 
 # -- the (i32, i64) class: BERT/GPT epilogues, serving linears, ResNet tail ---
@@ -215,16 +279,14 @@ def test_paged_flash_admission_prefill_gpt2_small(chip, R, T):
 
 
 def test_paged_decode_has_nothing_to_search_and_the_step_keeps_its_grid():
-    # the tile is fixed by the shape and the heads a step by rule: the
-    # kernel is not among the autotuner's, so two checkouts of one tree
+    # the tile is fixed by the shape and the heads a step by rule (nothing
+    # is searched: tests/test_autotune.py), so two checkouts of one tree
     # build one program.  A one-tile call (the decode step's [32, 1], the
     # verify width) keeps the grid (slots, head blocks) it had
     import re
 
-    from paddle_tpu.ops import autotune
     from paddle_tpu.ops.paged_attention import paged_flash_decode
 
-    assert "paged_decode" not in autotune.registered_kernels()
     B, H, hd, page, G, pages = 32, 12, 64, 16, 64, 2049
     for T in (1, 5):
         shapes = [((B, H, T, hd), f32), ((pages, page, H * hd), f32),
@@ -616,12 +678,8 @@ def test_gated_delta_step_32_states_of_128_by_128_from_16_key_heads(chip):
     args = [jax.ShapeDtypeStruct(s, f32, sharding=one) for s in (
         (B, Hk, d), (B, Hk, d), (B, Hv, d), (B, Hv), (B, Hv),
         (B + 1, Hv, d, d))]
-    from paddle_tpu.ops import autotune
-
     # a rule of the shape since PR 44, no measured search
     assert gd.step_heads(*args) == 8
-    assert not {"gated_delta_step", "gated_delta_chunk", "kda_step",
-                "kda_chunk"} & set(autotune.registered_kernels())
     text = jax.jit(gd.gated_delta_step, donate_argnums=(5,)).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text and "gated_delta_step" in text

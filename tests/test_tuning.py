@@ -1,4 +1,5 @@
-"""Measured-search engine beyond kernels (paddle_tpu.tuning): plan-space
+"""Measured-search engine (paddle_tpu.tuning; no kernel searches through
+it since PR 48, a tile is a rule of its kernel's shapes): plan-space
 enumeration + check_plan pre-filtering, deterministic serving-space
 search over a fixed trace, v2 disk-cache round-trips for both spaces,
 stale-schema tolerance, scope-aware clearing, and K701 on post-warm
@@ -28,8 +29,7 @@ def _clean_tuner_state():
     engine.reset_counters()
     engine.reset_warm()
     yield
-    set_flags({"kernel_autotune": "on", "kernel_tuning_cache": "",
-               "measured_search": "on"})
+    set_flags({"kernel_tuning_cache": "", "measured_search": "on"})
     engine.clear_cache()
     engine.reset_counters()
     engine.reset_warm()
@@ -249,9 +249,14 @@ class TestDiskCache:
     def test_stale_schema_entries_ignored(self, tmp_path):
         path = str(tmp_path / "tuning.json")
         # a PR-4-era kernel-only cache: no version/space fields
+        # and a v2 entry of the kernel tile search that PR 48 removed
         stale = {"version": 1, "entries": {
             "flash_fwd|128x64:float32|TPU v4": {
                 "kernel": "flash_fwd", "config": {"block_q": 512},
+                "best_ms": 1.0},
+            "kernel|flash_fwd|128x64:bfloat16|TPU v5 lite": {
+                "space": "kernel", "name": "flash_fwd", "version": 2,
+                "kernel": "flash_fwd", "config": {"block_q": 256},
                 "best_ms": 1.0}}}
         with open(path, "w") as f:
             json.dump(stale, f)
@@ -287,6 +292,75 @@ class TestDiskCache:
                                    measure=_score_serving)
         assert engine.get_counters("t-plan")["hits"] == 1
         assert engine.get_counters("t-serve")["searches"] == 1
+
+
+    def test_cache_path_flag_forms(self, tmp_path):
+        import os
+
+        from paddle_tpu import sysconfig
+        from paddle_tpu.ops import autotune
+        set_flags({"kernel_tuning_cache": "off"})
+        assert engine.cache_path() is None
+        set_flags({"kernel_tuning_cache": str(tmp_path / "t.json")})
+        assert engine.cache_path() == str(tmp_path / "t.json")
+        set_flags({"kernel_tuning_cache": ""})
+        assert engine.cache_path() == os.path.join(
+            sysconfig.cache_root(), "kernel_tuning.json")
+        # the two other names the path goes by (benchmarks/run.py reads
+        # the second)
+        assert sysconfig.kernel_tuning_cache_path() == engine.cache_path()
+        assert autotune.cache_path() == engine.cache_path()
+
+
+class TestProfilerSection:
+    def test_summary_section_renders_and_resets(self):
+        from paddle_tpu import profiler
+        profiler.reset_profiler()
+        set_flags({"kernel_tuning_cache": "off"})
+        plan_space.tune_plan("t-plan", shapes=SHAPES, mesh=_mesh(model=4),
+                             measure=_score_plan)
+        s = profiler.summary()
+        assert "Measured search" in s and "t-plan" in s and "plan" in s
+        profiler.reset_profiler()
+        assert profiler.summary() == ""  # deltas cleared with the rest
+
+
+class TestResolve:
+    def test_every_candidate_failing_raises_the_first_error(self):
+        # an all-failed search is a broken client, not a tuning outcome:
+        # it must not quietly hand back the untimed default
+        set_flags({"kernel_tuning_cache": "off"})
+
+        def boom(cand):
+            raise RuntimeError(f"refused block={cand['block']}")
+
+        with pytest.raises(RuntimeError, match="refused block=8"):
+            engine.resolve("plan", "t-broken", "k",
+                           candidates=[{"block": 8}, {"block": 16}],
+                           measure=boom, heuristic={"block": 8},
+                           measurable=True)
+        c = engine.get_counters("t-broken")
+        assert c["search_failures"] == 2 and c["searches"] == 0
+
+    def test_search_then_hit_events_published(self):
+        from paddle_tpu.framework import trace_events
+        seen = []
+        cb = lambda site, info: seen.append((tuple(site), dict(info)))  # noqa: E731
+        trace_events.register(cb)
+        set_flags({"kernel_tuning_cache": "off"})
+        try:
+            for _ in range(2):
+                engine.resolve("plan", "t-probe", "k",
+                               candidates=[{"block": 8}, {"block": 16}],
+                               measure=lambda c: float(c["block"]),
+                               heuristic={"block": 8}, measurable=True)
+        finally:
+            trace_events.unregister(cb)
+        kinds = [info["event"] for site, info in seen
+                 if site == ("autotune", "t-probe")]
+        assert kinds == ["search", "hit"]
+        assert seen[0][1]["n_timed"] == 2
+        assert seen[0][1]["counters"]["searches"] == 1
 
 
 class TestMeasure:

@@ -37,7 +37,6 @@ from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.framework import device as pdevice
-from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.distributed import mesh as pmesh
 from paddle_tpu import nn
 from paddle_tpu.serving.generation import GenerationEngine
@@ -66,7 +65,6 @@ topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 chip = topo.devices[0]
 pdevice.on_tpu = lambda: True
 pmesh.set_mesh(pmesh.build_mesh(devices=[chip]))
-set_flags({"kernel_autotune": "off"})
 jax.config.update("jax_enable_compilation_cache", False)
 one = SingleDeviceSharding(chip)
 _LOC = re.compile(

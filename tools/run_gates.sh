@@ -11,7 +11,6 @@
 #   dryrun  __graft_entry__.dryrun_multichip(8) on a virtual CPU mesh
 #   perf-smoke tools/perf_smoke.py   (fused run_steps vs per-step, CPU, seconds)
 #   serving-smoke tools/serving_smoke.py (closed compile set + KV-decode identity)
-#   kernel-smoke tools/kernel_smoke.py (autotuner search + warm-restart cache hit)
 #   tune-smoke tools/tune_smoke.py  (plan + serving measured search, warm replay, K701)
 #   scenario-smoke tools/scenario_smoke.py (autoscaling loop under traffic chaos + disagg)
 #   moe-smoke tools/moe_smoke.py (expert-sharded decode: closed set + balanced routing)
@@ -25,7 +24,7 @@
 #   elastic-smoke tools/elastic_smoke.py (NaN rollback + exact resume + collective watchdog)
 #   pod-smoke tools/pod_smoke.py (N-process gang: sharded bit identity, SIGKILL -> gang restore, wedge watchdog, router failover, F803)
 #
-# Usage:  tools/run_gates.sh [--skip analyze|fast|suite|audit|dryrun|perf-smoke|serving-smoke|kernel-smoke|tune-smoke|scenario-smoke|moe-smoke|chaos-smoke|obs-smoke|router-smoke|gen-smoke|tenancy-smoke|quant-smoke|slo-smoke|elastic-smoke|pod-smoke]...
+# Usage:  tools/run_gates.sh [--skip analyze|fast|suite|audit|dryrun|perf-smoke|serving-smoke|tune-smoke|scenario-smoke|moe-smoke|chaos-smoke|obs-smoke|router-smoke|gen-smoke|tenancy-smoke|quant-smoke|slo-smoke|elastic-smoke|pod-smoke]...
 #         tools/run_gates.sh --only suite
 # Exit code: 0 iff every stage that ran passed.
 set -u
@@ -112,10 +111,7 @@ run_stage perf-smoke env JAX_PLATFORMS=cpu python tools/perf_smoke.py
 # serving: closed compile set + exact padded/unpadded answers + KV-decode
 # token identity (CPU correctness gate, not a throughput claim)
 run_stage serving-smoke env JAX_PLATFORMS=cpu python tools/serving_smoke.py
-# kernel autotuner: forced measured search in interpret mode, then a second
-# process that must resolve every key from the on-disk cache (zero searches)
-run_stage kernel-smoke env JAX_PLATFORMS=cpu python tools/kernel_smoke.py
-# measured search beyond kernels: sharding-plan candidates timed as real
+# measured search: sharding-plan candidates timed as real
 # fused train steps + serving dials timed against the deterministic bench
 # trace, winners persisted (schema v2); a second process replays both from
 # disk with zero searches, K701 silent on hits and firing on an injected

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Measured-search gate: plan + serving spaces end-to-end, on CPU.
 
-One-command proof of the ``paddle_tpu.tuning`` contracts, the
-plan/serving twin of ``kernel_smoke.py``:
+One-command proof of the ``paddle_tpu.tuning`` contracts:
 
 1. **Cold process** — with a fresh cache file, a sharding-plan search
    times REAL fused train steps (``Executor.run_steps`` on a tiny MLP
