@@ -20,6 +20,8 @@ class Checks:
         self._row(name, bool(ok), True, "is", bool(ok), note)
 
     def _row(self, name, value, limit, op, ok, note):
+        if hasattr(value, "item"):  # a numpy scalar: a plain number
+            value = value.item()
         row = {"check": name, "value": value, "op": op, "limit": limit,
                "ok": ok}
         if note:

@@ -8,9 +8,16 @@ import time
 
 from . import checks as hc
 
-#: the benchmark's own host spans, the causes an idle gap is attributed to
-HOST_SPANS = ("submit", "schedule_wait", "window_dispatch", "loss_readback",
-              "drain")
+#: the serving loop's phases (``paddle_tpu/serving/metrics.py:LoopClock``:
+#: ``serve/<phase>`` spans on the engine's thread, which tile every iteration)
+SERVE_PHASES = tuple("serve/" + p for p in (
+    "sched", "admit.host", "admit.device", "decode.pack", "decode.device",
+    "harvest", "publish", "wait"))
+#: the host spans an idle gap of the device is named after: the benchmark's
+#: own and the serving loop's phases.  The load generator's naps
+#: (``schedule_wait``, ``drain``) are not among them: it waits all the while
+#: the system works, so its spans say nothing of what the device waited for
+HOST_SPANS = ("submit", "window_dispatch", "loss_readback") + SERVE_PHASES
 
 
 class RunContext:

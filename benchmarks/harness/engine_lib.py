@@ -1,8 +1,7 @@
 """Arithmetic over the serving engine's own counters, as the runner
 differences them over the window (``ev["facts"]["counters"]``; the program
 keeps them in ``paddle_tpu/serving/metrics.py:LOOP_COUNTERS``).  Every
-number but ``stall_ms`` is a ratio of two counter deltas, and none uses
-``ev["seconds"]``: the two snapshots fall at arbitrary phases of a step, and
+number is a ratio of two counter deltas, and none uses ``ev["seconds"]``: the two snapshots fall at arbitrary phases of a step, and
 the loop adds all of an iteration's counters under one lock, so numerator
 and divisor always cover the same iterations.  A reader returns None, and
 its metric is left out, where a counter is missing (a program from before
@@ -10,28 +9,19 @@ they existed) or the divisor is 0."""
 
 HOST_PHASES = ("loop_us_sched", "loop_us_admit_host", "loop_us_decode_pack",
                "loop_us_harvest", "loop_us_publish")
-ADMIT_PHASES = ("loop_us_admit_host", "loop_us_admit_device")
-LONGEST = tuple("loop_max_us_" + p for p in (
-    "sched", "admit_host", "admit_device", "decode_pack", "decode_device",
-    "harvest", "publish", "wait"))
 
 
-def _ratio(ev, num, den, scale, minus=()):
-    """``scale x sum(num) / (sum(den) - sum(minus))`` over counter names."""
+def _ratio(ev, num, den, scale):
+    """``scale x sum(num) / sum(den)`` over counter names."""
     c = ev["facts"].get("counters") or {}
-    if any(k not in c for k in (*num, *den, *minus)):
+    if any(k not in c for k in (*num, *den)):
         return None
-    d = sum(c[k] for k in den) - sum(c[k] for k in minus)
+    d = sum(c[k] for k in den)
     return scale * sum(c[k] for k in num) / d if d > 0 else None
 
 
 def decode_step_ms(ev):
     return _ratio(ev, ("loop_us_decode_device",), ("decode_steps",), 1e-3)
-
-
-def admit_time_share(ev):
-    return _ratio(ev, ADMIT_PHASES, ("loop_us_total",), 100.0,
-                  minus=("loop_us_wait",))
 
 
 def host_ms_per_step(ev):
@@ -62,14 +52,3 @@ def ttft_mean_ms(ev):
 def admit_call_ms(ev):
     return _ratio(ev, ("loop_us_admit_device",), ("admit_steps",), 1e-3)
 
-
-def stall_ms(ev):
-    """``loop_max_us_<phase>`` is the longest single interval of a phase
-    since the engine started, so its delta is how far an interval inside the
-    window outlasted every one before it (the warm traffic's): a few
-    milliseconds in a steady run, seconds when the process stood still.
-    The largest over the phases; a lower bound of the stall."""
-    c = ev["facts"].get("counters") or {}
-    if any(k not in c for k in LONGEST):
-        return None
-    return 1e-3 * max(c[k] for k in LONGEST)

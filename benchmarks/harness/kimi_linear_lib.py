@@ -24,6 +24,9 @@ from benchmarks.harness.latent_moe_lib import (_OP_NAME, _counters,
 
 CHUNK = 64  # paddle_tpu/ops/gated_delta.py:CHUNK
 experts_touched_per_step = latent_moe_lib.experts_touched_per_step
+# the NoPE latent layers' decode walk is the latent cell's kernel, its pages
+# laid out alike: the same count
+latent_decode_roofline_share = latent_moe_lib.latent_decode_roofline_share
 _least = qwen3_next_lib._least
 
 

@@ -1,7 +1,9 @@
 """Per-layer readers for a latent-attention decoder with routed experts
 (``paddle_tpu/models/latent_moe.py``): the operations and bytes of its
-grouped gated-MLP kernel, the experts a decode step touches, and the share
-of device-busy time inside the expert layers and inside the attention.
+grouped gated-MLP kernel and of its two attention kernels (the prompt's, and
+the decode step's walk over the latent pages), the experts a decode step
+touches, and the share of device-busy time inside the expert layers and
+inside the attention.
 
 ``ev`` carries no raw ops, so a trace reader loads the xplane itself from
 ``ev["facts"]["trace_dir"]`` (what the cell's runner put there) and reduces
@@ -53,13 +55,16 @@ def gated_mlp_bytes(rows, experts, d_model, width, itemsize=2):
     return itemsize * (3.0 * experts * d_model * width + 2.0 * rows * d_model)
 
 
-def _kernel_calls(ev, name):
+def _kernel_calls(ev, name, own=False):
     """(mean seconds, count) of the device events of the Pallas kernel
-    ``name`` inside the traced window."""
+    ``name`` inside the traced window.  An event's text holds its operands'
+    names too: ``own`` keeps the events whose OWN instruction is named after
+    the kernel (``%latent_decode.4 = ...``)."""
     t = _trace(ev)
     if t is None:
         return None
-    durs = [d for text, _, d in t["ops"] if name in text
+    durs = [d for text, _, d in t["ops"]
+            if name in (text.split(" = ", 1)[0] if own else text)
             and trace_reduce.MOSAIC_MARK in text]
     return (sum(durs) / len(durs) / 1e9, len(durs)) if durs else None
 
@@ -136,6 +141,52 @@ def prefill_attention_roofline_share(ev):
         prompt_attention_bytes(tokens, tokens, h, qk, v,
                                sizes["qk_rope_head_dim"])
         / ev["peaks"]["hbm_bytes_per_s"])
+    return 100.0 * least / calls[0]
+
+
+def latent_page_lanes(sizes):
+    """Lanes of one row of a latent page: the compressed K/V and the shared
+    positional key side by side, padded to whole 128-lane tiles (512 + 64 ->
+    640 at both latent cells' sizes; the model owns the layout)."""
+    return -(-(sizes["kv_lora_rank"] + sizes["qk_rope_head_dim"]) // 128) * 128
+
+
+def latent_decode_flops(keys, heads, row_lanes, value_lanes):
+    """Per swept key and head a score over the row's lanes and a context
+    over its first ``value_lanes``, 2 FLOPs a multiply-add."""
+    return 2.0 * keys * heads * (row_lanes + value_lanes)
+
+
+def latent_decode_bytes(keys, slots, heads, row_lanes, value_lanes,
+                        itemsize=2):
+    """The swept pages' rows ONCE (the scores read all of a row's lanes, the
+    values are the first ``value_lanes`` of the same rows), the
+    ``[slots, heads, row_lanes]`` queries in and the
+    ``[slots, heads, value_lanes]`` contexts out."""
+    return itemsize * (keys * row_lanes
+                       + slots * heads * (row_lanes + value_lanes))
+
+
+def latent_decode_roofline_share(ev):
+    """``latent_decode``, one event a latent layer of a decode step: the keys
+    of the pages the walk fetched (``kv_pages_swept_steps / decode_steps`` x
+    the page size, the window's mean from the counters) over the traced
+    events' mean time."""
+    c, sizes = _counters(ev), ev["facts"].get("sizes") or {}
+    page, slots = ev["facts"].get("kv_page_size"), ev["facts"].get("slots")
+    keys = ("kv_lora_rank", "qk_rope_head_dim", "num_attention_heads")
+    calls = _kernel_calls(ev, "latent_decode", own=True)
+    if (not c.get("decode_steps") or "kv_pages_swept_steps" not in c
+            or not page or not slots or calls is None
+            or any(k not in sizes for k in keys)):
+        return None
+    swept = c["kv_pages_swept_steps"] / c["decode_steps"] * page
+    h, lanes, v = (sizes["num_attention_heads"], latent_page_lanes(sizes),
+                   sizes["kv_lora_rank"])
+    least = max(latent_decode_flops(swept, h, lanes, v)
+                / ev["peaks"]["bf16_flops"],
+                latent_decode_bytes(swept, slots, h, lanes, v)
+                / ev["peaks"]["hbm_bytes_per_s"])
     return 100.0 * least / calls[0]
 
 

@@ -15,14 +15,6 @@ def device_idle_share(ev):
                     / t["window_ns"])
 
 
-def mosaic_time_share(ev):
-    t = ev.get("trace")
-    if not t:
-        return None
-    d = t["per_device"][t["first_device"]]
-    return 100.0 * d["mosaic_ns"] / d["busy_ns"] if d["busy_ns"] else None
-
-
 def step_device_ms(ev):
     t = ev.get("trace")
     if not t or not t.get("step") or not t["step"]["durations_ns"]:
@@ -45,17 +37,6 @@ def mfu(ev):
     if ms is None or not flops:
         return None
     return 100.0 * flops / (ms / 1e3 * ev["chips"] * ev["peaks"]["bf16_flops"])
-
-
-def step_wall_ms(ev):
-    c = ev["facts"].get("counters") or {}
-    return (ev["seconds"] * 1e3 / c["decode_steps"]
-            if c.get("decode_steps") else None)
-
-
-def live_slots_per_step(ev):
-    c = ev["facts"].get("counters") or {}
-    return c["tokens"] / c["decode_steps"] if c.get("decode_steps") else None
 
 
 def generator_lateness_p95_ms(ev):

@@ -12,6 +12,7 @@ spans land on the thread that opened them.  All times are nanoseconds on
 one clock; the device clock was seen about a millisecond behind the host's,
 so a gap shorter than that cannot be attributed reliably.
 """
+import bisect
 import glob
 import os
 import re
@@ -115,8 +116,10 @@ def reduce(trace, span=None, step_module=None, steps_per_execution=1,
     """The reduction.  ``span`` is ``(lo, hi)`` ns, by default from the first
     to the last device event.  ``step_module`` is a substring that names the
     step program on the ``XLA Modules`` line (by default the module with the
-    most device time).  Returns a dict of plain numbers, per device and
-    averaged, that the per-layer readers pick from."""
+    most device time).  An idle gap is named after the one of
+    ``gap_span_names`` (all host spans read, by default) that covers most of
+    it.  Returns a dict of plain numbers, per device and averaged, that the
+    per-layer readers pick from."""
     devs = trace["devices"]
     if not devs or not any(d["ops"] for d in devs.values()):
         raise ValueError("the trace holds no device operation")
@@ -130,8 +133,7 @@ def reduce(trace, span=None, step_module=None, steps_per_execution=1,
     for i, d in sorted(devs.items()):
         ops = clip(d["ops"], lo, hi)
         busy = union_ns([(s, du) for _, s, du in ops])
-        mosaic = sum(du for n, _, du in ops if MOSAIC_MARK in n)
-        per_dev[i] = {"busy_ns": busy, "mosaic_ns": mosaic,
+        per_dev[i] = {"busy_ns": busy,
                       "op_time_ns": sum(du for _, _, du in ops),
                       "n_ops": len(ops)}
     first = sorted(devs)[0]
@@ -174,14 +176,14 @@ def reduce(trace, span=None, step_module=None, steps_per_execution=1,
     # longest idle gaps on the first device, by the host span that covers them
     idle = sorted(gaps([(s, du) for _, s, du in clip(d0["ops"], lo, hi)],
                        lo, hi), key=lambda g: -g[1])
-    spans = [s for s in trace["host_spans"] if s[0] in set(gap_span_names)] \
-        if gap_span_names else trace["host_spans"]
+    spans = _Spans(s for s in trace["host_spans"]
+                   if not gap_span_names or s[0] in gap_span_names)
     by_cause = {}
     for s, du in idle:
-        by_cause.setdefault(_covering(spans, s, du), []).append(du)
+        by_cause.setdefault(spans.covering(s, du), []).append(du)
     gap_rows = sorted(((c, sum(v)) for c, v in by_cause.items()),
                       key=lambda kv: -kv[1])[:10]
-    longest = [(_covering(spans, s, du), du) for s, du in idle[:5]]
+    longest = [(spans.covering(s, du), du) for s, du in idle[:5]]
     n = len(per_dev)
     return {
         "window_ns": window_ns,
@@ -198,16 +200,30 @@ def reduce(trace, span=None, step_module=None, steps_per_execution=1,
     }
 
 
-def _covering(spans, start, dur):
-    """Name of the host span that overlaps the gap most (the innermost of
-    equal overlaps, i.e. the shortest), or ``unattributed``."""
-    best, best_key = "unattributed", (0.0, 0.0)
-    for name, s, d in spans:
-        if s > start + dur:
-            break
-        ov = min(s + d, start + dur) - max(s, start)
-        if ov > 0:
-            key = (ov, -d)
-            if key > best_key:
-                best, best_key = name, key
-    return best
+class _Spans:
+    """Host spans indexed for lookup by time: sorted by start, with the
+    running maximum of their ends, so that the spans that can overlap a gap
+    are found by bisection (a traced window of a loaded serving engine holds
+    tens of thousands of spans and as many gaps)."""
+
+    def __init__(self, spans):
+        self.spans = sorted(spans, key=lambda s: s[1])
+        self.starts = [s[1] for s in self.spans]
+        self.ends_so_far, end = [], float("-inf")
+        for _, s, d in self.spans:
+            end = max(end, s + d)
+            self.ends_so_far.append(end)
+
+    def covering(self, start, dur):
+        """Name of the span that overlaps the gap most (the innermost of
+        equal overlaps, i.e. the shortest), or ``unattributed``."""
+        best, best_key = "unattributed", (0.0, 0.0)
+        lo = bisect.bisect_right(self.ends_so_far, start)
+        hi = bisect.bisect_left(self.starts, start + dur)
+        for name, s, d in self.spans[lo:hi]:
+            ov = min(s + d, start + dur) - max(s, start)
+            if ov > 0:
+                key = (ov, -d)
+                if key > best_key:
+                    best, best_key = name, key
+        return best

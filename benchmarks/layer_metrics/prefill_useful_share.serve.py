@@ -1,2 +1,2 @@
-"""Prompt tokens prefilled / token slots of the [B x bucket] admission programs that ran (counters admit_tokens / admit_token_slots), closed-loop cells."""
+"""Prompt tokens admitted / token slots the admission calls ran over, R(bucket) rows x the chunk's bucket a call (counters admit_tokens / admit_token_slots): what is lost is a call's second row left empty and each row's padding to its bucket, gpt2_small.docs_closed."""
 from benchmarks.harness.engine_lib import prefill_useful_share as read  # noqa: F401

@@ -8,8 +8,8 @@ fall-back, never ``JAX_PLATFORMS`` set here), builds the model from
 ``--seed``, warms only this cell's shapes, measures for ``--seconds``,
 checks what the timed path produced against the plain reference outside
 the window, and prints the contract's one JSON line last.  Earlier lines
-carry set-up phases, the schedule summary, kernel counters, compile counts
-and every compared number beside its limit.
+carry set-up phases, the schedule summary, compile counts and every
+compared number beside its limit.
 
 ``--rehearse`` (CPU, tiny family presets) exists for ``benchmarks/tests``
 and for rehearsing a change to the harness: it prints no device metric and
@@ -83,13 +83,11 @@ def main(argv=None):
 
     from paddle_tpu import sysconfig
     from paddle_tpu.framework.flags import set_flags
-    from paddle_tpu.ops import autotune
 
     cache_dir = sysconfig.enable_persistent_compilation_cache()
     # a device error surfaces at its first occurrence, not after a backoff
     set_flags({"transient_max_retries": 1})
-    emit({"xla_cache_dir": cache_dir,
-          "kernel_tuning_cache": autotune.cache_path()})
+    emit({"xla_cache_dir": cache_dir})
 
     family = loader.load_module("families", config["family"], bench_dir)
     if args.rehearse:
@@ -116,7 +114,6 @@ def main(argv=None):
     ctx.metric("setup_s", ctx.setup_s)
     if not ctx.checks.rows or ctx.setup_s is None:
         raise RuntimeError("the runner checked nothing or never ended set-up")
-    emit({"kernel_counters": autotune.get_counters()})
     emit({"end_to_end": ctx.metrics})
     if args.control:
         emit({"control_correct": ctx.control_checks.correct,
@@ -164,7 +161,17 @@ def main(argv=None):
         result.update(metrics=out, device=device,
                       breakdown={"device_ops": red["top_ops"],
                                  "idle_gaps": gaps})
+    # the builder's contract: each number compared beside its limit as the
+    # last lines of standard error and as the last key of the result's
+    # line, which is all that is kept of a run that is not correct
+    result["compared"] = {r["check"]: {"value": r["value"],
+                                       "limit": r["limit"], "ok": r["ok"]}
+                          for r in ctx.checks.rows}
     sys.stdout.flush()
+    for name, r in result["compared"].items():
+        print(f"{name} {r['value']} {'ok' if r['ok'] else 'NOT OK'}: limit "
+              f"{r['limit']}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

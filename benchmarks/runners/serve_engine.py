@@ -36,11 +36,13 @@ def _build(ctx, weights):
 
 
 class _Rec:
-    __slots__ = ("req", "due", "sent", "done", "tokens", "error", "future")
+    __slots__ = ("req", "due", "sent", "done", "tokens", "error", "future",
+                 "refused")
 
     def __init__(self, req, due):
         self.req, self.due = req, due
         self.sent = self.done = self.tokens = self.error = self.future = None
+        self.refused = 0  # times the engine's door turned the request away
 
 
 def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
@@ -54,13 +56,16 @@ def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
     now = lambda: time.perf_counter() - t0  # noqa: E731
     recs, done_q, marks = [], queue.SimpleQueue(), {}
 
-    def send(req, due):
-        rec = _Rec(req, due)
-        with span("submit"):
-            rec.sent = now()
-            rec.future = engine.submit(req["prompt"], req["max_new_tokens"])
+    def offer(rec):
+        """Hand the request to the engine; False where its door refuses."""
+        try:
+            rec.future = engine.submit(rec.req["prompt"],
+                                       rec.req["max_new_tokens"])
+        except Exception as e:  # shed at the door: a full queue
+            rec.error, rec.refused = repr(e), rec.refused + 1
+            return False
 
-        def on_done(f, rec=rec):
+        def on_done(f):
             t = now()
             try:
                 rec.tokens = np.asarray(f.result(), np.int32)
@@ -69,9 +74,31 @@ def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
             rec.done = t  # last: a record with ``done`` set is complete
             done_q.put(rec)
 
+        rec.error = None
         rec.future.add_done_callback(on_done)
+        return True
+
+    def send(req, due):
+        rec = _Rec(req, due)
         recs.append(rec)
+        with span("submit"):
+            rec.sent = now()
+            if not offer(rec):
+                turned_away(rec)
         return rec
+
+    # the door's own words are "load shed (retry with backoff)": an open
+    # loop's caller does, after 0.1 s doubling to 1 s, and the wait is in its
+    # latency; one still refused a minute past the close has failed.  A
+    # closed loop's caller fails at once: its loop never fills the queue
+    retries = [] if traffic["loop"] == "open" else None
+
+    def turned_away(rec):
+        if retries is None:
+            rec.done = now()
+            done_q.put(rec)
+        else:
+            retries.append((now() + min(0.05 * 2 ** rec.refused, 1.0), rec))
 
     def open_window():
         marks["open"] = engine.metrics.snapshot()
@@ -80,6 +107,10 @@ def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
     if traffic["loop"] == "open":
         def sleep_until(t):
             while True:
+                for item in [x for x in retries if x[0] <= now()]:
+                    retries.remove(item)
+                    if not offer(item[1]):
+                        turned_away(item[1])
                 left = t - now()
                 if left <= 0:
                     return
@@ -97,6 +128,11 @@ def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
             sleep_until(req["due_s"])
             send(req, req["due_s"])
         sleep_until(seconds)
+        marks["close"] = engine.metrics.snapshot()
+        while retries and now() < seconds + 60.0:
+            sleep_until(now() + 0.05)
+        for _, rec in retries:
+            rec.done = now()
         window = [r for r in recs if r.due >= 0]
     else:
         nxt = iter(range(10 ** 9))
@@ -126,7 +162,7 @@ def drive(engine, traffic, reqs, seconds, span, tick=lambda t: None,
         open_window()
         pump(seconds)
         window = [r for r in recs if r.sent >= 0]
-    marks["close"] = engine.metrics.snapshot()
+        marks["close"] = engine.metrics.snapshot()
     return recs, window, marks
 
 

@@ -5,7 +5,8 @@ the engine is given the configuration's ``cache_len``; the reference is
 handed the weights as they are served (it upcasts them where it uses them:
 11 GB of bfloat16 would be 22 GB of float32 at once); and the facts carry
 what this model's per-layer readers need (the trace's directory, the
-per-expert routed counts over the window, the configuration's sizes).
+per-expert routed counts over the window, the configuration's sizes, the
+engine's slots and page size).
 """
 import gc
 import time
@@ -86,6 +87,8 @@ def run(ctx):
     ctx.failed = len(failed)
     delta = {k: marks["close"][k] - v for k, v in marks["open"].items()
              if isinstance(v, int) and isinstance(marks["close"].get(k), int)}
+    serve = {**cfg["serve"], **(cfg.get("serve_rehearse", {})
+                                if ctx.rehearse else {})}
     if not ctx.rehearse:
         ctx.emit({"requests_completed_in_window": len(in_window),
                   "tokens_completed_in_window": tokens,
@@ -97,6 +100,7 @@ def run(ctx):
     ctx.facts.update(
         counters=delta, loop=traffic["loop"], warmup_executables=compiled,
         trace_dir=ctx.trace_dir, expert_routed=routed.tolist(),
+        kv_page_size=serve["kv_page_size"], slots=serve["batch_size"],
         prompt_pairs_mean=float(np.mean([
             len(r["prompt"]) * (len(r["prompt"]) + 1) / 2 for r in reqs])),
         sizes={k: v for k, v in cfg.items() if isinstance(v, (int, float))

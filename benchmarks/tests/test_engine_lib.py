@@ -2,7 +2,7 @@
 (``harness/engine_lib.py``): their arithmetic on a hand-made window, that
 they find nothing (and do not raise) on a program without the counters,
 which cells they go to, and that a rehearsed serving run prints every
-counter they read."""
+counter they read and names its phases as the harness knows them."""
 import importlib.util
 import json
 import os
@@ -23,14 +23,9 @@ COUNTERS = {
     "admit_token_slots": 40960,
     "kv_pages_live_steps": 25_600, "kv_page_slots_steps": 204_800,
     "queue_wait_us": 600_000, "ttft_us": 2_400_000,
-    # how far each phase's longest interval rose inside the window: one
-    # decode call stood still for 2.5 s
-    **{k: 0 for k in engine_lib.LONGEST},
-    "loop_max_us_admit_device": 3_000, "loop_max_us_decode_device": 2_500_000,
 }
 EXPECTED = {
     "decode_step_ms": 40.0,
-    "admit_time_share": 100.0 * 600_000 / 5_000_000,
     "host_ms_per_step": 4.3,
     "decode_live_slots": 24.0,
     "prefill_useful_share": 5.0,
@@ -38,11 +33,12 @@ EXPECTED = {
     "queue_wait_mean_ms": 20.0,
     "ttft_mean_ms": 80.0,
     "admit_call_ms": 57.0,
-    "stall_ms": 2500.0,
 }
+#: the entries these readers have in the two GPT-2 cells (PR 49 retired
+#: ``decode_live_slots.serve``: a closed loop that is always full reads 32)
 NEW = [f"{name}.{suffix}" for name in EXPECTED
        for suffix in (("chat",) if name.endswith("_mean_ms")
-                      else ("serve", "chat"))]
+                      or name == "decode_live_slots" else ("serve", "chat"))]
 
 
 def _ev(counters):
@@ -65,32 +61,21 @@ def test_reader_value_and_none(name):
     for k in used:
         assert read(_ev({c: v for c, v in COUNTERS.items() if c != k})) \
             is None
-    zero = {"admit_time_share": {"loop_us_total": 2_000_000}}.get(
-        name, {k: 0 for k in ("decode_steps", "admit_token_slots",
-                              "kv_page_slots_steps", "admit_rows",
-                              "admit_steps")})
-    if name == "stall_ms":  # no divisor: a steady window reads 0
-        assert read(_ev({**COUNTERS, **{k: 0 for k in engine_lib.LONGEST}})) \
-            == 0.0
-    else:
-        assert read(_ev({**COUNTERS, **zero})) is None
+    zero = {k: 0 for k in ("decode_steps", "admit_token_slots",
+                           "kv_page_slots_steps", "admit_rows",
+                           "admit_steps")}
+    assert read(_ev({**COUNTERS, **zero})) is None
 
 
-def test_every_new_entry_has_its_reader_and_the_fields_of_the_old_ones():
-    man = loader.manifest()
-    by_name = {m["name"]: m for m in man["per_layer"]}
-    old = by_name["step_wall_ms.serve"]
-    assert [m["name"] for m in man["per_layer"]][-len(NEW):] == NEW
-    for full in NEW:
-        m = by_name[full]
-        assert set(m) == set(old) and m["source"] == "program_counter"
-        assert m["moves"] == {"serve": "serve_tok_s",
-                              "chat": "req_latency_p95_ms"}[
-                                  full.rsplit(".", 1)[1]]
-        reader = loader.load_module("layer_metrics", full)
-        assert reader.read(_ev(COUNTERS)) == pytest.approx(
-            EXPECTED[full.rsplit(".", 1)[0]])
-        assert reader.__doc__
+@pytest.mark.parametrize("full", NEW)
+def test_an_entry_re_exports_its_reader(full):
+    # tests/test_manifest.py holds every entry to its file, cells and fields
+    (m,) = [m for m in loader.manifest()["per_layer"] if m["name"] == full]
+    assert m["source"] == "program_counter"
+    assert m["moves"] == {"serve": "serve_tok_s",
+                          "chat": "req_latency_p95_ms"}[full.rsplit(".", 1)[1]]
+    assert loader.load_module("layer_metrics", full).read(
+        _ev(COUNTERS)) == pytest.approx(EXPECTED[full.rsplit(".", 1)[0]])
 
 
 def test_serve_readers_go_to_docs_closed_and_chat_readers_to_chat_open():
@@ -106,7 +91,11 @@ def test_serve_readers_go_to_docs_closed_and_chat_readers_to_chat_open():
 
 
 def test_a_rehearsed_chat_run_prints_every_loop_counter(capsys):
-    from paddle_tpu.serving.metrics import LOOP_COUNTERS
+    from benchmarks.harness.context import SERVE_PHASES
+    from paddle_tpu.serving.metrics import LOOP_COUNTERS, LOOP_PHASES
+
+    # the names an idle gap of the device is attributed to are the loop's
+    assert SERVE_PHASES == tuple("serve/" + p for p in LOOP_PHASES)
 
     spec = importlib.util.spec_from_file_location(
         "bench_run", os.path.join(loader.BENCH_DIR, "run.py"))
@@ -130,7 +119,5 @@ def test_a_rehearsed_chat_run_prints_every_loop_counter(capsys):
     for name in EXPECTED:
         assert getattr(engine_lib, name)(ev) is not None, name
     assert 0 < engine_lib.decode_live_slots(ev) <= 4  # serve_rehearse slots
-    assert engine_lib.stall_ms(ev) * 1e3 <= delta["loop_us_total"]
-    for name in ("admit_time_share", "prefill_useful_share",
-                 "kv_live_page_share"):
+    for name in ("prefill_useful_share", "kv_live_page_share"):
         assert 0 < getattr(engine_lib, name)(ev) <= 100, name

@@ -67,11 +67,16 @@ def test_the_manifest_names_the_cell_and_its_readers():
     e2e, layer = loader.metrics_of(CELL, man)
     assert {m["name"] for m in e2e} == {"serve_tok_s", "setup_s"}
     names = {m["name"] for m in layer}
-    assert all(n.endswith(".rag") for n in names) and len(names) == 16
+    assert names and all(n.endswith(".rag") for n in names)
+    assert {"moe_gated_mlp_tm16_roofline_share.rag",
+            "moe_gated_mlp_tm128_roofline_share.rag",
+            "latent_prefill_attention_roofline_share.rag",
+            "latent_decode_roofline_share.rag", "mla_time_share.rag",
+            "moe_time_share.rag", "experts_touched_per_step.rag"} <= names
     # the accepted closed-loop readers stay with the cell they were for
     _, docs = loader.metrics_of("gpt2_small.docs_closed", man)
-    assert not {m["name"] for m in docs} & names
-    assert len([m for m in docs if m["name"].endswith(".serve")]) == 12
+    assert docs and not {m["name"] for m in docs} & names
+    assert all(m["name"].endswith(".serve") for m in docs)
 
 
 def test_the_kernel_counts():

@@ -87,7 +87,7 @@ def test_the_manifest_names_the_cell_and_its_readers():
     e2e, layer = loader.metrics_of(CELL, man)
     assert {m["name"] for m in e2e} == {"serve_tok_s", "setup_s"}
     names = {m["name"] for m in layer}
-    assert all(n.endswith(".kex") for n in names) and len(names) == 22
+    assert names and all(n.endswith(".kex") for n in names)
     for n in names:   # every reader is a file that loads, and finds nothing
         read = loader.load_module("layer_metrics", n).read   # in an empty run
         assert callable(read) and read({"facts": {}, "peaks": {}}) is None

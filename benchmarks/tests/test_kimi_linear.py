@@ -104,7 +104,7 @@ def test_the_manifest_names_the_cell_and_its_readers():
         assert callable(read) and read({"facts": {}, "peaks": {}}) is None
     assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
                for m in layer)
-    assert {"decode_step_ms.kml", "admit_call_ms.kml", "admit_time_share.kml",
+    assert {"decode_step_ms.kml", "admit_call_ms.kml",
             "host_ms_per_step.kml", "device_idle_share.kml",
             "kda_time_share.kml", "mla_time_share.kml", "moe_time_share.kml",
             "kda_chunk_roofline_share.kml", "kda_step_roofline_share.kml",

@@ -104,16 +104,15 @@ def test_the_manifest_names_the_cell_and_its_readers():
     e2e, layer = loader.metrics_of(CELL, man)
     assert {m["name"] for m in e2e} == {"serve_tok_s", "setup_s"}
     names = {m["name"] for m in layer}
-    assert all(n.endswith(".hyb") for n in names) and len(names) == 14
+    assert names and all(n.endswith(".hyb") for n in names)
     for n in names:   # every reader is a file that loads
         assert callable(loader.load_module("layer_metrics", n).read)
     # the other closed-loop cells keep their own readers and gain none
-    for cell, suffix, count in (("joyai_flash.ragdocs_closed", ".rag", 16),
-                                ("gpt2_small.docs_closed", ".serve", 12)):
+    for cell, suffix in (("joyai_flash.ragdocs_closed", ".rag"),
+                         ("gpt2_small.docs_closed", ".serve")):
         _, theirs = loader.metrics_of(cell, man)
-        assert not {m["name"] for m in theirs} & names
-        assert len([m for m in theirs
-                    if m["name"].endswith(suffix)]) == count
+        assert theirs and not {m["name"] for m in theirs} & names
+        assert all(m["name"].endswith(suffix) for m in theirs)
 
 
 def test_the_configuration_carries_the_published_widths():

@@ -87,19 +87,18 @@ def test_the_manifest_names_the_cell_and_its_readers():
     e2e, layer = loader.metrics_of(CELL, man)
     assert {m["name"] for m in e2e} == {"serve_tok_s", "setup_s"}
     names = {m["name"] for m in layer}
-    assert all(n.endswith(".qnx") for n in names) and len(names) == 21
+    assert names and all(n.endswith(".qnx") for n in names)
     for n in names:   # every reader is a file that loads, and finds nothing
         read = loader.load_module("layer_metrics", n).read   # in an empty run
         assert callable(read) and read({"facts": {}, "peaks": {}}) is None
     assert all(m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
                for m in layer)
     # the other cells keep their own readers and gain none
-    for cell, suffix, count in (("joyai_flash.ragdocs_closed", ".rag", 16),
-                                ("olmo_hybrid.ragdocs_closed", ".hyb", 14)):
+    for cell, suffix in (("joyai_flash.ragdocs_closed", ".rag"),
+                         ("olmo_hybrid.ragdocs_closed", ".hyb")):
         _, theirs = loader.metrics_of(cell, man)
-        assert not {m["name"] for m in theirs} & names
-        assert len([m for m in theirs
-                    if m["name"].endswith(suffix)]) == count
+        assert theirs and not {m["name"] for m in theirs} & names
+        assert all(m["name"].endswith(suffix) for m in theirs)
     cell, config, traffic = loader.load_cell(CELL)
     assert (config["serve"]["batch_size"], traffic["clients"],
             traffic["warm_seconds"]) == (64, 128, 20)

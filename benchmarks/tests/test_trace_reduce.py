@@ -35,18 +35,17 @@ def test_idle_share(reduced):
     assert layer_lib.device_idle_share(ev) == pytest.approx(99.065, abs=0.01)
 
 
-def test_mosaic_share_counts_only_tpu_custom_calls(reduced):
-    _, red = reduced
-    d = red["per_device"][0]
+def test_a_mosaic_kernel_is_told_by_its_custom_call_target(reduced):
+    trace, red = reduced
+    mosaic = [d for n, _, d in trace["devices"][0]["ops"]
+              if tr.MOSAIC_MARK in n]
     # 12 executions of the kernel at ~11.34 us each
-    assert d["mosaic_ns"] == pytest.approx(12 * 11342, rel=0.01)
-    assert layer_lib.mosaic_time_share({"trace": red}) == pytest.approx(
-        100 * d["mosaic_ns"] / d["busy_ns"])
+    assert sum(mosaic) == pytest.approx(12 * 11342, rel=0.01)
     assert ["step.1:tpu_custom_call", pytest.approx(136.1e-6, rel=0.01)] in \
         red["top_ops"]
     # XLA's own small custom-calls are not Mosaic kernels
-    assert d["mosaic_ns"] < sum(v for k, v in red["top_ops"]
-                                if "custom" in k) * 1e9
+    assert sum(mosaic) < sum(v for k, v in red["top_ops"]
+                             if "custom" in k) * 1e9
 
 
 def test_module_durations_and_gaps(reduced):
@@ -70,6 +69,32 @@ def test_gap_attribution(reduced):
     assert red["longest_gaps"][0][0] == "loss_readback"
     assert sum(causes.values()) == pytest.approx(
         (red["window_ns"] - red["busy_ns"]) / 1e9, rel=1e-6)
+
+
+def test_a_serving_gap_is_named_by_the_loop_s_phase_not_the_generator_s_nap():
+    # the device ran at 0-1, 9-10 and 20-21 ms; the generator napped all the
+    # while, the serving loop was in a 3 ms admission call, then in 2 ms of
+    # harvest
+    from benchmarks.harness.context import HOST_SPANS
+
+    ms = 1e6
+    trace = {"devices": {0: {"modules": [], "ops": [
+        ("%a = f32[] add()", 0.0, ms), ("%b = f32[] add()", 9 * ms, ms),
+        ("%c = f32[] add()", 20 * ms, ms)]}},
+        "host_spans": [("schedule_wait", 0.0, 30 * ms),
+                       ("serve/admit.device", 2 * ms, 3 * ms),
+                       ("serve/harvest", 5 * ms, 2 * ms)]}
+    plain = tr.reduce(trace)  # every span read competes: the nap wins
+    assert [g[0] for g in plain["longest_gaps"]] == ["schedule_wait"] * 2
+    red = tr.reduce(trace, gap_span_names=HOST_SPANS)
+    # the 8 ms gap goes to the phase that covers most of it; no phase
+    # touches the 10 ms gap
+    assert red["longest_gaps"] == [["unattributed", pytest.approx(0.010)],
+                                   ["serve/admit.device",
+                                    pytest.approx(0.008)]]
+    assert dict(red["idle_by_cause"]) == {
+        "unattributed": pytest.approx(0.010),
+        "serve/admit.device": pytest.approx(0.008)}
 
 
 def test_interval_arithmetic():
